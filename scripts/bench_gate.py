@@ -4,7 +4,7 @@
 Runs bench.py with configs 2 and 6 (the north-star concurrent-kNN pass and
 the columnar filtered-SELECT scan) at a smoke scale, then FAILS if:
   - config 2 shows any errors, concurrent qps below the committed floor,
-    or recall@10 below its floor (the collapse signatures, VERDICT r5);
+    or recall@10 below its floor (the round-5 collapse signatures);
   - config 6 shows columnar output diverging from the row path, columnar
     qps below its floor, or a columnar/row speedup below the ratio floor
     (the columnar scan path regressing back to per-row work).

@@ -63,7 +63,9 @@ def fixture_sites():
         return x.sum(axis=1)  # f32, but the contract below declares int32
 
     def sharded_builds():
-        from surrealdb_tpu.parallel.mesh import make_mesh, shard_map
+        from jax import shard_map
+
+        from surrealdb_tpu.parallel.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         import functools
 
@@ -74,6 +76,7 @@ def fixture_sites():
             @functools.partial(
                 shard_map, mesh=mesh,
                 in_specs=(P("data", None),), out_specs=P("data", None),
+                check_vma=False,
             )
             def bad(x_local):
                 # an undeclared whole-corpus reduction: O(N) over ICI
@@ -88,6 +91,7 @@ def fixture_sites():
             @functools.partial(
                 shard_map, mesh=mesh,
                 in_specs=(P("data", None),), out_specs=P("data", None),
+                check_vma=False,
             )
             def bad(x_local):
                 # gather the WHOLE corpus to every chip, then slice this

@@ -4,7 +4,7 @@ Nothing here executes a kernel: `jax.make_jaxpr` traces the builder's
 function over ShapeDtypeStructs and `jax.jit(...).lower(...)` emits the
 StableHLO text XLA would compile — the audit sees exactly the IR the
 serving path ships, without paying a compile. Tracing runs under
-`jax.experimental.enable_x64` so an implicit float64 promotion is
+`jax.enable_x64` so an implicit float64 promotion is
 VISIBLE in the jaxpr instead of being silently truncated to f32 by the
 default x64-disabled mode (the truncation would hide the exact bug
 GC002 exists to catch).
@@ -125,7 +125,6 @@ def lower_site(contract: dict, shape: dict) -> Lowered:
     that itself fails — a broken contract is a finding-level event the
     caller converts (GC000), never a silent skip."""
     import jax
-    from jax.experimental import enable_x64
 
     fn, args = contract["build"](dict(shape))
     low = Lowered(subsystem=contract["subsystem"], label=shape["label"])
@@ -145,7 +144,7 @@ def lower_site(contract: dict, shape: dict) -> Lowered:
     # mode silently truncates a float64 promotion to f32, which would
     # hide exactly the bug GC002 exists to catch. Integer widening under
     # x64 (arange -> i64) is an audit artifact and is not collected.
-    with enable_x64():
+    with jax.enable_x64(True):
         closed64 = jax.make_jaxpr(fn)(*args)
         _walk_jaxpr(closed64.jaxpr, set(), low.aval_dtypes)
     low.hlo_sha256 = hashlib.sha256(low.hlo_text.encode()).hexdigest()

@@ -168,11 +168,17 @@ def _start(args) -> int:
     if args.user and args.password:
         from surrealdb_tpu.sql.value import format_value
 
-        srv.httpd.RequestHandlerClass.ds.execute(
+        srv.ds.execute(
             f"DEFINE USER {args.user} ON ROOT PASSWORD {format_value(args.password)} ROLES OWNER;",
             Session.owner(None, None),
         )
-    print(f"Started surrealdb-tpu on {srv.url} (storage: {args.path})", file=sys.stderr)
+    b = srv.backend
+    print(
+        f"Started surrealdb-tpu on {srv.url} (storage: {args.path}; "
+        f"platform: {b['platform']}, device_kind: {b['device_kind']}, "
+        f"devices: {b['device_count']})",
+        file=sys.stderr,
+    )
     try:
         srv.serve_forever()
     except KeyboardInterrupt:

@@ -70,6 +70,7 @@ class VectorMirror:
         self._dev_matrix = None
         self._dev_mask = None  # sharded mask (mesh placement only)
         self._mesh = None  # mesh the device arrays are placed over
+        self._upload_t0 = None  # start of an upload nobody has timed yet
         self.ivf = None  # IvfState, built on demand
         self._ivf_building = False
         self._ivf_done = threading.Event()  # signals a finished train round
@@ -96,6 +97,7 @@ class VectorMirror:
         with self._build_lock:
             if self.built:
                 return
+            t_build = _time.perf_counter()
             with self._lock:
                 self._pending = []
             ns, db = ctx.ns_db()
@@ -130,6 +132,11 @@ class VectorMirror:
                 # be overwritten by a stale replay
                 for rid, vec in pending:
                     self.apply(rid, vec)
+            from surrealdb_tpu import telemetry
+
+            telemetry.observe(
+                "vector_mirror_build", _time.perf_counter() - t_build
+            )
 
     # ------------------------------------------------------------ deltas
     def apply(self, rid, vec) -> None:
@@ -280,7 +287,14 @@ class VectorMirror:
                 ):
                     import ml_dtypes
 
+                    from surrealdb_tpu import telemetry
+
+                    t_cast = _time.perf_counter()
                     data = data.astype(ml_dtypes.bfloat16)  # host-side cast
+                    telemetry.observe(
+                        "vector_mirror_cast", _time.perf_counter() - t_cast
+                    )
+                self._upload_t0 = _time.perf_counter()
                 if mesh is not None:
                     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -307,7 +321,17 @@ class VectorMirror:
         even if the mirror compacts while the batch is on device."""
         with self._lock:
             m, mask = self.device_view(mesh)
-            return m, mask, self.rids
+            rids = self.rids
+            t_up, self._upload_t0 = self._upload_t0, None
+        if t_up is not None:
+            # the statement that uploaded waits for the transfer here, with
+            # the lock released (its kernel would wait for it anyway), so
+            # the upload is timed under its own name and holds no writer
+            from surrealdb_tpu import telemetry
+
+            m.block_until_ready()
+            telemetry.observe("vector_mirror_upload", _time.perf_counter() - t_up)
+        return m, mask, rids
 
     def device_sharded_mask(self):
         with self._lock:

@@ -307,6 +307,11 @@ class GroupCommit:
 
 class Datastore:
     def __init__(self, path: str = "memory", clock=None):
+        # before any kernel of this engine can compile: place XLA's
+        # persistent compilation cache (device.py holds the rule)
+        from surrealdb_tpu import device
+
+        device.configure_compile_cache()
         self.path = path
         self.backend = self._open(path)
         self.clock = clock or SystemClock()

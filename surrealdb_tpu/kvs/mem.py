@@ -17,75 +17,7 @@ from __future__ import annotations
 from surrealdb_tpu.utils import locks as _locks
 from typing import Dict, List, Optional, Tuple
 
-try:
-    from sortedcontainers import SortedList
-except ImportError:  # gate the missing dep: minimal bisect-backed fallback
-    from bisect import bisect_left, bisect_right, insort
-
-    class SortedList:  # type: ignore[no-redef]
-        """Drop-in subset of sortedcontainers.SortedList (add/update/remove/
-        irange) over a plain sorted list. update() keeps the bulk-merge
-        property that matters here: one sort of the combined batch instead
-        of per-key insorts."""
-
-        __slots__ = ("_data",)
-
-        def __init__(self, iterable=()):
-            self._data = sorted(iterable)
-
-        def add(self, value) -> None:
-            insort(self._data, value)
-
-        def update(self, iterable) -> None:
-            items = list(iterable)
-            if not items:
-                return
-            if len(items) <= 8:
-                for v in items:
-                    insort(self._data, v)
-            else:
-                self._data.extend(items)
-                self._data.sort()
-
-        def remove(self, value) -> None:
-            i = bisect_left(self._data, value)
-            if i == len(self._data) or self._data[i] != value:
-                raise ValueError(f"{value!r} not in list")
-            del self._data[i]
-
-        def irange(self, minimum=None, maximum=None, inclusive=(True, True)):
-            data = self._data
-            if minimum is None:
-                lo = 0
-            else:
-                lo = (
-                    bisect_left(data, minimum)
-                    if inclusive[0]
-                    else bisect_right(data, minimum)
-                )
-            if maximum is None:
-                hi = len(data)
-            else:
-                hi = (
-                    bisect_right(data, maximum)
-                    if inclusive[1]
-                    else bisect_left(data, maximum)
-                )
-            # lazy, like sortedcontainers: _merged_range islices a CHUNK at a
-            # time with an advancing cursor — materializing data[lo:hi] here
-            # would copy the whole remaining range per chunk (quadratic scan)
-            return (data[i] for i in range(lo, hi))
-
-        def __len__(self) -> int:
-            return len(self._data)
-
-        def __iter__(self):
-            return iter(self._data)
-
-        def __contains__(self, value) -> bool:
-            i = bisect_left(self._data, value)
-            return i < len(self._data) and self._data[i] == value
-
+from sortedcontainers import SortedList
 
 from surrealdb_tpu.err import TxConflictError
 from .api import KV, BackendDatastore, BackendTransaction

@@ -1157,8 +1157,14 @@ class Server:
         tls_key: Optional[str] = None,
         cors_origins="*",
     ):
-        from surrealdb_tpu import cnf
+        from surrealdb_tpu import cnf, device
 
+        # initialise the JAX backend NOW, not inside the first kNN
+        # statement: a process whose accelerator failed to come up serves
+        # every kernel on JAX's CPU backend, and start-up is where that
+        # has to show (cli.py prints this before accepting a connection)
+        self.backend = device.describe()
+        self.ds = ds
         handler = type(
             "BoundHandler",
             (SurrealHandler,),

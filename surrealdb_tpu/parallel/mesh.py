@@ -21,35 +21,15 @@ slice (deployment).
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from surrealdb_tpu.ops.distances import pairwise_distance
-
-# jax moved shard_map out of experimental (>=0.6) and renamed its replication
-# check check_rep -> check_vma; support both so the mesh path runs on the
-# image's jax as well as current releases
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{_CHECK_KW: check_vma}
-    )
+from surrealdb_tpu.ops.distances import map_queries, pairwise_distance
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "data") -> Mesh:
@@ -63,6 +43,37 @@ def shard_corpus(mesh: Mesh, x: np.ndarray, axis: str = "data") -> jax.Array:
     the device count — callers pad with masked rows first."""
     sharding = NamedSharding(mesh, P(axis, None))
     return jax.device_put(x, sharding)
+
+
+@functools.lru_cache(maxsize=64)
+def _knn_searcher(mesh, k, metric, axis):
+    """Jitted sharded exact kNN, cached per (mesh, params): one compiled
+    executable per query-tile shape. Called bare, a shard_map function runs
+    eagerly — re-traced and dispatched op by op on every statement."""
+
+    @functools.partial(
+        shard_map,
+        mesh=mesh,
+        in_specs=(P(axis, None), P(axis), P(None, None)),
+        out_specs=(P(None, None), P(None, None)),
+        check_vma=False,
+    )
+    def _knn(x_local, m_local, q):
+        d = pairwise_distance(q, x_local, metric)  # [Q, N/n]
+        d = jnp.where(m_local[None, :], d, jnp.inf)
+        shard_rows = x_local.shape[0]
+        kk = min(k, shard_rows)
+        neg, idx_local = jax.lax.top_k(-d, kk)  # [Q, kk]
+        # globalize indices: this shard's row-offset
+        shard_id = jax.lax.axis_index(axis)
+        idx_global = idx_local + shard_id * shard_rows
+        # gather every shard's candidates -> [n_dev*kk] per query
+        d_all = jax.lax.all_gather(-neg, axis, axis=1, tiled=True)  # [Q, n*kk]
+        i_all = jax.lax.all_gather(idx_global, axis, axis=1, tiled=True)
+        neg2, pos = jax.lax.top_k(-d_all, k)  # [Q, k]
+        return -neg2, jnp.take_along_axis(i_all, pos, axis=1)
+
+    return jax.jit(_knn)
 
 
 def sharded_knn(
@@ -82,42 +93,7 @@ def sharded_knn(
     Per-shard local top-k (all MXU work stays on-chip), then an all_gather of
     the k-candidate sets — the ICI payload is tiny.
     """
-    n_dev = mesh.shape[axis]
-    n_total = corpus.shape[0]
-    shard_rows = n_total // n_dev
-
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(axis, None), P(axis), P(None, None)),
-        out_specs=(P(None, None), P(None, None)),
-        check_vma=False,
-    )
-    def _knn(x_local, m_local, q):
-        d = pairwise_distance(q, x_local, metric)  # [Q, N/n]
-        d = jnp.where(m_local[None, :], d, jnp.inf)
-        kk = min(k, x_local.shape[0])
-        neg, idx_local = jax.lax.top_k(-d, kk)  # [Q, kk]
-        # globalize indices: this shard's row-offset
-        shard_id = jax.lax.axis_index(axis)
-        idx_global = idx_local + shard_id * shard_rows
-        # gather every shard's candidates -> [n_dev*kk] per query
-        d_all = jax.lax.all_gather(-neg, axis, axis=1, tiled=True)  # [Q, n*kk]
-        i_all = jax.lax.all_gather(idx_global, axis, axis=1, tiled=True)
-        neg2, pos = jax.lax.top_k(-d_all, k)  # [Q, k]
-        return -neg2, jnp.take_along_axis(i_all, pos, axis=1)
-
-    return _knn(corpus, mask, queries)
-
-
-def sharded_knn_jit(mesh: Mesh, k: int, metric: str, axis: str = "data"):
-    """A jitted closure for repeated sharded kNN calls."""
-
-    @jax.jit
-    def run(corpus, mask, queries):
-        return sharded_knn(mesh, corpus, mask, queries, k, metric, axis)
-
-    return run
+    return _knn_searcher(mesh, k, metric, axis)(corpus, mask, queries)
 
 
 def sharded_knn_2d(
@@ -209,7 +185,9 @@ def _ivf_searcher(mesh, k, nprobe, kk, k_out, metric, probe_metric, axis):
             g = jnp.where(neg > -jnp.inf, rows[idx] + shard_id * shard_rows, -1)
             return -neg, g
 
-        d_loc, i_loc = jax.vmap(one)(q, probes)  # [Q, kk]
+        d_loc, i_loc = map_queries(
+            one, q, probes, nprobe * int(lr.shape[1]), x_local
+        )  # [Q, kk]
         # gather every shard's k candidates — ICI payload O(k*devices)
         d_all = jax.lax.all_gather(d_loc, axis, axis=1, tiled=True)
         i_all = jax.lax.all_gather(i_loc, axis, axis=1, tiled=True)

@@ -184,6 +184,7 @@ class WsEngine:
         self._notifications: Dict[str, "queue.Queue[Any]"] = {}
         self._lock = _locks.Lock("sdk.ws_client")
         self._closed = False
+        self._dead = False  # the reader has gone: no reply can arrive
         # registered service thread (graftlint GL001): the reader shows up
         # in the task registry as bg:sdk_reader:<host>:<port> instead of an
         # anonymous daemon — embedded test/SDK processes share the registry
@@ -222,17 +223,34 @@ class WsEngine:
                     q.put(msg)
         except (ConnectionError, OSError):
             pass
+        finally:
+            # the socket is gone: release every caller still waiting
+            with self._lock:
+                self._dead = True
+                pending, self._pending = self._pending, {}
+            for q in pending.values():
+                q.put(None)
 
     def rpc(self, method: str, params: List[Any]) -> Any:
         mid = next(self._ids)
         q: "queue.Queue[Any]" = queue.Queue()
         with self._lock:
+            if self._dead:
+                raise SurrealError("the WebSocket connection is closed")
             self._pending[mid] = q
         frame = wsproto.encode_frame(
             wsproto.OP_BINARY, pack({"id": mid, "method": method, "params": params}), mask=True
         )
         self.sock.sendall(frame)
-        msg = q.get(timeout=60)
+        # wait as long as the socket lives. A statement may run for minutes
+        # (the first kNN after a 1M-row load builds the vector mirror), and
+        # giving up here would leave the server working for nobody; the
+        # reader releases this wait when the connection goes.
+        msg = q.get()
+        if msg is None:
+            raise SurrealError(
+                f"the WebSocket connection closed before the reply to RPC {method!r}"
+            )
         if msg.get("error"):
             raise SurrealError(msg["error"].get("message", "RPC error"))
         return msg.get("result")

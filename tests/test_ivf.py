@@ -47,6 +47,32 @@ def test_ivf_recall_floor():
     assert nprobe * maxlen < n, "IVF probes the whole corpus"
 
 
+def test_bounded_gather_gives_the_vmap_answers(monkeypatch):
+    """A query tile whose candidate gather exceeds the device's budget runs
+    as sequential sub-batches inside the kernel: same shapes, same answers."""
+    import jax.numpy as jnp
+
+    from surrealdb_tpu.idx import ivf as ivfm
+
+    x = _mixture(4096, 32, seed=9)
+    state = ivfm.IvfState.train(x, np.ones(len(x), dtype=bool))
+    mat = jnp.asarray(x)
+    qs = x[:8] + 0.01
+    want_d, want_r = state.search_batch(qs, mat, "euclidean", 10, 4)
+    from surrealdb_tpu.ops import distances
+
+    monkeypatch.setattr(distances, "gather_budget_bytes", lambda: 1)  # one query at a time
+    ivfm._ivf_search.clear_cache()
+    try:
+        got_d, got_r = state.search_batch(qs, mat, "euclidean", 10, 4)
+    finally:
+        ivfm._ivf_search.clear_cache()
+    assert (got_r == want_r).all()
+    # |q|^2 + |x|^2 - 2qx cancels: the batched and the one-at-a-time matmul
+    # round differently by ~eps * |x|^2 (f32, |x|^2 ~ 500) in d^2
+    np.testing.assert_allclose(got_d**2, want_d**2, atol=1e-3)
+
+
 def test_ivf_self_hit():
     """Every corpus point must find itself at distance 0."""
     from surrealdb_tpu.idx.ivf import IvfState

@@ -158,6 +158,62 @@ def test_ws_sdk_remote(server):
     db.close()
 
 
+def test_ws_rpc_waits_while_the_socket_lives():
+    """The WS engine has no deadline of its own (the first kNN statement
+    after a 1M-row load runs for minutes): an RPC waits for its reply as
+    long as the connection is alive and is released when it goes."""
+    import socket
+
+    from surrealdb_tpu.err import SurrealError
+    from surrealdb_tpu.net import ws as wsproto
+    from surrealdb_tpu.sdk.remote import WsEngine
+
+    lsock = socket.create_server(("127.0.0.1", 0))
+    conns = []
+
+    def silent_server():  # upgrades, then never answers
+        c, _ = lsock.accept()
+        req = b""
+        while b"\r\n\r\n" not in req:
+            req += c.recv(4096)
+        key = next(
+            line.split(b":", 1)[1].strip().decode()
+            for line in req.split(b"\r\n")
+            if line.lower().startswith(b"sec-websocket-key:")
+        )
+        c.sendall(
+            b"HTTP/1.1 101 Switching Protocols\r\nUpgrade: websocket\r\n"
+            b"Connection: Upgrade\r\nSec-WebSocket-Accept: "
+            + wsproto.accept_key(key).encode() + b"\r\n\r\n"
+        )
+        conns.append(c)
+
+    t = threading.Thread(target=silent_server)
+    t.start()
+    eng = WsEngine(f"ws://127.0.0.1:{lsock.getsockname()[1]}/rpc")
+    t.join()
+    raised = []
+
+    def call():
+        try:
+            eng.rpc("query", ["SELECT 1"])
+        except SurrealError as e:
+            raised.append(str(e))
+
+    caller = threading.Thread(target=call)
+    caller.start()
+    caller.join(0.3)
+    assert caller.is_alive()  # no reply yet, socket alive: still waiting
+    conns[0].close()
+    caller.join(5)
+    assert not caller.is_alive()
+    assert "closed before the reply" in raised[0]
+    with pytest.raises(SurrealError, match="closed"):
+        eng.rpc("ping", [])
+    eng.close()
+    lsock.close()
+
+
 def test_signin_http(server):
     import http.client
     import json

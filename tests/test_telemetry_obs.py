@@ -82,7 +82,7 @@ def test_dispatch_transient_retry_counted_by_cause():
     def flaky(payloads):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise RuntimeError("device UNAVAILABLE: tunnel dropped")
+            raise RuntimeError("UNAVAILABLE: device is not responding")
         return [p * 2 for p in payloads]
 
     assert q.submit("k", 21, flaky) == 42
@@ -188,7 +188,7 @@ def test_metrics_and_slow_endpoints_roundtrip(monkeypatch):
     def flaky(ps):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise RuntimeError("DEADLINE_EXCEEDED on tunnel")
+            raise RuntimeError("DEADLINE_EXCEEDED: execution timed out")
         return list(ps)
 
     q.submit("k", 1, flaky)

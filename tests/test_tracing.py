@@ -414,8 +414,6 @@ def test_node_runtime_metrics_exposed(ds):
     text = telemetry.render_prometheus()
     assert "surreal_process_resident_memory_bytes" in text  # linux /proc
     assert "surreal_live_queries 2" in text
-    if telemetry._jit_cache_stats() is not None:
-        assert "surreal_jit_cache_misses" in text
 
 
 def test_metrics_endpoint_serves_node_gauges():
