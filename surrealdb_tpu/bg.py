@@ -65,6 +65,7 @@ class Task:
         "id", "kind", "target", "state", "owner", "trace_id", "deadline_s",
         "scheduled_ts", "start_ts", "end_ts", "duration_s", "error",
         "retries", "stalled", "thread", "service", "stack", "tenant",
+        "stages",
     )
 
     def __init__(self, tid, kind, target, owner, trace_id, deadline_s):
@@ -91,6 +92,9 @@ class Task:
         # the (ns, db) whose statement ARMED this task — the same parent
         # link trace_id rides; the task's run time is charged to it
         self.tenant: Optional[tuple] = None
+        # timed stages the body reported (telemetry.stage): what a trace's
+        # spans are to a statement, for work no statement waits for
+        self.stages: List[dict] = []
 
     def to_dict(self) -> dict:
         return {
@@ -112,6 +116,7 @@ class Task:
             "stack": self.stack,
             "thread": self.thread.name if self.thread is not None else None,
             "tenant": list(self.tenant) if self.tenant is not None else None,
+            "stages": list(self.stages),
         }
 
 
@@ -120,6 +125,7 @@ _tasks: Dict[int, Task] = {}  # id -> Task (bounded: finished tasks trimmed)
 _next_id = 0
 _watchdog: Optional[threading.Thread] = None
 _watchdog_stop = threading.Event()
+_running = threading.local()  # .task: the Task whose body this thread runs
 
 
 def _trim_locked() -> None:
@@ -233,6 +239,7 @@ def run(task_id: int, rename_thread: bool = True):
             if rename_thread:
                 cur.name = f"bg:{t.kind}:{t.target}" if t.target else f"bg:{t.kind}"
     err: Optional[BaseException] = None
+    outer, _running.task = getattr(_running, "task", None), t
     try:
         if t is not None:
             # chaos hook: every background-task body is an injection site
@@ -246,6 +253,7 @@ def run(task_id: int, rename_thread: bool = True):
         err = e
         raise
     finally:
+        _running.task = outer
         now = time.time()
         with _lock:
             t = _tasks.get(task_id)
@@ -287,6 +295,14 @@ def run(task_id: int, rename_thread: bool = True):
                     tenant[0], tenant[1], bg_kind=kind,
                     bg_s=t.duration_s, bg_tasks=1,
                 )
+
+
+def note_stage(name: str, seconds: float, labels: dict) -> None:
+    """Append one timed stage to the record of the task this thread is
+    running (telemetry.stage, outside any trace); no-op outside a task."""
+    t = getattr(_running, "task", None)
+    if t is not None and len(t.stages) < 64:
+        t.stages.append({"name": name, "dur_ms": round(seconds * 1e3, 3), **labels})
 
 
 def spawn(

@@ -101,7 +101,7 @@ def _retry_cause(e: BaseException) -> str:
 class _Req:
     __slots__ = (
         "payload", "runner", "event", "result", "error", "promoted", "done",
-        "t_submit", "trace_ctx", "tenant",
+        "t_submit", "t_done", "trace_ctx", "tenant",
     )
 
     def __init__(self, payload, runner):
@@ -113,6 +113,7 @@ class _Req:
         self.promoted = False  # woken to take over bucket leadership
         self.done = False
         self.t_submit = _time.perf_counter()  # queue-wait accounting
+        self.t_done: float | None = None  # when the leader handed the result out
         # the submitting request's trace position: whoever LEADS the batch
         # re-parents the kernel spans onto every rider here (tracing.py)
         from surrealdb_tpu import accounting, tracing
@@ -214,14 +215,26 @@ class DispatchQueue:
         if not leader:
             req.event.wait()
             if not req.promoted:
-                if req.error is not None:
-                    raise req.error
-                return req.result
+                return self._outcome(req)
             # promoted: the previous leader handed the bucket over; our own
             # request is still queued and rides the batch we now dispatch
         self._lead(b)
+        return self._outcome(req)
+
+    @staticmethod
+    def _outcome(req: _Req) -> Any:
+        """The submitter's way out: its result, and in its trace the
+        `dispatch_wake` span from the leader's hand-out to this thread's
+        running again (a rider's wake-up; for a leader, its bucket chores)."""
         if req.error is not None:
             raise req.error
+        if req.t_done is not None and req.trace_ctx is not None:
+            from surrealdb_tpu import tracing
+
+            tracing.record_span_into(
+                req.trace_ctx, "dispatch_wake", {},
+                req.t_done, _time.perf_counter() - req.t_done,
+            )
         return req.result
 
     def _lead(self, b: _Bucket) -> None:
@@ -505,9 +518,11 @@ class DispatchQueue:
                 ),
             )
             return
+        t_done = _time.perf_counter()
         for r, res in zip(batch, results):
             r.result = res
             r.done = True
+            r.t_done = t_done
             r.event.set()
 
     def _fail(self, batch: List[_Req], e: BaseException, start: Optional[float] = None) -> None:

@@ -72,6 +72,9 @@ class Executor:
         # (plan artifacts installed under a stale token are refused)
         self.slot_values: Optional[tuple] = None
         self.cache_warm = False
+        # when the statement's device operator (kNN search, graph count)
+        # returned: Iterator.output() starts the `materialise` span there
+        self.op_end: Optional[float] = None
         self.plan_gen: Optional[tuple] = None
         self._ddl_open: List[tuple] = []  # DDL brackets held to COMMIT/CANCEL
         self._buffered: List[dict] = []  # responses inside the explicit txn
@@ -181,7 +184,7 @@ class Executor:
                 self._push(out, {"status": "ERR", "result": _FAILED_TX, "time": _fmt_time(0)})
                 continue
 
-            resp = self._run_statement(ctx, stm, src)
+            resp = self._run_statement(ctx, stm, src, t0)
             resp["time"] = _fmt_time(time.perf_counter() - t0)
             self._push(out, resp)
 
@@ -203,7 +206,9 @@ class Executor:
         else:
             out.append(resp)
 
-    def _run_statement(self, ctx: Context, stm, src: Optional[str] = None) -> dict:
+    def _run_statement(
+        self, ctx: Context, stm, src: Optional[str] = None, t_begin: Optional[float] = None
+    ) -> dict:
         # session-state statements need no transaction
         if isinstance(stm, (UseStatement, OptionStatement)):
             try:
@@ -246,6 +251,14 @@ class Executor:
         self.plan_gen = pc.gen_token(self.session.ns, self.session.db)
         if ddl:
             pc.ddl_begin(self.session.ns, self.session.db)
+        at = tracing.current()
+        if t_begin is not None:
+            # the bookkeeping round the statement, before and after it:
+            # fingerprint, activations, counter and dispatch snapshots
+            tracing.record_span_into(
+                at, "stmt_accounting", {"phase": "begin"},
+                t_begin, time.perf_counter() - t_begin,
+            )
         try:
             resp = self._execute_statement(ctx, stm)
         finally:
@@ -334,6 +347,10 @@ class Executor:
                     else None,
                 }
             )
+        tracing.record_span_into(
+            at, "stmt_accounting", {"phase": "end"},
+            t0 + dt, time.perf_counter() - (t0 + dt),
+        )
         return resp
 
     def _session_info(self) -> dict:

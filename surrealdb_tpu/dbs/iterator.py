@@ -17,9 +17,10 @@ dispatch (SURVEY §2.5).
 from __future__ import annotations
 
 import random
+import time
 from typing import Any, Iterable as PyIterable, List, Optional, Tuple
 
-from surrealdb_tpu import cnf
+from surrealdb_tpu import cnf, tracing
 from surrealdb_tpu import key as keys
 from surrealdb_tpu.err import (
     IgnoreError,
@@ -307,6 +308,14 @@ class Iterator:
             rows = self._postprocess(rows)
         elif not isinstance(rows, list):
             rows = rows.to_list()
+        t_op = getattr(ctx.executor, "op_end", None)
+        if t_op is not None:
+            # the rows' fetch and projection since the device operator
+            # returned, up to the statement's result
+            ctx.executor.op_end = None
+            tracing.record_span_into(
+                tracing.current(), "materialise", {}, t_op, time.perf_counter() - t_op
+            )
         return rows
 
     def _iterate_parallel(self) -> None:

@@ -8,11 +8,12 @@ Iterator, run the planner for SELECT, apply ONLY/EXPLAIN/TIMEOUT semantics.
 
 from __future__ import annotations
 
+import time
 from typing import Any, List, Optional
 
 import uuid as _uuid
 
-from surrealdb_tpu import cnf
+from surrealdb_tpu import cnf, tracing
 from surrealdb_tpu import key as keys
 from surrealdb_tpu.err import SurrealError, TypeError_
 from surrealdb_tpu.sql.ast import Expr
@@ -58,6 +59,7 @@ def _only(stm, rows: List[Any]):
 
 # ------------------------------------------------------------------ SELECT
 def select_compute(ctx, stm) -> Any:
+    t_setup = time.perf_counter()
     with _with_timeout(ctx, stm) as c:
         sources = classify_sources(c, stm.what, "select")
 
@@ -191,6 +193,12 @@ def select_compute(ctx, stm) -> Any:
             # single-source guarantee lets ranked plans fill their score
             # lookup lazily (only yielded docs are ever probed)
             sources[0].plan.order_pushed = True
+        # sources classified, the columnar fronts declined, the plan made:
+        # what a row-path SELECT costs before it reads its first source
+        tracing.record_span_into(
+            tracing.current(), "select_setup", {},
+            t_setup, time.perf_counter() - t_setup,
+        )
         try:
             rows = it.output()
         except OrderPushdownBailout:

@@ -22,13 +22,14 @@ the exact KV walk (sql/path.py graph_hop).
 from __future__ import annotations
 
 import threading
+import time as _time
 from surrealdb_tpu.utils import locks as _locks
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from surrealdb_tpu import key as keys
+from surrealdb_tpu import key as keys, telemetry
 from surrealdb_tpu.key.encode import prefix_end
 from surrealdb_tpu.sql.value import Thing
 from surrealdb_tpu.utils.num import next_pow2 as _next_pow2
@@ -120,6 +121,7 @@ class PointerCsr:
             _locks.assert_held(self._lock, "graph.adjacency")
             if not self.dirty and self.n_built == n and self.indptr is not None:
                 return
+            t0 = _time.perf_counter()
             # indptr spans a pow2-padded node capacity and indices a pow2
             # buffer so XLA kernel shapes stay stable while edges trickle in
             # (a recompile per RELATE would dwarf the gather itself)
@@ -143,6 +145,10 @@ class PointerCsr:
             self._dev_csc = None
             self.n_built = n
             self.dirty = False
+            telemetry.stage(
+                "graph_csr_build", t0, _time.perf_counter() - t0,
+                bytes=indptr.nbytes + indices.nbytes,
+            )
 
     def device_arrays(self):
         import jax.numpy as jnp
@@ -163,6 +169,7 @@ class PointerCsr:
 
         self.ensure_arrays()
         if self._dev_csc is None:
+            t0 = _time.perf_counter()
             cap = len(self.indptr) - 1
             nnz = int(self.indptr[-1])
             E = len(self.indices)
@@ -178,7 +185,19 @@ class PointerCsr:
             cptr = np.zeros(cap + 2, dtype=np.int32)
             np.cumsum(counts, out=cptr[1:])
             self._dev_csc = (jnp.asarray(cptr[: cap + 1]), jnp.asarray(csrc))
+            telemetry.stage(
+                "graph_csc_build", t0, _time.perf_counter() - t0,
+                bytes=cptr.nbytes + csrc.nbytes,
+            )
         return self._dev_csc
+
+
+def _prepared(t_enter: Optional[float]) -> None:
+    """Close a count statement's `graph_prepare` span at the dispatch
+    submit: hop specs, frontier, work estimate, operand look-ups (and, on
+    a first statement, the builds inside them) since chain_count's entry."""
+    if t_enter is not None:
+        telemetry.stage("graph_prepare", t_enter, _time.perf_counter() - t_enter)
 
 
 # ------------------------------------------------------------------ kernels
@@ -484,6 +503,7 @@ class GraphMirrors:
                 if key3 in self._built:
                     return
                 self._building[key3] = []
+            t0 = _time.perf_counter()
             it = self.interner(ns, db)
             adjs: Dict[Tuple[bytes, str], Dict[int, List[int]]] = {}
             pre = keys.graph_prefix(ns, db, src_tb)
@@ -506,6 +526,10 @@ class GraphMirrors:
                 for delta in pending:
                     self._apply_one(delta)
                 self._built.add(key3)
+            telemetry.stage(
+                "graph_scan", t0, _time.perf_counter() - t0,
+                edges=sum(len(v) for adj in adjs.values() for v in adj.values()),
+            )
 
     # ------------------------------------------------------------ deltas
     def _apply_one(self, delta: tuple) -> None:
@@ -860,6 +884,7 @@ class GraphMirrors:
             return op
         # host composition: one pass over m1's edges, mapping each middle
         # edge-record to its m2 destinations
+        t0 = _time.perf_counter()
         inv_s, inv_d = sp_s["inv"], sp_d["inv"]
         ns_pad, nd_pad = self._pad128(n_s), self._pad128(n_d)
         A = np.zeros((ns_pad + 1, nd_pad), dtype=np.float32)
@@ -906,9 +931,12 @@ class GraphMirrors:
         }
         with self._lock:
             self._dense[key] = op
+        telemetry.stage(
+            "graph_dense_compose", t0, _time.perf_counter() - t0, bytes=op["A"].nbytes
+        )
         return op
 
-    def _dense_chain_count(self, ns, db, frontier, counts, specs, dispatch):
+    def _dense_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
         """Count chain as composed dense matmuls (see dense_count_batch).
         Returns None when the chain doesn't fit the dense form (odd spec
         count, multi-table hops, oversized tables, fat multiplicities) —
@@ -982,11 +1010,12 @@ class GraphMirrors:
 
             return collect
 
+        _prepared(t_enter)
         return dispatch.submit(key, (fr, cw), runner)
 
     def _device_chain(
         self, ns, db, frontier: np.ndarray, counts: np.ndarray, specs,
-        count_only: bool = False, dispatch=None,
+        count_only: bool = False, dispatch=None, t_enter=None,
     ):
         """Run the remaining hops entirely on device in ONE fused dispatch:
         one upload, H weighted gathers with on-device scatter-add dedup
@@ -1072,6 +1101,7 @@ class GraphMirrors:
 
                 return collect
 
+            _prepared(t_enter)
             return dispatch.submit(key, (fr, cw), runner)
         from surrealdb_tpu import compile_log
 
@@ -1096,6 +1126,7 @@ class GraphMirrors:
         count when count_only (the device chain then downloads one int)."""
         from surrealdb_tpu import cnf
 
+        t_enter = _time.perf_counter()
         ns, db = ctx.ns_db()
         it = self.interner(ns, db)
         dir_map = {"out": [keys.DIR_OUT], "in": [keys.DIR_IN], "both": [keys.DIR_IN, keys.DIR_OUT]}
@@ -1129,12 +1160,14 @@ class GraphMirrors:
             # no GIL serialization across concurrent clients, and every
             # query shares one compiled shape so they coalesce). Preferred
             # form: composed dense matmuls on the MXU; CSC cumsum otherwise.
-            res = self._dense_chain_count(ns, db, frontier, counts, specs, dispatch)
+            res = self._dense_chain_count(
+                ns, db, frontier, counts, specs, dispatch, t_enter=t_enter
+            )
             if res is not None:
                 return res
             return self._device_chain(
                 ns, db, frontier, counts, specs,
-                count_only=True, dispatch=dispatch,
+                count_only=True, dispatch=dispatch, t_enter=t_enter,
             )
         i = 0
         while i < len(specs):
@@ -1154,7 +1187,7 @@ class GraphMirrors:
             if not cnf.TPU_DISABLE and device_now:
                 res = self._device_chain(
                     ns, db, frontier, counts, specs[i:],
-                    count_only=count_only, dispatch=dispatch,
+                    count_only=count_only, dispatch=dispatch, t_enter=t_enter,
                 )
                 if count_only:
                     return res

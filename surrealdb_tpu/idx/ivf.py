@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import math
+import time as _time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -185,6 +186,10 @@ class IvfState:
         centroids cross the slow host<->device link."""
         import jax.numpy as jnp
 
+        from surrealdb_tpu import telemetry
+
+        iters = 8
+        t_train = _time.perf_counter()
         rows = np.nonzero(alive)[0]
         c = nlists or default_nlists(rows.size)
         if matrix is not None and rows.size:
@@ -192,7 +197,9 @@ class IvfState:
             train_n = min(rows.size, max(c * 64, 16384))
             sample_slots = rng.choice(rows, size=train_n, replace=False)
             xs = matrix[jnp.asarray(sample_slots.astype(np.int32))]
-            cents_dev = _kmeans_xs(xs, c)
+            cents_dev = _kmeans_xs(xs, c, iters)
+            cents_dev.block_until_ready()  # the assignment waits for it anyway
+            t_assign = _time.perf_counter()
             # full assignment by device gather, chunked index uploads only
             from surrealdb_tpu.utils.num import pad_tail, tile_slices
 
@@ -207,8 +214,14 @@ class IvfState:
             cents = np.asarray(cents_dev, dtype=np.float32)
         else:
             x = np.ascontiguousarray(data[rows], dtype=np.float32)
-            cents = _kmeans(x, c)
+            cents = _kmeans(x, c, iters)
+            t_assign = _time.perf_counter()
             assign2 = _full_assign(x, cents, k_assign=2)
+        t_lists = _time.perf_counter()
+        telemetry.stage(
+            "ivf_train", t_train, t_assign - t_train, rows=rows.size, lists=c, iters=iters
+        )
+        telemetry.stage("ivf_assign", t_assign, t_lists - t_assign, rows=rows.size)
         # balanced assignment: top-2 candidate cells with spill to the
         # runner-up once the nearest is over 2x the mean size — bounds the
         # padded gather at ~2·N/C per probe instead of the worst cell
@@ -217,7 +230,9 @@ class IvfState:
         for slot, (a1, a2) in zip(rows.tolist(), assign2.tolist()):
             a = a1 if len(lists[a1]) < cap or len(lists[a2]) >= len(lists[a1]) else a2
             lists[int(a)].append(slot)
-        return IvfState(cents, lists, rows.size)
+        state = IvfState(cents, lists, rows.size)
+        telemetry.stage("ivf_lists", t_lists, _time.perf_counter() - t_lists, rows=rows.size)
+        return state
 
     # ------------------------------------------------------------ writes
     def add(self, slot: int, vec: np.ndarray) -> None:
@@ -286,8 +301,6 @@ class IvfState:
         """
         if metric not in ("euclidean", "cosine"):
             raise ValueError(f"search_host supports euclidean/cosine, not {metric!r}")
-        import time as _time
-
         from surrealdb_tpu import telemetry
 
         _t_probe = _time.perf_counter()

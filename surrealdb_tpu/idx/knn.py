@@ -90,6 +90,7 @@ class VectorMirror:
         fall between the scan and the built flag, and (b) the querying
         transaction's own uncommitted writes never leak into the shared
         mirror (they are served by the exact overlay path instead)."""
+        from surrealdb_tpu import telemetry
         from surrealdb_tpu.idx.vector_index import scan_vectors
 
         if self.built:
@@ -112,6 +113,8 @@ class VectorMirror:
                     rows.append(vec)
             finally:
                 txn.cancel()
+            t_stack = _time.perf_counter()
+            telemetry.stage("mirror_scan", t_build, t_stack - t_build, rows=len(rows))
             with self._lock:
                 dim = len(rows[0]) if rows else int(ix["index"].get("dimension") or 0)
                 cap = max(_pow2(len(rows)), cnf.TPU_BATCH_MIN_TILE)
@@ -123,6 +126,9 @@ class VectorMirror:
                 self.rids = rids
                 self.slot_of = {_rid_key(r): i for i, r in enumerate(rids)}
                 self.n_slots = len(rids)
+                telemetry.stage(
+                    "mirror_stack", t_stack, _time.perf_counter() - t_stack, rows=len(rows)
+                )
                 self.dirty = True
                 self.gen += 1
                 self.built = True
@@ -132,8 +138,6 @@ class VectorMirror:
                 # be overwritten by a stale replay
                 for rid, vec in pending:
                     self.apply(rid, vec)
-            from surrealdb_tpu import telemetry
-
             telemetry.observe(
                 "vector_mirror_build", _time.perf_counter() - t_build
             )
@@ -291,9 +295,9 @@ class VectorMirror:
 
                     t_cast = _time.perf_counter()
                     data = data.astype(ml_dtypes.bfloat16)  # host-side cast
-                    telemetry.observe(
-                        "vector_mirror_cast", _time.perf_counter() - t_cast
-                    )
+                    dt = _time.perf_counter() - t_cast
+                    telemetry.observe("vector_mirror_cast", dt)
+                    telemetry.stage("mirror_cast", t_cast, dt, bytes=data.nbytes)
                 self._upload_t0 = _time.perf_counter()
                 if mesh is not None:
                     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -330,7 +334,9 @@ class VectorMirror:
             from surrealdb_tpu import telemetry
 
             m.block_until_ready()
-            telemetry.observe("vector_mirror_upload", _time.perf_counter() - t_up)
+            dt = _time.perf_counter() - t_up
+            telemetry.observe("vector_mirror_upload", dt)
+            telemetry.stage("mirror_upload", t_up, dt, bytes=m.nbytes)
         return m, mask, rids
 
     def device_sharded_mask(self):
@@ -462,6 +468,18 @@ class VectorMirror:
 
 
 
+
+
+def _submit_prepared(ds, t_iter: float, key, q, runner):
+    """Hand one query to the dispatch queue, closing its statement's
+    `knn_prepare` span: what it did on the host since `t_iter` before the
+    device could have its work (mirror and quantizer look-ups, the key)."""
+    from surrealdb_tpu import tracing
+
+    tracing.record_span_into(
+        tracing.current(), "knn_prepare", {}, t_iter, _time.perf_counter() - t_iter
+    )
+    return ds.dispatch.submit(key, q, runner)
 
 
 def _exact_device_launch(qs: np.ndarray, matrix, mask, metric: str, k: int, owner=None):
@@ -728,6 +746,7 @@ class KnnPlan(_KnnExecutorMixin):
         return overlay or None
 
     def iterate(self, ctx):
+        t_iter = _time.perf_counter()
         ctx.qe = self
         ds = ctx.ds()
         ns, db = ctx.ns_db()
@@ -744,8 +763,6 @@ class KnnPlan(_KnnExecutorMixin):
         if n == 0:
             return
         k = min(self.k, n)
-        import time as _time
-
         from surrealdb_tpu import telemetry, tracing
 
         # kernel-level node in the request's span tree: opened BEFORE the
@@ -807,7 +824,7 @@ class KnnPlan(_KnnExecutorMixin):
 
                         return collect
 
-                    dists, slots = ds.dispatch.submit(key, q, runner)
+                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
                 else:
                     self.strategy = (
                         "exact-sharded(ivf-training)" if want_ivf else "exact-sharded"
@@ -848,7 +865,7 @@ class KnnPlan(_KnnExecutorMixin):
                             one_slice(lo, hi)
                         return list(zip(dd, rr))
 
-                    dists, slots = ds.dispatch.submit(key, q, runner)
+                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
             elif (
                 not cnf.TPU_DISABLE
                 and approx_ok
@@ -884,7 +901,7 @@ class KnnPlan(_KnnExecutorMixin):
 
                         return finish
 
-                    dists, slots = ds.dispatch.submit(key, q, runner)
+                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
                 else:
                     from surrealdb_tpu.idx.ivf import default_nprobe
 
@@ -919,7 +936,7 @@ class KnnPlan(_KnnExecutorMixin):
 
                         return finish
 
-                    dists, slots = ds.dispatch.submit(key, q, runner)
+                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
             elif not cnf.TPU_DISABLE and n >= cnf.TPU_KNN_ONDEVICE_THRESHOLD:
                 self.strategy = "exact-device"
                 matrix, mask, rids = mirror.device_snapshot()
@@ -942,7 +959,7 @@ class KnnPlan(_KnnExecutorMixin):
 
                     return finish
 
-                dists, slots = ds.dispatch.submit(key, q, runner)
+                dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
             else:
                 # CPU serving path: an already-trained quantizer serves ANN on
                 # host too (probe + exact rerank, idx/ivf.py search_host) — the
@@ -1002,6 +1019,7 @@ class KnnPlan(_KnnExecutorMixin):
                     {"strategy": self.strategy, "n": n, "k": k},
                     t_search, dur, _search_err,
                 )
+            ctx.executor.op_end = t_search + dur
         self._count_strategy(n)
         for d, s in zip(np.asarray(dists), np.asarray(slots)):
             if not np.isfinite(d) or s < 0 or s >= len(rids):

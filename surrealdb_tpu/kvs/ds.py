@@ -455,8 +455,14 @@ class Datastore:
             # (with this text's literal values bound as executor slots)
             # and skips the parse entirely; cold parses are observed so
             # the shape installs once it crosses the min-hits floor
+            at = tracing.current()
+            t_fetch = _time.perf_counter()
             served = self.plan_cache.fetch(text)
             if served is not None:
+                tracing.record_span_into(
+                    at, "plan_fetch", {"outcome": "hit"},
+                    t_fetch, _time.perf_counter() - t_fetch,
+                )
                 return self.process(
                     served.query,
                     session or Session.owner(),
@@ -468,6 +474,10 @@ class Datastore:
             ast = parse_query(text)
             self.plan_cache.observe(
                 text, ast, (_time.perf_counter() - t0) * 1e6
+            )
+            tracing.record_span_into(
+                at, "plan_fetch", {"outcome": "parse"},
+                t_fetch, _time.perf_counter() - t_fetch,
             )
             return self.process(ast, session or Session.owner(), vars)
 

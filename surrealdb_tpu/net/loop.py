@@ -123,7 +123,7 @@ class _Conn:
         "cid", "loop", "sock", "sink", "peer", "inbuf", "outq", "out_bytes",
         "state", "accepted_t", "first_byte_t", "header_deadline",
         "body_total", "http_busy", "close_after_flush", "closed", "ws",
-        "want_write", "__weakref__",
+        "want_write", "read_t", "__weakref__",
     )
 
     def __init__(self, loop: "_Loop", sock: Optional[socket.socket], sink):
@@ -136,7 +136,10 @@ class _Conn:
         except OSError:
             self.peer = ("?", 0)
         self.inbuf = bytearray()
-        self.outq: Deque[memoryview] = deque()
+        self.read_t = 0.0  # perf_counter of the read that filled inbuf last
+        # (chunk, mark): mark is None, or the (trace, enqueue stamp) of a WS
+        # reply whose trace is complete when the chunk's last byte is accepted
+        self.outq: Deque[Tuple[memoryview, Optional[tuple]]] = deque()
         self.out_bytes = 0
         self.state = "headers"  # headers -> body -> (headers | ws)
         self.accepted_t = time.monotonic()
@@ -261,16 +264,19 @@ class _Loop:
     def _wake(self) -> None:
         _nb_send_some(self._wake_w, b"\x00")
 
-    def enqueue_write(self, conn: _Conn, data: bytes) -> None:
+    def enqueue_write(self, conn: _Conn, data: bytes, mark: Optional[tuple] = None) -> None:
         """Append one atomic chunk (a full response / frame) to a
-        connection's bounded write queue; any thread may call this."""
+        connection's bounded write queue; any thread may call this. `mark`
+        (trace, enqueue stamp) rides the entry: the loop closes that
+        trace's `ws_write` span and stores it, complete, when the chunk is
+        flushed."""
         from surrealdb_tpu import telemetry
 
         overflow = False
         with self._lock:
             if conn.closed:
                 return
-            conn.outq.append(memoryview(bytes(data)))
+            conn.outq.append((memoryview(bytes(data)), mark))
             conn.out_bytes += len(data)
             if conn.out_bytes > max(cnf.NET_WRITE_BUF_MAX, 4096):
                 overflow = True
@@ -338,6 +344,7 @@ class _Loop:
             if cmd == "feed":
                 if conn is not None and not conn.closed:
                     conn.inbuf += arg
+                    conn.read_t = time.perf_counter()
                     self._process(conn)
                     self._dirty_virtual.add(conn)
             elif cmd == "drain":
@@ -405,6 +412,7 @@ class _Loop:
                 self._close(conn, "eof")
                 return
             conn.inbuf += data
+            conn.read_t = time.perf_counter()
             budget -= len(data)
             self._process(conn)
 
@@ -583,6 +591,9 @@ class _Loop:
             "inflight": 0,
             "exclusive": False,  # a session-mutating method is running alone
             "pending": deque(),
+            # when the last traced reply was flushed; the next frame takes
+            # it as the start of its `ws_conn_idle` span (loop thread only)
+            "flushed_t": None,
         }
         conn.state = "ws"
         server.ds.enable_notifications()
@@ -653,10 +664,13 @@ class _Loop:
             return True
         if op not in (wsproto.OP_TEXT, wsproto.OP_BINARY):
             return True
-        self._ws_message(conn, op == wsproto.OP_BINARY, payload)
+        self._ws_message(conn, op == wsproto.OP_BINARY, payload, conn.read_t)
         return True
 
-    def _ws_message(self, conn: _Conn, binary: bool, payload: bytes) -> None:
+    def _ws_message(self, conn: _Conn, binary: bool, payload: bytes, t_frame: float) -> None:
+        """One complete message, read at `t_frame`: decode, admit, park.
+        The stamps taken here and in _ws_start_ready ride the pending item
+        to run_ws_frame, which records them as the request's wire spans."""
         ws = conn.ws
         ws["binary"] = binary
         try:
@@ -686,9 +700,9 @@ class _Loop:
                     fp = stats.fingerprint(params[0])[0]
                 except Exception:  # noqa: BLE001 — cost estimate only
                     fp = None
-        server = self.server
-
         is_session = method in _WS_SESSION_METHODS
+        idle_from, ws["flushed_t"] = ws["flushed_t"], None
+        stamps = (idle_from, t_frame, time.perf_counter())
 
         def on_admit():
             with self._lock:
@@ -696,7 +710,7 @@ class _Loop:
                     released = True
                 else:
                     conn.ws["pending"].append(
-                        (req, binary, sess.ns, sess.db, is_session)
+                        (req, binary, sess.ns, sess.db, is_session, stamps)
                     )
                     released = False
             if released:
@@ -738,14 +752,18 @@ class _Loop:
                     break
                 ws["inflight"] += 1
                 starts.append(ws["pending"].popleft())
+        if starts:
+            t_submit = time.perf_counter()
         for item in starts:
-            self.server.pool.submit(lambda it=item: self._ws_run_one(conn, it))
+            self.server.pool.submit(
+                lambda it=item: self._ws_run_one(conn, it, t_submit)
+            )
 
-    def _ws_run_one(self, conn: _Conn, item: tuple) -> None:
-        req, binary, ns, db, is_session = item
+    def _ws_run_one(self, conn: _Conn, item: tuple, t_submit: float) -> None:
+        req, binary, ns, db, is_session, stamps = item
         try:
             if conn.ws is not None and not conn.closed:
-                self.server.run_ws_frame(conn, req, binary)
+                self.server.run_ws_frame(conn, req, binary, stamps + (t_submit,))
         finally:
             qos.release(ns, db)
             with self._lock:
@@ -792,7 +810,7 @@ class _Loop:
         """Drain as much of the write queue as the socket accepts; manage
         EVENT_WRITE interest."""
         while conn.outq:
-            view = conn.outq[0]
+            view, mark = conn.outq[0]
             n = _nb_send_some(conn.sock, view)
             if n < 0:
                 self._close(conn, "eof")
@@ -804,8 +822,10 @@ class _Loop:
                 conn.out_bytes -= n
             if n == len(view):
                 conn.outq.popleft()
+                if mark is not None:
+                    self._reply_flushed(conn, mark)
             else:
-                conn.outq[0] = view[n:]
+                conn.outq[0] = (view[n:], mark)
         want = bool(conn.outq)
         if want != conn.want_write:
             conn.want_write = want
@@ -816,6 +836,19 @@ class _Loop:
                 pass
         if not conn.outq and conn.close_after_flush:
             self._close(conn, "server")
+
+    def _reply_flushed(self, conn: _Conn, mark: tuple) -> None:
+        """The last byte of a traced WS reply was accepted by its transport:
+        close the trace's `ws_write` span, store the now complete trace, and
+        start the clock of the connection's next `ws_conn_idle`."""
+        from surrealdb_tpu import tracing
+
+        tr, t_enq = mark
+        t = time.perf_counter()
+        ws = conn.ws
+        if ws is not None:
+            ws["flushed_t"] = t
+        tracing.finish(tr, ("ws_write", t_enq, t))
 
     def _drain_virtual(self) -> None:
         while self._dirty_virtual:
@@ -828,8 +861,10 @@ class _Loop:
                     chunks = list(conn.outq)
                     conn.outq.clear()
                     conn.out_bytes = 0
-                for view in chunks:
+                for view, mark in chunks:
                     conn.sink(bytes(view))
+                    if mark is not None:
+                        self._reply_flushed(conn, mark)
             if not conn.outq and conn.close_after_flush:
                 self._close(conn, "server")
 
@@ -917,6 +952,29 @@ class _Loop:
             except (KeyError, ValueError):
                 pass
         self.sel.close()
+
+
+def _wire_spans(tr, stamps: tuple) -> float:
+    """Record a WS request's time outside its root span, as children of the
+    root: what the loop did before an executor thread opened it, and the
+    reply's encoding after it closed. Returns the stamp taken now, where
+    `ws_encode` ends and `ws_write` starts."""
+    from surrealdb_tpu import tracing
+
+    idle_from, t_frame, t_decoded, t_submit = stamps
+    sid, _, t_root, dur = tr.root
+    at = tracing.SpanCtx(tr, sid)
+    now = time.perf_counter()
+    if idle_from is not None and idle_from <= t_frame:
+        tracing.record_span_into(at, "ws_conn_idle", {}, idle_from, t_frame - idle_from)
+    for name, t0, t1 in (
+        ("ws_decode", t_frame, t_decoded),
+        ("ws_admit_wait", t_decoded, t_submit),
+        ("ws_exec_wait", t_submit, tr.t0),
+        ("ws_encode", t_root + dur, now),
+    ):
+        tracing.record_span_into(at, name, {}, t0, t1 - t0)
+    return now
 
 
 # ------------------------------------------------------------------ the server
@@ -1015,9 +1073,15 @@ class EventLoopServer:
         SurrealHandler routes against in-memory files."""
         self._adapter_cls(conn, raw)
 
-    def run_ws_frame(self, conn: _Conn, req: dict, binary: bool) -> None:
+    def run_ws_frame(
+        self, conn: _Conn, req: dict, binary: bool, stamps: Optional[tuple] = None
+    ) -> None:
         """Executor side: one WS RPC frame — the same trace/deny/execute/
-        encode contract as the threaded ingress's per-frame handler."""
+        encode contract as the threaded ingress's per-frame handler.
+        `stamps` are the loop's perf_counter readings for this frame
+        (previous reply flushed or None, frame read, request decoded,
+        handed to the pool): with them the trace also gets its wire spans,
+        and is complete when the reply is flushed, not when the root closes."""
         from surrealdb_tpu import tracing
         from surrealdb_tpu.err import InvalidAuthError, SurrealError
         from surrealdb_tpu.sql.value import to_json_value
@@ -1039,7 +1103,8 @@ class EventLoopServer:
         tr = None
         try:
             with tracing.request(
-                "ws_rpc", trace_id=tid, parent_id=t_parent, method=str(method)
+                "ws_rpc", trace_id=tid, parent_id=t_parent,
+                defer=stamps is not None, method=str(method),
             ) as tr:
                 denied = shim._rpc_denied(method, ctx.session)
                 if denied is not None:
@@ -1059,7 +1124,13 @@ class EventLoopServer:
             frame = wsproto.encode_frame(
                 wsproto.OP_TEXT, json.dumps(to_json_value(resp)).encode()
             )
-        conn.loop.enqueue_write(conn, frame)
+        mark = None
+        if tr is not None and tr.root is not None:
+            mark = (tr, _wire_spans(tr, stamps))
+            # stored before the reply can be read, so that the id it echoes
+            # resolves at once; the flush stores it again with `ws_write`
+            tracing.finish(tr)
+        conn.loop.enqueue_write(conn, frame, mark)
 
     # ------------------------------------------------------------ notifications
     def _notify_pump(self) -> None:

@@ -13,6 +13,7 @@ large frontiers; the semantics here are the per-record reference behavior.
 
 from __future__ import annotations
 
+import time
 from typing import Any, List, Optional, Tuple
 
 from surrealdb_tpu import key as keys
@@ -433,7 +434,9 @@ def graph_chain_count(ctx, expr) -> "int | None":
             return None
     # no exception guard: deadline/internal errors must propagate, not
     # silently re-run the whole traversal on the slow path
-    return ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(expr.parts))
+    n = ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(expr.parts))
+    ctx.executor.op_end = time.perf_counter()
+    return n
 
 
 def _mirror_eligible(ctx, p: PGraph) -> bool:

@@ -9,6 +9,9 @@ environment has no OTLP collector, so the equivalent surface is:
   Prometheus text exposition (`_bucket`/`_sum`/`_count`) at GET /metrics;
 - duration histograms fed by `span()`/`observe()` around statement
   execution, device dispatches, RPC methods and HTTP requests;
+- the interpreter's collections (one `gc.callbacks` hook): the
+  `gc_collections{gen}` counter, the `gc_pause{gen}` duration histogram,
+  and a `gc_pause` span in the trace active on the collecting thread;
 - a structured slow-query ring buffer (sql, duration, plan summary,
   dispatch stats, error) drained via `snapshot()` or GET /slow;
 - span recording around statement execution and device dispatches,
@@ -22,7 +25,9 @@ environment has no OTLP collector, so the equivalent surface is:
 
 from __future__ import annotations
 
+import gc
 import threading
+from surrealdb_tpu import tracing
 from surrealdb_tpu.utils import locks as _locks
 import time
 from bisect import bisect_left
@@ -39,8 +44,7 @@ _counters: Dict[Tuple[str, _LabelKey], float] = {}
 _gauges: Dict[Tuple[str, _LabelKey], float] = {}
 # family -> (buckets, {labels: [counts per bucket + overflow, sum, count, max]})
 _hists: Dict[str, Tuple[Tuple[float, ...], Dict[_LabelKey, list]]] = {}
-# summary view kept alongside the histograms (cheap INFO-style inspection)
-_durations: Dict[str, List[float]] = {}  # labeled name -> [count, total_s, max_s]
+_DURATION_SUFFIX = "_duration_seconds"
 
 # fixed log-scale buckets — one shared shape per unit so every duration /
 # size / count metric is comparable and the exposition stays small
@@ -141,17 +145,67 @@ def observe_hist(name: str, value: float, buckets: Tuple[float, ...] = SIZE_BUCK
 
 
 def observe(name: str, seconds: float, **labels) -> None:
-    """Duration histogram `surreal_<name>_duration_seconds` + summary view."""
-    _hist_observe(f"{name}_duration_seconds", DURATION_BUCKETS, seconds, labels)
-    dname = name + (_fmt_labels(_key(labels)) if labels else "")
-    with _lock:
-        d = _durations.get(dname)
-        if d is None:
-            _durations[dname] = [1.0, seconds, seconds]
-        else:
-            d[0] += 1
-            d[1] += seconds
-            d[2] = max(d[2], seconds)
+    """Duration histogram `surreal_<name>_duration_seconds`; its count, sum
+    and max are also the `durations` summary of snapshot()."""
+    _hist_observe(name + _DURATION_SUFFIX, DURATION_BUCKETS, seconds, labels)
+
+
+def stage(name: str, start: float, seconds: float, **labels) -> None:
+    """One timed stage of load-path work (mirror scan, cast, upload, IVF
+    training, graph operator builds), measured by the caller: a span in the
+    trace of the statement that pays for it or, where a background task
+    does the work, an entry in that task's record."""
+    ctx = tracing.current()
+    if ctx is not None:
+        tracing.record_span_into(ctx, name, labels, start, seconds)
+    else:
+        from surrealdb_tpu import bg
+
+        bg.note_stage(name, seconds, labels)
+
+
+# ------------------------------------------------------------------ collections
+def _gc_zero() -> list:
+    return [0] * (len(DURATION_BUCKETS) + 1) + [0.0, 0, 0.0]
+
+
+# generation -> histogram cell of `gc_pause`, laid out as a `_hists` series.
+# The hook runs inside whichever allocation tripped the collector, on a
+# thread that may already hold `_lock`, so it takes no lock: the interpreter
+# runs one collection at a time, and snapshot() / export_state() /
+# render_prometheus() fold the cells into the registry under `_lock`.
+_gc_cells: Dict[int, list] = {g: _gc_zero() for g in range(3)}
+_gc_t0 = 0.0
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t0 = _gc_t0
+    dur = time.perf_counter() - t0
+    gen = info["generation"]
+    h = _gc_cells[gen]
+    h[bisect_left(DURATION_BUCKETS, dur)] += 1
+    h[-3] += dur
+    h[-2] += 1
+    h[-1] = max(h[-1], dur)
+    ctx = tracing.current()
+    if ctx is not None:
+        tracing.record_span_into(ctx, "gc_pause", {"gen": gen}, t0, dur)
+
+
+def _fold_gc_locked() -> None:
+    for gen, h in _gc_cells.items():
+        if h[-2]:
+            lk = (("gen", str(gen)),)
+            _counters[("gc_collections", lk)] = float(h[-2])
+            fam = _hists.setdefault("gc_pause" + _DURATION_SUFFIX, (DURATION_BUCKETS, {}))
+            fam[1][lk] = list(h)
+
+
+gc.callbacks.append(_gc_hook)
 
 
 @contextmanager
@@ -161,8 +215,6 @@ def span(name: str, **labels: str):
     the flat profiling entry only while profiling is enabled (reference
     #[instrument] spans). With no active trace and profiling off the extra
     cost is one ContextVar read."""
-    from surrealdb_tpu import tracing
-
     t0 = time.perf_counter()
     tok = tracing.push()
     err = None
@@ -278,6 +330,7 @@ def trace_annotation(name: str):
 def snapshot() -> dict:
     """Current metrics + slow queries + (when profiling) recent spans."""
     with _lock:
+        _fold_gc_locked()
         return {
             "counters": {
                 name + (_fmt_labels(labels) if labels else ""): v
@@ -288,8 +341,14 @@ def snapshot() -> dict:
                 for (name, labels), v in _gauges.items()
             },
             "durations": {
-                name: {"count": int(d[0]), "total_s": round(d[1], 6), "max_s": round(d[2], 6)}
-                for name, d in _durations.items()
+                fam[: -len(_DURATION_SUFFIX)] + (_fmt_labels(labels) if labels else ""): {
+                    "count": h[-2],
+                    "total_s": round(h[-3], 6),
+                    "max_s": round(h[-1], 6),
+                }
+                for fam, (_, series) in _hists.items()
+                if fam.endswith(_DURATION_SUFFIX)
+                for labels, h in series.items()
             },
             "histograms": {
                 fam + (_fmt_labels(labels) if labels else ""): {
@@ -316,7 +375,8 @@ def reset() -> None:
         _counters.clear()
         _gauges.clear()
         _hists.clear()
-        _durations.clear()
+        for h in _gc_cells.values():
+            h[:] = _gc_zero()
         _spans.clear()
         _slow.clear()
         _errors.clear()
@@ -465,6 +525,7 @@ def export_state() -> dict:
     [family, buckets, labels, cells]. The coordinator re-labels every
     series with node=<id> and renders one merged exposition."""
     with _lock:
+        _fold_gc_locked()
         return {
             "counters": [[n, dict(k), v] for (n, k), v in _counters.items()],
             "gauges": [[n, dict(k), v] for (n, k), v in _gauges.items()],
@@ -541,6 +602,7 @@ def render_prometheus() -> str:
     histograms render cumulative `_bucket{le=...}` + `_sum` + `_count`."""
     lines: List[str] = []
     with _lock:
+        _fold_gc_locked()
         by_counter: Dict[str, List[Tuple[_LabelKey, float]]] = {}
         for (name, labels), v in _counters.items():
             by_counter.setdefault(name, []).append((labels, v))

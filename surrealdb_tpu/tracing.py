@@ -21,6 +21,15 @@ Mechanics:
 - the dispatch queue re-parents kernel spans onto EVERY rider of a
   coalesced batch (`record_span_into`), so a query that rode someone
   else's kernel launch still shows its own dispatch/kernel levels;
+- a loop-served WebSocket request has no dark time: the event loop stamps
+  the frame on its way in and the reply on its way out, and the trace gets
+  them as children of the `ws_rpc` root lying outside its interval
+  (`ws_conn_idle`, `ws_decode`, `ws_admit_wait`, `ws_exec_wait` before it,
+  `ws_encode`, `ws_write` after it). Such an ingress opens its root with
+  `request(defer=True)` and stores the trace itself with finish(): once
+  before the reply is handed out, once more, complete, when it is flushed.
+  The root stays the doc's only parentless span, and `duration_ms`, `ts`
+  and the `t0` that `start_ms` counts from stay the root's;
 - retention is tail-based: traces with errors, over the slow-query
   threshold, force-kept (slow-query log), or client-tagged are always
   stored; the rest with probability `cnf.TRACE_SAMPLE`. Recording itself is
@@ -34,6 +43,7 @@ import contextvars
 import itertools
 import random
 import re
+from surrealdb_tpu import cnf
 from surrealdb_tpu.utils import locks as _locks
 import time
 import uuid
@@ -55,7 +65,7 @@ class Trace:
 
     __slots__ = (
         "trace_id", "t0", "ts", "explicit", "force", "spans", "_ids",
-        "dropped", "meta", "client_parent",
+        "dropped", "meta", "client_parent", "root", "sampled",
     )
 
     def __init__(self, trace_id: str, explicit: bool = False, client_parent: Optional[str] = None):
@@ -69,6 +79,10 @@ class Trace:
         self.dropped = 0
         self.meta: Dict[str, Any] = {}  # session info (ns/db/auth level)
         self.client_parent = client_parent  # inbound traceparent span id
+        # (span id, name, start, dur) of a closed root whose ingress deferred
+        # the store to finish() (the reply is still to be written)
+        self.root: Optional[tuple] = None
+        self.sampled: Optional[str] = None  # retention class, once stored
 
     def next_id(self) -> int:
         return next(self._ids)
@@ -83,8 +97,6 @@ class Trace:
         dur: float,
         error: Optional[str],
     ) -> None:
-        from surrealdb_tpu import cnf
-
         if len(self.spans) >= cnf.TRACE_MAX_SPANS:
             self.dropped += 1
             return
@@ -108,8 +120,6 @@ _store: "OrderedDict[str, dict]" = OrderedDict()  # trace_id -> finished doc
 
 
 def enabled() -> bool:
-    from surrealdb_tpu import cnf
-
     return cnf.TRACE_ENABLED
 
 
@@ -347,6 +357,7 @@ def request(
     trace_id: Optional[str] = None,
     parent_id: Optional[str] = None,
     nest: bool = True,
+    defer: bool = False,
     **labels: Any,
 ):
     """Ingress seam: mint a trace whose root span is `name`, honoring a
@@ -354,7 +365,10 @@ def request(
     Datastore.execute) become plain child spans of the active trace —
     unless nest=False, for seams whose adjacent telemetry.span() already
     provides the node (RpcContext.execute under a transport ingress).
-    Yields the Trace (or None when tracing is disabled)."""
+    With defer=True a minted trace is not stored when the root closes: the
+    ingress still has spans to add (the reply's encode and write) and
+    calls finish() itself. Yields the Trace (or None when tracing is
+    disabled)."""
     if not enabled():
         yield None
         return
@@ -382,7 +396,29 @@ def request(
         dur = time.perf_counter() - t0
         _current.reset(token)
         tr.add(sid, None, name, labels, t0, dur, _error_name(err))
-        _finish(tr, name, dur)
+        if defer:
+            tr.root = (sid, name, t0, dur)
+        else:
+            _finish(tr, name, dur)
+
+
+def finish(tr: Trace, last: Optional[tuple] = None) -> None:
+    """Store a trace whose ingress deferred it (`request(defer=True)`). The
+    ingress calls it once before it hands the reply out, so that an echoed
+    trace id resolves at once, and once more with `last` = (name, start,
+    end), a final child of the root that ends after it (the reply's write):
+    the complete doc then replaces the first. `duration_ms`, `ts` and the
+    slow threshold stay the root's; what the first call sampled out stays
+    out, and nothing is stored after the call that brought `last`."""
+    root = tr.root
+    if root is None:
+        return
+    sid, name, _, dur = root
+    if last is not None:
+        tr.root = None
+        tr.add(tr.next_id(), sid, last[0], {}, last[1], last[2] - last[1], None)
+    if not _finish(tr, name, dur):
+        tr.root = None
 
 
 # retention classes, weakest first: probabilistic samples are evicted
@@ -392,18 +428,18 @@ def request(
 _RANK = {"probabilistic": 0, "client": 1, "pinned": 2}
 
 
-def _finish(tr: Trace, name: str, dur: float) -> None:
-    from surrealdb_tpu import cnf
-
+def _finish(tr: Trace, name: str, dur: float) -> bool:
+    """Store the trace's doc if its retention class keeps it; says whether."""
     first_error = next((e for (_, _, _, _, _, _, e) in tr.spans if e), None)
     if tr.force or first_error is not None or dur >= cnf.SLOW_QUERY_THRESHOLD_SECS:
         sampled = "pinned"
     elif tr.explicit:
         sampled = "client"
-    elif random.random() < cnf.TRACE_SAMPLE:
+    elif tr.sampled is not None or random.random() < cnf.TRACE_SAMPLE:
         sampled = "probabilistic"
     else:
-        return
+        return False
+    tr.sampled = sampled
     doc = {
         "trace_id": tr.trace_id,
         "name": name,
@@ -435,7 +471,7 @@ def _finish(tr: Trace, name: str, dur: float) -> None:
             # a reused id never downgrades what it names: the pinned doc a
             # slow-log entry cites must not be replaced by a later
             # unrelated (weaker) request wearing the same trace id
-            return
+            return True
         _store[tr.trace_id] = doc
         _store.move_to_end(tr.trace_id)
         while len(_store) > max(cnf.TRACE_STORE_SIZE, 1):
@@ -458,6 +494,7 @@ def _finish(tr: Trace, name: str, dur: float) -> None:
                 del _store[victim]
             else:
                 _store.popitem(last=False)
+    return True
 
 
 # ------------------------------------------------------------------ store
