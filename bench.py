@@ -682,7 +682,7 @@ def bench_graph_3hop(ds, s, rng):
     seq_eps = sum(edges_per_seed.values()) / (len(queries) / qps)
 
     # concurrent pass: dispatch coalescing batches count chains into one
-    # dense-matmul launch (idx/graph_csr.py dense_count_batch)
+    # dense-matmul launch (idx/graph_csr.py chain_count_batch_dense)
     import threading
 
     stats0 = ds.dispatch.stats()

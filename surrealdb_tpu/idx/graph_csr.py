@@ -220,6 +220,22 @@ def _csc_shape_key(lanes: int, fsz: int, n_cap: int, csc_hops, last_hop) -> tupl
     )
 
 
+def _stack_lanes(payloads, fsz: int, pad: int):
+    """One coalesced batch of (frontier, weights) payloads as two
+    [lanes, fsz] int32 arrays at a fixed lane count: a batch of 1 and a
+    batch of 32 share the same compiled executable (padding lanes carry
+    zero weights and cost nothing next to the dispatch RTT)."""
+    from surrealdb_tpu import cnf
+
+    bp = max(_next_pow2(len(payloads)), cnf.TPU_GRAPH_BATCH_LANES)
+    frs = np.full((bp, fsz), pad, dtype=np.int32)
+    cws = np.zeros((bp, fsz), dtype=np.int32)
+    for i, (f, c) in enumerate(payloads):
+        frs[i] = f
+        cws[i] = c
+    return frs, cws
+
+
 def _kernels():
     """Lazily build the jitted hop kernels (keeps jax off the import path).
 
@@ -356,29 +372,42 @@ def _kernels():
         return total
 
     @partial(jax.jit, static_argnames=("n0",))
-    def dense_count_batch(As, outdeg, frontiers, weights, n0):
+    def chain_count_batch_dense(As, outdeg, frontiers, weights, n0):
         """Batched count chains as MXU matmuls: each logical `->edge->node`
-        pair is pre-composed into a dense node->node adjacency (bf16, exact
-        for small integer multiplicities), so B concurrent 3-hop counts are
-        TWO [B, n]x[n, n] matmuls + a degree dot-product in ONE dispatch —
-        the gather/scatter-free formulation of graph traversal this
-        hardware actually wants. seeds arrive as compact LOCAL ids."""
+        pair is pre-composed into a dense node->node operator (bf16, exact
+        for multiplicities < 256), so B concurrent 3-hop counts are TWO
+        matmuls + a degree dot-product in ONE dispatch. Counts ride as
+        int32: per operator the frontier splits into four unsigned 8-bit
+        limbs stacked on the row axis ([4B, n]: 128 MXU rows at 32 lanes),
+        ONE bf16 x bf16 -> float32 product (both operands exact in bf16),
+        and an int32 shift-and-add recombination. A float32 accumulator
+        holds at most 255 x the operator's largest column sum, which
+        _dense_pair keeps under 2**24, so every product is exact and the
+        int32 sums wrap as chain_count_batch's do: the two forms agree bit
+        for bit. (The XLA module name keeps the `jit_chain_count_batch`
+        prefix: trace readers find whichever form served the count.)
+        seeds arrive as compact LOCAL ids."""
         B = frontiers.shape[0]
         lane = (jnp.arange(B) * (n0 + 1))[:, None]
         safe = jnp.where(weights > 0, jnp.clip(frontiers, 0, n0), n0)
         x = (
-            jnp.zeros(B * (n0 + 1), dtype=jnp.float32)
+            jnp.zeros(B * (n0 + 1), dtype=jnp.int32)
             .at[(lane + safe).reshape(-1)]
-            .add(weights.reshape(-1).astype(jnp.float32))
+            .add(weights.reshape(-1))
             .reshape(B, n0 + 1)[:, :n0]
         )
         for A in As:
-            x = jnp.dot(x, A.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+            limbs = jnp.concatenate(
+                [jax.lax.shift_right_logical(x, jnp.int32(8 * k)) & 0xFF for k in range(4)]
+            ).astype(jnp.bfloat16)
+            y = jnp.dot(limbs, A, preferred_element_type=jnp.float32)
+            y = y.astype(jnp.int32).reshape(4, B, -1)
+            x = y[0] + (y[1] << 8) + (y[2] << 16) + (y[3] << 24)
         return (x * outdeg[None, :]).sum(axis=1)
 
     _JITTED["chain"] = chain_kernel
     _JITTED["chain_count_batch"] = chain_count_batch
-    _JITTED["dense_count_batch"] = dense_count_batch
+    _JITTED["chain_count_batch_dense"] = chain_count_batch_dense
     return chain_kernel
 
 
@@ -693,7 +722,7 @@ class GraphMirrors:
         import jax.numpy as jnp
 
         _kernels()
-        dense_kernel = _JITTED["dense_count_batch"]
+        dense_kernel = _JITTED["chain_count_batch_dense"]
         csc_kernel = _JITTED["chain_count_batch"]
         with self._lock:
             mkeys = [k for k in self._m if k[0] == ns and k[1] == db]
@@ -852,8 +881,12 @@ class GraphMirrors:
     def _dense_pair(self, ns, db, spec1, spec2):
         """Composed dense operator for one `->edge->node` spec pair:
         A[local_src, local_dst] = number of 2-hop paths through the edge
-        table (bf16 on device — exact for multiplicities < 256; falls back
-        to None if anything about the pair doesn't fit the dense form)."""
+        table, bf16 on device. None if anything about the pair doesn't fit
+        the dense form: multi-table hops, a node table over
+        TPU_GRAPH_DENSE_MAX, or an operator past the limb limit of
+        chain_count_batch_dense (a multiplicity of 256 or more, which bf16
+        would round, or 255 x the largest column sum reaching 2**24, the
+        float32 accumulator's exact range)."""
         import jax.numpy as jnp
         from surrealdb_tpu import cnf
 
@@ -881,13 +914,12 @@ class GraphMirrors:
         with self._lock:
             op = self._dense.get(key)
         if op is not None and op["gen"] == gen:
-            return op
+            return op if op["fits"] else None
         # host composition: one pass over m1's edges, mapping each middle
         # edge-record to its m2 destinations
         t0 = _time.perf_counter()
         inv_s, inv_d = sp_s["inv"], sp_d["inv"]
         ns_pad, nd_pad = self._pad128(n_s), self._pad128(n_d)
-        A = np.zeros((ns_pad + 1, nd_pad), dtype=np.float32)
         # copy both adjacencies up front: the O(paths) composition loop must
         # not hold mirror locks (it would stall every concurrent RELATE)
         with m1._lock:
@@ -905,42 +937,44 @@ class GraphMirrors:
                     if ld is not None:
                         rows_s.append(ls)
                         rows_d.append(ld)
-        if rows_s:
-            np.add.at(
-                A,
-                (np.asarray(rows_s, np.int64), np.asarray(rows_d, np.int64)),
-                1.0,
-            )
-        if float(A.max(initial=0.0)) >= 256.0:
-            return None  # bf16 would round the multiplicity
-        outdeg = A[:ns_pad].sum(axis=1).astype(np.float32)
-        import ml_dtypes
+        rows_s = np.asarray(rows_s, np.int64)
+        rows_d = np.asarray(rows_d, np.int64)
+        cells, mult = np.unique(rows_s * nd_pad + rows_d, return_counts=True)
+        colsum = np.bincount(rows_d, minlength=nd_pad)
+        op = {"gen": gen, "fits": False}
+        if mult.max(initial=0) < 256 and 255 * int(colsum.max()) < 1 << 24:
+            import ml_dtypes
 
-        op = {
-            "gen": gen,
-            "n_src": n_s,
-            "n_dst": n_d,
-            "ns_pad": ns_pad,
-            "nd_pad": nd_pad,
-            "A": jnp.asarray(A[:ns_pad].astype(ml_dtypes.bfloat16)),
-            "outdeg": jnp.asarray(outdeg),
-            # ∞-norm of the operator: bounds count growth per hop for the
-            # f32-exactness guard in _dense_chain_count
-            "rowmax": float(outdeg.max(initial=0.0)),
-            "space_src": sp_s,
-        }
+            A = np.zeros(ns_pad * nd_pad, dtype=ml_dtypes.bfloat16)
+            A[cells] = mult.astype(ml_dtypes.bfloat16)
+            op.update(
+                fits=True,
+                n_src=n_s,
+                n_dst=n_d,
+                ns_pad=ns_pad,
+                nd_pad=nd_pad,
+                A=jnp.asarray(A.reshape(ns_pad, nd_pad)),
+                outdeg=jnp.asarray(
+                    np.bincount(rows_s, minlength=ns_pad).astype(np.int32)
+                ),
+                space_src=sp_s,
+            )
+        # a refusal is remembered too: an operator past the limb limit is
+        # not recomposed by every statement of its generation
         with self._lock:
             self._dense[key] = op
+        if not op["fits"]:
+            return None
         telemetry.stage(
             "graph_dense_compose", t0, _time.perf_counter() - t0, bytes=op["A"].nbytes
         )
         return op
 
     def _dense_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
-        """Count chain as composed dense matmuls (see dense_count_batch).
-        Returns None when the chain doesn't fit the dense form (odd spec
-        count, multi-table hops, oversized tables, fat multiplicities) —
-        the caller then uses the CSC path."""
+        """Count chain as composed dense matmuls (chain_count_batch_dense),
+        exact under 2**31 at any degree. Returns None when the chain doesn't
+        fit the dense form (odd spec count, or a pair _dense_pair refuses) —
+        _device_chain then uses the CSC form."""
         import jax.numpy as jnp
         from surrealdb_tpu import cnf
 
@@ -956,16 +990,9 @@ class GraphMirrors:
         for a, b in zip(ops, ops[1:]):
             if a["nd_pad"] != b["ns_pad"] or a["n_dst"] != b["n_src"]:
                 return None
-        # f32 matmuls are exact only below 2^24: bound the worst-case count
-        # (Σ seed weights × Π per-hop ∞-norms) and fall back to the exact
-        # int32 CSC path when it could overflow the mantissa
-        bound = float(counts.sum())
-        for op in ops:
-            bound *= max(op["rowmax"], 1.0)
-        if bound >= float(1 << 24):
-            return None
+        telemetry.inc("graph_count_form", form="dense")
         _kernels()
-        kernel = _JITTED["dense_count_batch"]
+        kernel = _JITTED["chain_count_batch_dense"]
         n0 = ops[0]["ns_pad"]
         inv0 = ops[0]["space_src"]["inv"]
         fsz = _next_pow2(max(frontier.size, cnf.TPU_GRAPH_FRONTIER_PAD))
@@ -990,25 +1017,61 @@ class GraphMirrors:
         def runner(payloads):
             from surrealdb_tpu import compile_log
 
-            B = len(payloads)
-            bp = max(_next_pow2(B), cnf.TPU_GRAPH_BATCH_LANES)
-            frs = np.full((bp, fsz), n0, dtype=np.int32)
-            cws = np.zeros((bp, fsz), dtype=np.int32)
-            for i, (f, c) in enumerate(payloads):
-                frs[i] = f
-                cws[i] = c
+            frs, cws = _stack_lanes(payloads, fsz, n0)
             with compile_log.tracked(
-                "graph_dense", _dense_shape_key(bp, fsz, n0, As)
+                "graph_dense", _dense_shape_key(len(frs), fsz, n0, As)
             ):
                 out = kernel(
                     As, outdeg, jnp.asarray(frs), jnp.asarray(cws), n0=n0
                 )
+            return lambda: np.asarray(out)[: len(payloads)].tolist()
 
-            def collect():
-                vals = np.asarray(out)
-                return [int(round(float(vals[i]))) for i in range(B)]
+        _prepared(t_enter)
+        return dispatch.submit(key, (fr, cw), runner)
 
-            return collect
+    def _csc_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
+        """Count chain in the scatter-free CSC cumsum form
+        (chain_count_batch): what no dense operator can hold."""
+        import jax.numpy as jnp
+
+        from surrealdb_tpu import cnf
+
+        telemetry.inc("graph_count_form", form="csc")
+        hop_mirrors = [self._hop_mirrors(ns, db, sp) for sp in specs]
+        if not all(hop_mirrors):
+            return 0
+        _kernels()
+        batch_kernel = _JITTED["chain_count_batch"]
+        n_cap = _next_pow2(len(self.interner(ns, db)))
+        fsz = _next_pow2(max(frontier.size, cnf.TPU_GRAPH_FRONTIER_PAD))
+        fr = np.full(fsz, n_cap, dtype=np.int32)
+        fr[: frontier.size] = frontier
+        cw = np.zeros(fsz, dtype=np.int32)
+        cw[: counts.size] = counts
+        csc_hops = tuple(
+            tuple(m.device_csc() for m in mirrors) for mirrors in hop_mirrors[:-1]
+        )
+        last_hop = tuple((m.device_arrays()[0],) for m in hop_mirrors[-1])
+        key = (
+            "gchain", fsz, n_cap, len(specs),
+            tuple(id(a) for hop in csc_hops for pair in hop for a in pair),
+            tuple(id(p) for (p,) in last_hop),
+        )
+
+        def runner(payloads):
+            from surrealdb_tpu import compile_log
+
+            frs, cws = _stack_lanes(payloads, fsz, n_cap)
+            with compile_log.tracked(
+                "graph_csc",
+                _csc_shape_key(len(frs), fsz, n_cap, csc_hops, last_hop),
+            ):
+                out = batch_kernel(
+                    csc_hops, last_hop,
+                    jnp.asarray(frs), jnp.asarray(cws),
+                    n_cap=n_cap,
+                )
+            return lambda: np.asarray(out)[: len(payloads)].tolist()
 
         _prepared(t_enter)
         return dispatch.submit(key, (fr, cw), runner)
@@ -1017,14 +1080,28 @@ class GraphMirrors:
         self, ns, db, frontier: np.ndarray, counts: np.ndarray, specs,
         count_only: bool = False, dispatch=None, t_enter=None,
     ):
-        """Run the remaining hops entirely on device in ONE fused dispatch:
-        one upload, H weighted gathers with on-device scatter-add dedup
-        between hops, one download at the end (a scalar when count_only).
-        Every static dimension (frontier size, max degree, node capacity,
-        dedup output) is pow2-rounded so steady writes don't recompile."""
+        """Run the remaining hops entirely on device in ONE dispatch. The
+        one place a device count is produced: with a dispatcher a count
+        coalesces with its concurrent peers (dbs/dispatch.py
+        leader-follower) as composed dense matmuls on the MXU, or in the
+        CSC cumsum form where the chain doesn't fit a dense operator.
+        Otherwise the fused chain kernel: one upload, H weighted gathers
+        with on-device scatter-add dedup between hops, one download at the
+        end. Every static dimension (frontier size, max degree, node
+        capacity, dedup output) is pow2-rounded so steady writes don't
+        recompile."""
+        if count_only and dispatch is not None:
+            res = self._dense_chain_count(
+                ns, db, frontier, counts, specs, dispatch, t_enter=t_enter
+            )
+            if res is None:
+                res = self._csc_chain_count(
+                    ns, db, frontier, counts, specs, dispatch, t_enter=t_enter
+                )
+            return res
         import jax.numpy as jnp
 
-        from surrealdb_tpu import cnf
+        from surrealdb_tpu import cnf, compile_log
 
         chain_kernel = _kernels()
         it = self.interner(ns, db)
@@ -1038,6 +1115,8 @@ class GraphMirrors:
         cw = np.zeros(fsz, dtype=np.int32)
         cw[: counts.size] = counts
 
+        if count_only:
+            telemetry.inc("graph_count_form", form="csc")
         hops, mds, out_sizes = [], [], []
         width = fsz
         for spec in specs:
@@ -1058,53 +1137,6 @@ class GraphMirrors:
             width = _next_pow2(min(total, n_cap))
             out_sizes.append(width)
         hops, mds, out_sizes = tuple(hops), tuple(mds), tuple(out_sizes)
-        if count_only and dispatch is not None:
-            # coalesce concurrent count-chains with identical shape/adjacency
-            # into one batched dispatch (dbs/dispatch.py leader-follower)
-            batch_kernel = _JITTED["chain_count_batch"]
-            csc_hops = tuple(
-                tuple(m.device_csc() for m in self._hop_mirrors(ns, db, sp))
-                for sp in specs[:-1]
-            )
-            last_hop = tuple((pair[0],) for pair in hops[-1])
-            key = (
-                "gchain", fsz, n_cap, len(specs),
-                tuple(id(a) for hop in csc_hops for pair in hop for a in pair),
-                tuple(id(p) for (p,) in last_hop),
-            )
-
-            def runner(payloads):
-                from surrealdb_tpu import compile_log
-
-                B = len(payloads)
-                # fixed lane count: a batch of 1 and a batch of 32 share the
-                # same compiled executable (padding lanes carry zero weights
-                # and cost nothing next to the dispatch RTT)
-                bp = max(_next_pow2(B), cnf.TPU_GRAPH_BATCH_LANES)
-                frs = np.full((bp, fsz), n_cap, dtype=np.int32)
-                cws = np.zeros((bp, fsz), dtype=np.int32)
-                for i, (f, c) in enumerate(payloads):
-                    frs[i] = f
-                    cws[i] = c
-                with compile_log.tracked(
-                    "graph_csc", _csc_shape_key(bp, fsz, n_cap, csc_hops, last_hop)
-                ):
-                    out = batch_kernel(
-                        csc_hops, last_hop,
-                        jnp.asarray(frs), jnp.asarray(cws),
-                        n_cap=n_cap,
-                    )
-
-                def collect():
-                    vals = np.asarray(out)
-                    return [int(vals[i]) for i in range(B)]
-
-                return collect
-
-            _prepared(t_enter)
-            return dispatch.submit(key, (fr, cw), runner)
-        from surrealdb_tpu import compile_log
-
         with compile_log.tracked(
             "graph_chain", (fsz, n_cap, mds, out_sizes, bool(count_only))
         ):
@@ -1158,13 +1190,7 @@ class GraphMirrors:
             # big count chain: straight to device from the seed — the whole
             # chain is one tiny-upload batched dispatch (no host hops means
             # no GIL serialization across concurrent clients, and every
-            # query shares one compiled shape so they coalesce). Preferred
-            # form: composed dense matmuls on the MXU; CSC cumsum otherwise.
-            res = self._dense_chain_count(
-                ns, db, frontier, counts, specs, dispatch, t_enter=t_enter
-            )
-            if res is not None:
-                return res
+            # query shares one compiled shape so they coalesce)
             return self._device_chain(
                 ns, db, frontier, counts, specs,
                 count_only=True, dispatch=dispatch, t_enter=t_enter,
@@ -1196,6 +1222,7 @@ class GraphMirrors:
             frontier, counts = self._host_hop(ns, db, frontier, counts, specs[i])
             i += 1
         if count_only:
+            telemetry.inc("graph_count_form", form="host")
             return int(counts.sum())
         return frontier, counts, it
 
@@ -1247,7 +1274,7 @@ def graftcheck_sites():
         import ml_dtypes
 
         _kernels()
-        kernel = _JITTED["dense_count_batch"]
+        kernel = _JITTED["chain_count_batch_dense"]
         lanes = shape["lanes"]
         As = tuple(
             jax.ShapeDtypeStruct((n0, n0), jnp.dtype(ml_dtypes.bfloat16))
@@ -1255,7 +1282,7 @@ def graftcheck_sites():
         )
         args = (
             As,
-            jax.ShapeDtypeStruct((n0,), jnp.float32),
+            jax.ShapeDtypeStruct((n0,), jnp.int32),
             jax.ShapeDtypeStruct((lanes, fsz), jnp.int32),
             jax.ShapeDtypeStruct((lanes, fsz), jnp.int32),
         )
@@ -1315,8 +1342,13 @@ def graftcheck_sites():
             "module": __name__,
             "kind": "single",
             "allowed_collectives": (),
-            "out_dtypes": ("float32",),
-            "shapes": lane_shapes,
+            "out_dtypes": ("int32",),
+            # as served: TPU_GRAPH_BATCH_LANES lanes and up (four limbs a
+            # lane: 128 MXU rows at 32), one operator a pair before the last
+            "shapes": [
+                {"label": f"l{lanes}_f{fsz}_n{n0}_h2", "lanes": lanes, "hops": 2}
+                for lanes in (32, 64)
+            ],
             "build": build_dense,
         },
         {

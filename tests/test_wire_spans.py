@@ -275,7 +275,8 @@ def test_a_collection_inside_a_traced_statement_is_a_span_and_a_count(ds, gen, m
     pauses = [s for s in _named(doc, "gc_pause") if s["labels"]["gen"] == str(gen)]
     assert pauses and all(p["dur_ms"] >= 0 for p in pauses)
     by_id = {s["id"]: s for s in doc["spans"]}
-    assert by_id[pauses[0]["parent"]]["name"] == "execute"
+    # (a spontaneous gen-0 collection may land in the request before `execute` opens)
+    assert "execute" in {by_id[p["parent"]]["name"] for p in pauses}
     snap = telemetry.snapshot()
     assert snap["counters"][key] >= before + 1
     assert snap["histograms"][f'gc_pause_duration_seconds{{gen="{gen}"}}']["count"] == snap["counters"][key]
