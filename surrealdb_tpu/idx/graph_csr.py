@@ -184,20 +184,36 @@ class PointerCsr:
             counts = np.bincount(edst, minlength=cap + 1)
             cptr = np.zeros(cap + 2, dtype=np.int32)
             np.cumsum(counts, out=cptr[1:])
-            self._dev_csc = (jnp.asarray(cptr[: cap + 1]), jnp.asarray(csrc))
+            t1 = _time.perf_counter()
             telemetry.stage(
-                "graph_csc_build", t0, _time.perf_counter() - t0,
-                bytes=cptr.nbytes + csrc.nbytes,
+                "graph_csc_build", t0, t1 - t0, bytes=cptr.nbytes + csrc.nbytes
+            )
+            self._dev_csc = (jnp.asarray(cptr[: cap + 1]), jnp.asarray(csrc))
+            # the transfer is asynchronous: the stage times the host's
+            # hand-off, and its bytes say what the first kernel to read the
+            # arrays waits for inside its collect
+            telemetry.stage(
+                "graph_csc_upload", t1, _time.perf_counter() - t1,
+                bytes=sum(a.nbytes for a in self._dev_csc),
             )
         return self._dev_csc
 
 
-def _prepared(t_enter: Optional[float]) -> None:
-    """Close a count statement's `graph_prepare` span at the dispatch
-    submit: hop specs, frontier, work estimate, operand look-ups (and, on
-    a first statement, the builds inside them) since chain_count's entry."""
+def _served(form: str, t_enter: Optional[float]) -> None:
+    """One count chain served by `form` (`dense`, `csc` or `host`): the
+    `graph_count_form` counter and the `form` label of the statement's
+    `graph_prepare` span come from this one argument, so they cannot
+    disagree. The span closes here, at the dispatch submit (for a count no
+    dispatcher carries: where its fused chain starts): hop specs, frontier,
+    work estimate, the dense form's refusal, operand look-ups (and, on a
+    first statement, the builds inside them) since chain_count's entry. A
+    `host` count is known for one only when its walk has ended, so there the
+    span holds the whole count; a reader of preparation time leaves it out."""
+    telemetry.inc("graph_count_form", form=form)
     if t_enter is not None:
-        telemetry.stage("graph_prepare", t_enter, _time.perf_counter() - t_enter)
+        telemetry.stage(
+            "graph_prepare", t_enter, _time.perf_counter() - t_enter, form=form
+        )
 
 
 # ------------------------------------------------------------------ kernels
@@ -990,7 +1006,6 @@ class GraphMirrors:
         for a, b in zip(ops, ops[1:]):
             if a["nd_pad"] != b["ns_pad"] or a["n_dst"] != b["n_src"]:
                 return None
-        telemetry.inc("graph_count_form", form="dense")
         _kernels()
         kernel = _JITTED["chain_count_batch_dense"]
         n0 = ops[0]["ns_pad"]
@@ -1006,6 +1021,7 @@ class GraphMirrors:
                 cw[j] = c
                 j += 1
         if j == 0:
+            _served("dense", t_enter)
             return 0
         As = tuple(op["A"] for op in ops[:-1])
         outdeg = ops[-1]["outdeg"]
@@ -1026,7 +1042,7 @@ class GraphMirrors:
                 )
             return lambda: np.asarray(out)[: len(payloads)].tolist()
 
-        _prepared(t_enter)
+        _served("dense", t_enter)
         return dispatch.submit(key, (fr, cw), runner)
 
     def _csc_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
@@ -1036,9 +1052,9 @@ class GraphMirrors:
 
         from surrealdb_tpu import cnf
 
-        telemetry.inc("graph_count_form", form="csc")
         hop_mirrors = [self._hop_mirrors(ns, db, sp) for sp in specs]
         if not all(hop_mirrors):
+            _served("csc", t_enter)
             return 0
         _kernels()
         batch_kernel = _JITTED["chain_count_batch"]
@@ -1073,7 +1089,7 @@ class GraphMirrors:
                 )
             return lambda: np.asarray(out)[: len(payloads)].tolist()
 
-        _prepared(t_enter)
+        _served("csc", t_enter)
         return dispatch.submit(key, (fr, cw), runner)
 
     def _device_chain(
@@ -1116,7 +1132,7 @@ class GraphMirrors:
         cw[: counts.size] = counts
 
         if count_only:
-            telemetry.inc("graph_count_form", form="csc")
+            _served("csc", t_enter)
         hops, mds, out_sizes = [], [], []
         width = fsz
         for spec in specs:
@@ -1222,7 +1238,7 @@ class GraphMirrors:
             frontier, counts = self._host_hop(ns, db, frontier, counts, specs[i])
             i += 1
         if count_only:
-            telemetry.inc("graph_count_form", form="host")
+            _served("host", t_enter)
             return int(counts.sum())
         return frontier, counts, it
 
