@@ -62,6 +62,18 @@ class NodeInterner:
         return self.id_of.get((t.tb, repr(t.id)))
 
 
+def _csc_arrays(src: np.ndarray, dst: np.ndarray, cap: int):
+    """Edges `src -> dst` over `cap` nodes as the arrays chain_count_batch
+    sweeps: `csrc`, the sources in destination order (stable), padded to a
+    power of two with the sentinel `cap`, and `cptr` [cap + 1], the bounds
+    of each destination's bin in it (pad slots lie past the last bin)."""
+    csrc = np.full(_next_pow2(max(src.size, 1)), cap, dtype=np.int32)
+    csrc[: src.size] = src[np.argsort(dst, kind="stable")]
+    cptr = np.zeros(cap + 1, dtype=np.int32)
+    np.cumsum(np.bincount(dst, minlength=cap), out=cptr[1:])
+    return cptr, csrc
+
+
 class PointerCsr:
     """Adjacency for one (src_tb, direction, foreign_tb) pointer keyspace.
 
@@ -150,6 +162,13 @@ class PointerCsr:
                 bytes=indptr.nbytes + indices.nbytes,
             )
 
+    def host_arrays(self):
+        """(indptr, indices) of one compaction: ensure_arrays swaps the two
+        under the mirror's lock, so they are read under it, together."""
+        self.ensure_arrays()
+        with self._lock:
+            return self.indptr, self.indices
+
     def device_arrays(self):
         import jax.numpy as jnp
 
@@ -164,7 +183,11 @@ class PointerCsr:
         into v becomes cumsum over dst-sorted x[csrc] + a boundary gather —
         gathers and a prefix-scan only, no scatter (TPU scatter-add is
         serial-slow; cumsum + gather ride the VPU). Padding edges carry the
-        sentinel src/dst `cap` and fall outside every real bin."""
+        sentinel src `cap` and lie past every real bin. These are
+        the record-level operands, over the id space persons and edge
+        records share: what chain_count_batch sweeps for a chain that is
+        not one of composable `->edge->node` pairs (GraphMirrors._csc_pair
+        builds the same arrays over one table's compact ids for those)."""
         import jax.numpy as jnp
 
         self.ensure_arrays()
@@ -172,23 +195,15 @@ class PointerCsr:
             t0 = _time.perf_counter()
             cap = len(self.indptr) - 1
             nnz = int(self.indptr[-1])
-            E = len(self.indices)
-            esrc = np.full(E, cap, dtype=np.int32)
-            esrc[:nnz] = np.repeat(
-                np.arange(cap, dtype=np.int32), np.diff(self.indptr)
+            cptr, csrc = _csc_arrays(
+                np.repeat(np.arange(cap, dtype=np.int32), np.diff(self.indptr)),
+                self.indices[:nnz], cap,
             )
-            edst = self.indices.astype(np.int64, copy=True)
-            edst[nnz:] = cap
-            order = np.argsort(edst, kind="stable")
-            csrc = esrc[order]
-            counts = np.bincount(edst, minlength=cap + 1)
-            cptr = np.zeros(cap + 2, dtype=np.int32)
-            np.cumsum(counts, out=cptr[1:])
             t1 = _time.perf_counter()
             telemetry.stage(
                 "graph_csc_build", t0, t1 - t0, bytes=cptr.nbytes + csrc.nbytes
             )
-            self._dev_csc = (jnp.asarray(cptr[: cap + 1]), jnp.asarray(csrc))
+            self._dev_csc = (jnp.asarray(cptr), jnp.asarray(csrc))
             # the transfer is asynchronous: the stage times the host's
             # hand-off, and its bytes say what the first kernel to read the
             # arrays waits for inside its collect
@@ -199,20 +214,28 @@ class PointerCsr:
         return self._dev_csc
 
 
-def _served(form: str, t_enter: Optional[float]) -> None:
+def _served(form: str, t_enter: Optional[float], operand: Optional[str] = None) -> None:
     """One count chain served by `form` (`dense`, `csc` or `host`): the
     `graph_count_form` counter and the `form` label of the statement's
     `graph_prepare` span come from this one argument, so they cannot
-    disagree. The span closes here, at the dispatch submit (for a count no
-    dispatcher carries: where its fused chain starts): hop specs, frontier,
-    work estimate, the dense form's refusal, operand look-ups (and, on a
-    first statement, the builds inside them) since chain_count's entry. A
-    `host` count is known for one only when its walk has ended, so there the
-    span holds the whole count; a reader of preparation time leaves it out."""
+    disagree. A `csc` count also says which `operand` its kernel swept
+    (`composed`: node->node operators in a table's compact id space;
+    `records`: the record-level mirrors in the shared id space), to the
+    `graph_csc_operand` counter and the span's `operand` label alike. The
+    span closes here, at the dispatch submit (for a count no dispatcher
+    carries: where its fused chain starts): hop specs, frontier, work
+    estimate, the dense form's refusal, operand look-ups (and, on a first
+    statement, the builds inside them) since chain_count's entry. A `host`
+    count is known for one only when its walk has ended, so there the span
+    holds the whole count; a reader of preparation time leaves it out."""
     telemetry.inc("graph_count_form", form=form)
+    labels = {"form": form}
+    if operand is not None:
+        telemetry.inc("graph_csc_operand", operand=operand)
+        labels["operand"] = operand
     if t_enter is not None:
         telemetry.stage(
-            "graph_prepare", t_enter, _time.perf_counter() - t_enter, form=form
+            "graph_prepare", t_enter, _time.perf_counter() - t_enter, **labels
         )
 
 
@@ -250,6 +273,58 @@ def _stack_lanes(payloads, fsz: int, pad: int):
         frs[i] = f
         cws[i] = c
     return frs, cws
+
+
+def _local_seeds(inv: dict, frontier: np.ndarray, counts: np.ndarray, fsz: int, pad: int):
+    """A chain's weighted seeds in a table's compact ids ([fsz] int32 each,
+    pad slots at the sentinel `pad` with weight 0) and how many seeds the
+    table holds: a seed outside it starts no path of the chain."""
+    fr = np.full(fsz, pad, dtype=np.int32)
+    cw = np.zeros(fsz, dtype=np.int32)
+    j = 0
+    for g, c in zip(frontier.tolist(), counts.tolist()):
+        loc = inv.get(int(g))
+        if loc is not None:
+            fr[j] = loc
+            cw[j] = c
+            j += 1
+    return fr, cw, j
+
+
+def _local_ids(space: dict, size: int) -> np.ndarray:
+    """table_space()'s global->local map as an array over `size` global ids
+    (-1: not a node of the table), for whole-array look-ups."""
+    g = np.asarray(space["globals"], dtype=np.int64)
+    inv = np.full(max(size, int(g.max(initial=-1)) + 1), -1, dtype=np.int64)
+    inv[g] = np.arange(g.size)
+    return inv
+
+
+def _compose_coo(ip1, ix1, ip2, ix2, space_src: dict, space_dst: dict, max_paths: int):
+    """src -> mid -> dst through two CSR mirrors over the shared id space, as
+    COO `(local_src, local_dst)` in the two tables' compact ids: one entry a
+    2-hop path, a repeated path a repeated entry. Whole-array NumPy (the
+    second mirror's degrees repeat the first mirror's edges), so a million
+    paths are a few array passes. None past `max_paths`, told from the
+    degrees before any path is laid out."""
+    n1, n2 = len(ip1) - 1, len(ip2) - 1
+    inv_s = _local_ids(space_src, max(n1, n2))
+    inv_d = inv_s if space_dst is space_src else _local_ids(space_dst, max(n1, n2))
+    ls = inv_s[np.repeat(np.arange(n1), np.diff(ip1))]
+    mid = ix1[: int(ip1[-1])].astype(np.int64)
+    ok = (ls >= 0) & (mid < n2)
+    ls, mid = ls[ok], mid[ok]
+    start = ip2[mid].astype(np.int64)
+    deg = ip2[mid + 1] - start
+    total = int(deg.sum())
+    if total > max_paths:
+        return None
+    # path k of m1-edge e reads ix2[start[e] + k]: the running slot number
+    # less the edge's first slot is k
+    take = np.repeat(start - (np.cumsum(deg) - deg), deg) + np.arange(total)
+    ld = inv_d[ix2[take]]
+    keep = ld >= 0
+    return np.repeat(ls, deg)[keep], ld[keep]
 
 
 def _kernels():
@@ -347,7 +422,11 @@ def _kernels():
         - the final hop of a count never materializes neighbors: it is a
           degree dot-product
         csc_hops: tuple per non-final hop of ((cptr, csrc), ...);
-        last_hop: ((ptr,), ...)."""
+        last_hop: ((ptr,), ...). The operands are either composed
+        node->node operators in one table's compact ids (_csc_pair: a hop
+        an `->edge->node` pair, n_cap the padded table) or the record-level
+        mirrors in the shared id space (PointerCsr.device_csc: a hop a
+        spec, n_cap the padded interner); the kernel cannot tell."""
         B = frontiers.shape[0]
         if not csc_hops and not last_hop:
             return jnp.zeros((B,), dtype=jnp.int32)
@@ -438,6 +517,7 @@ class GraphMirrors:
         # dense composed operators + per-table compact id spaces
         self._spaces: Dict[tuple, dict] = {}  # (ns,db,tb) -> space dict
         self._dense: Dict[tuple, dict] = {}  # pair key -> operator dict
+        self._csc: Dict[tuple, dict] = {}  # pair key -> composed sparse operator
         # tables mid-build: deltas committed during the build scan are
         # buffered here and replayed after load (closes the scan→built gap)
         self._building: Dict[Tuple[str, str, str], List[tuple]] = {}
@@ -489,6 +569,18 @@ class GraphMirrors:
     def table_built(self, ns: str, db: str, src_tb: str) -> bool:
         return (ns, db, src_tb) in self._built
 
+    def _forget_derived(self, stale) -> None:
+        """Drop what was composed from mirrors that are going (caller holds
+        _lock): id spaces, dense and sparse operators, warmed pairs whose
+        key `stale` selects. Their generations count from a mirror's
+        version and a table's size, and both start again with the new
+        mirror and interner: an operator kept here would be served for
+        the next graph of the same name."""
+        for d in (self._spaces, self._dense, self._csc):
+            for k in [k for k in d if stale(k)]:
+                del d[k]
+        self._warmed_pairs = {k for k in self._warmed_pairs if not stale(k)}
+
     def drop_table(self, ns: str, db: str, tb: str) -> None:
         """Forget a table's mirrors (REMOVE TABLE / bulk invalidation)."""
         with self._lock:
@@ -496,6 +588,7 @@ class GraphMirrors:
             self._building.pop((ns, db, tb), None)
             for k in [k for k in self._m if k[:3] == (ns, db, tb)]:
                 del self._m[k]
+            self._forget_derived(lambda k: k[:2] == (ns, db) and tb in k[2:])
 
     def drop_db(self, ns: str, db: str) -> None:
         """Forget everything of one database (REMOVE DATABASE)."""
@@ -505,6 +598,7 @@ class GraphMirrors:
             for k in [k for k in self._m if k[:2] == (ns, db)]:
                 del self._m[k]
             self._interners.pop((ns, db), None)
+            self._forget_derived(lambda k: k[:2] == (ns, db))
 
     def drop_ns(self, ns: str) -> None:
         """Forget everything of one namespace (REMOVE NAMESPACE)."""
@@ -515,6 +609,7 @@ class GraphMirrors:
                 del self._m[k]
             for k in [k for k in self._interners if k[0] == ns]:
                 del self._interners[k]
+            self._forget_derived(lambda k: k[0] == ns)
 
     def clear(self) -> None:
         with self._lock:
@@ -522,6 +617,7 @@ class GraphMirrors:
             self._built.clear()
             self._building.clear()
             self._interners.clear()
+            self._forget_derived(lambda k: True)
 
     # ------------------------------------------------------------ build
     def ensure_table(self, ctx, src_tb: str) -> None:
@@ -797,33 +893,44 @@ class GraphMirrors:
                             )
                 continue
             # dense doesn't fit (oversized tables / fat multiplicities):
-            # warm the CSC cumsum form the serving path will use instead
+            # warm the CSC cumsum form the serving path will use instead,
+            # over the operand it will sweep: the pair's composed operator,
+            # or the two record-level mirrors where that is refused
             try:
-                m1 = self._hop_mirrors(ns, db, spec1)
-                m2 = self._hop_mirrors(ns, db, spec2)
-                if len(m1) != 1 or len(m2) != 1:
-                    continue
-                n_cap = _next_pow2(len(self.interner(ns, db)))
-                csc1, csc2 = m1[0].device_csc(), m2[0].device_csc()
-                ptr2 = m2[0].device_arrays()[0]
                 from surrealdb_tpu import compile_log
 
+                cop = self._csc_pair(ns, db, spec1, spec2)
+                if cop is not None:
+                    n_cap, last_hop = cop["n_pad"], ((cop["indptr"],),)
+                    hop = ((cop["cptr"], cop["csrc"]),)
+                    chains = [(hop,) * (c - 1) for c in range(1, max_pairs + 1)]
+                else:
+                    m1 = self._hop_mirrors(ns, db, spec1)
+                    m2 = self._hop_mirrors(ns, db, spec2)
+                    if len(m1) != 1 or len(m2) != 1:
+                        continue
+                    n_cap = _next_pow2(len(self.interner(ns, db)))
+                    csc1, csc2 = m1[0].device_csc(), m2[0].device_csc()
+                    last_hop = ((m2[0].device_arrays()[0],),)
+                    # `->et->tb` repeated c times = 2c specs; the final
+                    # spec is a degree reduction (no CSC)
+                    chains = [
+                        tuple(
+                            ((csc1,) if i % 2 == 0 else (csc2,))
+                            for i in range(2 * c - 1)
+                        )
+                        for c in range(1, max_pairs + 1)
+                    ]
                 for lanes in lane_set:
                     frs = jnp.asarray(np.full((lanes, fsz), n_cap, dtype=np.int32))
                     cws = jnp.asarray(np.zeros((lanes, fsz), dtype=np.int32))
-                    for hops in range(1, max_pairs + 1):
-                        # `->et->tb` repeated `hops` times = 2*hops specs;
-                        # the final spec is a degree reduction (no CSC)
-                        csc_hops = tuple(
-                            ((csc1,) if i % 2 == 0 else (csc2,))
-                            for i in range(2 * hops - 1)
-                        )
+                    for csc_hops in chains:
                         with compile_log.tracked(
                             "graph_csc",
-                            _csc_shape_key(lanes, fsz, n_cap, csc_hops, ((ptr2,),)),
+                            _csc_shape_key(lanes, fsz, n_cap, csc_hops, last_hop),
                             prewarmed=True,
                         ):
-                            csc_kernel(csc_hops, ((ptr2,),), frs, cws, n_cap=n_cap)
+                            csc_kernel(csc_hops, last_hop, frs, cws, n_cap=n_cap)
             except Exception:
                 telemetry.inc("prewarm_errors", subsystem="graph_count")
 
@@ -894,18 +1001,11 @@ class GraphMirrors:
     def _pad128(n: int) -> int:
         return max(((n + 127) // 128) * 128, 128)
 
-    def _dense_pair(self, ns, db, spec1, spec2):
-        """Composed dense operator for one `->edge->node` spec pair:
-        A[local_src, local_dst] = number of 2-hop paths through the edge
-        table, bf16 on device. None if anything about the pair doesn't fit
-        the dense form: multi-table hops, a node table over
-        TPU_GRAPH_DENSE_MAX, or an operator past the limb limit of
-        chain_count_batch_dense (a multiplicity of 256 or more, which bf16
-        would round, or 255 x the largest column sum reaching 2**24, the
-        float32 accumulator's exact range)."""
-        import jax.numpy as jnp
-        from surrealdb_tpu import cnf
-
+    def _pair_parts(self, ns, db, spec1, spec2):
+        """What composing one `->edge->node` spec pair starts from: the
+        operator's cache key and generation, the two mirrors and the two
+        tables' compact id spaces. None for a pair no single operator
+        spans (a hop over several tables or directions, an empty table)."""
         srcs1, dirs1, fts1 = spec1
         srcs2, dirs2, fts2 = spec2
         if len(srcs1) != 1 or len(fts1) != 1 or len(dirs1) != 1:
@@ -923,10 +1023,31 @@ class GraphMirrors:
         n_s, n_d = len(sp_s["globals"]), len(sp_d["globals"])
         if not n_s or not n_d:
             return None
+        key = (ns, db, src_tb, dirs1[0], edge_tb, dirs2[0], dst_tb)
+        # the versions are read before either adjacency: an operator composed
+        # from a later state under this generation is recomposed by the next
+        # count, never served stale
+        return key, (m1.version, m2.version, n_s, n_d), m1, m2, sp_s, sp_d
+
+    def _dense_pair(self, ns, db, spec1, spec2):
+        """Composed dense operator for one `->edge->node` spec pair:
+        A[local_src, local_dst] = number of 2-hop paths through the edge
+        table, bf16 on device. None if anything about the pair doesn't fit
+        the dense form: multi-table hops, a node table over
+        TPU_GRAPH_DENSE_MAX, or an operator past the limb limit of
+        chain_count_batch_dense (a multiplicity of 256 or more, which bf16
+        would round, or 255 x the largest column sum reaching 2**24, the
+        float32 accumulator's exact range)."""
+        import jax.numpy as jnp
+        from surrealdb_tpu import cnf
+
+        parts = self._pair_parts(ns, db, spec1, spec2)
+        if parts is None:
+            return None
+        key, gen, m1, m2, sp_s, sp_d = parts
+        n_s, n_d = gen[2:]
         if max(n_s, n_d) > cnf.TPU_GRAPH_DENSE_MAX:
             return None
-        key = (ns, db, src_tb, dirs1[0], edge_tb, dirs2[0], dst_tb)
-        gen = (m1.version, m2.version, n_s, n_d)
         with self._lock:
             op = self._dense.get(key)
         if op is not None and op["gen"] == gen:
@@ -986,6 +1107,81 @@ class GraphMirrors:
         )
         return op
 
+    def _csc_pair(self, ns, db, spec1, spec2):
+        """Composed sparse operator for one `->edge->node` spec pair: the
+        2-hop paths src -> edge record -> dst as a node->node CSC in the two
+        tables' compact ids, shaped as PointerCsr.device_csc() shapes a
+        mirror's (dst-sorted `csrc` padded to a power of two with the
+        sentinel `n_pad`, bin bounds `cptr`, and the source-side `indptr`
+        whose differences are the out-degrees of a count's last pair), so
+        chain_count_batch sweeps one hop a pair over [lanes, n_pad + 1]
+        where the record-level mirrors cost two hops over the shared id
+        space (persons AND edge records: 2,097,152 slots for 24,328 persons
+        at SNB SF3). A repeated path stays a repeated entry: int32 sums wrap
+        the same in any order. Cached a generation like the dense operator,
+        the refusal too. None when no single operator spans the pair, or
+        when the operator would hold more entries than the two mirrors it
+        composes (a hop through a NODE table multiplies in-degrees by
+        out-degrees; through an edge table every record has one far end)."""
+        import jax.numpy as jnp
+
+        parts = self._pair_parts(ns, db, spec1, spec2)
+        if parts is None:
+            return None
+        key, gen, m1, m2, sp_s, sp_d = parts
+        with self._lock:
+            op = self._csc.get(key)
+        if op is not None and op["gen"] == gen:
+            return op if op["fits"] else None
+        # neither mirror's lock is held while composing: host_arrays() hands
+        # out one compaction's arrays, which later deltas leave as they are
+        ip1, ix1 = m1.host_arrays()
+        ip2, ix2 = m2.host_arrays()
+        t0 = _time.perf_counter()
+        coo = _compose_coo(
+            ip1, ix1, ip2, ix2, sp_s, sp_d, max_paths=int(ip1[-1]) + int(ip2[-1])
+        )
+        op = {"gen": gen, "fits": coo is not None}
+        if coo is not None:
+            ls, ld = coo
+            n_pad = _next_pow2(max(gen[2:]))
+            cptr, csrc = _csc_arrays(ls, ld, n_pad)
+            indptr = np.zeros(n_pad + 1, dtype=np.int32)
+            np.cumsum(np.bincount(ls, minlength=n_pad), out=indptr[1:])
+            nbytes = cptr.nbytes + csrc.nbytes + indptr.nbytes
+            t1 = _time.perf_counter()
+            telemetry.stage("graph_csc_build", t0, t1 - t0, bytes=nbytes)
+            op.update(
+                n_pad=n_pad,
+                src_tb=key[2],
+                dst_tb=key[6],
+                cptr=jnp.asarray(cptr),
+                csrc=jnp.asarray(csrc),
+                indptr=jnp.asarray(indptr),
+                space_src=sp_s,
+            )
+            # asynchronous, as device_csc()'s: the host's hand-off
+            telemetry.stage(
+                "graph_csc_upload", t1, _time.perf_counter() - t1, bytes=nbytes
+            )
+        with self._lock:
+            self._csc[key] = op
+        return op if op["fits"] else None
+
+    def _chain_pairs(self, ns, db, specs, pair_of):
+        """One composed operator a `->edge->node` pair of the chain, by
+        `pair_of` (_dense_pair or _csc_pair); None for an odd spec count or
+        a pair `pair_of` refuses."""
+        if len(specs) < 2 or len(specs) % 2 != 0:
+            return None
+        ops = []
+        for i in range(0, len(specs), 2):
+            op = pair_of(ns, db, specs[i], specs[i + 1])
+            if op is None:
+                return None
+            ops.append(op)
+        return ops
+
     def _dense_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
         """Count chain as composed dense matmuls (chain_count_batch_dense),
         exact under 2**31 at any degree. Returns None when the chain doesn't
@@ -994,14 +1190,9 @@ class GraphMirrors:
         import jax.numpy as jnp
         from surrealdb_tpu import cnf
 
-        if len(specs) < 2 or len(specs) % 2 != 0:
+        ops = self._chain_pairs(ns, db, specs, self._dense_pair)
+        if ops is None:
             return None
-        ops = []
-        for i in range(0, len(specs), 2):
-            op = self._dense_pair(ns, db, specs[i], specs[i + 1])
-            if op is None:
-                return None
-            ops.append(op)
         # chain spaces must line up: pair i's dst space is pair i+1's src
         for a, b in zip(ops, ops[1:]):
             if a["nd_pad"] != b["ns_pad"] or a["n_dst"] != b["n_src"]:
@@ -1009,18 +1200,11 @@ class GraphMirrors:
         _kernels()
         kernel = _JITTED["chain_count_batch_dense"]
         n0 = ops[0]["ns_pad"]
-        inv0 = ops[0]["space_src"]["inv"]
         fsz = _next_pow2(max(frontier.size, cnf.TPU_GRAPH_FRONTIER_PAD))
-        fr = np.full(fsz, n0, dtype=np.int32)
-        cw = np.zeros(fsz, dtype=np.int32)
-        j = 0
-        for g, c in zip(frontier.tolist(), counts.tolist()):
-            loc = inv0.get(int(g))
-            if loc is not None:
-                fr[j] = loc
-                cw[j] = c
-                j += 1
-        if j == 0:
+        fr, cw, seeded = _local_seeds(
+            ops[0]["space_src"]["inv"], frontier, counts, fsz, n0
+        )
+        if not seeded:
             _served("dense", t_enter)
             return 0
         As = tuple(op["A"] for op in ops[:-1])
@@ -1047,27 +1231,52 @@ class GraphMirrors:
 
     def _csc_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
         """Count chain in the scatter-free CSC cumsum form
-        (chain_count_batch): what no dense operator can hold."""
+        (chain_count_batch): what no dense operator can hold. A chain of
+        composable `->edge->node` pairs whose tables line up sweeps their
+        composed node->node operators (_csc_pair), one hop a pair in the
+        node table's compact ids; any other chain (a hop over several
+        tables, an odd spec count, pairs padded to different spaces) sweeps
+        the record-level mirrors, one hop a spec in the shared id space.
+        One kernel either way, chosen from what the chain is."""
         import jax.numpy as jnp
 
         from surrealdb_tpu import cnf
 
-        hop_mirrors = [self._hop_mirrors(ns, db, sp) for sp in specs]
-        if not all(hop_mirrors):
-            _served("csc", t_enter)
-            return 0
+        fsz = _next_pow2(max(frontier.size, cnf.TPU_GRAPH_FRONTIER_PAD))
+        ops = self._chain_pairs(ns, db, specs, self._csc_pair)
+        if ops is not None and any(
+            a["dst_tb"] != b["src_tb"] or a["n_pad"] != b["n_pad"]
+            for a, b in zip(ops, ops[1:])
+        ):
+            ops = None
+        if ops is not None:
+            operand, n_cap = "composed", ops[0]["n_pad"]
+            fr, cw, seeded = _local_seeds(
+                ops[0]["space_src"]["inv"], frontier, counts, fsz, n_cap
+            )
+            if not seeded:
+                _served("csc", t_enter, operand)
+                return 0
+            csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in ops[:-1])
+            last_hop = ((ops[-1]["indptr"],),)
+        else:
+            operand = "records"
+            hop_mirrors = [self._hop_mirrors(ns, db, sp) for sp in specs]
+            if not all(hop_mirrors):
+                _served("csc", t_enter, operand)
+                return 0
+            n_cap = _next_pow2(len(self.interner(ns, db)))
+            fr = np.full(fsz, n_cap, dtype=np.int32)
+            fr[: frontier.size] = frontier
+            cw = np.zeros(fsz, dtype=np.int32)
+            cw[: counts.size] = counts
+            csc_hops = tuple(
+                tuple(m.device_csc() for m in mirrors) for mirrors in hop_mirrors[:-1]
+            )
+            last_hop = tuple((m.device_arrays()[0],) for m in hop_mirrors[-1])
         _kernels()
         batch_kernel = _JITTED["chain_count_batch"]
-        n_cap = _next_pow2(len(self.interner(ns, db)))
-        fsz = _next_pow2(max(frontier.size, cnf.TPU_GRAPH_FRONTIER_PAD))
-        fr = np.full(fsz, n_cap, dtype=np.int32)
-        fr[: frontier.size] = frontier
-        cw = np.zeros(fsz, dtype=np.int32)
-        cw[: counts.size] = counts
-        csc_hops = tuple(
-            tuple(m.device_csc() for m in mirrors) for mirrors in hop_mirrors[:-1]
-        )
-        last_hop = tuple((m.device_arrays()[0],) for m in hop_mirrors[-1])
+        # the operands' ids: only chains over the same arrays coalesce
         key = (
             "gchain", fsz, n_cap, len(specs),
             tuple(id(a) for hop in csc_hops for pair in hop for a in pair),
@@ -1089,7 +1298,7 @@ class GraphMirrors:
                 )
             return lambda: np.asarray(out)[: len(payloads)].tolist()
 
-        _served("csc", t_enter)
+        _served("csc", t_enter, operand)
         return dispatch.submit(key, (fr, cw), runner)
 
     def _device_chain(
@@ -1132,7 +1341,7 @@ class GraphMirrors:
         cw[: counts.size] = counts
 
         if count_only:
-            _served("csc", t_enter)
+            _served("csc", t_enter, "records")
         hops, mds, out_sizes = [], [], []
         width = fsz
         for spec in specs:
