@@ -102,7 +102,7 @@ def require_tpu(backend: str, rows: int) -> None:
 # ------------------------------------------------------------------ data
 def gen_corpus(n: int, seed: int) -> np.ndarray:
     """Clustered corpus (mixture of gaussians: 4,000 centres, sigma 0.35),
-    the generator bench.py's config 2 uses, from --seed."""
+    rows of `BASELINE.json` config 2's width, from --seed."""
     rng = np.random.default_rng(seed)
     centers = rng.standard_normal((N_CLUSTERS, DIM)).astype(np.float32)
     out = np.empty((n, DIM), dtype=np.float32)
@@ -505,7 +505,7 @@ def check_recall(name: str, value: float, floor: float) -> None:
 
 # ------------------------------------------------------------------ phases
 def measure_rtt(n: int = 50) -> dict:
-    """A bare jitted dispatch + fetch round trip (bench.py's `rtt`)."""
+    """A bare jitted dispatch + fetch round trip."""
     import jax
     import jax.numpy as jnp
 

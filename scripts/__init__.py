@@ -1,1 +1,1 @@
-"""Repo tooling package (`python -m scripts.graftlint`, bench utilities)."""
+"""Repo tooling package (`python -m scripts.graftlint`, static analysis)."""

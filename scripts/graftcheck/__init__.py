@@ -33,9 +33,8 @@ point), a committed baseline (scripts/graftcheck/baseline.json;
 `--update-baseline` rewrites it), and a tier-1 gate in scripts/tier1.sh.
 The per-kernel audit report (rule results, declared collectives,
 lowered-shape matrix, HLO digest per shape key) is written as JSON and
-embedded as the `kernel_audit` debug-bundle section, so
-`bench_diff.py --bundles` flags collective/dtype/HLO drift between
-rounds.
+embedded as the `kernel_audit` debug-bundle section
+(`GET /debug/bundle`).
 
 `--fixtures` audits the seeded-violation kernels in fixtures.py instead
 (host callback, f64 promotion, undeclared collective, output-dtype

@@ -2,9 +2,9 @@
 
 `python -m scripts.graftcheck` writes this JSON; surrealdb_tpu/bundle.py
 embeds it as the `kernel_audit` debug-bundle section (path via
-cnf.KERNEL_AUDIT_REPORT), which rides into every bench artifact — so
-`bench_diff.py --bundles` can flag HLO-digest / declared-collective
-drift per kernel between rounds.
+cnf.KERNEL_AUDIT_REPORT), so `GET /debug/bundle` carries the HLO digest,
+declared collectives and rule verdicts of every audited kernel shape
+(tests/test_graftcheck.py::test_report_roundtrips_and_validates_in_bundle).
 """
 
 from __future__ import annotations

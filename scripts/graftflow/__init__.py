@@ -45,6 +45,5 @@ committed line-number-free baseline (scripts/graftflow/baseline.json via
 scripts/baselines.py), seeded-violation fixtures under
 tests/fixtures/graftflow/, a tier-1 gate (via `python -m scripts.analysis`),
 and a machine-readable `flow_audit` report embedded as debug-bundle
-section 11 (surrealdb-tpu-bundle/5) and drift-diffed by
-`bench_diff --bundles`.
+section 11 (surrealdb-tpu-bundle/5).
 """

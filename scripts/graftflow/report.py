@@ -2,12 +2,10 @@
 
 `python -m scripts.graftflow` writes this JSON (cnf.FLOW_AUDIT_REPORT);
 surrealdb_tpu/bundle.py embeds it as the `flow_audit` debug-bundle
-section (bundle schema surrealdb-tpu-bundle/5), which rides into every
-bench artifact — `check_bench_artifact` rejects a /5 bundle whose
-call-graph stats are empty (a silently-degraded analyzer must be
-INVALID, not vacuously green), and `bench_diff --bundles` flags
-round-over-round drift in the stats, the static lock graph, and the
-per-rule results.
+section (bundle schema surrealdb-tpu-bundle/5):
+tests/test_graftflow.py::test_bundle_embeds_flow_audit_section holds its
+call-graph stats above 0 (a silently-degraded analyzer must fail, not
+pass vacuously).
 """
 
 from __future__ import annotations

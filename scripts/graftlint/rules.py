@@ -910,7 +910,7 @@ def gl012(modules: List[Module]) -> List[Finding]:
 # the budget crossing detection and the store/fp-cap eviction; an ad-hoc
 # writer reaching into the private store, the activation/tally tables, or
 # the entry class would bypass all three — and break the conservation
-# property the bench validator enforces. Outside accounting.py, touching
+# property tests/test_accounting.py holds. Outside accounting.py, touching
 # any private member of the accounting module is a finding.
 GL013_ALLOWED_FILES = frozenset({"surrealdb_tpu/accounting.py"})
 GL013_ACCT_MODULE = "surrealdb_tpu.accounting"

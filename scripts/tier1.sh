@@ -29,8 +29,8 @@
 # carry their own diagnostics. If the process died before the hook could
 # run, a skeleton bundle is captured from a fresh interpreter as a fallback.
 #
-# Opt-in perf companion (run when touching the dispatch/kNN hot path):
-#   python scripts/bench_gate.py   # smoke-scale concurrent-kNN floor gate
+# Speed is not a tier-1 matter: it is measured on the chip, by
+# `python3 benchmarks/run.py --workload <cell>` (BENCHMARK.json, PERF.md).
 #
 # Opt-in FULL-suite sanitizer (mines lock-order edges the smoke subset
 # cannot reach — e.g. the group-commit flusher and delta-feed apply sites):

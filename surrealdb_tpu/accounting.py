@@ -26,8 +26,8 @@ What gets charged, and where:
   time with a per-node breakdown (``cluster/executor.py``).
 
 Surfaces: system-gated ``GET /tenants`` (``?cluster=1`` federates
-node-tagged member stores), the debug bundle's ``tenants`` section,
-``INFO FOR ROOT``, and bench per-window embeds.
+node-tagged member stores), the debug bundle's ``tenants`` section
+and ``INFO FOR ROOT``.
 
 Budgets are observe-only (the advisor's observe->propose contract):
 ``SURREAL_TENANT_BUDGET_{CPU_S,DISPATCH_S,ROWS,BYTES}`` define soft
@@ -406,7 +406,7 @@ def export_state(limit: int = 100) -> List[dict]:
 
 
 def reset() -> None:
-    """Drop every meter (tests / bench accounting windows)."""
+    """Drop every meter (tests, measurement windows)."""
     global _evicted
     with _lock:
         _store.clear()

@@ -31,8 +31,9 @@ evidence refreshed — never a duplicate) while its evidence persists, and
 EXPIRES after `SURREAL_ADVISOR_EXPIRE_SWEEPS` consecutive sweeps without
 it (kept in a bounded expired ring; `advisor.expired` event). Every
 evidence entry is machine-checkable: it names the PLANE and METRIC it was
-read from, so a consumer (scripts/check_bench_artifact.py rule 14) can
-resolve the chain against the same artifact's embedded plane state.
+read from, so a consumer can resolve the chain against that plane's own
+endpoint or bundle section (tests/test_advisor.py resolves index.create's
+fingerprints in the stats store).
 
 Construction goes through ONE door, :func:`propose` — graftlint GL014
 enforces statically that no call site builds a proposal record ad hoc or
@@ -44,7 +45,7 @@ Surfaces: system-gated ``GET /advisor`` (``?cluster=1`` federates via the
 two nodes is ONE record, node-tagged), ``INFO FOR ROOT``
 (``system.advisor``), debug-bundle section 15 (schema bundle/8),
 ``advisor_proposals{kind,severity}`` gauges + ``advisor_sweep`` duration
-metrics, and per-phase embeds in bench config 12.
+metrics.
 
 Observe-only contract: nothing here mutates engine state, schedules a
 rebuild, or touches a knob. PR 18+'s opt-in apply mode is the only
@@ -83,8 +84,8 @@ KINDS: Dict[str, str] = {
 
 SEVERITIES = ("info", "warn", "critical")
 
-# evidence plane vocabulary — check_bench_artifact resolves pointers by
-# plane name, so the set is closed like the kinds
+# evidence plane vocabulary — a consumer resolves pointers by plane
+# name, so the set is closed like the kinds
 EVIDENCE_PLANES = frozenset({"stats", "accounting", "telemetry", "idx", "cluster"})
 
 _EVIDENCE_KEYS = ("plane", "metric", "window", "value", "threshold")
@@ -782,8 +783,8 @@ def ensure_started(ds=None) -> bool:
 
 
 def pause() -> None:
-    """Park the sweep loop without stopping the service (the bench
-    overhead A/B measures with the advisor parked vs live)."""
+    """Park the sweep loop without stopping the service (an overhead
+    A/B measures with the advisor parked vs live)."""
     _paused.set()
 
 
@@ -882,7 +883,7 @@ def export_state(limit: int = 100) -> List[dict]:
 
 
 def reset() -> None:
-    """Drop every proposal + sweep statistic (tests / bench windows).
+    """Drop every proposal + sweep statistic (tests, measurement windows).
     The service keeps running; the counter baseline RE-PRIMES to the
     current telemetry counters, so the next sweep's decline deltas
     measure growth since THIS reset — not since process start (clearing

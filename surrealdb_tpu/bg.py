@@ -24,7 +24,7 @@ attribute everything):
 The registry is process-global (like telemetry/tracing): tasks carry an
 `owner` token (id of the owning Datastore) so per-datastore teardown only
 joins its own work. Finished tasks are kept in a bounded ring
-(cnf.BG_REGISTRY_CAP) for the debug bundle and bench overlap accounting.
+(cnf.BG_REGISTRY_CAP) for the debug bundle (`tasks`).
 
 Knobs: SURREAL_BG_WATCHDOG, SURREAL_BG_WATCHDOG_INTERVAL,
 SURREAL_BG_WATCHDOG_DEADLINE (per-task override at register time).
@@ -628,7 +628,7 @@ def shutdown(owner: Optional[int] = None, timeout: float = 10.0) -> bool:
 
 def wait_idle(timeout: float = 30.0, owner: Optional[int] = None) -> bool:
     """Block until no scheduled/running task (of `owner`, or any) remains —
-    test/bench determinism helper, never used on the query path."""
+    test determinism helper, never used on the query path."""
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         with _lock:
@@ -679,9 +679,8 @@ def snapshot() -> dict:
 
 
 def window(t0: float, t1: Optional[float] = None) -> List[dict]:
-    """Tasks whose RUN overlapped [t0, t1] wall-clock (t1 = now): the
-    bench's structural overlap accounting — which background work ran
-    inside a measurement window, and for how long."""
+    """Tasks whose RUN overlapped [t0, t1] wall-clock (t1 = now): which
+    background work ran inside a measurement window, and for how long."""
     if t1 is None:
         t1 = time.time()
     out = []

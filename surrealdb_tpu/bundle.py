@@ -40,9 +40,9 @@ versioned document — the artifact you attach to any perf report:
                      cnf.FLOW_AUDIT_REPORT, or computed in-process
                      (memoized; the analysis is pure AST) when no
                      `python -m scripts.graftflow` run wrote the file.
-                     check_bench_artifact rejects a /5 bundle whose
-                     call-graph stats are empty: a silently-degraded
-                     analyzer must be INVALID, not vacuously green.
+                     tests/test_graftflow.py holds the call-graph
+                     stats above 0: a silently-degraded analyzer
+                     must fail, not pass vacuously.
 12. `statements`   — the workload statistics plane (stats.py): per-
                      statement-fingerprint cumulative stats — calls,
                      errors, latency quantiles, rows in/out, the
@@ -68,8 +68,7 @@ versioned document — the artifact you attach to any perf report:
                      and the recent eviction log (new in bundle/9).
 
 Served by `GET /debug/bundle` (system-user-gated) and embedded via
-`INFO FOR ROOT` (`system.bundle`); bench.py embeds one per artifact so a
-perf number always ships with the engine state that produced it. Works
+`INFO FOR ROOT` (`system.bundle`). Works
 with `ds=None` too (global registries only) — the tier-1 failure hook
 uses that to dump diagnostics from a dying test process.
 
@@ -168,7 +167,7 @@ _flow_audit_lock = threading.Lock()
 def _flow_audit_state() -> Dict[str, Any]:
     """The last graftflow flow_audit report. File handoff first (the
     tier-1 gate's run, or the conftest prime); when absent — a bare
-    pytest or bench process in a repo checkout — the analysis runs
+    pytest process in a repo checkout — the analysis runs
     in-process once under a lock (pure AST, no jax) and is memoized.
     A generate() failure is NOT cached: the next bundle retries rather
     than latching every later /5 artifact INVALID on a transient."""

@@ -370,8 +370,8 @@ class ClusterExecutor:
             thread_name_prefix="cluster-scatter",
         )
         self.admission = _Admission()
-        # slowest per-shard profile since the last reset (bench artifacts
-        # embed it; raw lock — leaf-only, never nests)
+        # slowest per-shard profile since the last reset (raw lock —
+        # leaf-only, never nests)
         self._profile_lock = threading.Lock()
         self._slowest_profile: Optional[dict] = None
         # write-degradation watermark at attach: the pipeline pushdowns
@@ -409,7 +409,7 @@ class ClusterExecutor:
 
     def slowest_profile(self) -> Optional[dict]:
         """The slowest scattered statement's per-shard profile since the
-        last reset (bench config 7/8 artifacts embed it)."""
+        last reset."""
         with self._profile_lock:
             return dict(self._slowest_profile) if self._slowest_profile else None
 

@@ -10,16 +10,12 @@ events are simply absent. The request still answers 200; the hole is the
 signal.
 
 Used by net/server.py for `GET /metrics?cluster=1`,
-`GET /debug/bundle?cluster=1` and `GET /events?cluster=1`, and by bench.py
-for the config-7/8 artifact embeds.
+`GET /debug/bundle?cluster=1` and `GET /events?cluster=1`.
 
 IN-PROCESS caveat: telemetry / events / tracing registries are
-process-global, so the in-process clusters the tests and bench spin up
+process-global, so the in-process clusters the tests spin up
 (N Datastores, one interpreter) report the SAME registry state under each
-node label — per-node attribution is only real across PROCESSES. bench
-marks its embeds `in_process: true` so artifact readers know which regime
-produced them; the multi-process scale-out re-measure (ROADMAP) is where
-the labels start carrying distinct state.
+node label — per-node attribution is only real across PROCESSES.
 """
 
 from __future__ import annotations

@@ -30,13 +30,15 @@ channel:
 
 `join` / `leave` / `replace` compose the same flow. A replace of a DEAD
 node tolerates the corpse during both broadcasts (it is in `removed`), and
-its records stream from their surviving replicas — that is the chaos-bench
-scenario: kill a node mid-window, join its replacement, zero wrong answers.
+its records stream from their surviving replicas — kill a node, join its
+replacement, zero wrong answers
+(tests/test_elastic.py::test_replace_dead_node_zero_wrong_answers).
 
 Requests carry the sender's epoch; `rpc.handle` counts mismatches
 (`cluster_epoch_mismatch_total`) and answers with the local epoch, so a
-member stuck on an old ring version is visible as peer drift in the
-federated bundle (`bench_diff --bundles`).
+member stuck on an old ring version reads behind its peers in the
+federated bundle (`nodes.<id>.engine.cluster.epoch` of
+`GET /debug/bundle?cluster=1`).
 """
 
 from __future__ import annotations

@@ -76,7 +76,7 @@ def handle(ds, req: Dict[str, Any]) -> Dict[str, Any]:
         and op not in ("member_update", "membership")
     ):
         # one side of this call routed under a different ring version —
-        # counted here, flagged as peer drift by bench_diff --bundles
+        # counted here; the federated bundle shows each member's epoch
         telemetry.inc("cluster_epoch_mismatch_total", op=op)
     try:
         if fn is None:

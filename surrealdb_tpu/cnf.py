@@ -160,9 +160,10 @@ COLUMN_BLOCK_SIZE = _env_int("SURREAL_COLUMN_BLOCK_SIZE", 4096)
 # the background so the next query starts fresh. Query-time rebuilds are
 # rate-limited by the same window (stale + inside the window = row path).
 COLUMN_REBUILD_DEBOUNCE_SECS = _env_float("SURREAL_COLUMN_REBUILD_DEBOUNCE", 0.5)
-# lowerable residual WHERE conjuncts of a kNN statement prefilter the exact
-# search strategies (top-k among matching rows — the reference's condition-
-# checker semantics); IVF strategies keep post-filtering
+# lowerable residual WHERE conjuncts of a kNN statement prefilter every
+# search strategy, exact and IVF (top-k among matching rows — the
+# reference's condition-checker semantics; tests/test_knn_strategies.py
+# holds each strategy to it); only the txn overlay merge post-filters
 KNN_COLUMN_PREFILTER = _env_bool("SURREAL_KNN_COLUMN_PREFILTER", True)
 # vectorized SELECT pipeline (ops/pipeline.py): route large numeric masks /
 # sorts through a jitted device kernel. Off until the accelerator
@@ -310,8 +311,8 @@ TRACE_MAX_SPANS = _env_int("SURREAL_TRACE_MAX_SPANS", 512)
 # shape, oldest-by-use evicted past the cap (evictions counted). The
 # always-on sampling profiler wakes PROFILE_HZ times a second and folds
 # one sys._current_frames() snapshot per tick; 0 disables the service
-# entirely. The default rate is deliberately low — the measured overhead
-# on bench config 2 must stay <=3% (scripts/bench_gate.py enforces it).
+# entirely. The default rate is deliberately low; what a tick costs a
+# served statement on the chip is not measured.
 # PROFILE_MAX_STACKS bounds the distinct folded-stack series (overflow
 # folds into a per-thread <overflow> bucket).
 STATEMENTS_STORE_SIZE = _env_int("SURREAL_STATEMENTS_STORE_SIZE", 512)
@@ -323,9 +324,7 @@ PROFILE_MAX_STACKS = _env_int("SURREAL_PROFILE_MAX_STACKS", 512)
 # fingerprint drill-down entries per tenant). Budgets are OBSERVE-ONLY
 # soft limits: a plain float applies to every tenant, "ns:limit[,...]"
 # per namespace; a crossing emits tenant.budget_exceeded + bumps
-# tenant_budget_breaches{ns} — proposals, never enforcement. Measured
-# accounting overhead on bench config 2 must stay <=3%
-# (scripts/bench_gate.py enforces it, same gate as the profiler).
+# tenant_budget_breaches{ns} — proposals, never enforcement.
 TENANT_ACCOUNTING = _env_bool("SURREAL_TENANT_ACCOUNTING", True)
 TENANT_STORE_SIZE = _env_int("SURREAL_TENANT_STORE_SIZE", 256)
 TENANT_FP_CAP = _env_int("SURREAL_TENANT_FP_CAP", 32)
@@ -344,9 +343,7 @@ TENANT_BUDGET_BYTES = os.environ.get("SURREAL_TENANT_BUDGET_BYTES", "")
 # the per-call scanned-rows break-even floor for index.create,
 # DECLINE_MIN the per-sweep mirror-decline drift floor, SKEW_RATIO the
 # max/mean per-node scatter skew for cluster.rebalance, BREACH_MIN the
-# budget-breach recurrence floor. Measured sweep overhead on bench
-# config 2 must stay <=3% (scripts/bench_gate.py, same gate as the
-# profiler and accounting planes).
+# budget-breach recurrence floor.
 ADVISOR = _env_bool("SURREAL_ADVISOR", True)
 ADVISOR_INTERVAL_SECS = _env_float("SURREAL_ADVISOR_INTERVAL", 5.0)
 ADVISOR_STORE_SIZE = _env_int("SURREAL_ADVISOR_STORE_SIZE", 128)

@@ -545,7 +545,7 @@ class DispatchQueue:
 
     def stats(self) -> Dict[str, float]:
         """Scalar counters only — consumers diff these numerically (slow-
-        query records, bench accounting windows)."""
+        query records, the benchmark's `dispatch.width_mean`)."""
         with self._lock:
             return {
                 "submitted": self.submitted,
@@ -561,7 +561,7 @@ class DispatchQueue:
 
     def width_distribution(self) -> Dict[int, int]:
         """{batch width: dispatch count} since startup. Diff two snapshots
-        to attribute a measurement window (bench emits this per config so a
-        throughput collapse is diagnosable from the artifact alone)."""
+        to attribute a measurement window (the benchmark's `correct` line
+        reads it, and the bundle's `engine` section carries it)."""
         with self._lock:
             return dict(self.width_counts)

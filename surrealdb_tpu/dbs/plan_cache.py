@@ -854,7 +854,7 @@ class PlanCache:
             self._emit_evict(None, "epoch")
 
     def clear(self) -> None:
-        """Drop everything (tests / bench cold windows)."""
+        """Drop everything (tests, cold measurement windows)."""
         with self._lock:
             self._entries.clear()
             self._warm.clear()
@@ -862,7 +862,7 @@ class PlanCache:
             self._index_defs.clear()
 
     def reset_window(self) -> None:
-        """Zero counters and timing but KEEP entries — the bench's warm
+        """Zero counters and timing but KEEP entries — a warm
         measurement window starts here."""
         with self._lock:
             self._timing.clear()
@@ -907,8 +907,8 @@ class PlanCache:
         return {"cold_us": avg("cold"), "warm_us": avg("warm")}
 
     def window_stats(self, per_fp_limit: int = 20) -> dict:
-        """The bench embed: window hit rates + per-fingerprint pre-kernel
-        overhead, warm vs cold."""
+        """Window hit rates + per-fingerprint pre-kernel overhead, warm vs
+        cold, since `reset_window()`."""
         with self._lock:
             hits = dict(self._hits)
             misses = sum(self._misses.values())

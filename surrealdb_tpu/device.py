@@ -8,8 +8,8 @@ Two facts every entry point needs before the first kernel compiles:
   the environment: nothing is set in code, JAX reads the variable itself.
   Unset: `<checkout>/.jax_cache`, a fixed path (the path is part of the
   cache key, so a directory that moves never hits). `Datastore.__init__`
-  calls it, which covers the server, the embedded library, `bench.py` and
-  `chip_smoke.py`; `__graft_entry__.py` compiles without a datastore and
+  calls it, which covers the server, the embedded library,
+  `benchmarks/run.py` and `chip_smoke.py`; `__graft_entry__.py` compiles without a datastore and
   calls it itself.
 - `describe()` — initialises the JAX backend and says what it is:
   platform, device kind, device count and the JAX/jaxlib/libtpu versions.

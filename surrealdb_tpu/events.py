@@ -166,7 +166,7 @@ def last_seq() -> int:
 
 
 def reset() -> None:
-    """Clear the ring (tests / bench window isolation); seq keeps counting
+    """Clear the ring (tests, window isolation); seq keeps counting
     so `since()` cursors from before the reset stay monotonic."""
     with _lock:
         _ring.clear()

@@ -445,7 +445,7 @@ class ColumnMirrors:
                 self._running.discard(key3)
 
     def wait_rebuild(self, timeout: float = 30.0) -> bool:
-        """Block until no rebuild timer or build is pending (test/bench
+        """Block until no rebuild timer or build is pending (test
         determinism helper, never used on the query path)."""
         deadline = _time.monotonic() + timeout
         while _time.monotonic() < deadline:

@@ -790,7 +790,7 @@ class GraphMirrors:
                 self._prewarm_running.discard(key3)
 
     def wait_prewarm(self, timeout: float = 30.0) -> bool:
-        """Block until no prewarm timer or build is pending (test/bench
+        """Block until no prewarm timer or build is pending (test
         determinism helper, never used on the query path)."""
         import time as _time
 

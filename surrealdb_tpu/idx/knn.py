@@ -437,7 +437,7 @@ class VectorMirror:
 
     def wait_ivf(self, timeout: float = 60.0) -> bool:
         """Block until the in-flight training round (if any) finishes —
-        test/bench determinism helper, never used on the query path."""
+        test determinism helper, never used on the query path."""
         deadline = _time.monotonic() + timeout
         while _time.monotonic() < deadline:
             with self._lock:
@@ -830,6 +830,16 @@ class KnnPlan(_KnnExecutorMixin):
                         "exact-sharded(ivf-training)" if want_ivf else "exact-sharded"
                     )
                     key = ("knn-sharded", id(matrix), metric, k)
+                    # columnar residual prefilter, as every other strategy:
+                    # the matching slots AND the live ones, sharded as the
+                    # live mask is, the mask's content in the dispatch key
+                    if self.prefilter is not None:
+                        pre = self._prefilter_slot_mask(ctx, rids, len(mask))
+                        if pre is not None:
+                            import jax
+
+                            mask_dev = jax.device_put(mask & pre[0], mask_dev.sharding)
+                            key = key + pre[1]
 
                     def runner(qs):
                         from surrealdb_tpu import compile_log
@@ -963,9 +973,9 @@ class KnnPlan(_KnnExecutorMixin):
             else:
                 # CPU serving path: an already-trained quantizer serves ANN on
                 # host too (probe + exact rerank, idx/ivf.py search_host) — the
-                # same sublinear contract as the device path, and the honest
-                # CPU-ANN baseline for the bench. Never trains here (training
-                # needs the device matrix); exact scan otherwise.
+                # same sublinear contract as the device path. Never trains
+                # here (training needs the device matrix); exact scan
+                # otherwise.
                 ivf = mirror.ivf
                 if (
                     approx_ok

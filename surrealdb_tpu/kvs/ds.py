@@ -447,7 +447,7 @@ class Datastore:
         from surrealdb_tpu.dbs.session import Session
 
         # the executor level of the span tree: a root trace for embedded
-        # callers (SDK/bench), a child span under an HTTP/WS/RPC ingress.
+        # callers (SDK), a child span under an HTTP/WS/RPC ingress.
         # The sql label is trace-only (tracing never feeds metric families,
         # so truncated statement text can't mint unbounded series).
         with tracing.request("execute", sql=text[:120]):

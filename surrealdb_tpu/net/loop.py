@@ -35,8 +35,8 @@ Scale beyond the fd rlimit: connections are transport-agnostic. A
 `VirtualConn` (attach_virtual) runs the same state machine — HTTP
 parse, QoS admission, executor dispatch, bounded write queue — with
 byte buffers fed/drained by the caller instead of a kernel socket, so
-the connection-scale bench can hold 20k+ concurrent connections on a
-container whose hard RLIMIT_NOFILE is 20000.
+a test can hold more concurrent connections than the container's
+RLIMIT_NOFILE allows sockets.
 
 This module is event-loop-marked (graftlint GL016): blocking socket
 calls (`recv`/`sendall`/`accept` outside the `_nb_*` nonblocking

@@ -382,7 +382,7 @@ def queue_depths() -> Dict[str, int]:
 
 
 def reset() -> None:
-    """Drop all admission state (tests / bench windows)."""
+    """Drop all admission state (tests, measurement windows)."""
     global _internal_inflight, _vclock
     with _lock:
         _tenants.clear()

@@ -254,8 +254,7 @@ def sharded_frontier_hop(
     device count, frontier_mask: [F]. Each device expands its frontier slice
     with a fixed-width (max_degree) gather — compiler-friendly static shapes —
     then results all_gather back. Returns (neighbors [F*max_degree], mask).
-    Dedup happens host-side between hops (sort-unique on small id sets) or
-    on-device for the bench path.
+    Dedup happens host-side between hops (sort-unique on small id sets).
     """
 
     @functools.partial(

@@ -22,16 +22,14 @@ one `sys._current_frames()` snapshot per tick into bounded aggregates:
   exists to find.
 
 Overhead contract: one `sys._current_frames()` snapshot + a bounded
-frame walk per tick, everything precomputed outside the state lock. At
-the default rate the measured overhead on bench config 2 must stay <=3%
-(bench.py measures it sampler-on vs sampler-paused; scripts/bench_gate.py
-enforces the ceiling). `SURREAL_PROFILE_HZ=0` disables the service
-entirely; `pause()`/`resume()` gate sampling without stopping the thread
-(the bench A/B uses this).
+frame walk per tick, everything precomputed outside the state lock. What
+a tick costs a served statement on the chip is not measured.
+`SURREAL_PROFILE_HZ=0` disables the service entirely;
+`pause()`/`resume()` gate sampling without stopping the thread
+(tests/test_stats.py::test_profiler_service_runs_and_pauses).
 
-Exported as the debug bundle's `profiler` section (bundle.py), inside
-`GET /statements` artifacts via bench.py, and as raw folded stacks for
-flamegraph tooling.
+Exported as the debug bundle's `profiler` section (bundle.py) and as raw
+folded stacks for flamegraph tooling.
 """
 
 from __future__ import annotations
@@ -86,8 +84,8 @@ def ensure_started() -> bool:
 
 
 def pause() -> None:
-    """Stop taking samples without stopping the service (the bench
-    overhead A/B measures with the sampler parked vs live)."""
+    """Stop taking samples without stopping the service (an overhead
+    A/B measures with the sampler parked vs live)."""
     _paused.set()
 
 
@@ -224,7 +222,7 @@ def report(top: int = 50) -> dict:
 
 
 def summary(top: int = 5) -> dict:
-    """Compact per-window embed for bench artifact config lines."""
+    """Compact per-window form of `report()`."""
     full = report(top=top)
     return {
         "hz": full["hz"],
@@ -245,7 +243,7 @@ def folded_text() -> str:
 
 
 def reset() -> None:
-    """Drop aggregates (tests / bench accounting windows). The service
+    """Drop aggregates (tests, measurement windows). The service
     keeps running; counters restart from zero."""
     global _samples_total, _ticks, _dropped
     with _lock:

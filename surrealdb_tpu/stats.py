@@ -38,9 +38,9 @@ flip detection cannot be bypassed by an ad-hoc writer.
 
 Surfaces: `GET /statements` (system-gated; `?cluster=1` federates
 node-tagged per-member stores through cluster/federation.py),
-`INFO FOR ROOT` (`system.statements`), debug-bundle section 12
-(bundle.py), per-config embeds in bench artifacts (schema /12) and
-`scripts/bench_diff.py --statements` regression naming.
+`INFO FOR ROOT` (`system.statements`) and debug-bundle section 12
+(bundle.py). Two snapshots name a plan-mix flip's fingerprint on their own
+(tests/test_stats.py::test_drift_proof_end_to_end).
 """
 
 from __future__ import annotations
@@ -506,7 +506,7 @@ def export_state(limit: int = 100) -> List[dict]:
 
 
 def reset() -> None:
-    """Drop every entry (tests / bench accounting windows)."""
+    """Drop every entry (tests, measurement windows)."""
     global _evicted
     with _lock:
         _store.clear()

@@ -18,7 +18,7 @@ environment has no OTLP collector, so the equivalent surface is:
   enabled by `--profile` / SURREAL_PROFILE=1 (spans cost nothing when
   disabled), drained via `snapshot()` or INFO-style inspection;
 - `jax.profiler` hooks: `start_trace()/stop_trace()` capture a device
-  trace directory next to bench artifacts, and `trace_annotation()`
+  trace directory, and `trace_annotation()`
   labels dispatch launch/collect phases inside it. A trace that was
   asked for and cannot start raises.
 """

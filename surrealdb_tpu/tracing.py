@@ -7,7 +7,7 @@ has no collector, so finished traces land in a bounded in-memory store with
 tail-based sampling, served by `GET /trace/:id` + `GET /traces` and
 exportable as Chrome-trace JSON (`?format=chrome`) so a request tree drops
 into chrome://tracing / Perfetto next to the `jax.profiler` device traces
-that `bench.py --profile` captures.
+that `benchmarks/run.py --trace 1` captures.
 
 Mechanics:
 
@@ -479,8 +479,7 @@ def _finish(tr: Trace, name: str, dur: float) -> bool:
             # only runs on an already-full store, once per RETAINED trace
             # (sampled-out requests never reach it), and stops at the first
             # weak entry — for the default 512-entry store this is
-            # microseconds under the lock. bench.py additionally resets
-            # the store per accounting window so its hot path never fills.
+            # microseconds under the lock.
             victim = next(
                 (
                     k

@@ -22,8 +22,7 @@ The contracts under test:
   throttled;
 - surfacing: system-gated GET /tenants (sortable, 401 for non-system
   users, `?cluster=1` federated node-tagged from a 2-node cluster),
-  INFO FOR ROOT, bundle section 14, `/sql` byte metering, and
-  `bench_diff --tenants` naming a cost-share shift between artifacts;
+  INFO FOR ROOT, bundle section 14, `/sql` byte metering;
 - coordinator-only statements (cluster routing refusals): their error
   ring entries carry session{ns, db} instead of vanishing.
 """
@@ -524,51 +523,3 @@ def test_coordinator_refusal_keeps_session_in_error_ring(cluster2):
     assert entry["session"]["db"] == "ringdb" and entry.get("fingerprint")
     e = accounting.get("ringns", "ringdb")
     assert e is not None and e["errors"] >= 1 and e["statements"] >= 1
-
-
-# ============================================================ bench_diff
-def _artifact(per_tenant, config="11"):
-    return {
-        "schema": "surrealdb-tpu-bench/13",
-        "results": [{
-            "metric": "multi_tenant_mix", "value": 1.0, "config": config,
-            "tenants": {
-                "per_tenant": per_tenant, "global": {}, "count": len(per_tenant),
-                "evicted": 0,
-            },
-        }],
-    }
-
-
-def test_bench_diff_tenants_names_share_shift(capsys):
-    from scripts.bench_diff import diff_tenants, main
-
-    quiet_a = {
-        "ns": "acme", "db": "app", "statements": 100, "exec_s": 1.0,
-        "cpu_s": 0.5, "dispatch_s": 0.1, "rows_scanned": 1000.0,
-        "breaches": {},
-    }
-    quiet_b = dict(quiet_a, ns="globex")
-    noisy_b = dict(
-        quiet_b, exec_s=9.0, cpu_s=6.0, rows_scanned=90000.0,
-        breaches={"rows_scanned": 1},
-    )
-    rows = diff_tenants(
-        _artifact([quiet_a, quiet_b]), _artifact([quiet_a, noisy_b])
-    )
-    assert len(rows) == 2
-    flagged = {r["tenant"]: r["flags"] for r in rows}
-    assert any("share" in f for f in flagged["globex/app"])
-    assert any("rows_scanned/stmt" in f for f in flagged["globex/app"])
-    assert any("breaches" in f for f in flagged["globex/app"])
-    # the CLI path: exit 1 when flagged, tenant named
-    import tempfile
-
-    with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as fa:
-        json.dump(_artifact([quiet_a, quiet_b]), fa)
-    with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as fb:
-        json.dump(_artifact([quiet_a, noisy_b]), fb)
-    rc = main(["--tenants", fa.name, fb.name])
-    out = capsys.readouterr().out
-    assert rc == 1 and "globex/app" in out
-    assert main(["--tenants", fa.name, fa.name]) == 0

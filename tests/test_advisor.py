@@ -16,8 +16,7 @@ Covers the lifecycle contract the bench artifact replays:
 - the dead-member contract (satellite): /statements?cluster=1,
   /tenants?cluster=1 and /advisor?cluster=1 against a cluster with a
   KILLED node answer 200 with the member marked unreachable — a partial
-  view is labeled partial, never silently shrunk;
-- bench_diff --advisor: appeared / resolved / flapped attribution.
+  view is labeled partial, never silently shrunk.
 """
 
 import json
@@ -361,36 +360,3 @@ def test_killed_member_marks_unreachable_not_silent(cluster2):
     status, body = c.http_get("/advisor?cluster=1")
     live = [p for p in json.loads(body)["proposals"] if p.get("id")]
     assert live and "n1" in live[0]["nodes"]
-
-
-# ============================================================ bench_diff
-def test_bench_diff_advisor_names_lifecycle(capsys):
-    """--advisor: appeared / resolved / flapped between two artifacts."""
-    import scripts.bench_diff as bd
-
-    def art(phases, expired):
-        return {"results": [{
-            "config": "12", "metric": "advisor_shift",
-            "advisor": {"phases": phases, "expired": expired},
-        }]}
-
-    stay = {"id": "aaa", "kind": "index.create", "subject": "t:1",
-            "severity": "info", "last_seen_ts": 1.0}
-    gone = {"id": "bbb", "kind": "ivf.retrain", "subject": "t.t.i.e",
-            "severity": "warn", "last_seen_ts": 1.0}
-    old = art([{"phase": "p", "proposals": [stay, gone]}], [])
-    flap = dict(stay, last_seen_ts=9.0)
-    newp = {"id": "ccc", "kind": "tenant.quota_review", "subject": "t.t",
-            "severity": "warn", "last_seen_ts": 9.0}
-    new = art(
-        [{"phase": "p", "proposals": [flap, newp]}],
-        [dict(stay, last_seen_ts=5.0), dict(gone, last_seen_ts=5.0)],
-    )
-    rep = bd.diff_advisor(old, new)
-    assert [p["id"] for p in rep["appeared"]] == ["ccc"]
-    assert "bbb" in [p["id"] for p in rep["resolved"]]
-    # 'aaa' expired mid-round then re-armed (live with a NEWER ts): flapped
-    assert [p["id"] for p in rep["flapped"]] == ["aaa"]
-    assert bd._main_advisor(old, new) == 1
-    out = capsys.readouterr().out
-    assert "flapped" in out and "tenant.quota_review" in out

@@ -80,6 +80,42 @@ def test_delta_feed_applies_and_serves_in_key_order(small_bulk):
         ds.close()
 
 
+@pytest.mark.parametrize("feed", [True, False], ids=["feed-on", "feed-off"])
+def test_sustained_ingest_never_serves_stale(small_bulk, feed):
+    """A serving table ingesting steadily: rounds of a bulk INSERT and, at
+    once, a filtered SELECT against the live mirror. With the delta feed
+    on the mirror is current after every commit and serves; with it off
+    every bulk op leaves the mirror behind and the query falls to the row
+    path. Either way each round's answer is the row path's, never a mask
+    of the table as it was."""
+    small_bulk.setattr(cnf, "COLUMN_DELTA_FEED", feed)
+    ds = Datastore("memory")
+    sql = "SELECT VALUE id FROM t WHERE flag = true AND val < 10"
+    try:
+        q(ds, "DEFINE TABLE t SCHEMALESS")
+        q(ds, "INSERT INTO t $rows RETURN NONE",
+          {"rows": [{"id": i, "val": i % 100, "flag": i % 4 == 0} for i in range(256)]})
+        q(ds, sql)  # builds the mirror
+        assert ds.column_mirrors.get(KEY3) is not None
+        applied0 = delta_outcomes().get("applied", 0)
+        rounds, batch = 4, 64
+        for rnd in range(rounds):
+            base = 10_000 + rnd * batch
+            q(ds, "INSERT INTO t $rows RETURN NONE",
+              {"rows": [{"id": base + j, "val": 5, "flag": j % 2 == 0} for j in range(batch)]})
+            got = [str(x) for x in q(ds, sql)]
+            small_bulk.setattr(cnf, "COLUMN_MIRROR", False)
+            want = [str(x) for x in q(ds, sql)]
+            small_bulk.setattr(cnf, "COLUMN_MIRROR", True)
+            assert got == want, (rnd, len(got), len(want))
+            assert len(got) == 9 + (rnd + 1) * batch // 2
+        # on: one delta a commit; off: bulk.py offers the mirror nothing
+        applied = delta_outcomes().get("applied", 0) - applied0
+        assert applied == (rounds if feed else 0)
+    finally:
+        ds.close()
+
+
 def _rand_rows(rng, n, base):
     """Type-mixed rows: ints/floats/strings/bools/datetimes/NONE/missing,
     nested objects, lists (nested-unsafe parents), record links."""

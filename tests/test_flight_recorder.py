@@ -1,11 +1,9 @@
 """Flight recorder: background-task registry lifecycle + watchdog stalls,
 XLA compile-event attribution (one trace owns the compile, riders see a
-cache hit), the one-shot debug bundle (HTTP + INFO FOR ROOT + SDK),
-teardown joins on Datastore.close(), and the bench_diff tool."""
+cache hit), the one-shot debug bundle (HTTP + INFO FOR ROOT + SDK) and
+teardown joins on Datastore.close()."""
 
 import json
-import os
-import sys
 import threading
 import time
 
@@ -406,92 +404,3 @@ def test_datastore_close_joins_background(monkeypatch):
     )
     # registry idle -> watchdog parked (no daemon-thread leaks)
     assert not snap["watchdog_alive"]
-
-
-# ------------------------------------------------------------------ tooling
-def _load_script(name):
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "scripts"))
-    try:
-        return __import__(name)
-    finally:
-        sys.path.pop(0)
-
-
-def _cfg_line(value, phases=None, **extra):
-    line = {
-        "metric": "hybrid_knn", "value": value, "unit": "qps",
-        "vs_baseline": 1.0, "config": "4", "errors": {"statements": 0},
-        "retries": 0, "splits": 0,
-        "latency_ms": {"p50": 100.0, "p95": 200.0, "p99": 300.0},
-    }
-    if phases is not None:
-        line["phases"] = phases
-    line.update(extra)
-    return line
-
-
-def test_bench_diff_flags_and_names_culprit_phase():
-    bench_diff = _load_script("bench_diff")
-    old = {"results": [_cfg_line(10.0, {"knn_ms": 100.0, "filter_ms": 10.0, "expand_ms": 5.0})]}
-    new = {
-        "results": [
-            _cfg_line(
-                5.0,
-                {"knn_ms": 400.0, "filter_ms": 11.0, "expand_ms": 5.0},
-                bg_tasks={"kinds": {"ivf_train": {"count": 1, "overlap_s": 3.2, "stalled": 0}}, "tasks": []},
-                compiles={"on_demand": 2, "prewarm": 0, "events": []},
-            )
-        ]
-    }
-    rows = bench_diff.diff(old, new, threshold=0.25)
-    (r,) = rows
-    assert r["flags"], r
-    assert any("value dropped" in f for f in r["flags"])
-    assert r["culprit_phase"] == "knn_ms"
-    assert any("ivf_train" in s for s in r["suspects"])
-    assert any("on-demand" in s for s in r["suspects"])
-    # an unchanged round does not flag
-    assert not bench_diff.diff(old, old, threshold=0.25)[0]["flags"]
-
-
-def test_validator_schema5_rules(tmp_path):
-    cba = _load_script("check_bench_artifact")
-    line = _cfg_line(
-        10.0,
-        {"knn_ms": 100.0, "filter_ms": 10.0, "expand_ms": 5.0},
-        strategy={"ivf": 4},
-        batch={
-            "submitted": 8, "dispatches": 2, "batched": 6, "mean_width": 4.0,
-            "width_dist": {"4": 2}, "pipeline_wait_s": 0.0,
-        },
-        error_breakdown={},
-        slowest_trace=None,
-        slow_over_5s=0,
-        scan={},
-        bg_tasks={"kinds": {}, "tasks": []},
-        compiles={"on_demand": 0, "prewarm": 1, "events": []},
-    )
-    art = {
-        "schema": "surrealdb-tpu-bench/5", "scale": 0.02, "configs": ["4"],
-        "results": [
-            line,
-            {"metric": "north_star_knn", "value": 1.0, "unit": "qps", "vs_baseline": 2.0},
-        ],
-        "bundle": {sec: {} for sec in SECTIONS},
-    }
-    p = tmp_path / "bench_results_t.json"
-    p.write_text(json.dumps(art))
-    assert cba.validate(str(p)) == []
-    # a /5 line without structural overlap accounting is invalid
-    bad = json.loads(json.dumps(art))
-    bad["results"][0].pop("bg_tasks")
-    bad["results"][0]["compiles"] = {
-        "on_demand": 1, "prewarm": 0,
-        "events": [{"mode": "on_demand", "trace_id": None}],
-    }
-    bad.pop("bundle")
-    p.write_text(json.dumps(bad))
-    problems = cba.validate(str(p))
-    assert any("bg_tasks" in x for x in problems)
-    assert any("cites no trace_id" in x for x in problems)
-    assert any("bundle" in x for x in problems)

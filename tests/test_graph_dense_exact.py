@@ -165,6 +165,20 @@ def test_an_operator_past_the_limb_limit_is_refused_and_served_exactly_by_csc(so
     assert {e["subsystem"] for e in compile_log.events()} == {"graph_csc"}
 
 
+def serve_graph(ds, n: int, edges: np.ndarray):
+    """`n` persons and one knows record an edge, loaded through ds.execute()."""
+    sess = Session.owner(NS, DB)
+    ds.execute("DEFINE TABLE person SCHEMALESS; DEFINE TABLE knows SCHEMALESS", sess)
+    ds.execute("INSERT INTO person $rows RETURN NONE", sess, {"rows": [{"id": i} for i in range(n)]})
+    rows = [{"in": Thing("person", int(a)), "out": Thing("person", int(b))} for a, b in edges]
+    (res,) = ds.execute("INSERT RELATION INTO knows $rows RETURN NONE", sess, {"rows": rows})
+    assert res["status"] == "OK", res
+    return sess
+
+
+HOP3 = "SELECT count(->knows->person->knows->person->knows->person) AS c FROM person:{}"
+
+
 def test_a_served_count_on_a_degree_256_graph_is_dense(ds, monkeypatch):
     """Through ds.execute(): the compile log names `graph_dense` and not
     `graph_csc`, and `graph_count_form` counts one dense chain a statement."""
@@ -173,13 +187,8 @@ def test_a_served_count_on_a_degree_256_graph_is_dense(ds, monkeypatch):
     n, hub = 320, 300
     edges = np.asarray([(0, j) for j in range(1, hub + 1)] + [(j, (j * 7) % n) for j in range(1, n)]
                        + [(j, 0) for j in range(1, n, 3)])
-    sess = Session.owner(NS, DB)
-    ds.execute("DEFINE TABLE person SCHEMALESS; DEFINE TABLE knows SCHEMALESS", sess)
-    ds.execute("INSERT INTO person $rows RETURN NONE", sess, {"rows": [{"id": i} for i in range(n)]})
-    rows = [{"in": Thing("person", int(a)), "out": Thing("person", int(b))} for a, b in edges]
-    (res,) = ds.execute("INSERT RELATION INTO knows $rows RETURN NONE", sess, {"rows": rows})
-    assert res["status"] == "OK", res
-    sql = "SELECT count(->knows->person->knows->person->knows->person) AS c FROM person:{}"
+    sess = serve_graph(ds, n, edges)
+    sql = HOP3
     for start in (0, 5, 9):
         (res,) = ds.execute(sql.format(start), sess)
         assert res["status"] == "OK", res
@@ -193,6 +202,51 @@ def test_a_served_count_on_a_degree_256_graph_is_dense(ds, monkeypatch):
     (res,) = ds.execute("SELECT count(->knows->person) AS c FROM person:5", sess)
     assert res["result"][0]["c"] == walk_count(n, edges, {5: 1}, 1)
     assert forms() == {"dense": 3, "host": 1}
+
+
+@pytest.mark.parametrize("form", ["dense", "csc"])
+def test_concurrent_served_counts_all_answer_the_walk(ds, monkeypatch, form):
+    """Sixteen sessions ask 3-hop counts at once, twice over: every statement
+    is OK and equals the int64 walk, whichever riders shared its dispatch,
+    and every one was submitted to the dispatch queue in the asked form."""
+    import threading
+
+    monkeypatch.setattr(cnf, "TPU_GRAPH_COUNT_EDGES", 1)
+    monkeypatch.setattr(cnf, "GRAPH_PREWARM", False)
+    n = 400
+    if form == "csc":
+        monkeypatch.setattr(cnf, "TPU_GRAPH_DENSE_MAX", n - 1)
+    edges = lognormal_hub(n, hub_degree=120, seed=11)
+    sess = serve_graph(ds, n, edges)
+    sql = HOP3
+    (res,) = ds.execute(sql.format(0), sess)  # builds the mirrors, compiles the shape
+    assert res["result"][0]["c"] == walk_count(n, edges, {0: 1}, 3)
+    threads, rounds = 16, 2
+    starts = [(i * 37) % n for i in range(threads * rounds)]
+    got, errors = {}, []
+    barrier = threading.Barrier(threads)
+
+    def client(i):
+        barrier.wait()
+        for r in range(rounds):
+            j = i * rounds + r
+            try:
+                (res,) = ds.execute(sql.format(starts[j]), sess)
+                assert res["status"] == "OK", res
+                got[j] = res["result"][0]["c"]
+            except BaseException as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+
+    before = ds.dispatch.stats()["submitted"]
+    ts = [threading.Thread(target=client, args=(i,)) for i in range(threads)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(60)
+    assert not errors, errors[:1]
+    assert got == {j: walk_count(n, edges, {starts[j]: 1}, 3) for j in range(len(starts))}
+    assert ds.dispatch.stats()["submitted"] - before == len(starts)
+    assert forms() == {form: len(starts) + 1}
 
 
 def test_the_dense_entry_keeps_the_module_name_trace_readers_match():

@@ -233,7 +233,8 @@ def test_ivf_strategies_consume_columnar_prefilter(ds, monkeypatch):
 
     monkeypatch.setattr(cnf, "TPU_ANN_MIN_ROWS", 64)
     # keep the test off the MESH branch (the suite runs on a virtual
-    # 8-device mesh; ivf-sharded still post-filters — see ROADMAP)
+    # 8-device mesh; tests/test_knn_strategies.py holds `ivf-sharded` to
+    # the same prefilter)
     monkeypatch.setattr(cnf, "TPU_KNN_ONDEVICE_THRESHOLD", 1 << 60)
     monkeypatch.setattr(cnf, "COLUMN_MIRROR_MIN_ROWS", 4)
 

@@ -208,6 +208,43 @@ def test_shed_is_observable_via_event_counter_and_503(srv, monkeypatch):
         s.vc.close()
 
 
+def test_victim_tenant_is_never_shed_under_a_flood(srv, monkeypatch):
+    """The isolation contract end to end: one tenant floods past its
+    quota and queue and is shed; a well-behaved tenant's every request,
+    sent into the same flood, is answered 200 and never shed."""
+    monkeypatch.setattr(cnf, "NET_TENANT_INFLIGHT", 1)
+    monkeypatch.setattr(cnf, "NET_ADMIT_QUEUE", 1)
+    loop0 = srv.netloop.loops[0]
+    hog = loop0.attach_virtual()
+    hog.feed(_http("RETURN sleep(600ms);", ns="abuser", db="app"))
+    time.sleep(0.1)  # the slow request holds the abuser's only slot
+    flood = []
+    for _ in range(6):  # 1 queues, the rest overflow the bounded queue
+        vcn = loop0.attach_virtual()
+        flood.append(_Sink(vcn))
+        vcn.feed(_http("RETURN 1;", ns="abuser", db="app"))
+    victims = []
+    for i in range(8):  # one at a time: the victim stays inside its quota
+        vcn = loop0.attach_virtual()
+        sink = _Sink(vcn)
+        victims.append(sink)
+        vcn.feed(_http(f"RETURN {7000 + i};", ns="victim", db="app"))
+        assert _wait(lambda: sink.has(str(7000 + i).encode())), sink.buf[:200]
+        assert b"200 OK" in sink.buf and b"503" not in sink.buf, sink.buf[:200]
+    assert _wait(lambda: any(s.has(b"503") for s in flood)), [
+        s.buf[:120] for s in flood
+    ]
+    top = {(t["ns"], t["db"]): t for t in qos.snapshot()["top"]}
+    assert top[("abuser", "app")]["shed"] >= 1
+    assert top[("victim", "app")]["shed"] == 0
+    assert top[("victim", "app")]["admitted"] == len(victims)
+    shed = [e for e in events.snapshot() if e["kind"] == "net.admission_shed"]
+    assert shed and all(e["ns"] == "abuser" for e in shed), shed
+    hog.close()
+    for s in flood + victims:
+        s.vc.close()
+
+
 def test_throttle_queues_then_admits(srv, monkeypatch):
     monkeypatch.setattr(cnf, "NET_TENANT_INFLIGHT", 1)
     monkeypatch.setattr(cnf, "NET_ADMIT_QUEUE", 64)

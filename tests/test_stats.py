@@ -21,12 +21,12 @@ The contracts under test:
   flamegraph collapsed format, aggregates stay bounded;
 - surfacing: system-gated GET /statements (+`?cluster=1` federated
   node-tagged from a 2-node cluster), INFO FOR ROOT, bundle sections
-  12/13, and `bench_diff --statements` naming a plan-mix flip culprit;
+  12/13;
 - the end-to-end drift proof: the same SELECT battery with the mirror
   enabled then force-declined mid-run records the flip in one
   fingerprint's plan-mix vector, shows up merged node-tagged over
-  `?cluster=1`, and bench_diff names that fingerprint between the two
-  artifact windows.
+  `?cluster=1`, and the two `/statements` snapshots name that
+  fingerprint.
 """
 
 import json
@@ -464,51 +464,6 @@ def test_info_for_root_and_bundle_sections(ds):
     assert "by_thread" in b["profiler"] and "hz" in b["profiler"]
 
 
-# ============================================================ bench_diff
-def _artifact(top, config="2"):
-    return {
-        "schema": "surrealdb-tpu-bench/12",
-        "results": [{
-            "metric": "knn_qps", "value": 1.0, "config": config,
-            "statements": {"top": top, "profiler": {"samples": 0}},
-        }],
-    }
-
-
-def test_bench_diff_statements_names_flip_culprit(capsys):
-    from scripts.bench_diff import diff_statements, main
-
-    base = {
-        "fingerprint": "f" * 16, "sql": "SELECT * FROM t WHERE x > ?",
-        "calls": 100, "total_s": 1.0, "p99_ms": 12.0,
-        "plan_mix": {"columnar-scan": 100}, "plan_flips": 0, "flip_log": [],
-    }
-    flipped = dict(
-        base, total_s=8.0, p99_ms=95.0,
-        plan_mix={"columnar-scan": 3, "row": 97}, plan_flips=1,
-        flip_log=[{"ts": 1.0, "from": "columnar-scan", "to": "row"}],
-    )
-    rows = diff_statements(_artifact([base]), _artifact([flipped]))
-    assert len(rows) == 1
-    flags = rows[0]["flags"]
-    assert any("plan-mix flip: columnar-scan -> row" in f for f in flags)
-    assert any(f.startswith("qps") for f in flags)
-    assert any(f.startswith("p99") for f in flags)
-    assert any("in-window plan flips" in f for f in flags)
-    # the CLI path: exit 1 when flagged, culprit named with its SQL
-    import tempfile
-
-    with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as fa:
-        json.dump(_artifact([base]), fa)
-    with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as fb:
-        json.dump(_artifact([flipped]), fb)
-    rc = main(["--statements", fa.name, fb.name])
-    out = capsys.readouterr().out
-    assert rc == 1 and ("f" * 16) in out and "plan-mix flip" in out
-    # identical windows: exit 0, nothing flagged
-    assert main(["--statements", fa.name, fa.name]) == 0
-
-
 # ============================================================ cluster + drift
 class Cluster2:
     """Two in-process nodes on one ring (the test_cluster_obs harness
@@ -554,11 +509,9 @@ def test_drift_proof_end_to_end(cluster2):
     """The acceptance walk: same SELECT battery twice — mirror enabled,
     then force-declined mid-run — the fingerprint's plan-mix vector
     records the flip, `/statements?cluster=1` shows it merged node-tagged
-    from a 2-node cluster, and `bench_diff --statements` between the two
-    artifact windows names that fingerprint as the culprit."""
+    from a 2-node cluster, and the two `/statements` snapshots themselves
+    name that fingerprint: columnar-only before, mostly `row` after."""
     import copy
-
-    from scripts.bench_diff import diff_statements
 
     c = cluster2
     ok(c.coord.execute("DEFINE TABLE drift SCHEMALESS", c.s)[0])
@@ -609,12 +562,14 @@ def test_drift_proof_end_to_end(cluster2):
     assert {e["node"] for e in merged} == {"n1", "n2"}, merged
     assert all(e["fingerprint"] == culprit["fingerprint"] for e in merged)
 
-    # bench_diff between the two windows names the culprit fingerprint
+    # the two snapshots name the culprit on their own: the same
+    # fingerprint, no flip and no `row` serve in window A, the dominant
+    # plan gone from columnar to `row` in window B
     window_b = copy.deepcopy(stats.statements(limit=100))
-    rows = diff_statements(
-        _artifact(window_a, config="6"), _artifact(window_b, config="6")
-    )
-    by_fp = {r["fingerprint"]: r for r in rows}
-    assert culprit["fingerprint"] in by_fp
-    flags = by_fp[culprit["fingerprint"]]["flags"]
-    assert any("plan-mix flip" in f or "in-window plan flips" in f for f in flags), flags
+    a = {e["fingerprint"]: e for e in window_a}[culprit["fingerprint"]]
+    b = {e["fingerprint"]: e for e in window_b}[culprit["fingerprint"]]
+    assert a["plan_flips"] == 0 and "row" not in a["plan_mix"], a
+    assert max(a["plan_mix"], key=a["plan_mix"].get).startswith("columnar"), a
+    assert b["plan_flips"] >= 1 and b["calls"] > a["calls"], (a, b)
+    grew = {k: v - a["plan_mix"].get(k, 0) for k, v in b["plan_mix"].items()}
+    assert max(grew, key=grew.get) == "row", grew

@@ -5,7 +5,6 @@ leak detector, and the /metrics + /slow HTTP round trip
 
 import gc
 import json
-import os
 import re
 import warnings
 
@@ -258,51 +257,3 @@ def test_slow_endpoint_requires_system_user():
         conn.close()
     finally:
         srv.shutdown()
-
-
-# ------------------------------------------------------------------ bench artifact validator
-def test_bench_artifact_validator(tmp_path):
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "scripts"))
-    try:
-        from check_bench_artifact import validate
-    finally:
-        sys.path.pop(0)
-
-    line = {
-        "metric": "knn_qps", "value": 1.0, "unit": "qps", "vs_baseline": 2.0,
-        "config": "2", "errors": {"statements": 0}, "retries": 0,
-        "strategy": {"ivf-device": 4},
-        "batch": {"submitted": 8, "dispatches": 2, "batched": 6, "mean_width": 4.0},
-        "error_breakdown": {"dispatch_retries:UNAVAILABLE": 1},
-        "slowest_trace": {
-            "trace_id": "ab" * 16, "duration_ms": 12.5,
-            "spans": [{"id": 1, "parent": None, "name": "execute"}],
-        },
-    }
-    good = {
-        "schema": "surrealdb-tpu-bench/2", "scale": 0.02, "configs": ["2"],
-        "results": [
-            line,
-            {"metric": "north_star_knn", "value": 1.0, "unit": "qps", "vs_baseline": 2.0},
-        ],
-    }
-    p = tmp_path / "bench_results_test.json"
-    p.write_text(json.dumps(good))
-    assert validate(str(p)) == []
-
-    # a null slowest_trace is legal (a config may retain no trace)
-    p.write_text(json.dumps(dict(good, results=[dict(line, slowest_trace=None), good["results"][1]])))
-    assert validate(str(p)) == []
-
-    bad = dict(good, results=[dict(line, config="9"), good["results"][1]])
-    bad["results"][0].pop("retries")
-    bad["results"][0]["slowest_trace"] = {"trace_id": "x"}  # no spans
-    bad["results"][0]["error_breakdown"] = {"k": "not-an-int"}
-    p.write_text(json.dumps(bad))
-    problems = validate(str(p))
-    assert any("retries" in x for x in problems)
-    assert any("absent" in x for x in problems)
-    assert any("slowest_trace" in x for x in problems)
-    assert any("error_breakdown" in x for x in problems)
