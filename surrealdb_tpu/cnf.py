@@ -107,11 +107,11 @@ TPU_KNN_ONDEVICE_THRESHOLD = _env_int("SURREAL_TPU_KNN_ONDEVICE_THRESHOLD", 4096
 # candidate set is huge.
 TPU_FT_ONDEVICE_THRESHOLD = _env_int("SURREAL_TPU_FT_ONDEVICE_THRESHOLD", 262_144)
 TPU_GRAPH_ONDEVICE_THRESHOLD = _env_int("SURREAL_TPU_GRAPH_ONDEVICE_THRESHOLD", 2048)
-# static-shape stabilizers for the fused chain kernel: frontier pad floor and
-# fixed vmap lane count, so concurrent chain queries share ONE compiled
-# executable (XLA compiles per shape, seconds each)
+# static-shape stabilizer for the graph chain kernels: the frontier pad
+# floor, so concurrent chain queries share ONE compiled executable (XLA
+# compiles per shape, seconds each). A batched count's lane count is no
+# setting: it follows the batch (utils/num.py::count_lanes)
 TPU_GRAPH_FRONTIER_PAD = _env_int("SURREAL_TPU_GRAPH_FRONTIER_PAD", 256)
-TPU_GRAPH_BATCH_LANES = _env_int("SURREAL_TPU_GRAPH_BATCH_LANES", 32)
 # count-only chains over at least this many total edges skip host hops and
 # run the whole chain on device from the seed frontier
 TPU_GRAPH_COUNT_EDGES = _env_int("SURREAL_TPU_GRAPH_COUNT_EDGES", 50_000)

@@ -54,6 +54,9 @@ Two-phase runners (double buffering): a runner may return a CALLABLE instead
 of the results list — the callable is the "collect" phase (blocking result
 download). The bucket is handed to the next leader right after the launch
 phase returns, so the pipeline depth above is measured launch-to-collect.
+What a runner has to say about its launch rides on that callable: a dict
+attribute `launch_labels` (the graph count runners: `lanes`, the padded
+lane count) joins `batch` on every rider's `dispatch_launch` span.
 """
 
 from __future__ import annotations
@@ -378,7 +381,10 @@ class DispatchQueue:
             # charge riders the SAME elapsed launch_s just accumulated
             # (success and failure paths both) — conservation holds exactly
             self._charge_batch(batch, elapsed, "dispatch_s")
-        self._trace_batch(batch, "dispatch_launch", t0, _time.perf_counter() - t0)
+        self._trace_batch(
+            batch, "dispatch_launch", t0, _time.perf_counter() - t0,
+            **getattr(res, "launch_labels", {}),
+        )
         if not callable(res):
             self._distribute(batch, res)
             return None

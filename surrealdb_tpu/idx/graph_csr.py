@@ -32,7 +32,7 @@ import numpy as np
 from surrealdb_tpu import key as keys, telemetry
 from surrealdb_tpu.key.encode import prefix_end
 from surrealdb_tpu.sql.value import Thing
-from surrealdb_tpu.utils.num import next_pow2 as _next_pow2
+from surrealdb_tpu.utils.num import count_lane_set, count_lanes, next_pow2 as _next_pow2
 
 
 class NodeInterner:
@@ -261,18 +261,34 @@ def _csc_shape_key(lanes: int, fsz: int, n_cap: int, csc_hops, last_hop) -> tupl
 
 def _stack_lanes(payloads, fsz: int, pad: int):
     """One coalesced batch of (frontier, weights) payloads as two
-    [lanes, fsz] int32 arrays at a fixed lane count: a batch of 1 and a
-    batch of 32 share the same compiled executable (padding lanes carry
-    zero weights and cost nothing next to the dispatch RTT)."""
-    from surrealdb_tpu import cnf
-
-    bp = max(_next_pow2(len(payloads)), cnf.TPU_GRAPH_BATCH_LANES)
+    [lanes, fsz] int32 arrays, the lane count chosen from the riders
+    (utils/num.py::count_lanes: 8, 16, 32 or 64 at the dispatcher's width
+    cap of 64): batches of 1 to 8 share one compiled executable, and a
+    kernel's gathers, prefix sums and tables are as wide as the batch
+    needs, not as wide as the widest batch. Padding lanes carry zero
+    weights."""
+    bp = count_lanes(len(payloads))
     frs = np.full((bp, fsz), pad, dtype=np.int32)
     cws = np.zeros((bp, fsz), dtype=np.int32)
     for i, (f, c) in enumerate(payloads):
         frs[i] = f
         cws[i] = c
     return frs, cws
+
+
+def _collect_counts(out, riders: int, lanes: int):
+    """The collect phase of a batched count launched at `lanes` lanes: the
+    riders' counts off the device. The `graph_count_lanes` counter and the
+    `lanes` label the dispatcher puts on every rider's `dispatch_launch`
+    span come from this one argument (the pattern of _served): riders over
+    lanes is the fill."""
+    telemetry.inc("graph_count_lanes", lanes=lanes)
+
+    def collect():
+        return np.asarray(out)[:riders].tolist()
+
+    collect.launch_labels = {"lanes": lanes}
+    return collect
 
 
 def _local_seeds(inv: dict, frontier: np.ndarray, counts: np.ndarray, fsz: int, pad: int):
@@ -473,7 +489,8 @@ def _kernels():
         for multiplicities < 256), so B concurrent 3-hop counts are TWO
         matmuls + a degree dot-product in ONE dispatch. Counts ride as
         int32: per operator the frontier splits into four unsigned 8-bit
-        limbs stacked on the row axis ([4B, n]: 128 MXU rows at 32 lanes),
+        limbs stacked on the row axis ([4B, n]: 32 MXU rows at the 8 lanes
+        of a batch of up to 8 riders, 256 at 64),
         ONE bf16 x bf16 -> float32 product (both operands exact in bf16),
         and an int32 shift-and-add recombination. A float32 accumulator
         holds at most 255 x the operator's largest column sum, which
@@ -822,11 +839,13 @@ class GraphMirrors:
 
     def warm_count_kernels(self, ns: str, db: str) -> None:
         """Compile the batched count kernels for every composable
-        `->edge->node` OUT-pair over built mirrors, at the lane counts and
-        frontier pad the serving runners use — so a post-ingest burst of
-        count-chain queries starts on pre-compiled shapes (the r6 scale-1.0
-        log showed 84.8s/26.4s first-query stalls that were exactly these
-        compiles). Results are discarded; zero-weight lanes are harmless."""
+        `->edge->node` OUT-pair over built mirrors, at every lane count the
+        serving runners can return (utils/num.py::count_lane_set: 8, 16,
+        32, 64 at the dispatcher's width cap of 64) and the frontier pad
+        they use — so a post-ingest burst of count-chain queries of any
+        width starts on pre-compiled shapes (the r6 scale-1.0 log showed
+        84.8s/26.4s first-query stalls that were exactly these compiles).
+        Results are discarded; zero-weight lanes are harmless."""
         from surrealdb_tpu import cnf, telemetry
 
         if cnf.TPU_DISABLE:
@@ -846,15 +865,8 @@ class GraphMirrors:
             if tb2 == ft and d2 == keys.DIR_OUT
         ]
         fsz = _next_pow2(max(1, cnf.TPU_GRAPH_FRONTIER_PAD))
-        # every lane count the serving runners can pad to: bp =
-        # max(_next_pow2(B), LANES) with B capped by the dispatcher width,
-        # so the shape set is {LANES, ..., pow2(DISPATCH_MAX_WIDTH)}
-        lane_set = []
-        b = max(cnf.TPU_GRAPH_BATCH_LANES, 1)
-        top = max(_next_pow2(cnf.DISPATCH_MAX_WIDTH), b)
-        while b <= top:
-            lane_set.append(b)
-            b *= 2
+        # exactly the lane counts the serving runners can return
+        lane_set = count_lane_set()
         for tb, et, dt_ in pairs:
             pkey = (ns, db, tb, et, dt_)
             with self._lock:
@@ -1224,7 +1236,7 @@ class GraphMirrors:
                 out = kernel(
                     As, outdeg, jnp.asarray(frs), jnp.asarray(cws), n0=n0
                 )
-            return lambda: np.asarray(out)[: len(payloads)].tolist()
+            return _collect_counts(out, len(payloads), len(frs))
 
         _served("dense", t_enter)
         return dispatch.submit(key, (fr, cw), runner)
@@ -1296,7 +1308,7 @@ class GraphMirrors:
                     jnp.asarray(frs), jnp.asarray(cws),
                     n_cap=n_cap,
                 )
-            return lambda: np.asarray(out)[: len(payloads)].tolist()
+            return _collect_counts(out, len(payloads), len(frs))
 
         _served("csc", t_enter, operand)
         return dispatch.submit(key, (fr, cw), runner)
@@ -1557,9 +1569,10 @@ def graftcheck_sites():
             args,
         )
 
+    # as served: every lane count the runners can return (count_lane_set)
     lane_shapes = [
         {"label": f"l{lanes}_f{fsz}_n{n0}_h2", "lanes": lanes, "hops": 2}
-        for lanes in (1, 8)
+        for lanes in count_lane_set()
     ]
     return [
         {
@@ -1568,12 +1581,8 @@ def graftcheck_sites():
             "kind": "single",
             "allowed_collectives": (),
             "out_dtypes": ("int32",),
-            # as served: TPU_GRAPH_BATCH_LANES lanes and up (four limbs a
-            # lane: 128 MXU rows at 32), one operator a pair before the last
-            "shapes": [
-                {"label": f"l{lanes}_f{fsz}_n{n0}_h2", "lanes": lanes, "hops": 2}
-                for lanes in (32, 64)
-            ],
+            # four limbs a lane, one operator a pair before the last
+            "shapes": lane_shapes,
             "build": build_dense,
         },
         {

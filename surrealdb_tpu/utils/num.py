@@ -52,3 +52,30 @@ def warm_tile_sizes(cap: int = None):
 
         cap = cnf.DISPATCH_MAX_WIDTH
     return (1, 8, cap) if cap > 8 else ((1, cap) if cap > 1 else (1,))
+
+
+# An int32 tile is 8 sublanes and a batched count's frontier is
+# [lanes, n + 1] int32 with the lanes on the second-minor axis: below 8
+# nothing is saved (the sparse kernel alone at SNB SF3's composed shapes on
+# a v5e: 13.0 ms at 8 lanes, 15.2 at 4, 13.7 at 2, 32.8 at 1; PERF.md
+# section 6, PR 30), and the shape set stays at four.
+COUNT_LANES_MIN = 8
+
+
+def count_lanes(riders: int) -> int:
+    """Lane count of a batched graph count: the riders of the batch rounded
+    up to a power of two, no fewer than COUNT_LANES_MIN. The kNN path's
+    {1, 8, cap} is dispatch_tile's; this is the one rule of the graph count
+    runners, their warm-up and their audit shapes."""
+    return max(next_pow2(riders), COUNT_LANES_MIN)
+
+
+def count_lane_set(cap: int = None):
+    """Every lane count count_lanes can return for batches of up to `cap`
+    riders (default: the dispatcher's width cap, cnf.DISPATCH_MAX_WIDTH):
+    (8, 16, 32, 64) at a cap of 64. What warm-up compiles, no more."""
+    if cap is None:
+        from surrealdb_tpu import cnf
+
+        cap = cnf.DISPATCH_MAX_WIDTH
+    return tuple(sorted({count_lanes(r) for r in range(1, max(cap, 1) + 1)}))

@@ -12,6 +12,7 @@ from surrealdb_tpu.dbs.session import Session
 from surrealdb_tpu.idx import graph_csr
 from surrealdb_tpu.idx.graph_csr import GraphMirrors
 from surrealdb_tpu.sql.value import Thing
+from surrealdb_tpu.utils.num import count_lane_set
 from test_graph_dense_exact import (
     DB, NS, PAIR, as_int32, device_count, forms, lognormal_hub, mirrors_of, near_complete, walk_count,
 )
@@ -303,11 +304,11 @@ def test_warm_count_kernels_leaves_no_compile_for_a_first_composed_count(monkeyp
     gm.warm_count_kernels(NS, DB)
     warmed = compile_log.events()
     assert warmed and {e["mode"] for e in warmed} == {"prewarm"} and {e["subsystem"] for e in warmed} == {"graph_csc"}
-    assert {int(e["shape"].split("x")[0]) for e in warmed} >= {cnf.TPU_GRAPH_BATCH_LANES}
+    assert {int(e["shape"].split("x")[0]) for e in warmed} == set(count_lane_set()) == {8, 16, 32, 64}
     assert telemetry.counters_matching("prewarm_errors") == {}
     for pairs in (1, 2, 3):
         assert device_count(gm, persons, {0: 1}, pairs) == walk_count(n, edges, {0: 1}, pairs)
     assert operands() == {"composed": 3}
-    assert compile_log.events() == warmed  # served at 32 lanes from what the warm-up compiled
+    assert compile_log.events() == warmed  # served at 8 lanes from what the warm-up compiled
     hits = {dict(k)["outcome"] for k in telemetry.counters_matching("compile_cache")}
     assert "hit" in hits
