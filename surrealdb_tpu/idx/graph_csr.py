@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import threading
 import time as _time
+from collections import OrderedDict
 from surrealdb_tpu.utils import locks as _locks
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -214,14 +215,21 @@ class PointerCsr:
         return self._dev_csc
 
 
-def _served(form: str, t_enter: Optional[float], operand: Optional[str] = None) -> None:
+def _served(
+    form: str, t_enter: Optional[float], operand: Optional[str] = None, filter: str = "none"
+) -> None:
     """One count chain served by `form` (`dense`, `csc` or `host`): the
     `graph_count_form` counter and the `form` label of the statement's
     `graph_prepare` span come from this one argument, so they cannot
     disagree. A `csc` count also says which `operand` its kernel swept
     (`composed`: node->node operators in a table's compact id space;
     `records`: the record-level mirrors in the shared id space), to the
-    `graph_csc_operand` counter and the span's `operand` label alike. The
+    `graph_csc_operand` counter and the span's `operand` label alike; and
+    every count says what became of a predicate on the chain (`filter`, to
+    the `graph_count_filter` counter's `route` and the span's label: `none`,
+    the chain has no WHERE; `fused`, the predicate on its final node part
+    rode the count as a mask over the node table's column mirror; `host`,
+    a chain with a WHERE that fell back to the KV walk). The
     span closes here, at the dispatch submit (for a count no dispatcher
     carries: where its fused chain starts): hop specs, frontier, work
     estimate, the dense form's refusal, operand look-ups (and, on a first
@@ -229,7 +237,8 @@ def _served(form: str, t_enter: Optional[float], operand: Optional[str] = None) 
     count is known for one only when its walk has ended, so there the span
     holds the whole count; a reader of preparation time leaves it out."""
     telemetry.inc("graph_count_form", form=form)
-    labels = {"form": form}
+    telemetry.inc("graph_count_filter", route=filter)
+    labels = {"form": form, "filter": filter}
     if operand is not None:
         telemetry.inc("graph_csc_operand", operand=operand)
         labels["operand"] = operand
@@ -243,20 +252,24 @@ def _served(form: str, t_enter: Optional[float], operand: Optional[str] = None) 
 _JITTED: dict = {}
 
 
-def _dense_shape_key(lanes: int, fsz: int, n0: int, As) -> tuple:
+def _dense_shape_key(lanes: int, fsz: int, n0: int, As, weighted: bool = False) -> tuple:
     """Compile-cache key of the dense count kernel: lane count, frontier
-    pad, source space + each operator's padded dims (what XLA keys on)."""
-    return (lanes, fsz, n0, tuple(tuple(int(d) for d in a.shape) for a in As))
+    pad, source space + each operator's padded dims (what XLA keys on), and
+    a `w` where the count ends in per-rider weights: another program."""
+    key = (lanes, fsz, n0, tuple(tuple(int(d) for d in a.shape) for a in As))
+    return key + ("w",) if weighted else key
 
 
-def _csc_shape_key(lanes: int, fsz: int, n_cap: int, csc_hops, last_hop) -> tuple:
+def _csc_shape_key(lanes: int, fsz: int, n_cap: int, csc_hops, last_hop, weighted: bool = False) -> tuple:
     """Compile-cache key of the batched CSC count kernel: per-hop array
-    paddings decide the executable shape."""
-    return (
+    paddings decide the executable shape (and, as the dense key, whether
+    the count ends in per-rider weights)."""
+    key = (
         lanes, fsz, n_cap,
         tuple(int(a.shape[0]) for hop in csc_hops for pair in hop for a in pair),
         tuple(int(p.shape[0]) for (p,) in last_hop),
     )
+    return key + ("w",) if weighted else key
 
 
 def _stack_lanes(payloads, fsz: int, pad: int):
@@ -270,10 +283,29 @@ def _stack_lanes(payloads, fsz: int, pad: int):
     bp = count_lanes(len(payloads))
     frs = np.full((bp, fsz), pad, dtype=np.int32)
     cws = np.zeros((bp, fsz), dtype=np.int32)
-    for i, (f, c) in enumerate(payloads):
-        frs[i] = f
-        cws[i] = c
+    for i, p in enumerate(payloads):
+        frs[i] = p[0]
+        cws[i] = p[1]
     return frs, cws
+
+
+def _lane_end_weights(payloads, lanes: int) -> tuple:
+    """The riders' end weights (a payload's third member: one device array
+    a rider, cached a predicate binding by GraphMirrors._end_weights), one a
+    lane. A padding lane carries no seed weight, so whatever it ends in
+    counts nothing: it borrows the first rider's array."""
+    ws = [p[2] for p in payloads]
+    return tuple(ws + [ws[0]] * (lanes - len(ws)))
+
+
+def _weights_into(cptr: np.ndarray, csrc: np.ndarray, passing: np.ndarray, size: int) -> np.ndarray:
+    """End weights of a count whose last `->edge->node` pair keeps only the
+    destinations `passing` (local ids): w[v] = the pair's paths from v to
+    one of them, read off the pair's destination-sorted paths (`cptr`,
+    `csrc`: _csc_arrays) bin by bin, so the cost follows the paths that
+    pass, not the operator. int32 [size]."""
+    starts = cptr[passing].astype(np.int64)
+    return np.bincount(csrc[_slots(starts, cptr[passing + 1] - starts)], minlength=size).astype(np.int32)
 
 
 def _collect_counts(out, riders: int, lanes: int):
@@ -316,6 +348,13 @@ def _local_ids(space: dict, size: int) -> np.ndarray:
     return inv
 
 
+def _slots(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
+    """Every slot of the ranges `[starts[i], starts[i] + lens[i])`, range
+    after range: the running slot number less its range's first is the
+    offset inside the range."""
+    return np.repeat(starts - (np.cumsum(lens) - lens), lens) + np.arange(int(lens.sum()))
+
+
 def _compose_coo(ip1, ix1, ip2, ix2, space_src: dict, space_dst: dict, max_paths: int):
     """src -> mid -> dst through two CSR mirrors over the shared id space, as
     COO `(local_src, local_dst)` in the two tables' compact ids: one entry a
@@ -335,10 +374,8 @@ def _compose_coo(ip1, ix1, ip2, ix2, space_src: dict, space_dst: dict, max_paths
     total = int(deg.sum())
     if total > max_paths:
         return None
-    # path k of m1-edge e reads ix2[start[e] + k]: the running slot number
-    # less the edge's first slot is k
-    take = np.repeat(start - (np.cumsum(deg) - deg), deg) + np.arange(total)
-    ld = inv_d[ix2[take]]
+    # path k of m1-edge e reads ix2[start[e] + k]
+    ld = inv_d[ix2[_slots(start, deg)]]
     keep = ld >= 0
     return np.repeat(ls, deg)[keep], ld[keep]
 
@@ -425,7 +462,7 @@ def _kernels():
         return jnp.where((frj < n) & (cwj > 0), deg * cwj, 0).sum(axis=-1)
 
     @partial(jax.jit, static_argnames=("n_cap",))
-    def chain_count_batch(csc_hops, last_hop, frontiers, weights, n_cap):
+    def chain_count_batch(csc_hops, last_hop, frontiers, weights, n_cap, end_weights=None):
         """Batched count-only chains for B concurrent queries over the SAME
         adjacency (the cross-query coalescing seam, dbs/dispatch.py).
         Scatter-free: TPU scatter-add is serial-slow and vmapped
@@ -436,7 +473,11 @@ def _kernels():
           prefix-scan, difference at bin boundaries (y[v] = S[end_v] -
           S[start_v]) — gathers + one cumsum, VPU-friendly at any width
         - the final hop of a count never materializes neighbors: it is a
-          degree dot-product
+          degree dot-product, or, where the chain's final node part has a
+          predicate, a dot-product with the rider's own `end_weights` (one
+          [n_cap] array a lane, `last_hop` then empty: w[v] = the last
+          pair's paths from v to a node that passes; the bare count is the
+          case w = deg, and runs the program it always ran)
         csc_hops: tuple per non-final hop of ((cptr, csrc), ...);
         last_hop: ((ptr,), ...). The operands are either composed
         node->node operators in one table's compact ids (_csc_pair: a hop
@@ -444,6 +485,12 @@ def _kernels():
         mirrors in the shared id space (PointerCsr.device_csc: a hop a
         spec, n_cap the padded interner); the kernel cannot tell."""
         B = frontiers.shape[0]
+        ends = None if end_weights is None else jnp.stack(end_weights)
+        if not csc_hops and ends is not None:
+            # one filtered pair: the seeds' own end weights
+            fr_c = jnp.clip(frontiers, 0, n_cap - 1)
+            w = jnp.take_along_axis(ends, fr_c, axis=1)
+            return jnp.where((frontiers < n_cap) & (weights > 0), w * weights, 0).sum(axis=1)
         if not csc_hops and not last_hop:
             return jnp.zeros((B,), dtype=jnp.int32)
         if not csc_hops:
@@ -476,6 +523,8 @@ def _kernels():
                 y = y + (s[:, cptr[1:]] - s[:, cptr[:-1]])
             x = jnp.concatenate([y, zcol], axis=1)
         xr = x[:, :n_cap]
+        if ends is not None:
+            return (xr * ends).sum(axis=1)
         total = 0
         for (ptr,) in last_hop:
             deg = ptr[1 : n_cap + 1] - ptr[:n_cap]
@@ -483,7 +532,7 @@ def _kernels():
         return total
 
     @partial(jax.jit, static_argnames=("n0",))
-    def chain_count_batch_dense(As, outdeg, frontiers, weights, n0):
+    def chain_count_batch_dense(As, outdeg, frontiers, weights, n0, end_weights=None):
         """Batched count chains as MXU matmuls: each logical `->edge->node`
         pair is pre-composed into a dense node->node operator (bf16, exact
         for multiplicities < 256), so B concurrent 3-hop counts are TWO
@@ -498,7 +547,10 @@ def _kernels():
         int32 sums wrap as chain_count_batch's do: the two forms agree bit
         for bit. (The XLA module name keeps the `jit_chain_count_batch`
         prefix: trace readers find whichever form served the count.)
-        seeds arrive as compact LOCAL ids."""
+        seeds arrive as compact LOCAL ids. A count whose final node part
+        has a predicate ends in `end_weights` (one array a lane over the
+        last pair's source space, `outdeg` then None), as
+        chain_count_batch's does."""
         B = frontiers.shape[0]
         lane = (jnp.arange(B) * (n0 + 1))[:, None]
         safe = jnp.where(weights > 0, jnp.clip(frontiers, 0, n0), n0)
@@ -515,12 +567,70 @@ def _kernels():
             y = jnp.dot(limbs, A, preferred_element_type=jnp.float32)
             y = y.astype(jnp.int32).reshape(4, B, -1)
             x = y[0] + (y[1] << 8) + (y[2] << 16) + (y[3] << 24)
-        return (x * outdeg[None, :]).sum(axis=1)
+        ends = outdeg[None, :] if end_weights is None else jnp.stack(end_weights)
+        return (x * ends).sum(axis=1)
 
     _JITTED["chain"] = chain_kernel
     _JITTED["chain_count_batch"] = chain_count_batch
     _JITTED["chain_count_batch_dense"] = chain_count_batch_dense
     return chain_kernel
+
+
+_END_WEIGHTS_BYTES = 256 << 20  # device bytes of cached end weights a datastore
+_UNFUSED = object()  # a filtered count no composed operator can carry: the KV walk
+
+
+class _EndFilter:
+    """The predicate on a count chain's final node part, for one statement:
+    `compiled` (ops/predicates.py, its constants bound) over table `tb`,
+    answered from the table's column mirror as this reader may see it.
+    Every look-up may say None: the mirror is stale or being written by
+    this transaction, a referenced column holds values the mask cannot
+    judge, or a path is unknown to it; the count then takes the KV walk."""
+
+    def __init__(self, gm: "GraphMirrors", ctx, tb: str, compiled):
+        self.gm, self.ctx, self.tb, self.compiled = gm, ctx, tb, compiled
+        self.ns, self.db = ctx.ns_db()
+        self._mirror = self._mask = False  # not looked up yet
+
+    def mirror(self):
+        if self._mirror is False:
+            registry = getattr(self.ctx.ds(), "column_mirrors", None)
+            m = None if registry is None else registry.serveable(self.ctx, (self.ns, self.db, self.tb))
+            self._mirror = m if m is not None and m.n else None
+        return self._mirror
+
+    def local_mask(self) -> Optional[np.ndarray]:
+        """pred(node) over `tb`'s compact ids (GraphMirrors.table_space). A
+        node with no record behind it answers as a record of NONEs does,
+        which is what the KV walk fetches there."""
+        if self._mask is False:
+            self._mask = self._local_mask()
+        return self._mask
+
+    def _local_mask(self) -> Optional[np.ndarray]:
+        from surrealdb_tpu.idx.column_mirror import _all_none_column
+
+        mirror = self.mirror()
+        cols = None if mirror is None else mirror.columns_for(self.compiled.paths)
+        if cols is None:
+            return None
+        mask, needs_row = self.compiled.evaluate(cols)
+        if needs_row.any():
+            return None
+        space = self.gm.table_space(self.ns, self.db, self.tb)
+        rows = self.gm._rows_of(self.ns, self.db, self.tb, mirror, space)
+        absent, _ = self.compiled.evaluate({p: _all_none_column(1) for p in self.compiled.paths})
+        return np.where(rows >= 0, mask[np.maximum(rows, 0)], bool(absent[0]))
+
+    def span(self, t0: float, built: bool, rows: int) -> None:
+        """The statement's `graph_filter` span: the look-up (`hit`) or the
+        making (`build`) of what the predicate contributes, and how many
+        nodes pass it."""
+        telemetry.stage(
+            "graph_filter", t0, _time.perf_counter() - t0,
+            outcome="build" if built else "hit", rows=rows,
+        )
 
 
 class GraphMirrors:
@@ -535,6 +645,11 @@ class GraphMirrors:
         self._spaces: Dict[tuple, dict] = {}  # (ns,db,tb) -> space dict
         self._dense: Dict[tuple, dict] = {}  # pair key -> operator dict
         self._csc: Dict[tuple, dict] = {}  # pair key -> composed sparse operator
+        # (pair key, predicate binding) -> a filtered count's end weights on
+        # the device, oldest first, held under _END_WEIGHTS_BYTES; and
+        # (ns,db,tb) -> the column mirror's row of each compact id
+        self._endw: "OrderedDict[tuple, dict]" = OrderedDict()
+        self._mirror_rows: Dict[tuple, tuple] = {}
         # tables mid-build: deltas committed during the build scan are
         # buffered here and replayed after load (closes the scan→built gap)
         self._building: Dict[Tuple[str, str, str], List[tuple]] = {}
@@ -588,14 +703,16 @@ class GraphMirrors:
 
     def _forget_derived(self, stale) -> None:
         """Drop what was composed from mirrors that are going (caller holds
-        _lock): id spaces, dense and sparse operators, warmed pairs whose
-        key `stale` selects. Their generations count from a mirror's
+        _lock): id spaces, dense and sparse operators, end weights, warmed
+        pairs whose key `stale` selects. Their generations count from a mirror's
         version and a table's size, and both start again with the new
         mirror and interner: an operator kept here would be served for
         the next graph of the same name."""
-        for d in (self._spaces, self._dense, self._csc):
+        for d in (self._spaces, self._dense, self._csc, self._mirror_rows):
             for k in [k for k in d if stale(k)]:
                 del d[k]
+        for k in [k for k in self._endw if stale(k[0])]:
+            del self._endw[k]
         self._warmed_pairs = {k for k in self._warmed_pairs if not stale(k)}
 
     def drop_table(self, ns: str, db: str, tb: str) -> None:
@@ -887,22 +1004,26 @@ class GraphMirrors:
                 from surrealdb_tpu import compile_log
 
                 n0 = op["ns_pad"]
+                no_end = jnp.zeros(n0, dtype=jnp.int32)
                 for lanes in lane_set:
                     frs = jnp.asarray(np.full((lanes, fsz), n0, dtype=np.int32))
                     cws = jnp.asarray(np.zeros((lanes, fsz), dtype=np.int32))
-                    for c in range(1, max_pairs + 1):
-                        try:
-                            As = (op["A"],) * (c - 1)
-                            with compile_log.tracked(
-                                "graph_dense",
-                                _dense_shape_key(lanes, fsz, n0, As),
-                                prewarmed=True,
-                            ):
-                                dense_kernel(As, op["outdeg"], frs, cws, n0=n0)
-                        except Exception:
-                            telemetry.inc(
-                                "prewarm_errors", subsystem="graph_count"
-                            )
+                    # the bare count's program, and the one that ends in a
+                    # rider's weights (a predicate on the final node part)
+                    for outdeg, ends in ((op["outdeg"], None), (None, (no_end,) * lanes)):
+                        for c in range(1, max_pairs + 1):
+                            try:
+                                As = (op["A"],) * (c - 1)
+                                with compile_log.tracked(
+                                    "graph_dense",
+                                    _dense_shape_key(lanes, fsz, n0, As, ends is not None),
+                                    prewarmed=True,
+                                ):
+                                    dense_kernel(As, outdeg, frs, cws, n0=n0, end_weights=ends)
+                            except Exception:
+                                telemetry.inc(
+                                    "prewarm_errors", subsystem="graph_count"
+                                )
                 continue
             # dense doesn't fit (oversized tables / fat multiplicities):
             # warm the CSC cumsum form the serving path will use instead,
@@ -933,16 +1054,20 @@ class GraphMirrors:
                         )
                         for c in range(1, max_pairs + 1)
                     ]
+                # end weights ride the composed operand alone
+                no_end = None if cop is None else jnp.zeros(n_cap, dtype=jnp.int32)
                 for lanes in lane_set:
                     frs = jnp.asarray(np.full((lanes, fsz), n_cap, dtype=np.int32))
                     cws = jnp.asarray(np.zeros((lanes, fsz), dtype=np.int32))
-                    for csc_hops in chains:
-                        with compile_log.tracked(
-                            "graph_csc",
-                            _csc_shape_key(lanes, fsz, n_cap, csc_hops, last_hop),
-                            prewarmed=True,
-                        ):
-                            csc_kernel(csc_hops, last_hop, frs, cws, n_cap=n_cap)
+                    endings = [(last_hop, None)] + ([((), (no_end,) * lanes)] if cop is not None else [])
+                    for last, ends in endings:
+                        for csc_hops in chains:
+                            with compile_log.tracked(
+                                "graph_csc",
+                                _csc_shape_key(lanes, fsz, n_cap, csc_hops, last, ends is not None),
+                                prewarmed=True,
+                            ):
+                                csc_kernel(csc_hops, last, frs, cws, n_cap=n_cap, end_weights=ends)
             except Exception:
                 telemetry.inc("prewarm_errors", subsystem="graph_count")
 
@@ -1090,7 +1215,7 @@ class GraphMirrors:
         rows_d = np.asarray(rows_d, np.int64)
         cells, mult = np.unique(rows_s * nd_pad + rows_d, return_counts=True)
         colsum = np.bincount(rows_d, minlength=nd_pad)
-        op = {"gen": gen, "fits": False}
+        op = {"gen": gen, "fits": False, "key": key}
         if mult.max(initial=0) < 256 and 255 * int(colsum.max()) < 1 << 24:
             import ml_dtypes
 
@@ -1107,6 +1232,7 @@ class GraphMirrors:
                     np.bincount(rows_s, minlength=ns_pad).astype(np.int32)
                 ),
                 space_src=sp_s,
+                paths=(rows_s, rows_d),
             )
         # a refusal is remembered too: an operator past the limb limit is
         # not recomposed by every statement of its generation
@@ -1153,7 +1279,7 @@ class GraphMirrors:
         coo = _compose_coo(
             ip1, ix1, ip2, ix2, sp_s, sp_d, max_paths=int(ip1[-1]) + int(ip2[-1])
         )
-        op = {"gen": gen, "fits": coo is not None}
+        op = {"gen": gen, "fits": coo is not None, "key": key}
         if coo is not None:
             ls, ld = coo
             n_pad = _next_pow2(max(gen[2:]))
@@ -1171,6 +1297,7 @@ class GraphMirrors:
                 csrc=jnp.asarray(csrc),
                 indptr=jnp.asarray(indptr),
                 space_src=sp_s,
+                by_dst=(cptr, csrc),
             )
             # asynchronous, as device_csc()'s: the host's hand-off
             telemetry.stage(
@@ -1179,6 +1306,67 @@ class GraphMirrors:
         with self._lock:
             self._csc[key] = op
         return op if op["fits"] else None
+
+    # ------------------------------------------------ a predicate on the end
+    def _rows_of(self, ns, db, tb, mirror, space) -> np.ndarray:
+        """The column mirror's row of each compact id of `tb` (-1: a node
+        no record stands behind, the far end of a dangling edge), kept
+        until the mirror is rebuilt or the table's space grows."""
+        n = len(space["globals"])
+        with self._lock:
+            got = self._mirror_rows.get((ns, db, tb))
+        if got is not None and got[0] is mirror and got[1] == n:
+            return got[2]
+        row_of, node_of = mirror.id_index(), self.interner(ns, db).node_of
+        rows = np.fromiter(
+            (row_of.get(repr(node_of[g].id), -1) for g in space["globals"][:n]),
+            dtype=np.int64, count=n,
+        )
+        with self._lock:
+            self._mirror_rows[(ns, db, tb)] = (mirror, n, rows)
+        return rows
+
+    def _end_weights(self, end: "_EndFilter", op: dict, size: int):
+        """A filtered count's end weights over the last pair `op`, on the
+        device, and whether this statement made them: one array a
+        (pair, predicate text, bound values), good while the operator's
+        generation and the node table's column mirror are the ones it was
+        made from, so an acknowledged RELATE or UPDATE is seen by the next
+        count. None where the column mirror cannot answer for this reader."""
+        import jax.numpy as jnp
+
+        mirror = end.mirror()
+        if mirror is None:
+            return None
+        # the pair's key first (what _forget_derived selects by), then the
+        # weights' length: a pair's dense and sparse operators pad apart
+        key = (op["key"] + (size,), end.compiled.binding_key())
+        with self._lock:
+            got = self._endw.get(key)
+            if got is not None and got["gen"] == op["gen"] and got["mirror"] is mirror:
+                self._endw.move_to_end(key)
+                return got["w"], got["rows"], False
+        mask = end.local_mask()
+        if mask is None:
+            return None
+        t0 = _time.perf_counter()
+        by_dst = op.get("by_dst")
+        if by_dst is None:  # a dense operator sorts its paths on first need
+            by_dst = op["by_dst"] = _csc_arrays(*op["paths"], op["nd_pad"])
+        cptr, csrc = by_dst
+        passing = np.flatnonzero(mask[: len(cptr) - 1])
+        w = _weights_into(cptr, csrc, passing, size)
+        t1 = _time.perf_counter()
+        telemetry.stage("graph_filter_build", t0, t1 - t0, bytes=w.nbytes)
+        got = {"gen": op["gen"], "mirror": mirror, "w": jnp.asarray(w), "rows": int(mask.sum())}
+        telemetry.stage("graph_filter_upload", t1, _time.perf_counter() - t1, bytes=w.nbytes)
+        with self._lock:
+            self._endw[key] = got
+            self._endw.move_to_end(key)
+            held = sum(e["w"].nbytes for e in self._endw.values())
+            while held > _END_WEIGHTS_BYTES and len(self._endw) > 1:
+                held -= self._endw.popitem(last=False)[1]["w"].nbytes
+        return got["w"], got["rows"], True
 
     def _chain_pairs(self, ns, db, specs, pair_of):
         """One composed operator a `->edge->node` pair of the chain, by
@@ -1194,11 +1382,28 @@ class GraphMirrors:
             ops.append(op)
         return ops
 
-    def _dense_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
+    def _filtered(self, end: "_EndFilter", op: dict, size: int):
+        """The end weights of a count's last pair `op` under `end`, with the
+        statement's `graph_filter` span; None where the predicate cannot
+        ride (the KV walk)."""
+        t0 = _time.perf_counter()
+        got = self._end_weights(end, op, size)
+        if got is None:
+            return None
+        w, rows, built = got
+        end.span(t0, built, rows)
+        return w
+
+    def _dense_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None, end=None):
         """Count chain as composed dense matmuls (chain_count_batch_dense),
         exact under 2**31 at any degree. Returns None when the chain doesn't
         fit the dense form (odd spec count, or a pair _dense_pair refuses) —
-        _device_chain then uses the CSC form."""
+        _device_chain then uses the CSC form. With `end`, the predicate on
+        the chain's final node part, the count ends in the rider's end
+        weights where the bare count ends in the last pair's out-degrees:
+        riders of any bound values share a dispatch (the weights ride as
+        payload; the key says only that the batch is weighted). `_UNFUSED`
+        where the predicate cannot ride."""
         import jax.numpy as jnp
         from surrealdb_tpu import cnf
 
@@ -1216,32 +1421,42 @@ class GraphMirrors:
         fr, cw, seeded = _local_seeds(
             ops[0]["space_src"]["inv"], frontier, counts, fsz, n0
         )
+        how = "none" if end is None else "fused"
         if not seeded:
-            _served("dense", t_enter)
+            _served("dense", t_enter, filter=how)
             return 0
+        endw = None
+        if end is not None:
+            endw = self._filtered(end, ops[-1], ops[-1]["ns_pad"])
+            if endw is None:
+                return _UNFUSED
         As = tuple(op["A"] for op in ops[:-1])
-        outdeg = ops[-1]["outdeg"]
+        weighted = end is not None
+        outdeg = None if weighted else ops[-1]["outdeg"]
         key = (
             "gdense", fsz, n0,
-            tuple(id(a) for a in As), id(outdeg),
+            tuple(id(a) for a in As),
+            ("w", ops[-1]["ns_pad"]) if weighted else id(outdeg),
         )
 
         def runner(payloads):
             from surrealdb_tpu import compile_log
 
             frs, cws = _stack_lanes(payloads, fsz, n0)
+            ends = _lane_end_weights(payloads, len(frs)) if weighted else None
             with compile_log.tracked(
-                "graph_dense", _dense_shape_key(len(frs), fsz, n0, As)
+                "graph_dense", _dense_shape_key(len(frs), fsz, n0, As, weighted)
             ):
                 out = kernel(
-                    As, outdeg, jnp.asarray(frs), jnp.asarray(cws), n0=n0
+                    As, outdeg, jnp.asarray(frs), jnp.asarray(cws), n0=n0,
+                    end_weights=ends,
                 )
             return _collect_counts(out, len(payloads), len(frs))
 
-        _served("dense", t_enter)
-        return dispatch.submit(key, (fr, cw), runner)
+        _served("dense", t_enter, filter=how)
+        return dispatch.submit(key, (fr, cw, endw) if weighted else (fr, cw), runner)
 
-    def _csc_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None):
+    def _csc_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None, end=None):
         """Count chain in the scatter-free CSC cumsum form
         (chain_count_batch): what no dense operator can hold. A chain of
         composable `->edge->node` pairs whose tables line up sweeps their
@@ -1249,7 +1464,10 @@ class GraphMirrors:
         node table's compact ids; any other chain (a hop over several
         tables, an odd spec count, pairs padded to different spaces) sweeps
         the record-level mirrors, one hop a spec in the shared id space.
-        One kernel either way, chosen from what the chain is."""
+        One kernel either way, chosen from what the chain is. A predicate
+        on the final node part (`end`) rides the composed operand alone, as
+        _dense_chain_count's does: the end weights live in the node
+        table's compact ids."""
         import jax.numpy as jnp
 
         from surrealdb_tpu import cnf
@@ -1261,16 +1479,24 @@ class GraphMirrors:
             for a, b in zip(ops, ops[1:])
         ):
             ops = None
+        weighted = end is not None
+        endw, how = None, "fused" if weighted else "none"
         if ops is not None:
             operand, n_cap = "composed", ops[0]["n_pad"]
             fr, cw, seeded = _local_seeds(
                 ops[0]["space_src"]["inv"], frontier, counts, fsz, n_cap
             )
             if not seeded:
-                _served("csc", t_enter, operand)
+                _served("csc", t_enter, operand, filter=how)
                 return 0
+            if weighted:
+                endw = self._filtered(end, ops[-1], n_cap)
+                if endw is None:
+                    return _UNFUSED
             csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in ops[:-1])
-            last_hop = ((ops[-1]["indptr"],),)
+            last_hop = () if weighted else ((ops[-1]["indptr"],),)
+        elif weighted:
+            return _UNFUSED
         else:
             operand = "records"
             hop_mirrors = [self._hop_mirrors(ns, db, sp) for sp in specs]
@@ -1288,34 +1514,36 @@ class GraphMirrors:
             last_hop = tuple((m.device_arrays()[0],) for m in hop_mirrors[-1])
         _kernels()
         batch_kernel = _JITTED["chain_count_batch"]
-        # the operands' ids: only chains over the same arrays coalesce
+        # the operands' ids: only chains over the same arrays coalesce (a
+        # weighted batch's riders bring their own ends, whatever they bound)
         key = (
             "gchain", fsz, n_cap, len(specs),
             tuple(id(a) for hop in csc_hops for pair in hop for a in pair),
-            tuple(id(p) for (p,) in last_hop),
+            "w" if weighted else tuple(id(p) for (p,) in last_hop),
         )
 
         def runner(payloads):
             from surrealdb_tpu import compile_log
 
             frs, cws = _stack_lanes(payloads, fsz, n_cap)
+            ends = _lane_end_weights(payloads, len(frs)) if weighted else None
             with compile_log.tracked(
                 "graph_csc",
-                _csc_shape_key(len(frs), fsz, n_cap, csc_hops, last_hop),
+                _csc_shape_key(len(frs), fsz, n_cap, csc_hops, last_hop, weighted),
             ):
                 out = batch_kernel(
                     csc_hops, last_hop,
                     jnp.asarray(frs), jnp.asarray(cws),
-                    n_cap=n_cap,
+                    n_cap=n_cap, end_weights=ends,
                 )
             return _collect_counts(out, len(payloads), len(frs))
 
-        _served("csc", t_enter, operand)
-        return dispatch.submit(key, (fr, cw), runner)
+        _served("csc", t_enter, operand, filter=how)
+        return dispatch.submit(key, (fr, cw, endw) if weighted else (fr, cw), runner)
 
     def _device_chain(
         self, ns, db, frontier: np.ndarray, counts: np.ndarray, specs,
-        count_only: bool = False, dispatch=None, t_enter=None,
+        count_only: bool = False, dispatch=None, t_enter=None, end=None,
     ):
         """Run the remaining hops entirely on device in ONE dispatch. The
         one place a device count is produced: with a dispatcher a count
@@ -1329,11 +1557,11 @@ class GraphMirrors:
         recompile."""
         if count_only and dispatch is not None:
             res = self._dense_chain_count(
-                ns, db, frontier, counts, specs, dispatch, t_enter=t_enter
+                ns, db, frontier, counts, specs, dispatch, t_enter=t_enter, end=end
             )
             if res is None:
                 res = self._csc_chain_count(
-                    ns, db, frontier, counts, specs, dispatch, t_enter=t_enter
+                    ns, db, frontier, counts, specs, dispatch, t_enter=t_enter, end=end
                 )
             return res
         import jax.numpy as jnp
@@ -1389,10 +1617,14 @@ class GraphMirrors:
         keep = c > 0
         return u[keep].astype(np.int32), c[keep].astype(np.int32)
 
-    def _chain_frontier(self, ctx, start: List[Thing], parts: List, count_only: bool = False):
+    def _chain_frontier(self, ctx, start: List[Thing], parts: List, count_only: bool = False, where=None):
         """Shared frontier machinery for chain()/chain_count(): returns
         (frontier int32[], counts int32[], interner) — or the scalar path
-        count when count_only (the device chain then downloads one int)."""
+        count when count_only (the device chain then downloads one int).
+        `where` is the compiled predicate of a count chain's final node
+        part: the count takes the bare chain's route with it (from the
+        seed to the device, or hop by hop on the host where the chain is
+        small), or is None where the predicate cannot ride."""
         from surrealdb_tpu import cnf
 
         t_enter = _time.perf_counter()
@@ -1416,6 +1648,11 @@ class GraphMirrors:
         frontier = np.fromiter(sorted(cmap), dtype=np.int32, count=len(cmap))
         counts = np.array([cmap[int(i)] for i in frontier], dtype=np.int32)
         dispatch = getattr(ctx.ds(), "dispatch", None)
+        end = None
+        if where is not None:
+            end = _EndFilter(self, ctx, parts[-1].what[0], where)
+            if end.mirror() is None:
+                return None
         if (
             count_only
             and not cnf.TPU_DISABLE
@@ -1428,10 +1665,11 @@ class GraphMirrors:
             # chain is one tiny-upload batched dispatch (no host hops means
             # no GIL serialization across concurrent clients, and every
             # query shares one compiled shape so they coalesce)
-            return self._device_chain(
+            res = self._device_chain(
                 ns, db, frontier, counts, specs,
-                count_only=True, dispatch=dispatch, t_enter=t_enter,
+                count_only=True, dispatch=dispatch, t_enter=t_enter, end=end,
             )
+            return None if res is _UNFUSED else res
         i = 0
         while i < len(specs):
             # a hop goes on device once the CURRENT frontier is device-sized,
@@ -1447,7 +1685,11 @@ class GraphMirrors:
                 count_only
                 and frontier.size * md >= cnf.TPU_GRAPH_ONDEVICE_THRESHOLD
             )
-            if not cnf.TPU_DISABLE and device_now:
+            # a filtered count that did not leave from the seed is small
+            # by the estimate: it stays on the host, where the mask is
+            # applied to the last frontier (a mid-chain hand-over would
+            # sweep record-level operands, which carry no end weights)
+            if not cnf.TPU_DISABLE and device_now and end is None:
                 res = self._device_chain(
                     ns, db, frontier, counts, specs[i:],
                     count_only=count_only, dispatch=dispatch, t_enter=t_enter,
@@ -1459,7 +1701,16 @@ class GraphMirrors:
             frontier, counts = self._host_hop(ns, db, frontier, counts, specs[i])
             i += 1
         if count_only:
-            _served("host", t_enter)
+            if end is not None:
+                t0 = _time.perf_counter()
+                mask = end.local_mask()
+                if mask is None:
+                    return None
+                inv = self.table_space(ns, db, end.tb)["inv"]
+                local = [inv.get(int(g), -1) for g in frontier.tolist()]
+                counts = counts[[0 <= j < mask.size and bool(mask[j]) for j in local]]
+                end.span(t0, True, int(mask.sum()))
+            _served("host", t_enter, filter="none" if end is None else "fused")
             return int(counts.sum())
         return frontier, counts, it
 
@@ -1488,13 +1739,23 @@ class GraphMirrors:
             out.extend([it.node_of[int(j)]] * int(c))
         return out
 
-    def chain_count(self, ctx, start: List[Thing], parts: List) -> int:
+    def chain_count(self, ctx, start: List[Thing], parts: List, where=None) -> Optional[int]:
         """Path count of a chain WITHOUT materializing the expanded result —
         `count(->a->b->c)` sums the frontier's path counts directly (on a
         3-hop over 1M edges the Python expansion would dominate the whole
         query; the device already holds the counts, and the fused chain
-        kernel downloads a single scalar)."""
-        return self._chain_frontier(ctx, start, parts, count_only=True)
+        kernel downloads a single scalar). `where`: the compiled predicate
+        (ops/predicates.py) of `->(c WHERE ...)`, the final part, which
+        then names one node table; the count is of the paths that end at a
+        node passing it, or None where the predicate cannot ride the
+        mirrors (the caller walks the KV and says so: count_walked)."""
+        return self._chain_frontier(ctx, start, parts, count_only=True, where=where)
+
+    @staticmethod
+    def count_walked(t_enter: float) -> None:
+        """A count chain with a WHERE that the KV walk served, from
+        `t_enter` to now: `form=host`, `filter=host`."""
+        _served("host", t_enter, filter="host")
 
 
 def graftcheck_sites():
@@ -1517,12 +1778,15 @@ def graftcheck_sites():
             jax.ShapeDtypeStruct((n0, n0), jnp.dtype(ml_dtypes.bfloat16))
             for _ in range(shape["hops"])
         )
+        end = jax.ShapeDtypeStruct((n0,), jnp.int32)
         args = (
             As,
-            jax.ShapeDtypeStruct((n0,), jnp.int32),
+            (end,) * lanes if shape.get("weighted") else end,
             jax.ShapeDtypeStruct((lanes, fsz), jnp.int32),
             jax.ShapeDtypeStruct((lanes, fsz), jnp.int32),
         )
+        if shape.get("weighted"):
+            return (lambda A, ws, fr, cw: kernel(A, None, fr, cw, n0=n0, end_weights=ws)), args
         return (lambda A, od, fr, cw: kernel(A, od, fr, cw, n0=n0)), args
 
     def build_csc(shape):
@@ -1534,16 +1798,17 @@ def graftcheck_sites():
               jax.ShapeDtypeStruct((E,), jnp.int32)),)
             for _ in range(shape["hops"] - 1)
         )
+        lanes_fsz = jax.ShapeDtypeStruct((lanes, fsz), jnp.int32)
+        if shape.get("weighted"):
+            ends = (jax.ShapeDtypeStruct((n_cap,), jnp.int32),) * lanes
+            return (
+                lambda ch, ws, fr, cw: kernel(ch, (), fr, cw, n_cap=n_cap, end_weights=ws),
+                (csc_hops, ends, lanes_fsz, lanes_fsz),
+            )
         last_hop = ((jax.ShapeDtypeStruct((n_cap + 1,), jnp.int32),),)
-        args = (
-            csc_hops,
-            last_hop,
-            jax.ShapeDtypeStruct((lanes, fsz), jnp.int32),
-            jax.ShapeDtypeStruct((lanes, fsz), jnp.int32),
-        )
         return (
             lambda ch, lh, fr, cw: kernel(ch, lh, fr, cw, n_cap=n_cap),
-            args,
+            (csc_hops, last_hop, lanes_fsz, lanes_fsz),
         )
 
     def build_chain(shape):
@@ -1569,9 +1834,12 @@ def graftcheck_sites():
             args,
         )
 
-    # as served: every lane count the runners can return (count_lane_set)
+    # as served: every lane count the runners can return (count_lane_set),
+    # ending in the last pair's degrees or (`w`) in a rider's own weights
     lane_shapes = [
-        {"label": f"l{lanes}_f{fsz}_n{n0}_h2", "lanes": lanes, "hops": 2}
+        {"label": f"l{lanes}_f{fsz}_n{n0}_h2" + ("_w" if weighted else ""),
+         "lanes": lanes, "hops": 2, "weighted": weighted}
+        for weighted in (False, True)
         for lanes in count_lane_set()
     ]
     return [

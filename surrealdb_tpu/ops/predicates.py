@@ -34,7 +34,7 @@ from typing import Any, List, Optional, Set, Tuple
 import numpy as np
 
 from surrealdb_tpu.sql.ast import ArrayLit, BinaryOp, Expr, Literal, Param, UnaryOp
-from surrealdb_tpu.sql.path import Idiom
+from surrealdb_tpu.sql.path import Idiom, PField, PStart
 from surrealdb_tpu.sql.value import Datetime, is_none, is_null
 
 # column tag codes (idx/column_mirror.py writes these)
@@ -123,6 +123,22 @@ class CompiledPredicate:
         if root is None:
             return None
         return CompiledPredicate(root, self.paths, self.source)
+
+    def binding_key(self) -> tuple:
+        """What a mask of this predicate depends on besides the columns: its
+        text and the constants bound into its leaves, by type and repr
+        (`1`, `1.0` and `true` are different constants). Hashable."""
+        consts: List[Tuple[str, str]] = []
+
+        def walk(n: _Node) -> None:
+            if isinstance(n, _Bool):
+                for k in n.kids:
+                    walk(k)
+            else:
+                consts.append((type(n.const).__name__, repr(n.const)))
+
+        walk(self.root)
+        return (self.source, tuple(consts))
 
     def evaluate(self, columns) -> Tuple[np.ndarray, np.ndarray]:
         """columns: {path: Column} covering self.paths (idx/column_mirror)."""
@@ -252,6 +268,15 @@ def _is_const(e) -> bool:
         return True
     if isinstance(e, ArrayLit):
         return all(_is_const(x) for x in e.items)
+    if isinstance(e, Idiom) and len(e.parts) > 1 and isinstance(e.parts[0], PStart):
+        # `$q.fn`: a field of a bound parameter is as constant as the
+        # parameter. `$this` and `$parent` are bound a row, not a statement
+        head = e.parts[0].expr
+        return (
+            isinstance(head, Param)
+            and head.name not in ("this", "parent")
+            and all(isinstance(p, PField) for p in e.parts[1:])
+        )
     return False
 
 

@@ -416,11 +416,21 @@ def _prefix_end(p: bytes) -> bytes:
 
 
 def graph_chain_count(ctx, expr) -> "int | None":
-    """count(->a->b->c) fast path: when the argument is a pure cond-free
-    graph-chain idiom over the current record, sum the path counts on the
-    CSR frontier without expanding (idx/graph_csr.py chain_count). Returns
-    None when ineligible — the caller falls back to normal evaluation, so
-    this is purely an execution strategy, never a semantics change."""
+    """count(->a->b->c) fast path: when the argument is a graph-chain idiom
+    over the current record whose parts name their tables, sum the path
+    counts on the CSR frontier without expanding (idx/graph_csr.py
+    chain_count). Eligible: a chain with no WHERE at all, or one whose only
+    WHERE sits on its final part, names one node table there and lowers
+    onto that table's column mirror (ops/predicates.py compile_where:
+    comparisons of the node's own fields against constants or bound
+    parameters, AND / OR / NOT); the predicate then rides the count as a
+    mask. Returns None when ineligible — the caller falls back to normal
+    evaluation, so this is purely an execution strategy, never a semantics
+    change. A chain with a WHERE that cannot ride (on an edge part or a
+    middle part, not lowerable, several tables in the last part,
+    uncommitted edge writes, a column mirror this reader may not use) is
+    walked here as the caller would walk it, so that its `graph_prepare`
+    span can say `filter=host`."""
     if not isinstance(expr, Idiom) or not expr.parts:
         return None
     if not all(isinstance(p, PGraph) for p in expr.parts):
@@ -429,21 +439,53 @@ def graph_chain_count(ctx, expr) -> "int | None":
     rid = doc.rid if doc is not None else None
     if not isinstance(rid, Thing):
         return None
-    for p in expr.parts:
-        if not _mirror_eligible(ctx, p):
-            return None
-    # no exception guard: deadline/internal errors must propagate, not
-    # silently re-run the whole traversal on the slow path
-    n = ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(expr.parts))
+    if all(p.cond is None for p in expr.parts):
+        for p in expr.parts:
+            if not _mirror_eligible(ctx, p):
+                return None
+        # no exception guard: deadline/internal errors must propagate, not
+        # silently re-run the whole traversal on the slow path
+        n = ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(expr.parts))
+        ctx.executor.op_end = time.perf_counter()
+        return n
+    t_enter = time.perf_counter()
+    n = _filtered_chain_count(ctx, rid, expr.parts)
+    if n is None:
+        from surrealdb_tpu import fnc
+
+        n = fnc.run(ctx, "count", [expr.compute(ctx)], exprs=[expr])
+        mirrors = getattr(ctx.ds(), "graph_mirrors", None)
+        if mirrors is not None:
+            mirrors.count_walked(t_enter)
     ctx.executor.op_end = time.perf_counter()
     return n
 
 
-def _mirror_eligible(ctx, p: PGraph) -> bool:
+def _filtered_chain_count(ctx, rid: Thing, parts) -> "int | None":
+    """The count of a chain whose final part alone has a WHERE, off the
+    mirrors; None where the chain or the predicate is not of that kind."""
+    last = parts[-1]
+    if last.cond is None or len(last.what) != 1:
+        return None
+    if not all(_mirror_eligible(ctx, p) for p in parts[:-1]):
+        return None
+    if not _mirror_eligible(ctx, last, cond_ok=True):
+        return None
+    from surrealdb_tpu.ops.predicates import compile_where
+
+    where = compile_where(ctx, last.cond)
+    if where is None:
+        return None
+    return ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(parts), where=where)
+
+
+def _mirror_eligible(ctx, p: PGraph, cond_ok: bool = False) -> bool:
     """A hop can ride the CSR mirrors when its edge tables are named, it has
-    no per-record WHERE, and this transaction has no uncommitted edge writes
-    (those are only visible to the exact KV walk)."""
-    if p.cond is not None or not p.what:
+    no per-record WHERE (`cond_ok`: but for a count chain's final part,
+    whose WHERE graph_chain_count takes to the column mirror itself), and
+    this transaction has no uncommitted edge writes (those are only visible
+    to the exact KV walk)."""
+    if (p.cond is not None and not cond_ok) or not p.what:
         return False
     try:
         return ctx.ds() is not None and not ctx.txn().graph_deltas

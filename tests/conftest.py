@@ -21,10 +21,14 @@ import pytest
 
 @pytest.fixture()
 def ds():
-    """Fresh in-memory datastore."""
+    """Fresh in-memory datastore, closed when the test ends: a graph prewarm
+    the test armed is cancelled or waited out there, and does not go on
+    compiling (and logging its shapes) under the tests that follow."""
     from surrealdb_tpu.kvs.ds import Datastore
 
-    return Datastore("memory")
+    store = Datastore("memory")
+    yield store
+    store.close()
 
 
 def pytest_configure(config):

@@ -210,7 +210,10 @@ def test_graftcheck_audits_the_count_kernels_as_served(subsystem):
     from scripts.graftcheck import lowering, registry, rules
 
     (contract,) = registry.resolve_contracts([subsystem])
-    assert tuple(shape["lanes"] for shape in contract["shapes"]) == VOCABULARY
+    # the vocabulary twice: ending in the last pair's degrees, and in a rider's own end weights (ISSUE 31)
+    for weighted in (False, True):
+        assert tuple(s["lanes"] for s in contract["shapes"] if s["weighted"] is weighted) == VOCABULARY
+    assert len(contract["shapes"]) == 2 * len(VOCABULARY)
     for shape in contract["shapes"]:
         low = lowering.lower_site(contract, shape)
         assert rules.check(contract, shape, low) == [] and low.collectives == {}

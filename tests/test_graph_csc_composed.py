@@ -190,7 +190,7 @@ def test_span_and_counter_name_one_operand_and_the_form_stays_csc(ds, monkeypatc
     for i, start in enumerate((0, 3, 9)):
         got, spans = traced_count(ds, sess, start, f"operand-{i}")
         assert got == walk_count(n, edges, {start: 1}, 3)
-        assert [s["labels"] for s in named(spans, "graph_prepare")] == [{"form": "csc", "operand": "composed"}]
+        assert [s["labels"] for s in named(spans, "graph_prepare")] == [{"form": "csc", "filter": "none", "operand": "composed"}]
     # a person nobody relates from is not in the table's space: no seed, no dispatch, still a csc count
     assert forms() == {"csc": 3} and operands() == {"composed": 3}
     assert {e["subsystem"] for e in compile_log.events()} == {"graph_csc"}
@@ -204,11 +204,11 @@ def test_a_dense_or_host_count_names_no_operand(ds, monkeypatch):
     sess = loaded(ds, monkeypatch, n, edges)
     monkeypatch.setattr(cnf, "TPU_GRAPH_DENSE_MAX", 16384)
     _, spans = traced_count(ds, sess, 0, "dense")
-    assert [s["labels"] for s in named(spans, "graph_prepare")] == [{"form": "dense"}]
+    assert [s["labels"] for s in named(spans, "graph_prepare")] == [{"form": "dense", "filter": "none"}]
     monkeypatch.setattr(cnf, "TPU_GRAPH_COUNT_EDGES", 10**15)
     monkeypatch.setattr(cnf, "TPU_GRAPH_ONDEVICE_THRESHOLD", 10**9)
     _, spans = traced_count(ds, sess, 0, "host")
-    assert [s["labels"] for s in named(spans, "graph_prepare")] == [{"form": "host"}]
+    assert [s["labels"] for s in named(spans, "graph_prepare")] == [{"form": "host", "filter": "none"}]
     assert forms() == {"dense": 1, "host": 1} and operands() == {}
 
 
@@ -249,7 +249,7 @@ def test_a_hop_over_two_edge_tables_sweeps_the_records_exactly(monkeypatch):
                                t_enter=0.0)
     assert got == walk_count(n, np.concatenate([knows, follows]), seeds, 2)
     (span,) = named(tracing.get_trace("two-tables")["spans"], "graph_prepare")
-    assert span["labels"] == {"form": "csc", "operand": "records"}
+    assert span["labels"] == {"form": "csc", "filter": "none", "operand": "records"}
     assert operands() == {"records": 1} and gm._csc == {}
 
 
