@@ -56,117 +56,97 @@ def default_nlists(n: int) -> int:
 
 
 def default_nprobe(nlists: int, ef: Optional[int]) -> int:
-    """Map the HNSW-style ef beam width onto probed-list count. With
-    balanced lists each probe examines ~2·N/C candidates, so ef/10 probes
-    lands near the reference's beam-width semantics (search ef=80 → 8
-    probes ≈ 99% recall on clustered data, see tests/test_ivf.py)."""
+    """Map the HNSW-style ef beam width onto probed-list count. Training
+    leaves no list longer than twice the mean of its first assignment
+    (`IvfState.train` re-clusters what passes that), so a probe examines
+    at most ~2·N/C candidates and ef/10 probes lands near
+    the reference's beam-width semantics (search ef=80 → 8 probes ≈ 99%
+    recall on clustered data, see tests/test_ivf.py)."""
     if ef is not None and ef > 0:
         return min(max(4, round(ef / 10)), nlists)
     return min(max(4, nlists // 16), nlists)
 
 
-@functools.partial(jax.jit, static_argnames=("k_assign",))
-def _assign_chunk(chunk, cents, k_assign=1):
-    """Nearest-centroid assignment for one corpus tile (euclidean)."""
+_ASSIGN_CHUNK = 65536  # rows a call of `_assign_chunk`: one compiled shape
+# re-clustering rounds of one training: the pool shrank by ~0.55 a round where
+# it was measured (8 rounds at 1M x 768), so none is left long before this
+_MAX_ROUNDS = 32
+
+
+def _nearest(chunk, cents, n_cents, n_rows):
+    """Nearest of the first `n_cents` centroids for every row of a tile
+    (euclidean), with the per-centroid sums and counts of the tile's first
+    `n_rows` rows. Both tables are padded (the centroid table to the first
+    training's count, a tile to its fixed height) and the two counts say
+    how much of each is real, so every k-means step and every assignment
+    of one training runs the same two compiled programs."""
     import jax.numpy as jnp
 
+    c = cents.shape[0]
     d = D.pairwise_distance(chunk, cents, "euclidean")
-    if k_assign == 1:
-        return jnp.argmin(d, axis=1)
-    return jax.lax.top_k(-d, k_assign)[1]
+    a = jnp.argmin(jnp.where(jnp.arange(c)[None, :] < n_cents, d, jnp.inf), axis=1)
+    w = (jnp.arange(chunk.shape[0]) < n_rows).astype(jnp.float32)
+    sums = jax.ops.segment_sum(chunk.astype(jnp.float32) * w[:, None], a, num_segments=c)
+    return a, sums, jax.ops.segment_sum(w, a, num_segments=c)
 
 
-@functools.partial(jax.jit, static_argnames=("k_assign",))
-def _assign_gather(matrix, idx, cents, k_assign=1):
-    """Gather rows from the DEVICE-resident mirror matrix and assign them to
-    their nearest centroids — only the [chunk] index vector crosses the
-    host->device link, not the rows themselves (the corpus is already in
-    HBM; re-uploading 1Mx768 for assignment would move 3 GB again)."""
+_assign_chunk = jax.jit(_nearest)
+
+
+@jax.jit
+def _kmeans_step(xs, c, n_cents, n_rows):
     import jax.numpy as jnp
 
-    chunk = matrix[jnp.clip(idx, 0, matrix.shape[0] - 1)]
-    d = D.pairwise_distance(chunk, cents, "euclidean")
-    if k_assign == 1:
-        return jnp.argmin(d, axis=1)
-    return jax.lax.top_k(-d, k_assign)[1]
-
-
-@functools.partial(jax.jit, static_argnames=("nlists",))
-def _kmeans_step(xs, c, nlists: int):
-    import jax.numpy as jnp
-
-    d = D.pairwise_distance(xs, c, "euclidean")
-    a = jnp.argmin(d, axis=1)
-    sums = jax.ops.segment_sum(xs.astype(jnp.float32), a, num_segments=nlists)
-    cnts = jax.ops.segment_sum(jnp.ones(xs.shape[0], jnp.float32), a, num_segments=nlists)
+    _, sums, cnts = _nearest(xs, c, n_cents, n_rows)
     # empty clusters keep their previous centroid
     return jnp.where(cnts[:, None] > 0, sums / jnp.maximum(cnts[:, None], 1.0), c.astype(jnp.float32))
 
 
-def _kmeans_xs(xs, nlists: int, iters: int = 8, seed: int = 7):
-    """Device k-means over an already-device-resident sample [n, D]."""
+def _kmeans_xs(xs, n_rows: int, nlists: int, pad_lists: int, rng, iters: int = 8):
+    """Device k-means over the first `n_rows` rows of an already-device-
+    resident sample [n, D]: `nlists` centroids, seeded from sample rows, in
+    a table of `pad_lists` rows (only the first `nlists` mean anything)."""
     import jax.numpy as jnp
 
-    rng = np.random.default_rng(seed)
-    cents = xs[jnp.asarray(rng.choice(xs.shape[0], size=nlists, replace=False))]
+    from surrealdb_tpu.utils.num import pad_tail
+
+    seeds = rng.choice(n_rows, size=nlists, replace=False).astype(np.int32)
+    cents = xs[jnp.asarray(pad_tail(seeds, pad_lists))]
     for _ in range(iters):
-        cents = _kmeans_step(xs, cents, nlists)
+        cents = _kmeans_step(xs, cents, nlists, n_rows)
     return cents
 
 
-def _kmeans(x: np.ndarray, nlists: int, iters: int = 8, seed: int = 7) -> np.ndarray:
-    """Device k-means on a host training subsample; returns [C, D] centroids."""
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(seed)
-    n = x.shape[0]
-    train_n = min(n, max(nlists * 64, 16384))
-    sample = x[rng.choice(n, size=train_n, replace=False)] if train_n < n else x
-    xs = jnp.asarray(sample)
-    return np.asarray(_kmeans_xs(xs, nlists, iters, seed), dtype=np.float32)
-
-
-def _full_assign(
-    x: np.ndarray, cents: np.ndarray, chunk: int = 65536, k_assign: int = 1
-) -> np.ndarray:
-    """Assign every corpus row to its k nearest centroids, tiled so the
-    [N, C] distance matrix never materializes whole."""
-    import jax.numpy as jnp
-
-    cj = jnp.asarray(cents)
-    shape = (x.shape[0],) if k_assign == 1 else (x.shape[0], k_assign)
-    out = np.empty(shape, dtype=np.int32)
-    for lo in range(0, x.shape[0], chunk):
-        hi = min(lo + chunk, x.shape[0])
-        tile = x[lo:hi]
-        pad = chunk - (hi - lo)
-        if pad:
-            tile = np.concatenate([tile, np.zeros((pad, x.shape[1]), x.dtype)])
-        a = np.asarray(_assign_chunk(jnp.asarray(tile), cj, k_assign=k_assign))
-        out[lo:hi] = a[: hi - lo]
-    return out
+def _group(slots: np.ndarray, assign: np.ndarray, k: int) -> List[np.ndarray]:
+    """The slots of each of `k` lists, in the order they came."""
+    order = np.argsort(assign, kind="stable")
+    bounds = np.searchsorted(assign[order], np.arange(k + 1))
+    return [slots[order[lo:hi]] for lo, hi in zip(bounds[:-1], bounds[1:])]
 
 
 class IvfState:
     """Trained quantizer + inverted lists over mirror row slots.
 
     Host-authoritative: `lists` maps centroid → row slots; device arrays are
-    compacted lazily (numpy only — never a KV rescan). Incremental adds
-    assign to the nearest existing centroid; retrain happens when the corpus
-    outgrows the trained size by 50%.
+    compacted lazily (numpy only — never a KV rescan). Training leaves no
+    list longer than `cap`: it re-clusters the lists that pass it. An
+    incremental add assigns to the nearest existing centroid, and a list
+    may pass `cap` that way until the retrain, which happens when the
+    corpus outgrows the trained size by 50%.
     """
 
-    def __init__(self, centroids: np.ndarray, lists: List[List[int]], trained_n: int):
+    def __init__(self, centroids: np.ndarray, lists: List[List[int]], trained_n: int, cap: int):
         self.centroids = centroids  # [C, D] float32
         self.lists = lists  # C lists of row slots
         self.slot_list: Dict[int, int] = {s: i for i, l in enumerate(lists) for s in l}
         self.trained_n = trained_n
+        self.cap = cap  # the longest list training leaves: twice the mean of its first assignment
         self._n = len(self.slot_list)  # O(1) size, maintained by add/remove
         self.dirty = True
         self._dev = None  # (cents, list_rows, list_mask)
         self._mut = 0  # bumped on every list mutation; sharded cache keys off it
         self._sharded_cache = None  # (key, (cents, rows, mask, shard_rows))
-        self._warmed: set = set()  # (tile, k, nprobe, metric) combos compiled
+        self._warmed: set = set()  # (tile, k, nprobe, metric, pad) combos compiled
 
     @property
     def nlists(self) -> int:
@@ -180,68 +160,120 @@ class IvfState:
         nlists: Optional[int] = None,
         matrix=None,
     ) -> "IvfState":
-        """Train the quantizer. When `matrix` (the mirror's device-resident
-        [cap, D] array) is given, the training sample and the full corpus
-        assignment gather rows ON DEVICE — only index vectors and the [C, D]
-        centroids cross the slow host<->device link."""
+        """Train the quantizer: k-means over a sample, every row to its
+        nearest centroid, then the bound on a list's length (`cap`: twice
+        the mean of that assignment) enforced by POOLED RE-CLUSTERING. On clustered data
+        in many dimensions an under-trained centroid averages several
+        clusters and is the nearest one to every row whose own cluster got
+        none: a hub list of twenty times the mean, which every search then
+        probes (1M x 768: 108 of 1,024 lists held 594,143 rows, PERF.md
+        section 6, PR 32). The rows of ALL lists over the cap are pooled,
+        those lists and centroids dropped, `ceil(pool / (cap / 2))` new
+        centroids trained over a sample of the pool, the pool assigned to
+        them, and every non-empty one becomes a list whose centroid is the
+        mean of its rows; again on what is still over the cap, until nothing
+        is. Pooled, not list by list: a natural cluster scattered over
+        several equidistant hubs stays scattered over their children, and
+        recall pays for it. A round that separates nothing (duplicates), or
+        the `_MAX_ROUNDS`-th, is cut by order into lists of at most the cap.
+
+        When `matrix` (the mirror's device-resident [cap, D] array) is
+        given, samples and assignment tiles gather rows ON DEVICE — only
+        index vectors, assignments and [C, D] centroid tables cross the slow
+        host<->device link."""
         import jax.numpy as jnp
 
         from surrealdb_tpu import telemetry
+        from surrealdb_tpu.utils.num import pad_tail, tile_slices
 
         iters = 8
         t_train = _time.perf_counter()
         rows = np.nonzero(alive)[0]
         c = nlists or default_nlists(rows.size)
-        if matrix is not None and rows.size:
-            rng = np.random.default_rng(7)
-            train_n = min(rows.size, max(c * 64, 16384))
-            sample_slots = rng.choice(rows, size=train_n, replace=False)
-            xs = matrix[jnp.asarray(sample_slots.astype(np.int32))]
-            cents_dev = _kmeans_xs(xs, c, iters)
-            cents_dev.block_until_ready()  # the assignment waits for it anyway
-            t_assign = _time.perf_counter()
-            # full assignment by device gather, chunked index uploads only
-            from surrealdb_tpu.utils.num import pad_tail, tile_slices
-
-            chunk = 65536
-            assign2 = np.empty((rows.size, 2), dtype=np.int32)
-            for lo, hi in tile_slices(rows.size, chunk):
-                idx = pad_tail(rows[lo:hi].astype(np.int32), chunk)
-                a = np.asarray(
-                    _assign_gather(matrix, jnp.asarray(idx), cents_dev, k_assign=2)
-                )
-                assign2[lo:hi] = a[: hi - lo]
-            cents = np.asarray(cents_dev, dtype=np.float32)
+        cap = max(2 * (rows.size + c - 1) // c, 8)
+        rng = np.random.default_rng(7)
+        if matrix is not None:
+            take = lambda slots: matrix[jnp.asarray(slots.astype(np.int32))]  # noqa: E731
         else:
-            x = np.ascontiguousarray(data[rows], dtype=np.float32)
-            cents = _kmeans(x, c, iters)
-            t_assign = _time.perf_counter()
-            assign2 = _full_assign(x, cents, k_assign=2)
+            take = lambda slots: jnp.asarray(data[slots], dtype=jnp.float32)  # noqa: E731
+        # one sample height and one centroid-table height for the first
+        # training and every round after it: nothing compiles anew per round
+        sample_n = min(rows.size, max(c * 64, 16384))
+
+        def kmeans(pool: np.ndarray, k: int):
+            n = min(pool.size, max(k * 64, 16384))
+            sample = rng.choice(pool, size=n, replace=False) if n < pool.size else pool
+            return _kmeans_xs(take(pad_tail(sample, sample_n)), n, k, c, rng, iters)
+
+        def assign(pool: np.ndarray, cents, k: int):
+            """(nearest of the k centroids per pooled row, the lists' means)"""
+            out = np.empty(pool.size, dtype=np.int32)
+            sums = cnts = 0.0  # summed on the device: one download a call
+            for lo, hi in tile_slices(pool.size, _ASSIGN_CHUNK):
+                a, s, n = _assign_chunk(
+                    take(pad_tail(pool[lo:hi], _ASSIGN_CHUNK)), cents, k, hi - lo
+                )
+                out[lo:hi] = np.asarray(a)[: hi - lo]
+                sums, cnts = sums + s, cnts + n
+            return out, np.asarray(sums) / np.maximum(np.asarray(cnts), 1.0)[:, None]
+
+        cents_dev = kmeans(rows, c)
+        cents_dev.block_until_ready()  # the assignment waits for it anyway
+        t_assign = _time.perf_counter()
+        first, _ = assign(rows, cents_dev, c)
         t_lists = _time.perf_counter()
         telemetry.stage(
             "ivf_train", t_train, t_assign - t_train, rows=rows.size, lists=c, iters=iters
         )
         telemetry.stage("ivf_assign", t_assign, t_lists - t_assign, rows=rows.size)
-        # balanced assignment: top-2 candidate cells with spill to the
-        # runner-up once the nearest is over 2x the mean size — bounds the
-        # padded gather at ~2·N/C per probe instead of the worst cell
-        cap = max(2 * (rows.size + c - 1) // c, 8)
-        lists: List[List[int]] = [[] for _ in range(c)]
-        for slot, (a1, a2) in zip(rows.tolist(), assign2.tolist()):
-            a = a1 if len(lists[a1]) < cap or len(lists[a2]) >= len(lists[a1]) else a2
-            lists[int(a)].append(slot)
-        state = IvfState(cents, lists, rows.size)
-        telemetry.stage("ivf_lists", t_lists, _time.perf_counter() - t_lists, rows=rows.size)
+
+        def sift(pool: np.ndarray, a: np.ndarray, k: int):
+            """(the lists that fit, which of the k they are, those over the
+            cap). A list with no rows is dropped wherever it arises: its
+            centroid could only cost a search one of its probes."""
+            groups = _group(pool, a, k)
+            sizes = np.array([g.size for g in groups])
+            fits = (sizes > 0) & (sizes <= cap)
+            return [g for g, f in zip(groups, fits) if f], fits, [g for g in groups if g.size > cap]
+
+        kept, fits, over = sift(rows, first, c)
+        kept_cents = [np.asarray(cents_dev, dtype=np.float32)[fits]]
+        split, pooled, rounds = len(over), sum(g.size for g in over), 0
+        while over:
+            pool = np.concatenate(over)
+            rounds += 1
+            k = -(-2 * pool.size // cap)
+            a, means = assign(pool, kmeans(pool, k), k)
+            lists, fits, over = sift(pool, a, k)
+            kept.extend(lists)
+            kept_cents.append(means[:k][fits])
+            if sum(g.size for g in over) == pool.size or rounds == _MAX_ROUNDS:
+                for g in over:  # nothing separated: cut by order
+                    for lo, hi in tile_slices(g.size, cap):
+                        kept.append(g[lo:hi])
+                        kept_cents.append(data[g[lo:hi]].mean(axis=0, dtype=np.float32)[None, :])
+                break
+        state = IvfState(
+            np.concatenate(kept_cents).astype(np.float32), [g.tolist() for g in kept], rows.size, cap
+        )
+        if split:
+            telemetry.inc("ivf_list_splits", split, at="train")
+        telemetry.stage(
+            "ivf_lists", t_lists, _time.perf_counter() - t_lists, rows=rows.size,
+            lists=state.nlists, split=split, pooled=pooled, rounds=rounds,
+            longest=max((len(l) for l in state.lists), default=0),
+        )
         return state
 
     # ------------------------------------------------------------ writes
     def add(self, slot: int, vec: np.ndarray) -> None:
+        """Assign a new row to its nearest centroid. Nothing bounds the list
+        here: the count of lists, and so every compiled shape but the pad,
+        stays what training made it, and the retrain at 1.5x growth pools
+        whatever has passed `cap` by then."""
         if slot in self.slot_list:
             return  # idempotent (reconciliation may revisit a slot)
-        d2 = ((self.centroids - vec[None, :]) ** 2).sum(1)
-        a1, a2 = np.argpartition(d2, 1)[:2]
-        cap = max(2 * (self._n // max(self.nlists, 1) + 1), 8)
-        a = int(a1) if len(self.lists[a1]) < cap or len(self.lists[a2]) >= len(self.lists[a1]) else int(a2)
+        a = int(((self.centroids - vec[None, :]) ** 2).sum(1).argmin())
         self.lists[a].append(slot)
         self.slot_list[slot] = a
         self._n += 1
@@ -483,13 +515,16 @@ class IvfState:
         same kernel carry no correctness risk — results are discarded."""
         from surrealdb_tpu.utils.num import warm_tile_sizes
 
+        # the pad is the one table shape an insert can move between retrains
+        # (a list grown past a power of two): the other tiles warm again then
+        pad = int(list_rows.shape[1])
         todo = []
         for t in warm_tile_sizes():
-            key = (t, k, nprobe, metric)
+            key = (t, k, nprobe, metric, pad)
             if t != served_tile and key not in self._warmed:
                 self._warmed.add(key)
                 todo.append(t)
-        self._warmed.add((served_tile, k, nprobe, metric))
+        self._warmed.add((served_tile, k, nprobe, metric, pad))
         if not todo:
             return
 

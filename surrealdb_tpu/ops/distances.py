@@ -99,12 +99,13 @@ def map_queries(one, q, probes, cand_rows: int, x):
     kernel (IVF, single-device and per shard). The gather holds
     `cand_rows` rows of `x` per query in the corpus dtype plus the f32
     working copy the distance matmul may make of them. IVF lists pad to
-    the LONGEST one, and on clustered data the quantizer's hub lists run
-    ~20x the mean (1M x 768 on the chip, PR 21: 1,024 lists, mean 977
-    rows, longest 22,084, pad 32,768: 906 MB per query), so a tile that
-    fits `gather_budget_bytes()` is one vmap and a wider one runs as
-    sequential sub-batches INSIDE the same executable: same tile shapes,
-    same results, bounded HBM."""
+    the power of two above the LONGEST one, which training holds to twice
+    the mean (1M x 768 on the chip, PR 32: ~2,100 lists, none over 1,955
+    rows, pad 2,048: 6 probes are 12,288 rows, 57 MB per query, where the
+    unbounded hub lists of PR 21 padded to 32,768 and a query held
+    906 MB), so a tile that fits `gather_budget_bytes()` is one vmap and a
+    wider one runs as sequential sub-batches INSIDE the same executable:
+    same tile shapes, same results, bounded HBM."""
     per_query = cand_rows * int(x.shape[1]) * (x.dtype.itemsize + 4)
     batch = max(1, gather_budget_bytes() // per_query)
     if batch >= q.shape[0]:

@@ -5,7 +5,7 @@ count (ISSUE 27). The graph is skewed and person 0 has 300 friends."""
 import numpy as np
 import pytest
 
-from surrealdb_tpu import cnf, compile_log, telemetry, tracing
+from surrealdb_tpu import bg, cnf, compile_log, telemetry, tracing
 from surrealdb_tpu.dbs.session import Session
 from surrealdb_tpu.sql.value import Thing
 from test_graph_dense_exact import forms, lognormal_hub, walk_count
@@ -26,6 +26,9 @@ CHOOSES = {
 
 @pytest.fixture
 def loaded(ds, monkeypatch):
+    # a shape warmer an earlier file of this worker left compiling would log
+    # its subsystem under the test that asks which subsystems compiled
+    assert bg.wait_idle(120.0)
     telemetry.reset()
     compile_log.reset()
     tracing.store_reset()

@@ -273,3 +273,323 @@ def test_ivf_strategies_consume_columnar_prefilter(ds, monkeypatch):
     monkeypatch.setattr(cnf, "TPU_DISABLE", True)
     run_and_check("ivf-host")  # numpy probe+rerank twin
     assert telemetry.get_counter("knn_prefilter", outcome="applied") >= applied0 + 2
+
+
+# ------------------------------------------------------------------ the bound on a list
+def _hub_corpus(n=40000, d=256, centres=1600, sigma=0.35, seed=5):
+    """The benchmark's generator (`benchmarks/deployments/vector_knn.py`) at
+    a size a test can train: many more centres than lists, and enough
+    dimensions that a centroid averaging several clusters is the nearest
+    one to every row whose own cluster got none. (rows, each row's centre)"""
+    rng = np.random.default_rng(seed)
+    cen = rng.standard_normal((centres, d), dtype=np.float32)
+    cid = rng.integers(0, centres, n)
+    return cen[cid] + np.float32(sigma) * rng.standard_normal((n, d), dtype=np.float32), cid
+
+
+def _state(x, cents, assign):
+    """An `IvfState` over given centroids and a given assignment, with no
+    bound on a list (what training gave before it enforced one)."""
+    from surrealdb_tpu.idx.ivf import IvfState, _group
+
+    lists = [g.tolist() for g in _group(np.arange(len(x)), assign, len(cents))]
+    return IvfState(np.asarray(cents, np.float32), lists, len(x), cap=len(x))
+
+
+def _nearest_np(x, cents):
+    d = (x**2).sum(1)[:, None] + (cents**2).sum(1)[None, :] - 2.0 * (x @ cents.T)
+    return d.argmin(1)
+
+
+def _recall(state, x, qs, truth, nprobe, k=10):
+    _, got = state.search_host(qs, x, "euclidean", k, nprobe)
+    return float(np.mean([len(set(got[j].tolist()) & truth[j]) / k for j in range(len(qs))]))
+
+
+def _scatter(state, cid):
+    """Lists a natural cluster's rows are spread over, beyond the one a
+    selective index would need: the mean over the clusters."""
+    where = np.empty(len(cid), dtype=np.int64)
+    for i, l in enumerate(state.lists):
+        where[l] = i
+    return float(np.mean([np.unique(where[cid == j]).size for j in np.unique(cid)])) - 1.0
+
+
+@pytest.fixture(scope="module")
+def hubs():
+    """The hub corpus, its trained state, and the same first centroids with
+    the bare nearest-centroid lists the training starts from."""
+    import jax.numpy as jnp
+
+    from surrealdb_tpu.idx import ivf as ivfm
+
+    x, cid = _hub_corpus()
+    n = len(x)
+    state = ivfm.IvfState.train(x, np.ones(n, dtype=bool))
+    c = ivfm.default_nlists(n)
+    rng = np.random.default_rng(7)  # the first training's sample and seeds
+    sample = rng.choice(n, size=16384, replace=False)
+    cents = np.asarray(ivfm._kmeans_xs(jnp.asarray(x[sample]), 16384, c, c, rng))
+    bare = _state(x, cents, _nearest_np(x, cents))
+    rng = np.random.default_rng(31)
+    qs = x[rng.integers(0, n, 96)] + np.float32(0.05) * rng.standard_normal((96, x.shape[1]), dtype=np.float32)
+    truth = [_brute(q, x, 10) for q in qs]
+    return {"x": x, "cid": cid, "state": state, "bare": bare, "qs": qs, "truth": truth}
+
+
+def test_training_holds_every_list_to_the_cap_where_nearest_assignment_forms_hubs(hubs):
+    from surrealdb_tpu.utils.num import next_pow2
+
+    x, state, bare = hubs["x"], hubs["state"], hubs["bare"]
+    cap = state.cap
+    assert cap == 2 * (len(x) + 255) // 256 == 314
+    bare_sizes = np.array([len(l) for l in bare.lists])
+    # the corpus does form hubs: without the bound a list is many times the cap
+    assert bare_sizes.max() > 3 * cap and (bare_sizes > cap).sum() >= 8, bare_sizes.max()
+    sizes = np.array([len(l) for l in state.lists])
+    assert sizes.max() <= cap and sizes.min() > 0
+    assert sorted(s for l in state.lists for s in l) == list(range(len(x)))
+    assert state.nlists == state.centroids.shape[0] == len(state.lists) > 256
+    assert all(state.slot_list[s] == i for i, l in enumerate(state.lists) for s in l)
+    _, list_rows, list_mask = state._device()
+    assert list_rows.shape == (state.nlists, list_rows.shape[1]) and list_rows.shape[1] <= next_pow2(cap)
+    assert int(np.asarray(list_mask).sum()) == len(x)
+    _, bare_rows, _ = bare._device()
+    assert bare_rows.shape[1] >= 4 * list_rows.shape[1]  # the pad every probe gathers
+
+
+def test_training_reports_how_often_the_bound_engaged():
+    from surrealdb_tpu import bg, telemetry
+    from surrealdb_tpu.idx.ivf import IvfState
+
+    x, _ = _hub_corpus(n=8192, d=96, centres=1000)
+    before = telemetry.get_counter("ivf_list_splits", at="train")
+    tid = bg.register("ivf_train", target="item.ix", trace_id=None)
+    with bg.run(tid, rename_thread=False):
+        state = IvfState.train(x, np.ones(len(x), dtype=bool))
+    st = {s["name"]: s for s in bg.get(tid)["stages"]}["ivf_lists"]
+    assert st["rows"] == len(x) and st["lists"] == state.nlists
+    assert st["longest"] == max(len(l) for l in state.lists) <= state.cap
+    assert st["split"] > 0 and st["pooled"] > st["split"] * state.cap and st["rounds"] >= 1
+    assert telemetry.get_counter("ivf_list_splits", at="train") == before + st["split"]
+    assert 'surreal_ivf_list_splits_total{at="train"}' in telemetry.render_prometheus()
+
+
+def test_a_corpus_whose_lists_all_fit_is_split_nowhere():
+    from surrealdb_tpu import bg
+    from surrealdb_tpu.idx.ivf import IvfState
+
+    x = np.random.default_rng(1).uniform(size=(4096, 4)).astype(np.float32)
+    tid = bg.register("ivf_train", target="item.ix", trace_id=None)
+    with bg.run(tid, rename_thread=False):
+        state = IvfState.train(x, np.ones(len(x), dtype=bool))
+    st = {s["name"]: s for s in bg.get(tid)["stages"]}["ivf_lists"]
+    assert (st["split"], st["pooled"], st["rounds"], st["lists"]) == (0, 0, 0, 64)
+    assert state.nlists == 64 and max(len(l) for l in state.lists) <= state.cap
+
+
+def test_pooled_reclustering_keeps_recall_and_a_per_list_split_scatters(hubs):
+    """Why the over-cap lists are re-clustered TOGETHER: a natural cluster
+    whose rows were scattered over several equidistant hubs stays scattered
+    over their children when each hub is split by a k-means of its own."""
+    from surrealdb_tpu.idx.ivf import default_nprobe
+
+    x, cid, state, bare = hubs["x"], hubs["cid"], hubs["state"], hubs["bare"]
+    qs, truth = hubs["qs"], hubs["truth"]
+    nprobe = default_nprobe(state.nlists, 64)
+    assert nprobe == 6
+    pooled = _recall(state, x, qs, truth, nprobe)
+    assert pooled >= _recall(bare, x, qs, truth, nprobe) - 0.01 and pooled >= 0.99
+    # the same bound kept list by list: each hub split by a k-means of its own rows
+    cap, rng = state.cap, np.random.default_rng(7)
+    cents, assign = [], np.empty(len(x), dtype=np.int64)
+    for i, l in enumerate(bare.lists):
+        todo = [np.asarray(l, dtype=np.int64)]
+        while todo:
+            g = todo.pop()
+            if g.size <= cap:
+                assign[g] = len(cents)
+                cents.append(x[g].mean(0) if g.size else bare.centroids[i])
+                continue
+            k = -(-2 * g.size // cap)
+            sub = x[rng.choice(g, size=k, replace=False)]
+            for _ in range(8):  # the training's eight Lloyd steps, over this list alone
+                a = _nearest_np(x[g], sub)
+                sub = np.stack([x[g[a == j]].mean(0) if (a == j).any() else sub[j] for j in range(k)])
+            a = _nearest_np(x[g], sub)
+            parts = [g[a == j] for j in range(k)]
+            todo.extend(parts if max(p.size for p in parts) < g.size else np.array_split(g, 2))
+    per_list = _state(x, np.stack(cents), assign)
+    assert max(len(l) for l in per_list.lists) <= cap
+    # the hubs scatter one cluster in ten; splitting them one by one leaves
+    # it scattered, re-clustering them together gathers it again
+    assert _scatter(bare, cid) > 0.05
+    assert _scatter(per_list, cid) > 0.8 * _scatter(bare, cid)
+    assert _scatter(state, cid) < 0.4 * _scatter(per_list, cid)
+    assert _recall(state, x, qs, truth, 1) >= _recall(per_list, x, qs, truth, 1)
+
+
+def _grown_state(more_n=600):
+    """A trained state, and the corpus with the rows an insert workload
+    then adds next to its longest list (added already)."""
+    from surrealdb_tpu.idx.ivf import IvfState
+
+    x = _mixture(2048, 16, clusters=8, seed=4)
+    state = IvfState.train(x, np.ones(len(x), dtype=bool), nlists=8)
+    near = x[max(state.lists, key=len)]
+    rng = np.random.default_rng(8)
+    more = near[rng.integers(0, len(near), more_n)] + 0.01 * rng.standard_normal((more_n, 16)).astype(np.float32)
+    data = np.concatenate([x, more])
+    for s in range(2048, len(data)):
+        state.add(s, data[s])
+    return state, data
+
+
+def test_inserts_past_the_cap_grow_one_list_and_the_retrain_pools_it():
+    """Between retrains an insert goes to its nearest centroid and nothing
+    else moves: the count of lists is training's, so the only compiled
+    shape that can change is the pad. The retrain restores the bound."""
+    from surrealdb_tpu.idx.ivf import IvfState
+
+    x = _mixture(2048, 16, clusters=8, seed=4)
+    fresh = IvfState.train(x, np.ones(len(x), dtype=bool), nlists=8)
+    assert fresh.cap == 513 and max(len(l) for l in fresh.lists) <= fresh.cap
+    state, data = _grown_state(1100)
+    assert state.cap == fresh.cap and state.nlists == fresh.nlists == len(state.lists)
+    assert np.array_equal(state.centroids, fresh.centroids)
+    assert state.dirty and state._mut == fresh._mut + 1100
+    assert max(len(l) for l in state.lists) > state.cap  # nothing bounds a list here
+    assert state._device()[1].shape[0] == fresh._device()[1].shape[0] == fresh.nlists
+    # every slot in exactly one list, and slot_list says which
+    assert sorted(s for l in state.lists for s in l) == list(range(len(data)))
+    assert all(state.slot_list[s] == i for i, l in enumerate(state.lists) for s in l)
+    assert state.size() == len(data)
+    # a row of the overfull list is still found, and removed
+    gone = max(state.lists, key=len)[-1]
+    state.remove(gone)
+    assert gone not in state.slot_list and all(gone not in l for l in state.lists)
+    assert state.size() == len(data) - 1
+    # the corpus has outgrown its training by half: the retrain pools what passed the cap
+    assert state.needs_retrain()
+    again = IvfState.train(data, np.ones(len(data), dtype=bool), nlists=8)
+    assert again.cap == 788 < max(len(l) for l in state.lists)  # the grown list would not have fitted
+    assert max(len(l) for l in again.lists) <= again.cap
+    assert sorted(s for l in again.lists for s in l) == list(range(len(data)))
+
+
+@pytest.mark.parametrize("at", ["train", "retrain"])
+def test_identical_vectors_longer_than_the_cap_are_cut_by_order(at):
+    """No k-means separates duplicates: the bound still holds and training
+    terminates, on a corpus that has them and on one that got them as
+    inserts since its last training."""
+    from surrealdb_tpu.idx.ivf import IvfState
+
+    x = _mixture(1024, 8, clusters=4, seed=6)
+    dup = np.tile(np.full((1, 8), 3.25, dtype=np.float32), (700, 1))
+    data = np.concatenate([x, dup])
+    if at == "retrain":
+        grown = IvfState.train(x, np.ones(len(x), dtype=bool), nlists=8)
+        for s in range(1024, len(data)):
+            grown.add(s, data[s])
+        # the duplicates all went to one list, which nothing bounded
+        assert max(len(l) for l in grown.lists) >= 700 > grown.cap and grown.needs_retrain()
+    state = IvfState.train(data, np.ones(len(data), dtype=bool), nlists=8)
+    assert max(len(l) for l in state.lists) <= state.cap < 700
+    assert sorted(s for l in state.lists for s in l) == list(range(len(data)))
+    # the duplicates' lists all sit on the duplicated vector
+    held = [i for i, l in enumerate(state.lists) if l and min(l) >= 1024]
+    assert len(held) >= 2
+    np.testing.assert_allclose(state.centroids[held], 3.25, atol=1e-5)
+    d, s = state.search_host(data[1024:1025], data, "euclidean", 5, 4)
+    assert (s[0] >= 1024).all() and np.allclose(d[0], 0.0, atol=1e-3)
+
+
+def test_the_last_round_of_a_training_cuts_what_is_left_by_order(monkeypatch):
+    """The rounds are bounded: what the last one leaves over the cap is cut
+    by order, so a corpus whose pool shrinks slowly still trains."""
+    from surrealdb_tpu import bg
+    from surrealdb_tpu.idx import ivf as ivfm
+
+    x, _ = _hub_corpus(n=8192, d=96, centres=1000)
+    alive = np.ones(len(x), dtype=bool)
+    free = ivfm.IvfState.train(x, alive)
+    monkeypatch.setattr(ivfm, "_MAX_ROUNDS", 1)
+    tid = bg.register("ivf_train", target="item.ix", trace_id=None)
+    with bg.run(tid, rename_thread=False):
+        state = ivfm.IvfState.train(x, alive)
+    st = {s["name"]: s for s in bg.get(tid)["stages"]}["ivf_lists"]
+    assert st["rounds"] == 1 and st["longest"] <= state.cap == free.cap
+    assert sorted(s for l in state.lists for s in l) == list(range(len(x)))
+    assert all(state.slot_list[s] == i for i, l in enumerate(state.lists) for s in l)
+    # the unbounded training needed more rounds, and the cut made lists it did not
+    assert state.nlists != free.nlists
+    np.testing.assert_allclose(state.centroids[-1], x[state.lists[-1]].mean(0), atol=1e-4)
+
+
+def test_host_and_device_search_agree_after_inserts_past_the_cap():
+    import jax.numpy as jnp
+
+    from surrealdb_tpu import bg
+
+    state, x = _grown_state()
+    assert max(len(l) for l in state.lists) > state.cap
+    qs = x[::300] + 0.01
+    hd, hs = state.search_host(qs, x, "euclidean", 10, 4)
+    dd, ds_ = state.search_batch(qs, jnp.asarray(x), "euclidean", 10, 4)
+    assert bg.wait_idle(120.0)  # the other tiles' warmers end here, not under a later test
+    # the same lists probed and the same rows reranked: equal distances (the
+    # inserted rows are near-duplicates, so ids may swap inside a tie)
+    np.testing.assert_allclose(hd, dd, atol=2e-2)
+    assert np.mean([len(set(a) & set(b)) for a, b in zip(hs.tolist(), ds_.tolist())]) >= 9.0
+    assert (hs >= 0).all() and (ds_ >= 0).all()
+
+
+def test_a_list_grown_past_the_pad_warms_the_other_tiles_again():
+    """The pad is the one table shape inserts can move between retrains:
+    when a list grows past it the tiles a search did not serve are
+    compiled in the background again, not inside a later statement."""
+    import jax.numpy as jnp
+
+    from surrealdb_tpu import bg
+    from surrealdb_tpu.idx.ivf import IvfState
+    from surrealdb_tpu.utils.num import warm_tile_sizes
+
+    _, data = _grown_state()
+    state = IvfState.train(data[:2048], np.ones(2048, dtype=bool), nlists=8)
+    matrix = jnp.asarray(data)
+    state.search_batch(data[:1], matrix, "euclidean", 10, 4)
+    assert bg.wait_idle(120.0)
+    nlists, pad0 = (int(n) for n in state._device()[1].shape)
+    assert {key for key in state._warmed} == {(t, 10, 4, "euclidean", pad0) for t in warm_tile_sizes()}
+    for s in range(2048, len(data)):
+        state.add(s, data[s])
+    pad1 = int(state._device()[1].shape[1])
+    assert pad1 > pad0 and state._device()[1].shape[0] == nlists == state.nlists
+    state.search_batch(data[:1], matrix, "euclidean", 10, 4)
+    assert bg.wait_idle(120.0)
+    assert {key for key in state._warmed} == {
+        (t, 10, 4, "euclidean", pad) for t in warm_tile_sizes() for pad in (pad0, pad1)
+    }
+
+
+def test_the_sharded_tables_hold_every_slot_once_after_inserts_past_the_cap():
+    import jax
+
+    from surrealdb_tpu.parallel.mesh import make_mesh
+
+    state, x = _grown_state()
+    n_dev = min(len(jax.devices()), 4)
+    mesh = make_mesh(n_dev)
+    n_total = 4096  # the mirror's padded row count, a multiple of the mesh
+    cents, rows, mask, shard_rows = state._device_sharded(mesh, n_total)
+    rows, mask = np.asarray(rows), np.asarray(mask)
+    assert cents.shape[0] == rows.shape[1] == state.nlists and shard_rows == n_total // n_dev
+    seen = sorted(
+        int(rows[d, ci, j]) + d * shard_rows
+        for d in range(n_dev) for ci in range(state.nlists) for j in np.nonzero(mask[d, ci])[0]
+    )
+    assert seen == list(range(len(x)))
+    for d in range(n_dev):
+        for ci in range(state.nlists):
+            held = rows[d, ci][mask[d, ci]] + d * shard_rows
+            assert all(state.slot_list[int(s)] == ci for s in held)

@@ -230,7 +230,8 @@ def test_victim_tenant_is_never_shed_under_a_flood(srv, monkeypatch):
         victims.append(sink)
         vcn.feed(_http(f"RETURN {7000 + i};", ns="victim", db="app"))
         assert _wait(lambda: sink.has(str(7000 + i).encode())), sink.buf[:200]
-        assert b"200 OK" in sink.buf and b"503" not in sink.buf, sink.buf[:200]
+        # the status line, not the bytes: a random trace id holds "503" in one reply of seventy
+        assert b"200 OK" in sink.buf and b"HTTP/1.1 503" not in sink.buf, sink.buf[:200]
     assert _wait(lambda: any(s.has(b"503") for s in flood)), [
         s.buf[:120] for s in flood
     ]
