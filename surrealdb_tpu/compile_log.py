@@ -53,6 +53,8 @@ KERNEL_SITES = {
     "knn_sharded": "surrealdb_tpu.parallel.mesh:graftcheck_sites",
     "ivf": "surrealdb_tpu.idx.ivf:graftcheck_sites",
     "ivf_sharded": "surrealdb_tpu.parallel.mesh:graftcheck_sites",
+    "knn_subset": "surrealdb_tpu.idx.knn:graftcheck_sites",
+    "knn_subset_sharded": "surrealdb_tpu.parallel.mesh:graftcheck_sites",
     "graph_dense": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
     "graph_csc": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
     "graph_chain": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",

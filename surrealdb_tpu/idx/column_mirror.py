@@ -19,7 +19,18 @@ Staleness protocol (the part that must be airtight):
   the mirror ONLY when (a) its own transaction has no uncommitted writes to
   the table, (b) the mirror's build version still equals the table's
   current version, and (c) the reader's snapshot is at least as new as the
-  build snapshot. Any commit that could make the mirror wrong for that
+  table's last commit as of the build, else as the build snapshot itself
+  (a commit to ANOTHER table between a reader's snapshot and the build it
+  triggers must not send that reader to the row path after it has paid for
+  the build). The floor is the last commit's only under this invariant,
+  which the build checks and does not assume: NO COMMIT TO THE TABLE LIES
+  IN (last_commit, build snapshot]. Every commit records its store version
+  in `last_commit` beside the counter it bumped the table to, after it
+  lands and under the same lock; the build uses the record only when that
+  counter is still the table's. A commit that bumped and recorded nothing
+  (it failed, it dropped the table's scope, its backend names no commit
+  version) leaves the counters apart, and the floor is the snapshot. Any
+  commit that could make the mirror wrong for that
   reader is guaranteed to have bumped the version before the reader's
   snapshot even opened — a stale mask can never serve.
 - Commits into a mirrored table also arm a debounced background rebuild
@@ -244,7 +255,7 @@ class ColumnMirror:
         self._virtual: Dict[str, Column] = {}
         self._id_index: Optional[Dict[str, int]] = None
         # (id(rids list), n_slots) -> row permutation for the kNN prefilter
-        self._slot_perm: Optional[Tuple[int, int, np.ndarray]] = None
+        self._slot_perm: Optional[Tuple[Tuple[int, int], int, np.ndarray]] = None
 
     def key_order(self) -> Optional[np.ndarray]:
         """Row indices in record-key order, or None when rows are already
@@ -285,19 +296,23 @@ class ColumnMirror:
 
     def slot_permutation(self, rids: List[Any], cap: int) -> np.ndarray:
         """perm[slot] = column row of the vector-mirror slot's record (or -1),
-        cached per (rids identity, slot count) — rebuilding the mirror
-        installs a new ColumnMirror object, so the cache can't go stale."""
+        cached per (rids identity and length, slot count) — rebuilding the
+        mirror installs a new ColumnMirror object, and a vector mirror that
+        took a commit's rows after this mirror took them (kvs/tx.py applies
+        the column delta first) has a longer list, so the cache can't go
+        stale."""
+        name = (id(rids), len(rids))
         cached = self._slot_perm
-        if cached is not None and cached[0] == id(rids) and cached[1] == cap:
+        if cached is not None and cached[0] == name and cached[1] == cap:
             return cached[2]
         idx = self.id_index()
         perm = np.full(cap, -1, dtype=np.int64)
-        for slot, rid in enumerate(rids[:cap]):
+        for slot, rid in enumerate(rids[: min(cap, name[1])]):
             rid_id = rid.id if isinstance(rid, Thing) else rid
             row = idx.get(repr(rid_id))
             if row is not None:
                 perm[slot] = row
-        self._slot_perm = (id(rids), cap, perm)
+        self._slot_perm = (name, cap, perm)
         return perm
 
 
@@ -308,6 +323,10 @@ class ColumnMirrors:
     def __init__(self):
         self._lock = _locks.RLock("idx.column.registry")
         self.versions: Dict[Tuple[str, str, str], int] = {}
+        # table -> (its version counter as that commit bumped it, the store
+        # version the commit landed at), where the backend gave one
+        # (kvs/tx.py, under the commit lock): a built mirror's floor
+        self.last_commit: Dict[Tuple[str, str, str], Tuple[int, int]] = {}
         self._mirrors: Dict[Tuple[str, str, str], ColumnMirror] = {}
         self._build_locks: Dict[Tuple[str, str, str], threading.Lock] = {}
         self._ds = None  # weakref to the owning Datastore
@@ -346,6 +365,18 @@ class ColumnMirrors:
                 for k in list(self._mirrors):
                     if k[:w] == tuple(scope):
                         self.versions[k] = self.versions.get(k, 0) + 1
+
+    def committed(self, tables, commit_version) -> None:
+        """The backend commit that `invalidate` announced has landed at
+        `commit_version` (None: the backend names none), still under the
+        datastore commit lock: kept beside the version counter it bumped
+        the table to, the floor of the next mirror built at that counter."""
+        with self._lock:
+            for k in tables:
+                if commit_version is None:
+                    self.last_commit.pop(k, None)
+                else:
+                    self.last_commit[k] = (self.versions.get(k, 0), commit_version)
 
     def drop_table(self, ns: str, db: str, tb: str) -> None:
         with self._lock:
@@ -635,7 +666,7 @@ class ColumnMirrors:
             if m is None:
                 return None
         if snap < m.built_store_version:
-            return None  # reader's snapshot predates the build
+            return None  # reader's snapshot predates the table's state as built
         return m
 
     # ------------------------------------------------------------ build
@@ -657,12 +688,22 @@ class ColumnMirrors:
             with ds.commit_lock:
                 with self._lock:
                     v0 = self.versions.get(key3, 0)
+                    last = self.last_commit.get(key3)
                 txn = ds.transaction(False)
             t0 = _time.perf_counter()
             mirror = ColumnMirror()
             try:
                 mirror.built_version = v0
-                mirror.built_store_version = getattr(txn.tr, "snapshot", -1)
+                # a reader serves from here on: the table's last commit,
+                # where the counter says that commit was the last to bump it
+                # (no commit to the table lies in (that commit, this
+                # snapshot]: every one bumps the counter before it lands and
+                # is recorded after, under the lock held above; a dropped
+                # scope or a commit that failed bumps and records nothing,
+                # and the counters then differ), else the snapshot itself
+                snap = getattr(txn.tr, "snapshot", -1)
+                known = last is not None and last[0] == v0
+                mirror.built_store_version = min(snap, last[1]) if known else snap
                 self._scan(txn, ns, db, tb, mirror)
             except Exception:
                 telemetry.inc("column_mirror_rebuilds", cause="build_failed")
@@ -770,16 +811,25 @@ def _builder_for(builders, path, row, cap, max_fields, mirror):
 
 
 # ------------------------------------------------------------------ shared mask
-def columnar_mask(ctx, tb: str, compiled: CompiledPredicate):
-    """Evaluate a compiled predicate over `tb`'s mirror for THIS reader.
-    Returns (mask, needs_row, mirror) or None when the mirror can't serve
-    (stale, too small, unresolvable paths, txn writes...)."""
+def serveable_mirror(ctx, tb: str) -> Optional[ColumnMirror]:
+    """`tb`'s mirror as THIS reader may see it, or None (stale inside its
+    rebuild debounce, empty, written by the reader's own transaction)."""
     ns, db = ctx.ns_db()
     registry = getattr(ctx.ds(), "column_mirrors", None)
     if registry is None:
         return None
     mirror = registry.serveable(ctx, (ns, db, tb))
-    if mirror is None or mirror.n == 0:
+    return mirror if mirror is not None and mirror.n else None
+
+
+def columnar_mask(ctx, tb: str, compiled: CompiledPredicate, mirror: Optional[ColumnMirror] = None):
+    """Evaluate a compiled predicate over `tb`'s mirror for THIS reader
+    (`mirror`: what `serveable_mirror` gave the caller, else looked up).
+    Returns (mask, needs_row, mirror) or None when the mirror can't serve
+    (stale, too small, unresolvable paths, txn writes...)."""
+    if mirror is None:
+        mirror = serveable_mirror(ctx, tb)
+    if mirror is None:
         return None
     cols = mirror.columns_for(compiled.paths)
     if cols is None:

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import threading
 import time as _time
-from collections import OrderedDict
 from surrealdb_tpu.utils import locks as _locks
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -33,6 +32,7 @@ import numpy as np
 from surrealdb_tpu import key as keys, telemetry
 from surrealdb_tpu.key.encode import prefix_end
 from surrealdb_tpu.sql.value import Thing
+from surrealdb_tpu.utils.byte_cache import ByteBudgetCache
 from surrealdb_tpu.utils.num import count_lane_set, count_lanes, next_pow2 as _next_pow2
 
 
@@ -576,7 +576,6 @@ def _kernels():
     return chain_kernel
 
 
-_END_WEIGHTS_BYTES = 256 << 20  # device bytes of cached end weights a datastore
 _UNFUSED = object()  # a filtered count no composed operator can carry: the KV walk
 
 
@@ -646,9 +645,9 @@ class GraphMirrors:
         self._dense: Dict[tuple, dict] = {}  # pair key -> operator dict
         self._csc: Dict[tuple, dict] = {}  # pair key -> composed sparse operator
         # (pair key, predicate binding) -> a filtered count's end weights on
-        # the device, oldest first, held under _END_WEIGHTS_BYTES; and
+        # the device, oldest first, held under a byte budget; and
         # (ns,db,tb) -> the column mirror's row of each compact id
-        self._endw: "OrderedDict[tuple, dict]" = OrderedDict()
+        self._endw = ByteBudgetCache()
         self._mirror_rows: Dict[tuple, tuple] = {}
         # tables mid-build: deltas committed during the build scan are
         # buffered here and replayed after load (closes the scan→built gap)
@@ -711,8 +710,7 @@ class GraphMirrors:
         for d in (self._spaces, self._dense, self._csc, self._mirror_rows):
             for k in [k for k in d if stale(k)]:
                 del d[k]
-        for k in [k for k in self._endw if stale(k[0])]:
-            del self._endw[k]
+        self._endw.forget(lambda k, _: stale(k[0]))
         self._warmed_pairs = {k for k in self._warmed_pairs if not stale(k)}
 
     def drop_table(self, ns: str, db: str, tb: str) -> None:
@@ -1344,7 +1342,6 @@ class GraphMirrors:
         with self._lock:
             got = self._endw.get(key)
             if got is not None and got["gen"] == op["gen"] and got["mirror"] is mirror:
-                self._endw.move_to_end(key)
                 return got["w"], got["rows"], False
         mask = end.local_mask()
         if mask is None:
@@ -1361,11 +1358,7 @@ class GraphMirrors:
         got = {"gen": op["gen"], "mirror": mirror, "w": jnp.asarray(w), "rows": int(mask.sum())}
         telemetry.stage("graph_filter_upload", t1, _time.perf_counter() - t1, bytes=w.nbytes)
         with self._lock:
-            self._endw[key] = got
-            self._endw.move_to_end(key)
-            held = sum(e["w"].nbytes for e in self._endw.values())
-            while held > _END_WEIGHTS_BYTES and len(self._endw) > 1:
-                held -= self._endw.popitem(last=False)[1]["w"].nbytes
+            self._endw.put(key, got, w.nbytes)
         return got["w"], got["rows"], True
 
     def _chain_pairs(self, ns, db, specs, pair_of):

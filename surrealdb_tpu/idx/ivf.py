@@ -67,6 +67,35 @@ def default_nprobe(nlists: int, ef: Optional[int]) -> int:
     return min(max(4, nlists // 16), nlists)
 
 
+def subset_size(passing: int) -> int:
+    """Slots the subset search of `passing` rows gathers: the power of two
+    above them, so that steady writes to a filtered slice keep one compiled
+    shape, and never under 1,024 (below that a gather costs a launch's
+    fixed time whatever its height)."""
+    return max(_next_pow2(passing), 1024)
+
+
+def filtered_route(passing: int, alive: int, nlists: int, nprobe: int, pad: int) -> Tuple[str, int]:
+    """Which of two searches serves a kNN whose WHERE `passing` of the
+    `alive` rows pass, by the rows each would gather: the SUBSET search
+    scores every passing row exactly (`subset_size(passing)` slots), the
+    WIDENED search probes the IVF lists with the mask applied, its probes
+    multiplied by the inverse of the passing share so that it still sees as
+    many passing candidates as an unfiltered search sees rows, and rounded
+    up to a power of two (`min(nlists, next_pow2(ceil(nprobe * alive /
+    passing)))` lists of `pad` slots): the probe count is a compiled shape
+    of `_ivf_search`, so a threshold that varies meets log2(nlists)
+    programs at most, not one a passing share. The smaller wins:
+    ("subset", slots) or ("widened", probes). Read by every IVF strategy
+    (`ivf`, `ivf-sharded`, `ivf-host`), from two row counts the program
+    already has; nothing else chooses the route."""
+    slots = subset_size(passing)
+    probes = min(nlists, _next_pow2(-(-nprobe * alive // max(passing, 1))))
+    if slots <= probes * pad:
+        return "subset", slots
+    return "widened", probes
+
+
 _ASSIGN_CHUNK = 65536  # rows a call of `_assign_chunk`: one compiled shape
 # re-clustering rounds of one training: the pool shrank by ~0.55 a round where
 # it was measured (8 rounds at 1M x 768), so none is left long before this
@@ -147,6 +176,7 @@ class IvfState:
         self._mut = 0  # bumped on every list mutation; sharded cache keys off it
         self._sharded_cache = None  # (key, (cents, rows, mask, shard_rows))
         self._warmed: set = set()  # (tile, k, nprobe, metric, pad) combos compiled
+        self._pad = None  # (_mut it was read at, pad)
 
     @property
     def nlists(self) -> int:
@@ -298,13 +328,23 @@ class IvfState:
         return self.size() > 1.5 * max(self.trained_n, 1)
 
     # ------------------------------------------------------------ search
+    def pad(self) -> int:
+        """Slots a probed list costs a search: the power of two above the
+        longest list (the second dimension of the device's list tables)."""
+        got = self._pad
+        if got is None or got[0] != self._mut:
+            got = self._pad = (
+                self._mut, _next_pow2(max(max((len(l) for l in self.lists), default=1), 1))
+            )
+        return got[1]
+
     def _device(self):
         import jax.numpy as jnp
 
         if not self.dirty and self._dev is not None:
             return self._dev
         c = self.nlists
-        maxlen = _next_pow2(max(max((len(l) for l in self.lists), default=1), 1))
+        maxlen = self.pad()
         list_rows = np.zeros((c, maxlen), dtype=np.int32)
         list_mask = np.zeros((c, maxlen), dtype=bool)
         for i, l in enumerate(self.lists):
@@ -445,12 +485,16 @@ class IvfState:
         results. Lets the dispatch queue overlap the next batch's upload
         with this batch's compute/download (double buffering). `slot_mask`
         [cap] restricts the rerank to matching corpus slots (the columnar
-        residual prefilter — ROADMAP carried item)."""
+        residual prefilter): a device array as the statement's cached slot
+        filter holds it (idx/knn.py, nothing is uploaded a dispatch), or a
+        host mask, uploaded here."""
         import jax.numpy as jnp
 
         cents, list_rows, list_mask = self._device()
         if slot_mask is None:
             slot_ok = jnp.ones(int(matrix.shape[0]), dtype=bool)
+        elif isinstance(slot_mask, jax.Array):
+            slot_ok = slot_mask
         else:
             pad = int(matrix.shape[0]) - int(slot_mask.shape[0])
             if pad > 0:
@@ -620,9 +664,10 @@ class IvfState:
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Batched sharded probe+rerank over a mesh-sharded mirror matrix.
         Same contract as search_batch; misses surface as +inf/-1.
-        `slot_mask` is the columnar residual prefilter over corpus slots:
-        it rides into the kernel row-sharded alongside the corpus so top-k
-        is computed among MATCHING rows only."""
+        `slot_mask` is the columnar residual prefilter over corpus slots (a
+        host mask, or the cached device array already sharded as the
+        corpus rows are): it rides into the kernel row-sharded alongside
+        the corpus so top-k is computed among MATCHING rows only."""
         from surrealdb_tpu.parallel.mesh import sharded_ivf_search
         from surrealdb_tpu.utils.num import pad_tail, tile_slices
         import jax as _jax
@@ -638,18 +683,21 @@ class IvfState:
         nprobe = min(nprobe, self.nlists)
         qs = np.asarray(qs, dtype=np.float32)
         cap = int(matrix.shape[0])
-        if slot_mask is not None:
-            sm = np.asarray(slot_mask, dtype=bool)
-            if sm.shape[0] < cap:  # pad slots are dead anyway
-                sm = np.concatenate([sm, np.zeros(cap - sm.shape[0], dtype=bool)])
-            sm = sm[:cap]
+        if isinstance(slot_mask, _jax.Array):
+            slot_dev = slot_mask
         else:
-            # placed ONCE here (not per tile-slice launch inside the loop,
-            # and never as a replicated jnp.ones the shard_map must reshard)
-            sm = np.ones(cap, dtype=bool)
-        slot_dev = _jax.device_put(
-            sm, NamedSharding(mesh, _P(mesh.axis_names[0]))
-        )
+            if slot_mask is not None:
+                sm = np.asarray(slot_mask, dtype=bool)
+                if sm.shape[0] < cap:  # pad slots are dead anyway
+                    sm = np.concatenate([sm, np.zeros(cap - sm.shape[0], dtype=bool)])
+                sm = sm[:cap]
+            else:
+                # placed ONCE here (not per tile-slice launch inside the loop,
+                # and never as a replicated jnp.ones the shard_map must reshard)
+                sm = np.ones(cap, dtype=bool)
+            slot_dev = _jax.device_put(
+                sm, NamedSharding(mesh, _P(mesh.axis_names[0]))
+            )
         tile = dispatch_tile(qs.shape[0], tile)
         dd = np.full((qs.shape[0], k), np.inf, dtype=np.float32)
         rr = np.full((qs.shape[0], k), -1, dtype=np.int64)

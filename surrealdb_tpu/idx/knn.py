@@ -15,6 +15,7 @@ vector::distance::knn().
 
 from __future__ import annotations
 
+import itertools
 import threading
 from surrealdb_tpu.utils import locks as _locks
 import time as _time
@@ -28,6 +29,7 @@ from surrealdb_tpu.sql.path import get_path
 from surrealdb_tpu.sql.value import Thing, is_nullish
 
 from surrealdb_tpu.ops import distances as D
+from surrealdb_tpu.utils.byte_cache import ByteBudgetCache
 from surrealdb_tpu.utils.num import next_pow2 as _pow2
 
 
@@ -78,6 +80,10 @@ class VectorMirror:
         self._renumber = 0  # bumped when compaction renumbers slots
         self._pending: Optional[List[tuple]] = None  # deltas during build
         self._host_cache = None  # (contig data, sq-norms, rids) for host search
+        self._host_live = None  # (gen, alive[:n_slots] as of it) for host_view
+        # (predicate binding, slot count, mesh) -> _SlotFilter: what a residual
+        # WHERE contributes to the searches of one snapshot, oldest first
+        self._filters = ByteBudgetCache()
         self._lock = _locks.RLock("idx.knn.state")
         self._build_lock = _locks.Lock("idx.knn.build")
         self.label = ""  # "<table>.<index>", set on build (task attribution)
@@ -344,9 +350,14 @@ class VectorMirror:
             return self._dev_mask
 
     def host_view(self):
-        """(data [n, D], alive [n], rids) — numpy views for small corpora."""
+        """(data [n, D], alive [n], rids) — numpy views for small corpora.
+        `alive` is a copy as of this generation, the SAME object until the
+        next mutation: like the device view's mask, its identity names the
+        snapshot's live state (a slot filter is good while it stands)."""
         with self._lock:
-            return self.data[: self.n_slots], self.alive[: self.n_slots], self.rids
+            if self._host_live is None or self._host_live[0] != self.gen:
+                self._host_live = (self.gen, self.alive[: self.n_slots].copy())
+            return self.data[: self.n_slots], self._host_live[1], self.rids
 
     def host_search_view(self):
         """(contiguous live rows [m, D] f32, their squared norms [m], live
@@ -470,22 +481,122 @@ class VectorMirror:
 
 
 
-def _submit_prepared(ds, t_iter: float, key, q, runner):
+def _filter_route(route: str) -> None:
+    """Count how a statement's residual WHERE was served: `none` (it has
+    none), `subset` (the passing rows scored exactly), `widened` (IVF with
+    the mask and more probes), `masked` (an exact scan of every row with
+    the mask), `post` (the column mirror could not answer: the search ran
+    unfiltered and the executor filters its top-k, as before)."""
+    from surrealdb_tpu import telemetry
+
+    telemetry.inc("knn_filter_route", route=route)
+
+
+def _submit_prepared(ds, t_iter: float, key, q, runner, route: str = "none"):
     """Hand one query to the dispatch queue, closing its statement's
     `knn_prepare` span: what it did on the host since `t_iter` before the
-    device could have its work (mirror and quantizer look-ups, the key)."""
+    device could have its work (mirror and quantizer look-ups, the slot
+    filter, the key). The span's `filter` label and the route counter come
+    from the one argument."""
     from surrealdb_tpu import tracing
 
     tracing.record_span_into(
-        tracing.current(), "knn_prepare", {}, t_iter, _time.perf_counter() - t_iter
+        tracing.current(), "knn_prepare", {"filter": route}, t_iter,
+        _time.perf_counter() - t_iter,
     )
+    _filter_route(route)
     return ds.dispatch.submit(key, q, runner)
 
 
-def _exact_device_launch(qs: np.ndarray, matrix, mask, metric: str, k: int, owner=None):
+_FILTER_SERIAL = itertools.count(1)
+
+
+class _SlotFilter:
+    """What a residual WHERE contributes to the searches of one vector
+    snapshot: the mask over its slots of the rows that pass AND live
+    (`host`), how many they are (`rows`), their slots (`slot_ids`, made when
+    a host strategy first asks) and, for the device strategies, the mask
+    (`ok`), the passing slots padded to `size` (`slots`) and their count
+    (`n_pass`) ON the device. Made once a (predicate text, bound values,
+    column-mirror object, snapshot) and looked up afterwards; `serial`
+    names it in a dispatch key, so riders under one filter share a launch
+    and riders under different ones never do."""
+
+    __slots__ = ("serial", "col", "rids", "live", "mesh", "host", "rows", "size",
+                 "_slot_ids", "ok", "slots", "n_pass")
+
+    def __init__(self, col, rids, live, mesh, host: np.ndarray):
+        from surrealdb_tpu.idx.ivf import subset_size
+
+        self.serial = next(_FILTER_SERIAL)
+        self.col, self.rids, self.live, self.mesh, self.host = col, rids, live, mesh, host
+        self.rows = int(np.count_nonzero(host))
+        self.size = subset_size(self.rows)
+        self._slot_ids = self.ok = self.slots = self.n_pass = None
+
+    @property
+    def slot_ids(self) -> np.ndarray:
+        if self._slot_ids is None:
+            self._slot_ids = np.flatnonzero(self.host).astype(np.int32)
+        return self._slot_ids
+
+    def nbytes(self) -> int:
+        """Host and device bytes together: the mask on each side, the
+        padded slots on the device, the passing slots a host strategy made."""
+        held = 0 if self._slot_ids is None else self._slot_ids.nbytes
+        return self.host.nbytes + held + (0 if self.ok is None else self.host.nbytes + 4 * self.size)
+
+    def upload(self, cap: int) -> None:
+        """Put the mask (padded to the matrix's `cap` rows; sharded as the
+        corpus rows are on a mesh) and the padded passing slots on the
+        device, once."""
+        import jax
+        import jax.numpy as jnp
+
+        from surrealdb_tpu import telemetry
+        from surrealdb_tpu.utils.num import pad_tail
+
+        t0 = _time.perf_counter()
+        ok = pad_tail(self.host, cap) if self.host.shape[0] < cap else self.host[:cap]
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            ok_dev = jax.device_put(ok, NamedSharding(self.mesh, P(self.mesh.axis_names[0])))
+        else:
+            ok_dev = jnp.asarray(ok)
+        slots = jnp.asarray(pad_tail(np.flatnonzero(self.host).astype(np.int32), self.size))
+        self.n_pass = jnp.asarray(self.rows, dtype=jnp.int32)
+        self.slots, self.ok = slots, ok_dev
+        telemetry.stage(
+            "knn_filter_upload", t0, _time.perf_counter() - t0,
+            bytes=int(ok.nbytes + 4 * self.size),
+        )
+
+
+def _subset_shape_key(tile: int, matrix, size: int, metric: str, k: int):
+    """Compile-cache key of the subset kernel: the static dims XLA keys
+    its executable cache on."""
+    return (tile, int(matrix.shape[1]), int(matrix.shape[0]), str(matrix.dtype), size, metric, k)
+
+
+def _zipped(collect):
+    """A launch's collect() as the dispatch queue wants it: one
+    (dists, slots) pair a rider."""
+
+    def finish():
+        dd, rr = collect()
+        return list(zip(dd, rr))
+
+    return finish
+
+
+def _exact_device_launch(qs: np.ndarray, matrix, mask, metric: str, k: int, owner=None, subset=None):
     """Async fused exact distance+top-k over a [Q, D] query batch, Q padded
     to a pow2 tile (≤64) so coalesced batches of any size reuse one compiled
-    kernel shape. Returns a collect() closure (two-phase dispatch)."""
+    kernel shape: over every row `mask` lets live, or with `subset` (a
+    _SlotFilter) over the rows it lets through, gathered by their cached
+    slots (ops/distances.py::knn_subset_search). Returns a collect()
+    closure (two-phase dispatch)."""
     import jax.numpy as jnp
 
     from surrealdb_tpu.idx.ivf import _start_host_copy
@@ -495,14 +606,24 @@ def _exact_device_launch(qs: np.ndarray, matrix, mask, metric: str, k: int, owne
 
     nq = qs.shape[0]
     tile = dispatch_tile(nq)
-    mj = jnp.asarray(mask)
+    mj = None if subset is not None else jnp.asarray(mask)
     pending = []
-    # every distinct (tile, dim, cap, k, metric) is one XLA executable: the
-    # first call through a new shape IS the compile — record + attribute it
-    shape_key = _exact_shape_key(tile, matrix, metric, k)
-    with compile_log.tracked("knn_exact", shape_key):
+    # every distinct (tile, dim, cap, k, metric[, padded slots]) is one XLA
+    # executable: the first call through a new shape IS the compile —
+    # record + attribute it
+    if subset is None:
+        tracked = compile_log.tracked("knn_exact", _exact_shape_key(tile, matrix, metric, k))
+    else:
+        tracked = compile_log.tracked(
+            "knn_subset", _subset_shape_key(tile, matrix, subset.size, metric, k)
+        )
+    with tracked:
         for lo, hi in tile_slices(nq, tile):
-            d, r = D.knn_search(pad_tail(qs[lo:hi], tile), matrix, mj, metric, k)
+            qt = pad_tail(qs[lo:hi], tile)
+            if subset is None:
+                d, r = D.knn_search(qt, matrix, mj, metric, k)
+            else:
+                d, r = D.knn_subset_search(qt, matrix, subset.slots, subset.n_pass, metric, k)
             _start_host_copy(d, r)
             pending.append((lo, hi, d, r))
 
@@ -514,8 +635,38 @@ def _exact_device_launch(qs: np.ndarray, matrix, mask, metric: str, k: int, owne
             rr[lo:hi] = np.asarray(r)[: hi - lo]
         return dd, rr
 
-    _warm_exact_tiles(qs.shape[1], matrix, mj, metric, k, tile, owner)
+    _warm_exact_tiles(qs.shape[1], matrix, mj, metric, k, tile, owner, subset=subset)
     return collect
+
+
+def _subset_sharded_launch(qs: np.ndarray, mesh, matrix, flt: _SlotFilter, metric: str, k: int):
+    """`_exact_device_launch(subset=flt)` over a row-sharded matrix
+    (parallel/mesh.py), run on the leader's thread as the sharded scans are."""
+    from surrealdb_tpu import compile_log
+    from surrealdb_tpu.parallel.mesh import sharded_subset_knn
+    from surrealdb_tpu.utils.num import dispatch_tile, pad_tail, tile_slices
+
+    nq = qs.shape[0]
+    tile = dispatch_tile(nq)
+    dd = np.empty((nq, k), dtype=np.float32)
+    rr = np.empty((nq, k), dtype=np.int64)
+
+    def one_slice(lo, hi):
+        d, r = sharded_subset_knn(
+            mesh, matrix, flt.slots, flt.n_pass, pad_tail(qs[lo:hi], tile), k, metric
+        )
+        dd[lo:hi] = np.asarray(d)[: hi - lo]
+        rr[lo:hi] = np.asarray(r)[: hi - lo]
+
+    # only the FIRST slice can compile, so only it is tracked (graftlint GL002)
+    slices = list(tile_slices(nq, tile))
+    with compile_log.tracked(
+        "knn_subset_sharded", _subset_shape_key(tile, matrix, flt.size, metric, k)
+    ):
+        one_slice(*slices[0])
+    for lo, hi in slices[1:]:
+        one_slice(lo, hi)
+    return list(zip(dd, rr))
 
 
 _EXACT_WARMED: set = set()
@@ -527,20 +678,24 @@ def _exact_shape_key(tile: int, matrix, metric: str, k: int):
     return (tile, int(matrix.shape[1]), int(matrix.shape[0]), str(matrix.dtype), metric, k)
 
 
-def _warm_exact_tiles(dim, matrix, mask_j, metric, k, served_tile, owner=None) -> None:
-    """Background-compile the other dispatch tile shapes of the exact fused
-    kernel (same rationale as IvfState._warm_tiles). The warm set tracks
-    the dispatcher's width cap, so every width the coalescer can hand a
-    runner has a compiled shape waiting."""
+def _warm_exact_tiles(dim, matrix, mask_j, metric, k, served_tile, owner=None, subset=None) -> None:
+    """Background-compile the other dispatch tile shapes of an exact fused
+    kernel (same rationale as IvfState._warm_tiles): the scan of every row
+    under `mask_j`, or with `subset` (a _SlotFilter) the search over its
+    passing rows at its padded size. The warm set tracks the dispatcher's
+    width cap, so every width the coalescer can hand a runner has a
+    compiled shape waiting."""
     from surrealdb_tpu.utils.num import warm_tile_sizes
 
+    subsystem = "knn_exact" if subset is None else "knn_subset"
+    size = None if subset is None else subset.size
     todo = []
     for t in warm_tile_sizes():
-        key = (t, id(matrix), metric, k)
+        key = (t, id(matrix), metric, k, size)
         if t != served_tile and key not in _EXACT_WARMED:
             _EXACT_WARMED.add(key)
             todo.append(t)
-    _EXACT_WARMED.add((served_tile, id(matrix), metric, k))
+    _EXACT_WARMED.add((served_tile, id(matrix), metric, k, size))
     if not todo:
         return
 
@@ -550,25 +705,28 @@ def _warm_exact_tiles(dim, matrix, mask_j, metric, k, served_tile, owner=None) -
         from surrealdb_tpu import compile_log
 
         for t in todo:
+            shape = (
+                _exact_shape_key(t, matrix, metric, k) if subset is None
+                else _subset_shape_key(t, matrix, size, metric, k)
+            )
             try:
-                with compile_log.tracked(
-                    "knn_exact", _exact_shape_key(t, matrix, metric, k),
-                    prewarmed=True,
-                ):
-                    D.knn_search(
-                        jnp.zeros((t, dim), jnp.float32), matrix, mask_j, metric, k
-                    )
+                with compile_log.tracked(subsystem, shape, prewarmed=True):
+                    q0 = jnp.zeros((t, dim), jnp.float32)
+                    if subset is None:
+                        D.knn_search(q0, matrix, mask_j, metric, k)
+                    else:
+                        D.knn_subset_search(q0, matrix, subset.slots, subset.n_pass, metric, k)
             except Exception:
                 from surrealdb_tpu import telemetry
 
                 # a failed tile warm means the first real query at this
                 # width pays the XLA compile — count it so a cold p99 is
                 # attributable from metrics alone
-                telemetry.inc("prewarm_errors", subsystem="knn_exact")
+                telemetry.inc("prewarm_errors", subsystem=subsystem)
 
     from surrealdb_tpu import bg
 
-    bg.spawn("shape_warm", f"knn_exact:k{k}", warm, owner=owner)
+    bg.spawn("shape_warm", f"{subsystem}:k{k}", warm, owner=owner)
 
 
 def _exact_device_batch(qs: np.ndarray, matrix, mask, metric: str, k: int):
@@ -614,6 +772,21 @@ def graftcheck_sites():
             + [(8, "cosine", "float32"), (8, "euclidean", "bfloat16")]
         )
     ]
+    def build_subset(shape):
+        import jax
+        import jax.numpy as jnp
+
+        from surrealdb_tpu.ops.distances import knn_subset_search
+
+        args = (
+            jax.ShapeDtypeStruct((shape["tile"], dim), jnp.float32),
+            jax.ShapeDtypeStruct((cap, dim), jnp.float32),
+            jax.ShapeDtypeStruct((1024,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+        )
+        metric, kk = shape["metric"], shape["k"]
+        return (lambda q, x, s, n: knn_subset_search(q, x, s, n, metric, kk)), args
+
     return [
         {
             "subsystem": "knn_exact",
@@ -623,7 +796,21 @@ def graftcheck_sites():
             "out_dtypes": ("float32", "int32"),
             "shapes": shapes,
             "build": build,
-        }
+        },
+        {
+            # the exact search over a filter's passing slots, at the
+            # smallest padded size and the dispatcher's tile vocabulary
+            "subsystem": "knn_subset",
+            "module": __name__,
+            "kind": "single",
+            "allowed_collectives": (),
+            "out_dtypes": ("float32", "int32"),
+            "shapes": [
+                {"label": f"t{t}_d{dim}_c{cap}_s1024_{m}_k{k}", "tile": t, "metric": m, "k": k}
+                for t, m in [(t, "euclidean") for t in warm_tile_sizes()] + [(8, "cosine")]
+            ],
+            "build": build_subset,
+        },
     ]
 
 
@@ -688,35 +875,124 @@ class KnnPlan(_KnnExecutorMixin):
         # (set by the planner): exact strategies prefilter with it
         self.prefilter = None
 
-    def _prefilter_slot_mask(self, ctx, rids, cap):
-        """(mask over vector-mirror slots, coalescing key tag) — or None
-        when the column mirror can't serve this reader exactly. The mask
-        marks slots whose record satisfies the residual WHERE, so the
-        kernel's top-k is computed among matching rows only."""
+    def _slot_filter(self, ctx, mirror, rids, live, mesh=None, device=True):
+        """The statement's residual WHERE as a filter over the vector
+        snapshot's slots (`_SlotFilter`), looked up in the mirror's cache or
+        made and kept there — or None when the statement has none, or the
+        column mirror can't serve this reader exactly (the search then runs
+        unfiltered and the executor filters its top-k). `live` is the
+        snapshot's live mask over its slots (the matrix's rows for the
+        device strategies, which also get the device arrays; None where
+        `rids` holds live rows only, a new list a generation): a slot passes
+        when its record's committed fields pass AND it lives, so a slot a
+        DELETE or an UNSET of the vector left behind (its rid stays until
+        compaction) is never gathered. The key is what the mask depends on:
+        the predicate's text and bound constants, the slot count and the
+        placement; an entry is good while the ColumnMirror object, the
+        snapshot's rids list and its live mask are the ones it was made
+        from, so an acknowledged write to the table (a new ColumnMirror), a
+        mutation of the vector mirror (a new live mask: the views make one
+        a generation) or a compaction (a new rids list) is seen by the next
+        search. The statement's `knn_filter` span says which it was, and how
+        many rows pass."""
+        if self.prefilter is None:
+            return None
         from surrealdb_tpu import telemetry
-        from surrealdb_tpu.idx.column_mirror import columnar_mask
+        from surrealdb_tpu.idx.column_mirror import columnar_mask, serveable_mirror
 
-        res = columnar_mask(ctx, self.tb, self.prefilter)
-        if res is None:
+        t0 = _time.perf_counter()
+        col = serveable_mirror(ctx, self.tb)
+        if col is None:
             telemetry.inc("knn_prefilter", outcome="unavailable")
             return None
-        mask, needs_row, col = res
-        if needs_row.any():
-            # the mask abstained on mixed-type rows: post-filter semantics
-            # stay (dropping those rows from the search would be wrong)
-            telemetry.inc("knn_prefilter", outcome="mixed_rows")
-            return None
-        perm = col.slot_permutation(rids, cap)
-        ok = perm >= 0
-        out = np.zeros(cap, dtype=bool)
-        out[ok] = mask[perm[ok]]
+        cap = len(rids) if live is None else len(live)
+        key = (self.prefilter.binding_key(), cap, None if mesh is None else id(mesh))
+        with mirror._lock:
+            flt = mirror._filters.get(key)
+        built = (
+            flt is None or flt.col is not col or flt.rids is not rids
+            or flt.live is not live or flt.mesh is not mesh
+        )
+        if built:
+            t1 = _time.perf_counter()
+            res = columnar_mask(ctx, self.tb, self.prefilter, col)
+            if res is None:
+                telemetry.inc("knn_prefilter", outcome="unavailable")
+                return None
+            mask, needs_row, _ = res
+            if needs_row.any():
+                # the mask abstained on mixed-type rows: post-filter semantics
+                # stay (dropping those rows from the search would be wrong)
+                telemetry.inc("knn_prefilter", outcome="mixed_rows")
+                return None
+            perm = col.slot_permutation(rids, cap)
+            ok = perm >= 0 if live is None else (perm >= 0) & live
+            host = np.zeros(cap, dtype=bool)
+            host[ok] = mask[perm[ok]]
+            flt = _SlotFilter(col, rids, live, mesh, host)
+            telemetry.stage(
+                "knn_filter_build", t1, _time.perf_counter() - t1, bytes=int(host.nbytes)
+            )
+        kept = not built
+        if device and flt.ok is None:
+            flt.upload(cap)
+            kept = False  # holds more bytes than the cache counted
+        if not kept:
+            with mirror._lock:
+                # what was made from an older column mirror or snapshot can
+                # never be served again, and holds that mirror alive
+                mirror._filters.forget(
+                    lambda _, e: e.col is not col or e.rids is not rids or e.live is not live
+                )
+                mirror._filters.put(key, flt, flt.nbytes())
         telemetry.inc("knn_prefilter", outcome="applied")
-        # key the dispatch batch by MASK CONTENT, not predicate text: the
-        # same SQL with different $param bindings lowers to different masks,
-        # and a rider must never be served through a leader's tighter mask.
-        # Identical masks (same predicate+constants, same column build)
-        # still coalesce into one launch.
-        return out, (hash(out.tobytes()), id(col))
+        telemetry.stage(
+            "knn_filter", t0, _time.perf_counter() - t0,
+            outcome="build" if built else "hit", rows=flt.rows,
+        )
+        return flt
+
+    def _masked_route(self, flt) -> str:
+        """The `filter` label of an exact scan of every row."""
+        return "none" if self.prefilter is None else "post" if flt is None else "masked"
+
+    def _ivf_route(self, flt, n: int, ivf, ef, k: int):
+        """(route, its argument, k) of an IVF strategy's search over `n`
+        live rows: the bare search's probes without a filter (`none`, or
+        `post` where the column mirror could not answer), else what
+        idx/ivf.py::filtered_route chooses from the rows that pass:
+        `subset` and its padded size, or `widened` and its probes. Where
+        fewer than `k` rows pass, the answer is those."""
+        from surrealdb_tpu.idx.ivf import default_nprobe, filtered_route
+
+        nprobe = default_nprobe(ivf.nlists, ef)
+        if flt is None:
+            return self._masked_route(None), nprobe, k
+        how, arg = filtered_route(flt.rows, n, ivf.nlists, nprobe, ivf.pad())
+        return how, arg, min(k, flt.rows)
+
+    def _submit(self, ds, t_iter, key, q, runner, route: str, k: int):
+        """One IVF-strategy statement to the dispatch queue; a filter
+        nothing passes has nothing to search."""
+        if k == 0:
+            _filter_route(route)
+            return np.empty(0), np.empty(0)
+        return _submit_prepared(ds, t_iter, key, q, runner, route)
+
+    def _exact_device(self, ds, t_iter, mirror, matrix, mask, flt, metric, k, q):
+        """The exact fused scan of every row of the device matrix, the
+        filter's mask ANDed into the live one."""
+        key = ("knn-exact", id(matrix), metric, k)
+        if flt is not None:
+            mask = flt.host  # made from this very live mask: passing AND live
+            key = key + (flt.serial,)
+
+        def runner(qs):
+            return _zipped(_exact_device_launch(
+                np.stack(qs), matrix, mask, metric, k, owner=mirror._owner,
+            ))
+
+        return _submit_prepared(ds, t_iter, key, q, runner, self._masked_route(flt))
 
     def explain(self) -> dict:
         idx = self.ix["index"]
@@ -794,37 +1070,48 @@ class KnnPlan(_KnnExecutorMixin):
                 mask_dev = mirror.device_sharded_mask()
                 want_ivf = approx_ok and n >= cnf.TPU_ANN_MIN_ROWS and self.k * 4 <= n
                 ivf = mirror.ensure_ivf(matrix) if want_ivf else None
+                flt = self._slot_filter(
+                    ctx, mirror, rids, mask, mesh=mesh, device=ivf is not None
+                )
                 if ivf is not None:
-                    from surrealdb_tpu.idx.ivf import default_nprobe
-
-                    self.strategy = "ivf-sharded"
                     ef = self.ef or self.ix["index"].get("efc")
-                    nprobe = default_nprobe(ivf.nlists, ef)
-                    key = ("knn-ivf-sharded", id(matrix), id(ivf), metric, k, nprobe)
-                    # columnar residual prefilter (parity with ivf/ivf-host):
-                    # the slot mask shards alongside the corpus rows and the
-                    # dispatch key carries the MASK CONTENT so riders with
-                    # different $param bindings never share a leader's mask
-                    slot_mask = None
-                    if self.prefilter is not None:
-                        pre = self._prefilter_slot_mask(ctx, rids, len(mask))
-                        if pre is not None:
-                            slot_mask = pre[0]
-                            key = key + pre[1]
+                    how, arg, k = self._ivf_route(flt, n, ivf, ef, k)
+                    if how == "subset":
+                        # the passing rows themselves, scored exactly on the
+                        # shard that holds each (parallel/mesh.py)
+                        self.strategy = "exact-subset-sharded"
+                        key = ("knn-subset-sharded", id(matrix), flt.serial, metric, k, flt.size)
 
-                    def runner(qs):
-                        qm = np.stack(qs)
+                        def runner(qs):
+                            return _subset_sharded_launch(np.stack(qs), mesh, matrix, flt, metric, k)
 
-                        def collect():
-                            dd, rr = ivf.search_batch_sharded(
-                                qm, mesh, matrix, metric, k, nprobe,
-                                slot_mask=slot_mask,
-                            )
-                            return list(zip(dd, rr))
+                    else:
+                        self.strategy = "ivf-sharded"
+                        nprobe = arg
+                        key = ("knn-ivf-sharded", id(matrix), id(ivf), metric, k, nprobe)
+                        # columnar residual prefilter (parity with ivf/ivf-host):
+                        # the cached slot mask is sharded alongside the corpus
+                        # rows, and the dispatch key names the filter so riders
+                        # with different $param bindings never share a
+                        # leader's mask
+                        slot_mask = None
+                        if flt is not None:
+                            slot_mask = flt.ok
+                            key = key + (flt.serial,)
 
-                        return collect
+                        def runner(qs):
+                            qm = np.stack(qs)
 
-                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
+                            def collect():
+                                dd, rr = ivf.search_batch_sharded(
+                                    qm, mesh, matrix, metric, k, nprobe,
+                                    slot_mask=slot_mask,
+                                )
+                                return list(zip(dd, rr))
+
+                            return collect
+
+                    dists, slots = self._submit(ds, t_iter, key, q, runner, how, k)
                 else:
                     self.strategy = (
                         "exact-sharded(ivf-training)" if want_ivf else "exact-sharded"
@@ -832,14 +1119,12 @@ class KnnPlan(_KnnExecutorMixin):
                     key = ("knn-sharded", id(matrix), metric, k)
                     # columnar residual prefilter, as every other strategy:
                     # the matching slots AND the live ones, sharded as the
-                    # live mask is, the mask's content in the dispatch key
-                    if self.prefilter is not None:
-                        pre = self._prefilter_slot_mask(ctx, rids, len(mask))
-                        if pre is not None:
-                            import jax
+                    # live mask is, the filter's name in the dispatch key
+                    if flt is not None:
+                        import jax
 
-                            mask_dev = jax.device_put(mask & pre[0], mask_dev.sharding)
-                            key = key + pre[1]
+                        mask_dev = jax.device_put(flt.host, mask_dev.sharding)
+                        key = key + (flt.serial,)
 
                     def runner(qs):
                         from surrealdb_tpu import compile_log
@@ -875,7 +1160,9 @@ class KnnPlan(_KnnExecutorMixin):
                             one_slice(lo, hi)
                         return list(zip(dd, rr))
 
-                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
+                    dists, slots = _submit_prepared(
+                        ds, t_iter, key, q, runner, self._masked_route(flt)
+                    )
             elif (
                 not cnf.TPU_DISABLE
                 and approx_ok
@@ -888,88 +1175,58 @@ class KnnPlan(_KnnExecutorMixin):
                 # snapshot's rids list is tied to this matrix's numbering
                 matrix, mask, rids = mirror.device_snapshot()
                 ivf = mirror.ensure_ivf(matrix)
+                flt = self._slot_filter(ctx, mirror, rids, mask, device=ivf is not None)
                 if ivf is None:
                     # quantizer still training in the background: serve this
                     # query exactly (no latency cliff, full recall)
                     self.strategy = "exact-device(ivf-training)"
-                    key = ("knn-exact", id(matrix), metric, k)
-                    if self.prefilter is not None:
-                        pre = self._prefilter_slot_mask(ctx, rids, len(mask))
-                        if pre is not None:
-                            mask = mask & pre[0]
-                            key = key + pre[1]
-
-                    def runner(qs):
-                        collect = _exact_device_launch(
-                            np.stack(qs), matrix, mask, metric, k,
-                            owner=mirror._owner,
-                        )
-
-                        def finish():
-                            dd, rr = collect()
-                            return list(zip(dd, rr))
-
-                        return finish
-
-                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
+                    dists, slots = self._exact_device(ds, t_iter, mirror, matrix, mask, flt, metric, k, q)
                 else:
-                    from surrealdb_tpu.idx.ivf import default_nprobe
-
                     ef = self.ef or self.ix["index"].get("efc")
-                    nprobe = default_nprobe(ivf.nlists, ef)
-                    # concurrent same-shape queries coalesce into one kernel
-                    # launch (dbs/dispatch.py — the cross-query PARALLEL seam).
-                    # Keyed by the matrix/ivf identities so a batch never mixes
-                    # slot numberings.
-                    key = ("knn-ivf", id(matrix), id(ivf), metric, k, nprobe)
-                    # residual-WHERE prefilter (parity with the exact
-                    # strategies): the mask rides into the probe+rerank
-                    # kernel so top-k is computed among MATCHING rows; the
-                    # key carries the mask content so riders with different
-                    # $param bindings never share a leader's tighter mask
-                    slot_mask = None
-                    if self.prefilter is not None:
-                        pre = self._prefilter_slot_mask(ctx, rids, len(mask))
-                        if pre is not None:
-                            slot_mask = pre[0]
-                            key = key + pre[1]
+                    how, arg, k = self._ivf_route(flt, n, ivf, ef, k)
+                    if how == "subset":
+                        # fewer rows pass than a widened probe would gather:
+                        # score them all, exactly, from the cached slot array
+                        self.strategy = "exact-subset"
+                        key = ("knn-subset", id(matrix), flt.serial, metric, k, flt.size)
 
-                    def runner(qs):
-                        collect = ivf.search_batch_launch(
-                            np.stack(qs), matrix, metric, k, nprobe,
-                            owner=mirror._owner, slot_mask=slot_mask,
-                        )
+                        def runner(qs):
+                            return _zipped(_exact_device_launch(
+                                np.stack(qs), matrix, None, metric, k,
+                                owner=mirror._owner, subset=flt,
+                            ))
 
-                        def finish():
-                            dd, rr = collect()
-                            return list(zip(dd, rr))
+                    else:
+                        nprobe = arg
+                        # concurrent same-shape queries coalesce into one kernel
+                        # launch (dbs/dispatch.py — the cross-query PARALLEL seam).
+                        # Keyed by the matrix/ivf identities so a batch never mixes
+                        # slot numberings.
+                        key = ("knn-ivf", id(matrix), id(ivf), metric, k, nprobe)
+                        # residual-WHERE prefilter (parity with the exact
+                        # strategies): the cached device mask rides into the
+                        # probe+rerank kernel so top-k is computed among
+                        # MATCHING rows; the key names the filter so riders
+                        # with different $param bindings never share a
+                        # leader's tighter mask. Without a prefilter the key
+                        # and the program are the bare search's.
+                        slot_mask = None
+                        if flt is not None:
+                            slot_mask = flt.ok
+                            key = key + (flt.serial,)
 
-                        return finish
+                        def runner(qs):
+                            return _zipped(ivf.search_batch_launch(
+                                np.stack(qs), matrix, metric, k, nprobe,
+                                owner=mirror._owner, slot_mask=slot_mask,
+                            ))
 
-                    dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
+                    dists, slots = self._submit(ds, t_iter, key, q, runner, how, k)
             elif not cnf.TPU_DISABLE and n >= cnf.TPU_KNN_ONDEVICE_THRESHOLD:
                 self.strategy = "exact-device"
                 matrix, mask, rids = mirror.device_snapshot()
-                key = ("knn-exact", id(matrix), metric, k)
-                if self.prefilter is not None:
-                    pre = self._prefilter_slot_mask(ctx, rids, len(mask))
-                    if pre is not None:
-                        mask = mask & pre[0]
-                        key = key + pre[1]
-
-                def runner(qs):
-                    collect = _exact_device_launch(
-                        np.stack(qs), matrix, mask, metric, k,
-                        owner=mirror._owner,
-                    )
-
-                    def finish():
-                        dd, rr = collect()
-                        return list(zip(dd, rr))
-
-                    return finish
-
-                dists, slots = _submit_prepared(ds, t_iter, key, q, runner)
+                flt = self._slot_filter(ctx, mirror, rids, mask, device=False)
+                dists, slots = self._exact_device(ds, t_iter, mirror, matrix, mask, flt, metric, k, q)
             else:
                 # CPU serving path: an already-trained quantizer serves ANN on
                 # host too (probe + exact rerank, idx/ivf.py search_host) — the
@@ -985,34 +1242,36 @@ class KnnPlan(_KnnExecutorMixin):
                     and n >= cnf.TPU_ANN_MIN_ROWS
                     and self.k * 4 <= n
                 ):
-                    from surrealdb_tpu.idx.ivf import default_nprobe
-
                     self.strategy = "ivf-host"
                     ef = self.ef or self.ix["index"].get("efc")
                     data, alive, rids = mirror.host_view()
-                    slot_mask = None
-                    if self.prefilter is not None:
-                        pre = self._prefilter_slot_mask(ctx, rids, len(alive))
-                        if pre is not None:
-                            slot_mask = pre[0]
-                    dists, li = ivf.search_host(
-                        q[None, :], data, metric, k,
-                        default_nprobe(ivf.nlists, ef),
-                        slot_mask=slot_mask,
-                    )
-                    dists, slots = dists[0], li[0]
+                    flt = self._slot_filter(ctx, mirror, rids, alive, device=False)
+                    how, arg, k = self._ivf_route(flt, n, ivf, ef, k)
+                    _filter_route(how)
+                    if how == "subset":
+                        self.strategy = "exact-subset-host"
+                        dists = slots = np.empty(0)
+                        if k:
+                            dists, li = D.knn_search_host(q[None, :], data[flt.slot_ids], metric, k)
+                            dists, slots = dists[0], flt.slot_ids[li[0]]
+                    else:
+                        dists, li = ivf.search_host(
+                            q[None, :], data, metric, k, arg,
+                            slot_mask=None if flt is None else flt.host,
+                        )
+                        dists, slots = dists[0], li[0]
                 else:
                     self.strategy = "exact-host"
                     data, norms, rids = mirror.host_search_view()
-                    if self.prefilter is not None:
-                        pre = self._prefilter_slot_mask(ctx, rids, len(rids))
-                        if pre is not None:
-                            sel = np.nonzero(pre[0])[0]
-                            if sel.size == 0:
-                                return
-                            data, norms = data[sel], norms[sel]
-                            rids = [rids[int(i)] for i in sel]
-                            k = min(k, sel.size)
+                    flt = self._slot_filter(ctx, mirror, rids, None, device=False)
+                    _filter_route(self._masked_route(flt))
+                    if flt is not None:
+                        sel = flt.slot_ids
+                        if sel.size == 0:
+                            return
+                        data, norms = data[sel], norms[sel]
+                        rids = [rids[int(i)] for i in sel]
+                        k = min(k, sel.size)
                     dists, li = D.knn_search_host(
                         q[None, :], data, metric, k, x_sq_norms=norms
                     )

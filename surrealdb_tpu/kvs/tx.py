@@ -161,6 +161,8 @@ class Transaction:
                 )
             cm.invalidate(self.touched_tables, self.touched_scopes)
         self.tr.commit()
+        if cm is not None and self.touched_tables:
+            cm.committed(self.touched_tables, getattr(self.tr, "commit_version", None))
         touched, self.touched_tables = self.touched_tables, set()
         self.touched_scopes = set()
         if cm is not None and touched:

@@ -128,6 +128,25 @@ def knn_search(
     return -neg, idx
 
 
+@functools.partial(jax.jit, static_argnames=("metric", "k"))
+def knn_subset_search(
+    q: jax.Array, x: jax.Array, slots: jax.Array, n_pass: jax.Array, metric: str, k: int
+) -> Tuple[jax.Array, jax.Array]:
+    """Exact distance + top-k over the corpus rows a filter lets through.
+
+    q: [Q, D] queries; x: [N, D] corpus; slots: [S] int32 corpus slots of
+    the passing rows, the first `n_pass` (a scalar on the device, so a
+    slice that grows inside its padded size keeps this program) real and
+    the rest pad. One gather serves the whole query tile.
+    -> (dists [Q, k], corpus slots [Q, k]); misses surface as +inf / -1
+    """
+    cand = x[slots]  # [S, D] in the corpus dtype
+    d = pairwise_distance(q, cand, metric)
+    d = jnp.where(jnp.arange(slots.shape[0])[None, :] < n_pass, d, jnp.inf)
+    neg, idx = jax.lax.top_k(-d, k)
+    return -neg, jnp.where(neg > -jnp.inf, slots[idx], -1)
+
+
 def pad_rows(arr: np.ndarray, multiple: int) -> Tuple[np.ndarray, np.ndarray]:
     """Pad [N, D] to the next row-count multiple; returns (padded, mask)."""
     n = arr.shape[0]

@@ -96,6 +96,55 @@ def sharded_knn(
     return _knn_searcher(mesh, k, metric, axis)(corpus, mask, queries)
 
 
+@functools.lru_cache(maxsize=64)
+def _subset_searcher(mesh, k, metric, axis):
+    """Jitted sharded exact kNN over a filter's passing slots, cached per
+    (mesh, params) as `_knn_searcher` is."""
+
+    @functools.partial(
+        shard_map,
+        mesh=mesh,
+        in_specs=(P(axis, None), P(None), P(), P(None, None)),
+        out_specs=(P(None, None), P(None, None)),
+        check_vma=False,
+    )
+    def _subset(x_local, slots, n_pass, q):
+        # every chip scores the passing slots that fall in ITS rows: the
+        # gather is local, the rest of the slot array is masked out
+        shard_rows = x_local.shape[0]
+        local = slots - jax.lax.axis_index(axis) * shard_rows
+        mine = (local >= 0) & (local < shard_rows) & (jnp.arange(slots.shape[0]) < n_pass)
+        cand = x_local[jnp.clip(local, 0, shard_rows - 1)]
+        d = jnp.where(mine[None, :], pairwise_distance(q, cand, metric), jnp.inf)
+        neg, idx = jax.lax.top_k(-d, k)  # [Q, k]
+        found = jnp.where(neg > -jnp.inf, slots[idx], -1)
+        d_all = jax.lax.all_gather(-neg, axis, axis=1, tiled=True)  # [Q, n*k]
+        i_all = jax.lax.all_gather(found, axis, axis=1, tiled=True)
+        neg2, pos = jax.lax.top_k(-d_all, k)
+        return -neg2, jnp.take_along_axis(i_all, pos, axis=1)
+
+    return jax.jit(_subset)
+
+
+def sharded_subset_knn(
+    mesh: Mesh,
+    corpus: jax.Array,
+    slots: jax.Array,
+    n_pass: jax.Array,
+    queries: jax.Array,
+    k: int,
+    metric: str = "euclidean",
+    axis: str = "data",
+) -> Tuple[jax.Array, jax.Array]:
+    """Exact kNN among the corpus rows a filter lets through, over a
+    row-sharded corpus (the mesh composition of
+    ops/distances.py::knn_subset_search): `slots` [S] are their global
+    slots, replicated, the first `n_pass` real. Per-shard local top-k, then
+    the O(k*devices) all-gather `sharded_knn` makes. Returns
+    (dists [Q, k], global slots [Q, k]); misses surface as +inf / -1."""
+    return _subset_searcher(mesh, k, metric, axis)(corpus, slots, n_pass, queries)
+
+
 def sharded_knn_2d(
     mesh: Mesh,
     corpus: jax.Array,
@@ -329,6 +378,20 @@ def graftcheck_sites():
 
         return run, args
 
+    def build_subset(shape):
+        mesh = make_mesh(n_dev)
+        args = (
+            jax.ShapeDtypeStruct((cap, dim), jnp.float32),
+            jax.ShapeDtypeStruct((1024,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((shape["tile"], dim), jnp.float32),
+        )
+        metric, kk = shape["metric"], shape["k"]
+        return (
+            lambda c, s, n, q: sharded_subset_knn(mesh, c, s, n, q, kk, metric),
+            args,
+        )
+
     def tiles():
         from surrealdb_tpu.utils.num import warm_tile_sizes
 
@@ -341,6 +404,11 @@ def graftcheck_sites():
     ]
     ivf_shapes = [
         {"label": f"t{t}_d{dim}_c{cap}_C{C}_L{L}_p{nprobe}_{m}_k{k}_mesh{n_dev}",
+         "tile": t, "metric": m, "k": k}
+        for t, m in [(t, "euclidean") for t in tiles()] + [(8, "cosine")]
+    ]
+    subset_shapes = [
+        {"label": f"t{t}_d{dim}_c{cap}_s1024_{m}_k{k}_mesh{n_dev}",
          "tile": t, "metric": m, "k": k}
         for t, m in [(t, "euclidean") for t in tiles()] + [(8, "cosine")]
     ]
@@ -365,6 +433,16 @@ def graftcheck_sites():
             "out_dtypes": ("float32", "int32"),
             "shapes": ivf_shapes,
             "build": build_ivf,
+        },
+        {
+            "subsystem": "knn_subset_sharded",
+            "module": __name__,
+            "kind": "sharded",
+            "mesh_devices": n_dev,
+            "allowed_collectives": ("all-gather",),
+            "out_dtypes": ("float32", "int32"),
+            "shapes": subset_shapes,
+            "build": build_subset,
         },
     ]
 

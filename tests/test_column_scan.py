@@ -184,6 +184,137 @@ def test_post_build_commit_never_stale(ds):
     assert telemetry.get_counter("scan_strategy", strategy="columnar") == before + 1
 
 
+@pytest.mark.parametrize("writes_to", ["other", "t"])
+def test_a_commit_between_a_reader_s_snapshot_and_the_build_it_triggers(ds, monkeypatch, writes_to):
+    """The reader that triggers a build opened its snapshot before the
+    build's. A commit to ANOTHER table in between changes nothing the
+    mirror holds, so the reader is served from it (it paid for the build:
+    the read-back of a bulk load fell to a 50 s row scan this way in two
+    benchmark runs of fourteen, PERF.md section 7); a commit to THE table
+    in between is in the mirror and not in the reader's snapshot, so that
+    reader takes the row path and counts what ITS snapshot holds."""
+    from surrealdb_tpu import telemetry
+
+    ds.execute("DEFINE TABLE t SCHEMALESS; DEFINE TABLE other SCHEMALESS")
+    ok(ds.execute("INSERT INTO t $rows", vars={"rows": [{"id": i, "a": i} for i in range(40)]})[-1])
+    build = ds.column_mirrors.build
+
+    def write_then_build(store, ns, db, tb):
+        ok(store.execute(f"CREATE {writes_to}:900 SET a = 900")[-1])
+        return build(store, ns, db, tb)
+
+    monkeypatch.setattr(ds.column_mirrors, "build", write_then_build)
+    telemetry.reset()
+    got = ok(ds.execute("SELECT count() AS c FROM t WHERE a >= 0 GROUP ALL")[-1])
+    monkeypatch.undo()
+    served = {dict(k)["strategy"] for k in telemetry.counters_matching("scan_strategy")}
+    assert got == [{"c": 40}]  # the reader's snapshot predates row 900 either way
+    assert served == ({"columnar_count"} if writes_to == "other" else {"row_fallback"})
+    # the next reader's snapshot holds everything: served from the mirror that was built
+    telemetry.reset()
+    assert ok(ds.execute("SELECT count() AS c FROM t WHERE a >= 0 GROUP ALL")[-1]) == [
+        {"c": 40 if writes_to == "other" else 41}]
+    assert {dict(k)["strategy"] for k in telemetry.counters_matching("scan_strategy")} == {"columnar_count"}
+
+
+def test_the_floor_of_a_build_through_the_group_commit_drain(ds, monkeypatch):
+    """Three write transactions ride ONE group-commit flush (the leader
+    commits each with its `column_sink`): each records its own commit beside
+    the counter it bumped, so the table's record is its LAST member's, and
+    a reader whose build is preceded by a drained commit to another table
+    is still served from the mirror."""
+    import threading
+    import time as _t
+
+    from surrealdb_tpu import cnf, telemetry
+    from surrealdb_tpu.kvs.tx import Transaction
+
+    assert cnf.GROUP_COMMIT
+    ds.execute("DEFINE TABLE t SCHEMALESS; DEFINE TABLE other SCHEMALESS")
+    ok(ds.execute("INSERT INTO t $rows", vars={"rows": [{"id": i, "a": i} for i in range(40)]})[-1])
+    cm, key_t, key_o = ds.column_mirrors, ("test", "test", "t"), ("test", "test", "other")
+    sinks, direct = [], Transaction.commit_direct
+
+    def spy(self, column_sink=None):
+        if self.write:
+            sinks.append(column_sink)
+        return direct(self, column_sink)
+
+    monkeypatch.setattr(Transaction, "commit_direct", spy)
+    telemetry.reset()
+    writers = [threading.Thread(target=lambda q=q: ok(ds.execute(q)[-1])) for q in (
+        "CREATE t:900 SET a = 900", "CREATE other:1 SET a = 1", "CREATE t:901 SET a = 901")]
+    with ds.commit_lock:  # the flusher waits here, so all three are in its queue or its batch
+        for w in writers:
+            w.start()
+        deadline = _t.monotonic() + 30
+        while len(sinks) + len(ds.group_commit._queue) < 3 and _t.monotonic() < deadline:
+            _t.sleep(0.002)
+    for w in writers:
+        w.join(30)
+    assert len(sinks) == 3 and all(sk is not None for sk in sinks)  # every one by the leader
+    store_version = ds.transaction(False)
+    try:
+        newest = store_version.tr.snapshot
+    finally:
+        store_version.cancel()
+    # t's record: the counter its last commit left it at, and a commit of this drain
+    counter, landed = cm.last_commit[key_t]
+    assert counter == cm.versions[key_t] and newest - 2 <= landed <= newest
+    assert cm.last_commit[key_o][0] == cm.versions[key_o]
+    # a drained commit to `other` between a reader's snapshot and its build
+    build = cm.build
+
+    def write_then_build(store, ns, db, tb):
+        ok(store.execute("CREATE other:2 SET a = 2")[-1])
+        return build(store, ns, db, tb)
+
+    monkeypatch.setattr(cm, "build", write_then_build)
+    monkeypatch.setattr(cnf, "COLUMN_REBUILD_DEBOUNCE_SECS", 0.0)
+    telemetry.reset()
+    assert ok(ds.execute("SELECT count() AS c FROM t WHERE a >= 0 GROUP ALL")[-1]) == [{"c": 42}]
+    assert len(sinks) == 4 and sinks[-1] is not None
+    assert {dict(k)["strategy"] for k in telemetry.counters_matching("scan_strategy")} == {"columnar_count"}
+    assert cm.get(key_t).built_store_version == landed  # the floor is t's last commit, not the snapshot
+
+
+@pytest.mark.parametrize("then", ["gone", "made_again"])
+def test_a_remove_table_between_a_reader_s_snapshot_and_the_build(ds, monkeypatch, then):
+    """The reader's snapshot holds the 40 rows; a REMOVE TABLE lands before
+    the build it triggers (and, `made_again`, a row is created after it).
+    The drop bumps the table's counter and records no commit, so the build's
+    floor is its own snapshot: that reader counts what ITS snapshot holds on
+    the row path, never the mirror of a table it cannot see."""
+    from surrealdb_tpu import cnf, telemetry
+
+    ds.execute("DEFINE TABLE t SCHEMALESS")
+    ok(ds.execute("INSERT INTO t $rows", vars={"rows": [{"id": i, "a": i} for i in range(40)]})[-1])
+    cm, key_t = ds.column_mirrors, ("test", "test", "t")
+    build = cm.build
+
+    def drop_then_build(store, ns, db, tb):
+        ok(store.execute("REMOVE TABLE t")[-1])
+        if then == "made_again":
+            ok(store.execute("CREATE t:7 SET a = 7")[-1])
+        return build(store, ns, db, tb)
+
+    monkeypatch.setattr(cm, "build", drop_then_build)
+    monkeypatch.setattr(cnf, "COLUMN_REBUILD_DEBOUNCE_SECS", 0.0)
+    telemetry.reset()
+    got = ok(ds.execute("SELECT count() AS c FROM t WHERE a >= 0 GROUP ALL")[-1])
+    monkeypatch.setattr(cm, "build", build)
+    assert got == [{"c": 40}]
+    assert {dict(k)["strategy"] for k in telemetry.counters_matching("scan_strategy")} == {"row_fallback"}
+    rec = cm.last_commit.get(key_t)
+    if then == "gone":  # the drop recorded nothing: the counters stand apart
+        assert rec is None or rec[0] != cm.versions[key_t]
+    else:  # the CREATE after it is the table's last commit again
+        assert rec[0] == cm.versions[key_t]
+    # the next reader sees the table as it is now
+    assert ok(ds.execute("SELECT count() AS c FROM t WHERE a >= 0 GROUP ALL")[-1]) == (
+        [] if then == "gone" else [{"c": 1}])
+
+
 def test_remove_table_never_serves_ghosts(ds):
     ds.execute("DEFINE TABLE t SCHEMALESS")
     ok(ds.execute("INSERT INTO t $rows", vars={"rows": [{"id": i, "a": 1} for i in range(20)]})[-1])

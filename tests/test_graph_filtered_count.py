@@ -240,9 +240,9 @@ def test_the_weights_are_kept_a_binding_and_dropped_with_the_mirrors(loaded, edg
     ds, sess = loaded
     gm = ds.graph_mirrors
     for fn in ("Anna", "Bo", "Anna"):
-        ask(ds, sess, BY_NAME, {"p": 1, "fn": fn}, f"keep-{fn}-{len(gm._endw)}")
-    assert len(gm._endw) == 2 and all(k[0][:2] == (NS, DB) for k in gm._endw)
-    (entry,) = [e for k, e in gm._endw.items() if "'Bo'" in repr(k[1])]
+        ask(ds, sess, BY_NAME, {"p": 1, "fn": fn}, f"keep-{fn}-{len(gm._endw._d)}")
+    assert len(gm._endw._d) == 2 and all(k[0][:2] == (NS, DB) for k in gm._endw._d)
+    (entry,) = [e for k, (e, _) in gm._endw._d.items() if "'Bo'" in repr(k[1])]
     w = np.asarray(entry["w"])
     passing = {i for i in range(N) if person(i)["firstName"] == "Bo"}
     space = gm.table_space(NS, DB, "person")
@@ -254,7 +254,7 @@ def test_the_weights_are_kept_a_binding_and_dropped_with_the_mirrors(loaded, edg
             want[local[it.lookup(Thing("person", a))]] += 1
     assert (w == want).all() and entry["rows"] == len(passing)
     gm.drop_table(NS, DB, "knows")
-    assert len(gm._endw) == 0 and set(gm._mirror_rows) == {(NS, DB, "person")}
+    assert len(gm._endw._d) == 0 and set(gm._mirror_rows) == {(NS, DB, "person")}
     gm.drop_table(NS, DB, "person")
     assert gm._mirror_rows == {}
 
