@@ -33,7 +33,7 @@ from surrealdb_tpu import key as keys, telemetry
 from surrealdb_tpu.key.encode import prefix_end
 from surrealdb_tpu.sql.value import Thing
 from surrealdb_tpu.utils.byte_cache import ByteBudgetCache
-from surrealdb_tpu.utils.num import count_lane_set, count_lanes, next_pow2 as _next_pow2
+from surrealdb_tpu.utils.num import count_lane_set, count_lanes, next_pow2 as _next_pow2, path_slots
 
 
 class NodeInterner:
@@ -65,10 +65,12 @@ class NodeInterner:
 
 def _csc_arrays(src: np.ndarray, dst: np.ndarray, cap: int):
     """Edges `src -> dst` over `cap` nodes as the arrays chain_count_batch
-    sweeps: `csrc`, the sources in destination order (stable), padded to a
-    power of two with the sentinel `cap`, and `cptr` [cap + 1], the bounds
-    of each destination's bin in it (pad slots lie past the last bin)."""
-    csrc = np.full(_next_pow2(max(src.size, 1)), cap, dtype=np.int32)
+    sweeps: `csrc`, the sources in destination order (stable), padded to
+    utils/num.py::path_slots with the sentinel `cap` (the one place that
+    pads this axis: shape keys, warm-up and audit read the arrays), and
+    `cptr` [cap + 1], the bounds of each destination's bin in it (pad slots
+    lie past the last bin)."""
+    csrc = np.full(path_slots(src.size), cap, dtype=np.int32)
     csrc[: src.size] = src[np.argsort(dst, kind="stable")]
     cptr = np.zeros(cap + 1, dtype=np.int32)
     np.cumsum(np.bincount(dst, minlength=cap), out=cptr[1:])
@@ -202,7 +204,8 @@ class PointerCsr:
             )
             t1 = _time.perf_counter()
             telemetry.stage(
-                "graph_csc_build", t0, t1 - t0, bytes=cptr.nbytes + csrc.nbytes
+                "graph_csc_build", t0, t1 - t0, bytes=cptr.nbytes + csrc.nbytes,
+                paths=nnz, slots=csrc.size,
             )
             self._dev_csc = (jnp.asarray(cptr), jnp.asarray(csrc))
             # the transfer is asynchronous: the stage times the host's
@@ -1247,7 +1250,7 @@ class GraphMirrors:
         """Composed sparse operator for one `->edge->node` spec pair: the
         2-hop paths src -> edge record -> dst as a node->node CSC in the two
         tables' compact ids, shaped as PointerCsr.device_csc() shapes a
-        mirror's (dst-sorted `csrc` padded to a power of two with the
+        mirror's (dst-sorted `csrc` padded by _csc_arrays with the
         sentinel `n_pad`, bin bounds `cptr`, and the source-side `indptr`
         whose differences are the out-degrees of a count's last pair), so
         chain_count_batch sweeps one hop a pair over [lanes, n_pad + 1]
@@ -1286,7 +1289,7 @@ class GraphMirrors:
             np.cumsum(np.bincount(ls, minlength=n_pad), out=indptr[1:])
             nbytes = cptr.nbytes + csrc.nbytes + indptr.nbytes
             t1 = _time.perf_counter()
-            telemetry.stage("graph_csc_build", t0, t1 - t0, bytes=nbytes)
+            telemetry.stage("graph_csc_build", t0, t1 - t0, bytes=nbytes, paths=ls.size, slots=csrc.size)
             op.update(
                 n_pad=n_pad,
                 src_tb=key[2],
@@ -1788,7 +1791,7 @@ def graftcheck_sites():
         lanes = shape["lanes"]
         csc_hops = tuple(
             ((jax.ShapeDtypeStruct((n_cap + 1,), jnp.int32),
-              jax.ShapeDtypeStruct((E,), jnp.int32)),)
+              jax.ShapeDtypeStruct((path_slots(shape.get("paths", E)),), jnp.int32)),)
             for _ in range(shape["hops"] - 1)
         )
         lanes_fsz = jax.ShapeDtypeStruct((lanes, fsz), jnp.int32)
@@ -1852,7 +1855,11 @@ def graftcheck_sites():
             "kind": "single",
             "allowed_collectives": (),
             "out_dtypes": ("int32",),
-            "shapes": lane_shapes,
+            # and one operand whose slot count is no power of two: the
+            # path array pads to path_slots (1,025 paths: 1,152 slots)
+            "shapes": lane_shapes + [
+                {"label": f"l8_f{fsz}_n{n0}_h2_p{E + 1}", "lanes": 8, "hops": 2, "weighted": False, "paths": E + 1}
+            ],
             "build": build_csc,
         },
         {

@@ -2,8 +2,9 @@
 
 
 def next_pow2(x: int) -> int:
-    """Smallest power of two >= x (>=1). All mirror/kernel static dims round
-    through this so steady writes never change compiled shapes."""
+    """Smallest power of two >= x (>=1). Mirror/kernel static dims round
+    through this so steady writes never change compiled shapes; the slot
+    count of a destination-sorted path array follows path_slots instead."""
     return 1 << max(int(x) - 1, 0).bit_length()
 
 
@@ -68,6 +69,21 @@ def count_lanes(riders: int) -> int:
     {1, 8, cap} is dispatch_tile's; this is the one rule of the graph count
     runners, their warm-up and their audit shapes."""
     return max(next_pow2(riders), COUNT_LANES_MIN)
+
+
+def path_slots(paths: int) -> int:
+    """Slot count of a destination-sorted path array (`csrc`, what the
+    sparse count kernel's row gathers, prefix sums and transposes are as
+    long as): the paths rounded up to a sixteenth of the power of two above
+    them, so eight shapes an octave and at most an eighth of the slots hold
+    the sentinel where a power of two left up to half (SNB SF3: 1,130,494
+    paths in 1,179,648 slots, not 2,097,152; PERF.md section 6, PR 34).
+    Up to 1,024 paths the sixteenth is under a row of 128 lanes and the
+    power of two stays. The price is a new compiled shape every 6-12% of
+    growth in paths where there was one a doubling."""
+    top = next_pow2(paths)
+    q = top // 16
+    return top if q < 128 else -(-paths // q) * q
 
 
 def count_lane_set(cap: int = None):
