@@ -1,0 +1,19 @@
+"""Share of the window in which a batch was in flight: launched, and not yet
+seen ready by the host. The host's view, not the trace's: it holds the
+runtime's completion latency and misses what the device ran before the launch
+phase returned. From the dispatcher's state clock (`dbs/dispatch.py`):
+`stats()` carries four sums, `fed_s`, `launching_s`, `handoff_s`, `empty_s`,
+and every second is in exactly one, so their deltas over the window add up to
+the window's wall time as the program counted it. A program without the clock
+reports nothing."""
+
+NAME, UNIT, LAYER, MOVES, SOURCE = "dispatch.fed_share", "ratio", "dispatch", "stmt_per_s", "program_counter"
+STATES = ("fed_s", "launching_s", "handoff_s", "empty_s")
+
+
+def read(ctx):
+    d = ctx["window"]["dispatch"]
+    if any(k not in d for k in STATES):
+        return None
+    wall = sum(d[k] for k in STATES)
+    return d["fed_s"] / wall if wall > 0 else None

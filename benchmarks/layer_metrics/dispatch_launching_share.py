@@ -1,0 +1,18 @@
+"""Share of the window in which nothing was in flight and a leader was inside its
+runner's launch phase: uploads and look-ups while the device waits, then the
+jitted call (after whose return the device may already run). From the
+dispatcher's state clock (`dbs/dispatch.py`): `stats()` carries four sums,
+`fed_s`, `launching_s`, `handoff_s`, `empty_s`, and every second is in exactly
+one, so their deltas over the window add up to the window's wall time as the
+program counted it. A program without the clock reports nothing."""
+
+NAME, UNIT, LAYER, MOVES, SOURCE = "dispatch.launching_share", "ratio", "dispatch", "stmt_per_s", "program_counter"
+STATES = ("fed_s", "launching_s", "handoff_s", "empty_s")
+
+
+def read(ctx):
+    d = ctx["window"]["dispatch"]
+    if any(k not in d for k in STATES):
+        return None
+    wall = sum(d[k] for k in STATES)
+    return d["launching_s"] / wall if wall > 0 else None

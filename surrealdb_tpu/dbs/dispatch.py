@@ -54,9 +54,31 @@ Two-phase runners (double buffering): a runner may return a CALLABLE instead
 of the results list — the callable is the "collect" phase (blocking result
 download). The bucket is handed to the next leader right after the launch
 phase returns, so the pipeline depth above is measured launch-to-collect.
-What a runner has to say about its launch rides on that callable: a dict
-attribute `launch_labels` (the graph count runners: `lanes`, the padded
-lane count) joins `batch` on every rider's `dispatch_launch` span.
+What a runner has to say about its launch rides on that callable, as
+attributes: a dict `launch_labels` (the graph count runners: `lanes`, the
+padded lane count) joins `batch` on every rider's `dispatch_launch` span,
+and `outputs`, the device arrays the collect will read, lets the queue wait
+for the device itself (each array's `copy_to_host_async()`, then each
+array's `block_until_ready()`) before it calls the closure: the collect then splits into `dispatch_ready_wait` (the
+kernels in front, this kernel, the runtime's completion latency) and
+`dispatch_fetch` (the rest of the copy the launch started, and the decode).
+A closure without `outputs` has its whole collect counted as the wait.
+
+The device's books: the queue counts, over all its buckets (the device is
+one), the requests `queued` (submitted, not yet drawn into a batch), the
+leaders `launching` (inside their runner's launch phase) and the batches
+`inflight` (launched, not yet seen ready). Every change of a count first
+adds the time since the last change to ONE of four sums, by the counts as
+they stood: `fed_s` (a batch is in flight: the device has work as far as
+the host knows), else `launching_s` (a leader is in its launch phase:
+uploads and look-ups while the device waits, then the jitted call, after
+whose return the device may already run while the runner finishes), else
+`handoff_s` (requests wait and no leader runs: promotion, wake-up, the
+depth semaphore, the interpreter lock), else `empty_s` (no statement has
+reached the queue). The four sums of two stats() snapshots differ by the
+wall time between them. A synchronous runner (its result is no callable)
+has its whole run counted as `launching_s`, and so has a split-retry's
+re-execution; the served strategies of every benchmark cell are two-phase.
 """
 
 from __future__ import annotations
@@ -68,6 +90,8 @@ from typing import Any, Callable, Dict, Hashable, List, Optional, Sequence
 from surrealdb_tpu import cnf
 from surrealdb_tpu.utils import locks as _locks
 
+
+_STATES = ("fed", "launching", "handoff", "empty")  # dispatch_device_seconds{state}
 
 _TRANSIENT_MARKERS = (
     "UNAVAILABLE",
@@ -173,9 +197,17 @@ class DispatchQueue:
         self.splits = 0  # transiently-failed batches bisected for retry
         self.failures = 0  # batches that failed permanently (every rider errored)
         self.launch_s = 0.0  # time in runner launch phases (upload + enqueue)
-        self.collect_s = 0.0  # time awaiting device results (download)
+        self.collect_s = 0.0  # time in collect phases (the device's wait and the download)
+        self.ready_wait_s = 0.0  # of collect_s: until the outputs were ready on the device
+        self.fetch_s = 0.0  # of collect_s: from ready to the results as host values
         self.pipeline_wait_s = 0.0  # leaders blocked on the depth semaphore
         self.width_counts: Dict[int, int] = {}  # batch width -> dispatch count
+        # the state clock (module docstring): three counts, the stamp of
+        # their last change, and the seconds spent in each state
+        self._queued = self._launching = self._inflight = 0
+        self._t_state = _time.perf_counter()
+        self.fed_s = self.launching_s = self.handoff_s = self.empty_s = 0.0
+        self._reported = (0.0, 0.0, 0.0, 0.0)  # the four sums as /metrics last got them
 
     # ------------------------------------------------------------ knobs
     def _max_width(self) -> int:
@@ -196,6 +228,33 @@ class DispatchQueue:
             f = cnf.DISPATCH_SPLIT_FLOOR
         return max(int(f), 1)
 
+    def _move(self, queued: int = 0, launching: int = 0, inflight: int = 0) -> float:
+        """The state clock's one transition (caller holds self._lock): close
+        the running stretch into the sum of the state it was spent in, then
+        change the counts. Returns the stamp."""
+        now = _time.perf_counter()
+        dt, self._t_state = now - self._t_state, now
+        if self._inflight > 0:
+            self.fed_s += dt
+        elif self._launching > 0:
+            self.launching_s += dt
+        elif self._queued > 0:
+            self.handoff_s += dt
+        else:
+            self.empty_s += dt
+        self._queued += queued
+        self._launching += launching
+        self._inflight += inflight
+        return now
+
+    def _device_seconds_due(self) -> Dict[str, float]:
+        """What the four sums grew by since /metrics last got them (caller
+        holds self._lock; the caller hands it to telemetry outside)."""
+        sums = (self.fed_s, self.launching_s, self.handoff_s, self.empty_s)
+        due = {st: v - r for st, v, r in zip(_STATES, sums, self._reported)}
+        self._reported = sums
+        return due
+
     def _bucket(self, key: Hashable) -> _Bucket:
         with self._lock:
             # the queue counters + bucket map are one guarded unit
@@ -205,6 +264,7 @@ class DispatchQueue:
             if b is None:
                 b = self._buckets[key] = _Bucket(self._depth())
             self.submitted += 1
+            self._move(queued=1)
             return b
 
     def submit(self, key: Hashable, payload: Any, runner: Callable[[Sequence[Any]], Sequence[Any]]) -> Any:
@@ -313,6 +373,7 @@ class DispatchQueue:
             self.batched += len(batch) - 1
             self.pipeline_wait_s += pipeline_wait
             self.width_counts[len(batch)] = self.width_counts.get(len(batch), 0) + 1
+            self._move(queued=-len(batch), launching=1)
         payloads = [r.payload for r in batch]
         runner = batch[0].runner
 
@@ -341,6 +402,7 @@ class DispatchQueue:
             )
         from surrealdb_tpu import compile_log
 
+        res = due = None
         try:
             # detached: the leader thread's own trace must not swallow the
             # kernel spans — they are stamped onto every rider below. An
@@ -378,6 +440,14 @@ class DispatchQueue:
             with self._lock:
                 _locks.assert_held(self._lock, "dispatch.counters")
                 self.launch_s += elapsed
+                # a collect closure came back: the batch is on the device.
+                # Anything else (results, a failure) ends the dispatch here
+                landed = callable(res)
+                self._move(launching=-1, inflight=int(landed))
+                if not landed:
+                    due = self._device_seconds_due()
+            if due is not None:
+                telemetry.inc_each("dispatch_device_seconds", "state", due)
             # charge riders the SAME elapsed launch_s just accumulated
             # (success and failure paths both) — conservation holds exactly
             self._charge_batch(batch, elapsed, "dispatch_s")
@@ -389,15 +459,56 @@ class DispatchQueue:
             self._distribute(batch, res)
             return None
 
+        outputs = getattr(res, "outputs", None)
+
         def collect() -> None:
             t1 = _time.perf_counter()
+            t_ready = None
             try:
-                with tracing.detached(), compile_log.attribution(
-                    batch[0].trace_ctx
-                ), telemetry.span(
-                    "dispatch_collect"
-                ), telemetry.trace_annotation("dispatch_collect"):
-                    results = res()
+                try:
+                    with tracing.detached(), compile_log.attribution(
+                        batch[0].trace_ctx
+                    ), telemetry.span(
+                        "dispatch_collect"
+                    ), telemetry.trace_annotation("dispatch_collect"):
+                        if outputs is not None:
+                            # the ready stamp: wait for the device on the
+                            # arrays themselves, then read them back. This IS
+                            # the collect phase, after the bucket's hand-off.
+                            # The copies to the host start first (a no-op
+                            # where the launch started them), behind the
+                            # kernel: the read then finds the values on the
+                            # host and gives the interpreter lock up no
+                            # second time (0.7 ms a time under eight sessions)
+                            for a in outputs:
+                                a.copy_to_host_async()
+                            for a in outputs:
+                                a.block_until_ready()  # graftlint: disable=GL005
+                            with self._lock:
+                                _locks.assert_held(self._lock, "dispatch.counters")
+                                t_ready = self._move(inflight=-1)
+                        results = res()
+                finally:
+                    # before any triage below: a split-retry's re-execution
+                    # is outside collect_s and keeps its own books
+                    with self._lock:
+                        _locks.assert_held(self._lock, "dispatch.counters")
+                        fetched = t_ready is not None
+                        t2 = self._move(inflight=0 if fetched else -1)
+                        if not fetched:
+                            # no `outputs`, or the wait itself failed: the
+                            # whole collect was the wait
+                            t_ready = t2
+                        elapsed = t2 - t1
+                        self.collect_s += elapsed
+                        self.ready_wait_s += t_ready - t1
+                        self.fetch_s += t2 - t_ready
+                        due = self._device_seconds_due()
+                    telemetry.inc_each("dispatch_device_seconds", "state", due)
+                    telemetry.observe("dispatch_ready_wait", t_ready - t1)
+                    if fetched:
+                        telemetry.observe("dispatch_fetch", t2 - t_ready)
+                    self._charge_batch(batch, elapsed, "dispatch_s")
             except Exception as e:
                 if not _transient(e):
                     self._fail(batch, e, t1)
@@ -408,12 +519,9 @@ class DispatchQueue:
             except BaseException as e:
                 self._fail(batch, e, t1)
                 return
-            finally:
-                elapsed = _time.perf_counter() - t1
-                with self._lock:
-                    _locks.assert_held(self._lock, "dispatch.counters")
-                    self.collect_s += elapsed
-                self._charge_batch(batch, elapsed, "dispatch_s")
+            self._trace_batch(batch, "dispatch_ready_wait", t1, t_ready - t1)
+            if fetched:
+                self._trace_batch(batch, "dispatch_fetch", t_ready, t2 - t_ready)
             self._trace_batch(batch, "dispatch_collect", t1, _time.perf_counter() - t1)
             self._distribute(batch, results)
 
@@ -428,15 +536,18 @@ class DispatchQueue:
         from surrealdb_tpu import compile_log, tracing
 
         payloads = [r.payload for r in sub]
-        t0 = _time.perf_counter()
+        with self._lock:
+            _locks.assert_held(self._lock, "dispatch.counters")
+            t0 = self._move(launching=1)  # the state clock: as a synchronous run
         try:
             with tracing.detached(), compile_log.attribution(sub[0].trace_ctx):
                 res = sub[0].runner(payloads)
                 return res() if callable(res) else res
         finally:
-            self._charge_batch(
-                sub, _time.perf_counter() - t0, "dispatch_retry_s"
-            )
+            with self._lock:
+                _locks.assert_held(self._lock, "dispatch.counters")
+                t1 = self._move(launching=-1)
+            self._charge_batch(sub, t1 - t0, "dispatch_retry_s")
 
     def _split_retry(self, batch: List[_Req], cause: BaseException) -> None:
         """Memory-aware recovery from a transient batch failure: bisect
@@ -551,8 +662,12 @@ class DispatchQueue:
 
     def stats(self) -> Dict[str, float]:
         """Scalar counters only — consumers diff these numerically (slow-
-        query records, the benchmark's `dispatch.width_mean`)."""
+        query records, the benchmark's `dispatch.*` counter readers). The
+        state clock's running stretch is closed up to now first, so the
+        four `*_s` state sums of two snapshots differ by the wall time
+        between them."""
         with self._lock:
+            self._move()
             return {
                 "submitted": self.submitted,
                 "dispatches": self.dispatches,
@@ -563,6 +678,12 @@ class DispatchQueue:
                 "launch_s": round(self.launch_s, 4),
                 "collect_s": round(self.collect_s, 4),
                 "pipeline_wait_s": round(self.pipeline_wait_s, 4),
+                "ready_wait_s": self.ready_wait_s,
+                "fetch_s": self.fetch_s,
+                "fed_s": self.fed_s,
+                "launching_s": self.launching_s,
+                "handoff_s": self.handoff_s,
+                "empty_s": self.empty_s,
             }
 
     def width_distribution(self) -> Dict[int, int]:

@@ -316,13 +316,15 @@ def _collect_counts(out, riders: int, lanes: int):
     riders' counts off the device. The `graph_count_lanes` counter and the
     `lanes` label the dispatcher puts on every rider's `dispatch_launch`
     span come from this one argument (the pattern of _served): riders over
-    lanes is the fill."""
+    lanes is the fill. `outputs` hands the dispatcher the array to wait
+    for, so the collect's time splits into the device's and the read-back."""
     telemetry.inc("graph_count_lanes", lanes=lanes)
 
     def collect():
         return np.asarray(out)[:riders].tolist()
 
     collect.launch_labels = {"lanes": lanes}
+    collect.outputs = (out,)
     return collect
 
 

@@ -546,6 +546,7 @@ class IvfState:
                 rr[lo:hi] = np.asarray(r)[: hi - lo]
             return dd, rr
 
+        collect.outputs = tuple(a for _, _, d, r in pending for a in (d, r))
         self._warm_tiles(qs.shape[1], cents, list_rows, list_mask, matrix,
                          metric, probe_metric, k, nprobe, tile, owner)
         return collect

@@ -581,12 +581,14 @@ def _subset_shape_key(tile: int, matrix, size: int, metric: str, k: int):
 
 def _zipped(collect):
     """A launch's collect() as the dispatch queue wants it: one
-    (dists, slots) pair a rider."""
+    (dists, slots) pair a rider, and the device arrays it will read
+    (`outputs`, dbs/dispatch.py) carried along."""
 
     def finish():
         dd, rr = collect()
         return list(zip(dd, rr))
 
+    finish.outputs = getattr(collect, "outputs", None)
     return finish
 
 
@@ -635,6 +637,7 @@ def _exact_device_launch(qs: np.ndarray, matrix, mask, metric: str, k: int, owne
             rr[lo:hi] = np.asarray(r)[: hi - lo]
         return dd, rr
 
+    collect.outputs = tuple(a for _, _, d, r in pending for a in (d, r))
     _warm_exact_tiles(qs.shape[1], matrix, mj, metric, k, tile, owner, subset=subset)
     return collect
 

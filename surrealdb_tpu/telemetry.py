@@ -19,8 +19,9 @@ environment has no OTLP collector, so the equivalent surface is:
   disabled), drained via `snapshot()` or INFO-style inspection;
 - `jax.profiler` hooks: `start_trace()/stop_trace()` capture a device
   trace directory, and `trace_annotation()`
-  labels dispatch launch/collect phases inside it. A trace that was
-  asked for and cannot start raises.
+  labels dispatch launch/collect phases inside it (and under `--profile`;
+  in nobody else's trace). A trace that was asked for and cannot start
+  raises.
 """
 
 from __future__ import annotations
@@ -81,6 +82,15 @@ def inc(name: str, by: float = 1.0, **labels) -> None:
     key = (name, _key(labels))
     with _lock:
         _counters[key] = _counters.get(key, 0.0) + by
+
+
+def inc_each(name: str, label: str, by: Dict[str, float]) -> None:
+    """One counter family's series moved together, under one acquisition of
+    the registry lock: `by` maps a value of `label` to its increment."""
+    with _lock:
+        for value, d in by.items():
+            key = (name, ((label, str(value)),))
+            _counters[key] = _counters.get(key, 0.0) + d
 
 
 def get_counter(name: str, **labels) -> float:
@@ -317,8 +327,12 @@ def stop_trace() -> Optional[str]:
 
 
 def trace_annotation(name: str):
-    """Label a dispatch phase inside the device trace. Free when neither
-    --profile nor a trace capture is active."""
+    """Label a dispatch phase inside the device trace. Live only under
+    `surreal start --profile` (enable()) or between this module's
+    start_trace() and stop_trace(); otherwise a `nullcontext`. A trace
+    someone else started through `jax.profiler` (the benchmark's slice)
+    therefore holds no such label: what divides a dispatch there are the
+    spans and the stats() sums of dbs/dispatch.py."""
     if not _enabled and _trace_dir is None:
         return nullcontext()
     import jax
