@@ -21,6 +21,7 @@ the exact KV walk (sql/path.py graph_hop).
 
 from __future__ import annotations
 
+import math
 import threading
 import time as _time
 from surrealdb_tpu.utils import locks as _locks
@@ -183,14 +184,15 @@ class PointerCsr:
     def device_csc(self):
         """Destination-sorted (cptr, csrc) device arrays for scatter-free
         dense SpMV hops (batched count chains): y[v] = Σ x[src] over edges
-        into v becomes cumsum over dst-sorted x[csrc] + a boundary gather —
-        gathers and a prefix-scan only, no scatter (TPU scatter-add is
-        serial-slow; cumsum + gather ride the VPU). Padding edges carry the
-        sentinel src `cap` and lie past every real bin. These are
-        the record-level operands, over the id space persons and edge
-        records share: what chain_count_batch sweeps for a chain that is
-        not one of composable `->edge->node` pairs (GraphMirrors._csc_pair
-        builds the same arrays over one table's compact ids for those)."""
+        into v becomes a prefix sum over dst-sorted x[csrc] + a boundary
+        gather — gathers and a prefix-scan only, no scatter (TPU scatter-add
+        is serial-slow; _kernels' prefix_sum_rows + gather ride the VPU).
+        Padding edges carry the sentinel src `cap` and lie past every real
+        bin. These are the record-level operands, over the id space persons
+        and edge records share: what chain_count_batch sweeps for a chain
+        that is not one of composable `->edge->node` pairs
+        (GraphMirrors._csc_pair builds the same arrays over one table's
+        compact ids for those)."""
         import jax.numpy as jnp
 
         self.ensure_arrays()
@@ -466,17 +468,51 @@ def _kernels():
         deg = ptr[fr_c + 1] - ptr[fr_c]
         return jnp.where((frj < n) & (cwj > 0), deg * cwj, 0).sum(axis=-1)
 
+    SCAN_ROW = 128  # the longest row of prefix_sum_rows: an int32 tile's minor axis
+
+    def prefix_sum_rows(vals):
+        """Inclusive int32 prefix sum along axis 1 of `vals` [lanes, slots],
+        wrapping as int32 addition does (any re-association gives the same
+        bits). `jnp.cumsum` lowers on a TPU to a reduce-window within each
+        row of 128 slots, which XLA tiles one element a step whenever it
+        keeps the rows in its fast memory (2.1 ms a hop at SNB SF3's 8 x
+        1,179,648; PERF.md section 6, PR 34 and PR 37). So the rows are
+        scanned here as XLA lays them out for that step anyway, the in-row
+        positions on the major axis: the row totals (one reduction over the
+        slabs of [lanes, rows]), their exclusive prefix (a [lanes, rows]
+        operand, where a reduce-window is cheap), and one pass over the
+        slabs with a running sum that starts from it. A row is 128 slots,
+        an int32 tile's minor axis, and shorter only where that would be
+        more slabs than rows (under 16,384 slots: the power of two under the
+        square root, so a small operand takes few steps); slots that are no
+        multiple of it are padded with zeros here and cut off again."""
+        lanes, slots = vals.shape
+        row = min(SCAN_ROW, 1 << (math.isqrt(slots).bit_length() - 1))
+        rows = -(-slots // row)
+        vals = jnp.pad(vals, ((0, 0), (0, rows * row - slots)))
+        slabs = vals.reshape(lanes, rows, row).transpose(2, 0, 1)
+        totals = slabs.sum(axis=0)
+        before = jnp.cumsum(totals, axis=1) - totals
+
+        def step(run, slab):
+            run = run + slab
+            return run, run
+
+        _, out = jax.lax.scan(step, before, slabs)
+        return out.transpose(1, 2, 0).reshape(lanes, rows * row)[:, :slots]
+
     @partial(jax.jit, static_argnames=("n_cap",))
     def chain_count_batch(csc_hops, last_hop, frontiers, weights, n_cap, end_weights=None):
         """Batched count-only chains for B concurrent queries over the SAME
         adjacency (the cross-query coalescing seam, dbs/dispatch.py).
         Scatter-free: TPU scatter-add is serial-slow and vmapped
         nonzero/compaction is worse, so every hop is cast as dense SpMV in
-        cumsum form —
+        prefix-sum form —
         - seeds densify with one tiny scatter (B x frontier-width updates)
         - each non-final hop: gather counts at dst-sorted edge sources,
           prefix-scan, difference at bin boundaries (y[v] = S[end_v] -
-          S[start_v]) — gathers + one cumsum, VPU-friendly at any width
+          S[start_v]) — gathers + one prefix_sum_rows, VPU-friendly at any
+          width
         - the final hop of a count never materializes neighbors: it is a
           degree dot-product, or, where the chain's final node part has a
           predicate, a dot-product with the rider's own `end_weights` (one
@@ -524,7 +560,7 @@ def _kernels():
             y = 0
             for cptr, csrc in mirrors:
                 vals = x[:, csrc]  # sentinel src reads the zeroed column
-                s = jnp.concatenate([zcol, jnp.cumsum(vals, axis=1)], axis=1)
+                s = jnp.concatenate([zcol, prefix_sum_rows(vals)], axis=1)
                 y = y + (s[:, cptr[1:]] - s[:, cptr[:-1]])
             x = jnp.concatenate([y, zcol], axis=1)
         xr = x[:, :n_cap]
@@ -577,6 +613,7 @@ def _kernels():
 
     _JITTED["chain"] = chain_kernel
     _JITTED["chain_count_batch"] = chain_count_batch
+    _JITTED["prefix_sum_rows"] = jax.jit(prefix_sum_rows)
     _JITTED["chain_count_batch_dense"] = chain_count_batch_dense
     return chain_kernel
 
@@ -1029,7 +1066,7 @@ class GraphMirrors:
                                 )
                 continue
             # dense doesn't fit (oversized tables / fat multiplicities):
-            # warm the CSC cumsum form the serving path will use instead,
+            # warm the CSC prefix-sum form the serving path will use instead,
             # over the operand it will sweep: the pair's composed operator,
             # or the two record-level mirrors where that is refused
             try:
@@ -1455,7 +1492,7 @@ class GraphMirrors:
         return dispatch.submit(key, (fr, cw, endw) if weighted else (fr, cw), runner)
 
     def _csc_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None, end=None):
-        """Count chain in the scatter-free CSC cumsum form
+        """Count chain in the scatter-free CSC prefix-sum form
         (chain_count_batch): what no dense operator can hold. A chain of
         composable `->edge->node` pairs whose tables line up sweeps their
         composed node->node operators (_csc_pair), one hop a pair in the
@@ -1547,7 +1584,7 @@ class GraphMirrors:
         one place a device count is produced: with a dispatcher a count
         coalesces with its concurrent peers (dbs/dispatch.py
         leader-follower) as composed dense matmuls on the MXU, or in the
-        CSC cumsum form where the chain doesn't fit a dense operator.
+        CSC prefix-sum form where the chain doesn't fit a dense operator.
         Otherwise the fused chain kernel: one upload, H weighted gathers
         with on-device scatter-add dedup between hops, one download at the
         end. Every static dimension (frontier size, max degree, node

@@ -58,8 +58,8 @@ def warm_tile_sizes(cap: int = None):
 # An int32 tile is 8 sublanes and a batched count's frontier is
 # [lanes, n + 1] int32 with the lanes on the second-minor axis: below 8
 # nothing is saved (the sparse kernel alone at SNB SF3's composed shapes on
-# a v5e: 13.0 ms at 8 lanes, 15.2 at 4, 13.7 at 2, 32.8 at 1; PERF.md
-# section 6, PR 30), and the shape set stays at four.
+# a v5e: 6.51 ms at 8 lanes, 6.71 at 4, 6.68 at 2, 17.98 at 1, and 7.70 at
+# 16; PERF.md section 6, PR 37), and the shape set stays at four.
 COUNT_LANES_MIN = 8
 
 
