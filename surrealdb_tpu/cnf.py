@@ -102,10 +102,6 @@ REGEX_CACHE_SIZE = _env_int("SURREAL_REGEX_CACHE_SIZE", 1_000)
 TPU_BATCH_MIN_TILE = _env_int("SURREAL_TPU_BATCH_MIN_TILE", 128)
 TPU_VECTOR_DTYPE = os.environ.get("SURREAL_TPU_VECTOR_DTYPE", "bfloat16")
 TPU_KNN_ONDEVICE_THRESHOLD = _env_int("SURREAL_TPU_KNN_ONDEVICE_THRESHOLD", 4096)
-# BM25 scoring is memory-light (candidates x terms); host numpy scores a
-# 100k-candidate set in ~2ms, so a device dispatch only pays off when the
-# candidate set is huge.
-TPU_FT_ONDEVICE_THRESHOLD = _env_int("SURREAL_TPU_FT_ONDEVICE_THRESHOLD", 262_144)
 TPU_GRAPH_ONDEVICE_THRESHOLD = _env_int("SURREAL_TPU_GRAPH_ONDEVICE_THRESHOLD", 2048)
 # static-shape stabilizer for the graph chain kernels: the frontier pad
 # floor, so concurrent chain queries share ONE compiled executable (XLA

@@ -1,11 +1,11 @@
-"""Persistent inverted index + device-batched BM25 search.
+"""Persistent inverted index + its exact KV search.
 
 Role of the reference's FtIndex (reference: core/src/idx/ft/ — terms.rs
 dictionary, postings.rs, doclength.rs, termdocs.rs, offsets.rs,
 docids.rs). TPU-first redesign: the KV layout is flat ordered keys rather
-than B-trees (the host store is already ordered), and scoring happens as one
-batched BM25 kernel over the whole candidate set (ops/bm25.py) instead of a
-per-document loop.
+than B-trees (the host store is already ordered), and the KV search here
+(a transaction's own writes) scores the whole candidate set at once in
+NumPy; committed state is searched through idx/ft_mirror.py.
 
 Keyspace (under the index's state prefix `+{ix}!m`):
     s                      stats {dc, tl, nt, nd}
@@ -508,8 +508,8 @@ class FtIndex:
 
     # ------------------------------------------------------------ search
     def search(self, ctx, query: str) -> "FtResults":
-        """AND-match all analyzed query terms, score the candidate set with
-        the batched BM25 kernel."""
+        """AND-match all analyzed query terms over the KV postings, score
+        the candidate set in float64 NumPy."""
         az = self.analyzer(ctx)
         terms = az.terms(query)
         txn = ctx.txn()
@@ -549,27 +549,9 @@ class FtIndex:
 
         k1 = float(self.ix["index"].get("k1", 1.2))
         b = float(self.ix["index"].get("b", 0.75))
-        from surrealdb_tpu import cnf
+        from surrealdb_tpu.ops.bm25 import bm25_scores_host
 
-        if cnf.TPU_DISABLE or len(dids) < cnf.TPU_FT_ONDEVICE_THRESHOLD:
-            # tiny candidate sets score on host — a device dispatch (and
-            # worse, a first compile) costs far more
-            from surrealdb_tpu.ops.bm25 import bm25_scores_host
-
-            scores = bm25_scores_host(tf_mat, df, lens, st["dc"], st["tl"], k1, b)
-        else:
-            from surrealdb_tpu import compile_log
-            from surrealdb_tpu.ops.bm25 import bm25_scores
-
-            with compile_log.tracked(
-                "bm25", (int(tf_mat.shape[0]), int(tf_mat.shape[1]))
-            ):
-                scores = np.asarray(
-                    bm25_scores(
-                        tf_mat, df, lens,
-                        np.float32(st["dc"]), np.float32(st["tl"]), k1, b,
-                    )
-                )
+        scores = bm25_scores_host(tf_mat, df, lens, st["dc"], st["tl"], k1, b)
         resolve = self._rid_resolver(ctx)
         by_rid: Dict[Tuple[str, str], Tuple[Thing, float]] = {}
         for did, s in zip(dids, scores):

@@ -487,6 +487,8 @@ def _plan_matches(ctx, tb: str, indexes: List[dict], m: MatchesOp, stm):
             continue
         plan = MatchesPlan(tb, ix, m, m.r.compute(ctx))
         plan.provides_order = _matches_score_order(stm, m)
+        if plan.provides_order:
+            plan.top_k = _static_limit(ctx, stm)
         return plan
     return None
 

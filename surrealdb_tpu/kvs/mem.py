@@ -187,11 +187,12 @@ class MemTransaction(BackendTransaction):
     # -- range ops ---------------------------------------------------------
     _RANGE_CHUNK = 4096
 
-    def _merged_range(self, beg: bytes, end: bytes):
+    def _merged_range(self, beg: bytes, end: bytes, want: int = -1):
         """Iterate live (key, value) pairs in [beg, end) merging local writes.
 
-        Committed keys are pulled from the SortedList in fixed chunks rather
-        than materialized whole: `batch()` walks multi-million-key ranges
+        Committed keys are pulled from the SortedList in chunks (of `want`
+        keys where the caller wants few: a probe with a limit of 1 pays for
+        1) rather than materialized whole: `batch()` walks multi-million-key ranges
         (mirror builds, exports) by repeated scans with an advancing cursor,
         and materializing the full remaining range per scan made that
         quadratic — ~10^9 list appends over a 12M-posting range. Chunked
@@ -205,17 +206,29 @@ class MemTransaction(BackendTransaction):
         n_local = len(local)
         cursor = beg
         exhausted = False
+        step = self._RANGE_CHUNK if want < 0 else max(1, min(want, self._RANGE_CHUNK))
         while not exhausted:
             with store.lock:
                 committed = list(
-                    islice(
-                        store.sorted_keys.irange(cursor, end, inclusive=(True, False)),
-                        self._RANGE_CHUNK,
-                    )
+                    islice(store.sorted_keys.irange(cursor, end, inclusive=(True, False)), step)
                 )
-            if len(committed) < self._RANGE_CHUNK:
+                # the chunk's values at this snapshot, under the one
+                # acquisition that listed its keys (a mirror build walks
+                # millions of keys: a lock and a call a key was most of it);
+                # what a snapshot sees never changes, so reading here or at
+                # the yield is the same
+                data, snap = store.data, self.snapshot
+                visible = []
+                for k in committed:
+                    v = None
+                    for ver, val in reversed(data.get(k) or ()):
+                        if ver <= snap:
+                            v = val
+                            break
+                    visible.append(v)
+            if len(committed) < step:
                 exhausted = True
-            for k in committed:
+            for k, seen in zip(committed, visible):
                 while li < n_local and local[li] < k:
                     lk = local[li]
                     li += 1
@@ -226,7 +239,7 @@ class MemTransaction(BackendTransaction):
                     li += 1
                     v = self.writes[k]
                 else:
-                    v = store._read_at(k, self.snapshot)
+                    v = seen
                 if v is not None:
                     yield k, v
             if committed:
@@ -241,7 +254,7 @@ class MemTransaction(BackendTransaction):
     def keys(self, beg: bytes, end: bytes, limit: int = -1) -> List[bytes]:
         self._check_open()
         out = []
-        for k, _ in self._merged_range(beg, end):
+        for k, _ in self._merged_range(beg, end, limit):
             out.append(k)
             if limit >= 0 and len(out) >= limit:
                 break
@@ -250,7 +263,7 @@ class MemTransaction(BackendTransaction):
     def scan(self, beg: bytes, end: bytes, limit: int = -1) -> List[KV]:
         self._check_open()
         out = []
-        for kv in self._merged_range(beg, end):
+        for kv in self._merged_range(beg, end, limit):
             out.append(kv)
             if limit >= 0 and len(out) >= limit:
                 break

@@ -64,6 +64,7 @@ HIERARCHY: Dict[str, int] = {
     "idx.store": 40,           # index-store registry (RLock, re-entrant reads)
     "idx.knn.state": 42,       # vector-mirror state (RLock)
     "idx.ft.state": 44,        # FT mirror state (RLock)
+    "idx.ft.upload": 45,       # one FT generation's upload, once (never under idx.ft.state)
     "idx.column.registry": 46, # column-mirror registry (RLock)
     "idx.graph.registry": 48,  # graph-mirror registry (RLock)
     "idx.graph.mirror": 50,    # one graph mirror's adjacency state

@@ -1,6 +1,7 @@
 """FT mirror tests: CSR postings replica, incremental maintenance, overlay
-semantics, device-path scoring (idx/ft_mirror.py; reference analog:
-core/src/idx/ft/ + trees/store/cache.rs generation swap)."""
+semantics (idx/ft_mirror.py; reference analog: core/src/idx/ft/ +
+trees/store/cache.rs generation swap). The device route has
+tests/test_ft_device.py."""
 
 import numpy as np
 import pytest
@@ -98,31 +99,6 @@ def test_uncommitted_writes_use_exact_overlay(ds):
     assert m.count() == 2
     r = ds.execute("SELECT VALUE id FROM doc WHERE body @@ 'yankee';")
     assert ok(r[0]) == []
-
-
-def test_mirror_device_path_through_query(ds, monkeypatch):
-    """Cross TPU_FT_ONDEVICE_THRESHOLD through a real SQL query (VERDICT r2
-    weak item 9: FT device path was never engine-tested)."""
-    from surrealdb_tpu import cnf
-
-    monkeypatch.setattr(cnf, "TPU_FT_ONDEVICE_THRESHOLD", 4)
-    setup_ix(ds)
-    for i in range(12):
-        ds.execute(f"CREATE doc:{i} SET body = 'shared word{i}';")
-    r = ds.execute(
-        "SELECT id, search::score(1) AS s FROM doc WHERE body @1@ 'shared' ORDER BY id;"
-    )
-    rows = ok(r[0])
-    assert len(rows) == 12
-    # same candidates score identically on the host path
-    monkeypatch.setattr(cnf, "TPU_FT_ONDEVICE_THRESHOLD", 10_000)
-    rows_host = ok(
-        ds.execute(
-            "SELECT id, search::score(1) AS s FROM doc WHERE body @1@ 'shared' ORDER BY id;"
-        )[0]
-    )
-    for a, b in zip(rows, rows_host):
-        assert a["s"] == pytest.approx(b["s"], rel=1e-4)
 
 
 def test_highlight_still_works_via_mirror_path(ds):
