@@ -13,6 +13,12 @@ of it per fingerprint and serves hot shapes from memory:
   so `WHERE age > 30` and `WHERE age > 40` — and the `$param` spelling of
   the same shape — share one entry; the active execution's values ride the
   per-query Executor (`executor.slot_values`), never the shared nodes.
+  An inline record id is such a slot too (`ast.SlotThing`) where its id
+  part is one number, string or UUID token after the table's name:
+  `FROM person:42` and `FROM person:43` are one template. Every other
+  spelling of an id (an identifier, `likes:8abc2`, `t:8e2`, a range, an
+  array, object or expression id, an id whose value the statement repeats)
+  stays a fixed token, served for its exact text only: `_parameterize`.
 - **Dispatch skeleton.** Which `dbs/stmt_exec.select_compute` front
   resolved the statement (ml / count / pipeline / plan), so warm serves
   skip the fronts that declined cold.
@@ -85,11 +91,14 @@ _EVLOG_CAP = 64  # recent evictions kept for the advisor's thrash view
 
 class Served(NamedTuple):
     """One warm AST serve: the shared template Query plus this
-    execution's slot bindings (None when the variant is unparameterized)."""
+    execution's slot bindings (None when the variant is unparameterized),
+    and how it was found: `digest` (this exact text before, nothing
+    lexed) or `lexed` (a new text bound into a variant's signature)."""
 
     query: Any
     slot_values: Optional[Tuple[Any, ...]]
     fp: str
+    kind: str
 
 
 class _Route:
@@ -111,19 +120,24 @@ class _Variant:
     token signature that decides whether a new text can bind into it."""
 
     __slots__ = (
-        "query", "stmt", "kinds", "fixed", "slot_idx", "digests",
-        "routes", "parameterized", "trust", "text",
+        "query", "stmt", "kinds", "fixed", "slot_idx", "id_types", "defaults",
+        "digests", "routes", "parameterized", "trust", "text",
     )
 
-    def __init__(self, query, kinds, fixed, slot_idx, parameterized, text):
+    def __init__(self, query, kinds, fixed, slot_idx, id_types, defaults, text):
         self.query = query
         self.stmt = query.statements[0]
         self.kinds = kinds  # signature token kinds, source order
         self.fixed = fixed  # ((token_idx, value), ...) must match verbatim
         self.slot_idx = slot_idx  # token indices bound to SlotLiteral slots
+        # ((token_idx, type), ...) of the slots that are record ids: the
+        # parser builds `tb:5` (int) and `tb:5f` (the string "5f") from one
+        # token kind, so such a slot binds a value of the template's type only
+        self.id_types = id_types
+        self.defaults = defaults  # the installing text's own slot values
         self.digests: "OrderedDict[str, Optional[Tuple]]" = OrderedDict()
         self.routes: "OrderedDict[Tuple, _Route]" = OrderedDict()
-        self.parameterized = parameterized
+        self.parameterized = bool(slot_idx)
         self.trust = 0  # verified lex-serves; >= _VERIFY_TRUST skips verify
         self.text = text  # first-seen spelling (views/debug only)
 
@@ -132,7 +146,7 @@ class _Entry:
     """One fingerprint's cached variants and serve counters."""
 
     __slots__ = ("fp", "variants", "hits", "route_hits", "misses",
-                 "invalidations", "churn", "installed_ts")
+                 "invalidations", "churn", "bound", "refused", "installed_ts")
 
     def __init__(self, fp: str):
         self.fp = fp
@@ -142,6 +156,8 @@ class _Entry:
         self.misses = 0
         self.invalidations = 0
         self.churn = 0  # variant capacity evictions (thrash guard)
+        self.bound = False  # some spelling of the shape has had a slot
+        self.refused = 0  # observes turned away unexamined by the guard
         self.installed_ts = time.time()
 
 
@@ -214,8 +230,14 @@ def _collect_literal_sites(root) -> List[Tuple[Any, Any, Any]]:
     """Every exact-type ast.Literal reachable from `root`, as
     (container, key, node) so the node can be swapped for a SlotLiteral.
     Literals inside tuples/sets are unreplaceable and not collected —
-    their tokens stay fixed in the signature, which is always sound."""
+    their tokens stay fixed in the signature, which is always sound. So
+    do those of a projection that is named by its own text: an unaliased
+    `SELECT 5, person:1.name` keys its output by repr(expr)
+    (dbs/iterator.field_display_name), which on a shared template would
+    print the first-seen literal; a bare function call is named by the
+    function alone and SELECT VALUE names nothing."""
     from surrealdb_tpu.sql import ast as A
+    from surrealdb_tpu.sql import statements as S
 
     sites: List[Tuple[Any, Any, Any]] = []
     seen: set = set()
@@ -243,6 +265,14 @@ def _collect_literal_sites(root) -> List[Tuple[Any, Any, Any]]:
             for v in o:
                 walk(v)
         elif _is_sql_node(o):
+            if type(o) is S.Output or (
+                type(o) is S.SelectStatement and not o.value_mode
+            ):
+                seen.update(
+                    id(f) for f in o.fields or ()
+                    if f.alias is None and f.expr is not None
+                    and not isinstance(f.expr, A.FunctionCall)
+                )
             for name in _slot_names(o):
                 try:
                     v = getattr(o, name)
@@ -255,20 +285,30 @@ def _collect_literal_sites(root) -> List[Tuple[Any, Any, Any]]:
     return sites
 
 
+_UNSET = object()
+
+
 def _ast_equal(tmpl, fresh, slot_values: Tuple[Any, ...]) -> bool:
     """Structural equality of the bound template against a fresh parse —
     the serve-time proof that slot binding reproduces exactly what the
-    parser would have built for the new text."""
+    parser would have built for the new text. A `__slots__` name the
+    parser set on neither side (PGraph.expr_fields) is equal; one set on
+    one side only is not."""
     from surrealdb_tpu.sql import ast as A
+    from surrealdb_tpu.sql.value import Thing
 
     def eq(a, b) -> bool:
         if isinstance(a, A.SlotLiteral):
-            bound = (
-                slot_values[a.slot]
-                if a.slot < len(slot_values)
-                else a.value
-            )
-            return type(b) is A.Literal and _fixed_eq(bound, b.value)
+            if type(b) is not A.Literal or a.slot >= len(slot_values):
+                return False
+            bound = slot_values[a.slot]
+            if isinstance(a, A.SlotThing):
+                return (
+                    type(b.value) is Thing
+                    and b.value.tb == a.value.tb
+                    and _fixed_eq(bound, b.value.id)
+                )
+            return _fixed_eq(bound, b.value)
         if type(a) is not type(b):
             return False
         if isinstance(a, list) or isinstance(a, tuple):
@@ -279,11 +319,11 @@ def _ast_equal(tmpl, fresh, slot_values: Tuple[Any, ...]) -> bool:
             return all(eq(v, b[k]) for k, v in a.items())
         if _is_sql_node(a):
             for name in _slot_names(a):
-                try:
-                    va, vb = getattr(a, name), getattr(b, name)
-                except AttributeError:
-                    return False
-                if not eq(va, vb):
+                va, vb = getattr(a, name, _UNSET), getattr(b, name, _UNSET)
+                if va is _UNSET or vb is _UNSET:
+                    if va is not vb:
+                        return False
+                elif not eq(va, vb):
                     return False
             return True
         return _fixed_eq(a, b)
@@ -291,15 +331,46 @@ def _ast_equal(tmpl, fresh, slot_values: Tuple[Any, ...]) -> bool:
     return eq(tmpl, fresh)
 
 
+def _carries(node_value: Any, v: Any, tb: Any) -> bool:
+    """Does a bindable token's value `v`, whose signature neighbour to the
+    left is the identifier `tb` (None when it is no identifier), carry
+    this Literal's value? A plain literal: the token's value is the
+    node's. A record id `tb:v`: the node holds Thing(tb, v), and only for
+    an id the parser takes from the token as it is (int, string, UUID)."""
+    from surrealdb_tpu.sql.value import Thing, Uuid
+
+    if type(node_value) is Thing:
+        return (
+            tb == node_value.tb
+            and type(v) in (int, str, Uuid)
+            and _fixed_eq(node_value.id, v)
+        )
+    return _fixed_eq(node_value, v)
+
+
 def _parameterize(text: str, query) -> Optional[_Variant]:
     """Build a variant for `query` (parsed from `text`): lex the
     signature tokens, match bindable token values 1:1 against replaceable
-    Literal nodes, swap matches for SlotLiterals. Any ambiguity — a
-    duplicated value among tokens or among nodes, a token folded into a
-    non-Literal (record ids, negative-number folding) — demotes that
-    token to a fixed position; a variant with no slots still serves any
-    literal-identical respelling (case/whitespace) plus its routes."""
+    Literal nodes, swap matches for SlotLiterals.
+
+    A record id binds too (`ast.SlotThing`, the table stays a fixed
+    token) where its id part is ONE bindable token that directly follows
+    the table's identifier: `person:42`, `person:'a b'`, `person:u'..'`.
+    Every other spelling of an id stays fixed, each its own exact text:
+    an identifier (`person:alice`, backticks, angle brackets: not a
+    bindable kind, and the fingerprint keeps it), a digit-led id that
+    lexes as several tokens (`likes:8abc2`, and any number with an
+    identifier glued to it: signature kind GLUED), a number the parser
+    reads back as text (`t:8e2`, `t:1h`), ranges, array and object ids, an
+    expression id (ThingLit, no Literal).
+
+    Any ambiguity — a duplicated value among tokens (`person:5 ... WHERE
+    n = 5`) or among nodes, a token folded into a non-Literal (negative
+    ids and numbers) — demotes that token to a fixed position; a variant
+    with no slots still serves any literal-identical respelling
+    (case/whitespace) plus its routes."""
     from surrealdb_tpu.sql import ast as A
+    from surrealdb_tpu.sql.value import Thing
     from surrealdb_tpu.syn import parser as _parser
 
     lexed = _parser.lex_literal_slots(text)
@@ -316,9 +387,10 @@ def _parameterize(text: str, query) -> Optional[_Variant]:
     for i in bindable:
         v = values[i]
         dup = any(j != i and _fixed_eq(values[j], v) for j in bindable)
+        tb = values[i - 1] if i and kinds[i - 1] == "IDENT" else None
         matches = [
             s for s in sites
-            if id(s[2]) not in taken and _fixed_eq(s[2].value, v)
+            if id(s[2]) not in taken and _carries(s[2].value, v, tb)
         ]
         if dup or len(matches) != 1:
             fixed.append((i, v))
@@ -329,8 +401,13 @@ def _parameterize(text: str, query) -> Optional[_Variant]:
         if k not in _parser.BINDABLE_TOKEN_KINDS:
             fixed.append((i, values[i]))
     fixed.sort()
-    for slot, (_, (container, key, node)) in enumerate(slot_sites):
-        sl = A.SlotLiteral(slot, node.value)
+    id_types: List[Tuple[int, type]] = []
+    for slot, (i, (container, key, node)) in enumerate(slot_sites):
+        if type(node.value) is Thing:
+            sl = A.SlotThing(slot, node.value)
+            id_types.append((i, type(values[i])))
+        else:
+            sl = A.SlotLiteral(slot, node.value)
         if isinstance(container, list):
             container[key] = sl
         elif isinstance(container, dict):
@@ -342,7 +419,8 @@ def _parameterize(text: str, query) -> Optional[_Variant]:
         kinds,
         tuple(fixed),
         tuple(i for i, _ in slot_sites),
-        bool(slot_sites),
+        tuple(id_types),
+        tuple(values[i] for i, _ in slot_sites),
         _stmt_key(text)[:200],
     )
 
@@ -430,7 +508,7 @@ class PlanCache:
                 v.digests.move_to_end(dg)
                 entry.hits += 1
                 self._hits["ast"] += 1
-                return Served(v.query, v.digests[dg], entry.fp)
+                return Served(v.query, v.digests[dg], entry.fp, "digest")
         return None
 
     def _serve_lexed(
@@ -452,8 +530,10 @@ class PlanCache:
         with self._lock:
             match: Optional[_Variant] = None
             for v in entry.variants:
-                if v.kinds == kinds and all(
-                    _fixed_eq(values[i], fv) for i, fv in v.fixed
+                if (
+                    v.kinds == kinds
+                    and all(_fixed_eq(values[i], fv) for i, fv in v.fixed)
+                    and all(type(values[i]) is t for i, t in v.id_types)
                 ):
                     match = v
                     break
@@ -477,7 +557,7 @@ class PlanCache:
             if len(match.digests) >= _DIGEST_CAP:
                 match.digests.popitem(last=False)
             match.digests[dg] = slots or None
-        return Served(match.query, slots or None, fp)
+        return Served(match.query, slots or None, fp, "lexed")
 
     def _verify(self, variant: _Variant, key: str, slots: Tuple) -> bool:
         """Parse `key` fresh and prove the bound template reproduces it.
@@ -530,6 +610,16 @@ class PlanCache:
                 self._warm.popitem(last=False)
             if n < self._min_hits:
                 return
+            entry = self._entries.get(fp)
+            if entry is not None and entry.churn > 8 and not entry.bound:
+                # a high-cardinality shape no spelling of which has ever
+                # bound (`likes:8abc2`, folded literals): one more
+                # exact-text variant would only thrash the slots, so
+                # neither lex nor walk for it. Every `_REVALIDATE_EVERY`th
+                # is looked at all the same: no verdict is pinned forever
+                entry.refused += 1
+                if entry.refused % _REVALIDATE_EVERY:
+                    return
         variant = _parameterize(text, query)
         if variant is None:
             return
@@ -550,9 +640,8 @@ class PlanCache:
                     # raced install of the same spelling: keep the winner
                     return
             if entry.churn > 8 and not variant.parameterized:
-                # a high-cardinality unparameterizable shape (distinct
-                # record ids, folded literals): installing yet another
-                # exact-text variant would just keep thrashing the slots
+                # the same refusal where the shape has a spelling that
+                # binds beside this one that does not
                 return
             while len(entry.variants) >= _VARIANT_CAP:
                 old = entry.variants.pop(0)
@@ -560,7 +649,8 @@ class PlanCache:
                 self._invalidations["capacity"] += 1
                 entry.churn += 1
             entry.variants.append(variant)
-            variant.digests[dg] = tuple(self._defaults_of(variant)) or None
+            entry.bound = entry.bound or variant.parameterized
+            variant.digests[dg] = variant.defaults or None
             self._by_stmt[id(variant.stmt)] = (fp, variant)
             while len(self._entries) > self._cap:
                 old_fp, old_e = self._entries.popitem(last=False)
@@ -573,37 +663,6 @@ class PlanCache:
                 evicted.append((old_fp, "capacity"))
         for efp, cause in evicted:
             self._emit_evict(efp, cause)
-
-    @staticmethod
-    def _defaults_of(variant: _Variant) -> List[Any]:
-        """The installing text's own slot values (the SlotLiteral
-        defaults), so its digest serves without re-deriving bindings."""
-        from surrealdb_tpu.sql import ast as A
-
-        out: Dict[int, Any] = {}
-
-        def walk(o, seen):
-            if id(o) in seen:
-                return
-            seen.add(id(o))
-            if isinstance(o, A.SlotLiteral):
-                out[o.slot] = o.value
-                return
-            if isinstance(o, (list, tuple, set, frozenset)):
-                for v in o:
-                    walk(v, seen)
-            elif isinstance(o, dict):
-                for v in o.values():
-                    walk(v, seen)
-            elif _is_sql_node(o):
-                for name in _slot_names(o):
-                    try:
-                        walk(getattr(o, name), seen)
-                    except AttributeError:
-                        pass
-
-        walk(variant.stmt, set())
-        return [out[k] for k in sorted(out)]
 
     def _drop_variant(self, v: _Variant) -> None:
         """Lock held: detach a variant's identity-map entry and routes."""

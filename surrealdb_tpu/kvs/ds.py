@@ -460,7 +460,7 @@ class Datastore:
             served = self.plan_cache.fetch(text)
             if served is not None:
                 tracing.record_span_into(
-                    at, "plan_fetch", {"outcome": "hit"},
+                    at, "plan_fetch", {"outcome": served.kind},
                     t_fetch, _time.perf_counter() - t_fetch,
                 )
                 return self.process(

@@ -94,6 +94,21 @@ class SlotLiteral(Literal):
         return self.value
 
 
+class SlotThing(SlotLiteral):
+    """A record-id literal `tb:id` whose id part is slot `i`: the table is
+    a fixed token of the template's signature, the id is this execution's.
+    `value` keeps the first-seen text's Thing as the unbound default; no
+    reader but `compute` may take it for the statement's record."""
+
+    __slots__ = ()
+
+    def compute(self, ctx):
+        sv = getattr(ctx.executor, "slot_values", None)
+        if sv is not None and self.slot < len(sv):
+            return Thing(self.value.tb, sv[self.slot])
+        return self.value
+
+
 class ArrayLit(Expr):
     __slots__ = ("items",)
 

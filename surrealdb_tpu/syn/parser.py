@@ -2286,6 +2286,7 @@ def parse_query(text: str) -> S.Query:
 # whose converted value is exactly what an ast.Literal node would hold,
 # i.e. the ones a cached template can re-bind per execution; the rest
 # (idents, param names, regexes) must match the template verbatim.
+# `lex_literal_slots` adds one signature kind no token has: GLUED.
 SIGNATURE_TOKEN_KINDS = frozenset(
     {"IDENT", "PARAM", "NUMBER", "STRING", "DURATION",
      "DATETIME", "UUID", "BYTES", "REGEX", "SCRIPT"}
@@ -2308,11 +2309,21 @@ def lex_literal_slots(text: str) -> Optional[Tuple[Tuple[str, ...], Tuple[Any, .
         return None
     kinds: List[str] = []
     values: List[Any] = []
-    for t in tokens:
+    for n, t in enumerate(tokens):
         if t.kind == "EOF":
             break
         if t.kind in SIGNATURE_TOKEN_KINDS:
-            kinds.append(t.kind)
+            kind = t.kind
+            if kind == "NUMBER":
+                # `t:9 x` is the record t:9, `t:9x` the record t:⟨9x⟩
+                # (_thing_tail merges the run): a number with an identifier
+                # glued to it is a kind of its own, fixed and never bound
+                nxt = tokens[n + 1]
+                if nxt.kind in ("IDENT", "NUMBER", "DURATION") and not any(
+                    c.isspace() for c in text[t.pos : nxt.pos]
+                ):
+                    kind = "GLUED"
+            kinds.append(kind)
             values.append(t.value)
     return tuple(kinds), tuple(values)
 

@@ -491,3 +491,207 @@ def test_advisor_review_rows_flow_through_propose(ds):
     props = [p for p in advisor.proposals(limit=50)
              if p["kind"] == "plan_cache.review"]
     assert props and any(fp in (p.get("fingerprints") or []) for p in props)
+
+
+# ============================================================ record ids
+# ISSUE 39: an inline record id is a slot. A flavour is how the id is
+# spelled; the bindable ones (one NUMBER / STRING / UUID token) share a
+# template, an identifier id is kept by the fingerprint and is its own text.
+_U = "018a6680-bef9-701b-9025-e1754f29{:04x}"
+ID_FLAVOURS = {
+    "int": (True, lambda i: str(10 + i)),
+    "ident": (False, lambda i: f"alice{i}"),
+    "backtick": (False, lambda i: f"`a b{i}`"),
+    "angle": (False, lambda i: f"⟨a-b{i}⟩"),
+    "uuid_string": (True, lambda i: "'" + _U.format(i) + "'"),
+    "uuid": (True, lambda i: "u'" + _U.format(i) + "'"),
+}
+ID_STATEMENTS = {
+    "select": lambda a, b: f"SELECT * FROM person:{a}",
+    "hop3_count": lambda a, b: "SELECT count(->knows->person->knows->person->knows->person) "
+                               f"AS c FROM person:{a}",
+    "update": lambda a, b: f"UPDATE person:{a} SET seen = true",
+    "delete": lambda a, b: f"DELETE person:{a} RETURN BEFORE",
+    "relate": lambda a, b: f"RELATE person:{a}->likes->person:{b} RETURN in, out",
+    "return_field": lambda a, b: f"RETURN person:{a}.name",
+    "where_id": lambda a, b: f"SELECT name FROM person WHERE id = person:{a}",
+    "subquery": lambda a, b: f"SELECT name, ->knows->person AS k FROM (SELECT * FROM person:{a})",
+}
+N_IDS = 7
+
+
+def _both(setup):
+    warm_ds, cold_ds = _mk_ds(True), _mk_ds(False)
+    for d in (warm_ds, cold_ds):
+        for sql in setup:
+            ok(d.execute(sql)[-1])
+    stats.reset()
+    warm_ds.plan_cache.reset_window()
+    return warm_ds, cold_ds
+
+
+def _same_answers(warm_ds, cold_ds, statements):
+    for sql in statements:
+        w, c = _norm(warm_ds.execute(sql)), _norm(cold_ds.execute(sql))
+        assert w == c, f"diverged warm-vs-cold:\n  sql:  {sql}\n  warm: {w}\n  cold: {c}"
+        assert '"ERR"' not in w, (sql, w)
+
+
+def _id_slots(d, sql):
+    """The record-id slots of every variant cached for `sql`'s shape."""
+    e = d.plan_cache._entries.get(fp_of(sql))
+    return [v.id_types for v in e.variants] if e is not None else []
+
+
+@pytest.mark.parametrize("flavour", sorted(ID_FLAVOURS))
+@pytest.mark.parametrize("kind", sorted(ID_STATEMENTS))
+def test_record_id_served_from_template_equals_cold(kind, flavour, monkeypatch):
+    monkeypatch.setattr(cnf, "TPU_GRAPH_COUNT_EDGES", 0)  # a small count rides the dispatch queue too
+    bindable, spell = ID_FLAVOURS[flavour]
+    ids = [spell(i) for i in range(N_IDS)]
+    setup = [f"CREATE person:{x} SET name = 'p{i}', n = {100 + i}" for i, x in enumerate(ids)]
+    setup += [f"RELATE person:{ids[i]}->knows->person:{ids[(i * 3 + j) % N_IDS]}"
+              for i in range(N_IDS) for j in (1, 2)]
+    warm_ds, cold_ds = _both(setup)
+    try:
+        stmts = [ID_STATEMENTS[kind](ids[i], ids[(i + 1) % N_IDS]) for i in range(N_IDS)]
+        _same_answers(warm_ds, cold_ds, stmts + stmts)
+        snap = warm_ds.plan_cache.snapshot()
+        assert snap["verifies"]["failed"] == 0, snap
+        if bindable:
+            # one cold parse installs the template; every other text is
+            # bound into it (four of them verified), then each again by digest
+            assert snap["hits"]["ast"] == 2 * N_IDS - 1, snap
+            assert snap["verifies"]["ok"] == 4, snap
+            assert snap["misses"] == {"cold": 1}, snap
+            assert all(_id_slots(warm_ds, s) for s in stmts)
+        else:
+            # its own fingerprint a text: parsed once, then served by digest
+            assert snap["hits"]["ast"] == N_IDS, snap
+            assert not any(t for s in stmts for t in _id_slots(warm_ds, s))
+    finally:
+        warm_ds.close()
+        cold_ds.close()
+
+
+# what must stay fixed: (setup, statements, whether the odd texts may share a
+# shape with a template that has a record-id slot)
+_INTS = [f"SELECT * FROM person:{10 + i} PARALLEL" for i in range(6)]
+FIXED_CASES = {
+    "flexible": (["CREATE likes:8abc2 SET n = 1", "CREATE likes:9abc2 SET n = 2", "CREATE likes:17abc2 SET n = 3"],
+                 ["SELECT * FROM likes:8abc2", "SELECT * FROM likes:9abc2", "SELECT * FROM likes:17abc2"] * 2, False),
+    "range": ([], [f"SELECT * FROM person:{i}..{i + 5}" for i in range(8, 16)], False),
+    "array": ([f"CREATE person:[{i}, 'x'] SET n = {i}" for i in range(6)],
+              [f"SELECT * FROM person:[{i}, 'x']" for i in range(6)] * 2, False),
+    "object": ([f"CREATE person:{{ a: {i} }} SET n = {i}" for i in range(6)],
+               [f"SELECT * FROM person:{{ a: {i} }}" for i in range(6)] * 2, False),
+    "duplicated": ([], [f"SELECT * FROM person:{i} WHERE n = {i}" for i in range(10, 16)] * 2, False),
+    "negative": ([f"CREATE person:-{i} SET n = {i}" for i in range(1, 7)],
+                 [f"SELECT * FROM person:-{i}" for i in range(1, 7)] * 2, False),
+    "expression_id": ([], [f"SELECT * FROM person:[{i} + 1]" for i in range(6)] * 2, False),
+    # the parser builds int 42 for `person:42` and a string for each of these
+    "int_against_string": (["CREATE person:⟨42⟩ SET n = 'str'"],
+                           _INTS + ["SELECT * FROM person:'42' PARALLEL", "SELECT * FROM person:⟨42⟩ PARALLEL",
+                                    "SELECT * FROM person:42 PARALLEL"], True),
+    "number_read_as_text": (["CREATE person:⟨8e2⟩ SET n = 'e'", "CREATE person:⟨5f⟩ SET n = 'f'",
+                             "CREATE person:⟨1h⟩ SET n = 'h'", "CREATE person:800 SET n = 800"],
+                            _INTS + ["SELECT * FROM person:8e2 PARALLEL", "SELECT * FROM person:5f PARALLEL",
+                                     "SELECT * FROM person:1h PARALLEL", "SELECT * FROM person:800 PARALLEL"], True),
+    "glued_identifier": (["CREATE person:⟨9PARALLEL⟩ SET n = 'glued'"],
+                         _INTS + ["SELECT * FROM person:9PARALLEL", "SELECT * FROM person:9 PARALLEL"], True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FIXED_CASES))
+def test_record_id_that_must_stay_fixed(case):
+    setup, stmts, beside_a_template = FIXED_CASES[case]
+    setup = [f"CREATE person:{i} SET n = {i}" for i in range(8, 22)] + setup
+    warm_ds, cold_ds = _both(setup)
+    try:
+        _same_answers(warm_ds, cold_ds, stmts)
+        assert warm_ds.plan_cache.snapshot()["verifies"]["failed"] == 0
+        if beside_a_template:
+            # the ints earned trust: whatever came after was held to the
+            # parser's reading by the signature alone
+            assert warm_ds.plan_cache.snapshot()["verifies"]["ok"] == 4
+            assert any(t for t in _id_slots(warm_ds, _INTS[0]))
+        else:
+            assert not any(t for s in stmts for t in _id_slots(warm_ds, s)), case
+    finally:
+        warm_ds.close()
+        cold_ds.close()
+
+
+def test_unaliased_projection_keeps_its_own_text_as_key(ds):
+    """`SELECT 11, n` is keyed by repr(expr): a shared template would print
+    the first-seen literal, so such a literal is no slot."""
+    ok(ds.execute("CREATE person:1 SET n = 1, name = 'x'")[-1])
+    for i in range(10, 17):
+        assert ok(ds.execute(f"SELECT {i}, n FROM person")[-1]) == [{str(i): i, "n": 1}]
+        row = ok(ds.execute(f"SELECT person:1.name, {i} AS k, math::max([n, {i + 100}]) FROM person")[-1])[0]
+        assert row == {"person:1.name": "x", "k": i, "math::max": i + 100}
+        assert ok(ds.execute(f"SELECT VALUE n + {i} FROM person")[-1]) == [1 + i]
+    snap = ds.plan_cache.snapshot()
+    assert snap["verifies"] == {"ok": 8, "failed": 0}, snap  # the aliased and the VALUE shapes bind
+
+
+def test_graph_idiom_statement_earns_trust(ds):
+    """PGraph names a slot the parser never sets: unset on both sides is equal."""
+    for i in range(12):
+        ok(ds.execute(f"CREATE person:{i} SET n = {100 + i}")[-1])
+        ok(ds.execute(f"RELATE person:{i}->knows->person:{(i + 1) % 12}")[-1])
+    ds.plan_cache.reset_window()
+    for k in range(100, 112):
+        assert ok(ds.execute(f"SELECT count(->knows->person) AS c FROM person WHERE n = {k}")[-1]) == [{"c": 1}]
+    snap = ds.plan_cache.snapshot()
+    assert snap["verifies"] == {"ok": 4, "failed": 0}, snap
+    assert snap["hits"]["ast"] == 11, snap
+
+
+def test_ast_equal_slot_set_on_one_side_only_is_unequal():
+    from surrealdb_tpu.dbs.plan_cache import _ast_equal
+    from surrealdb_tpu.syn import parse_query
+
+    sql = "SELECT count(->knows->person) AS c FROM person"
+    a, b = parse_query(sql).statements[0], parse_query(sql).statements[0]
+    graph = lambda stm: stm.fields[0].expr.args[0].parts[0]  # noqa: E731
+    assert not hasattr(graph(a), "expr_fields")  # the lazily set slot
+    assert _ast_equal(a, b, ())
+    graph(a).expr_fields = None
+    assert not _ast_equal(a, b, ()) and not _ast_equal(b, a, ())
+    graph(b).expr_fields = None
+    assert _ast_equal(a, b, ())
+
+
+def test_observe_refuses_an_unbindable_churning_shape_before_it_works(ds, monkeypatch):
+    from surrealdb_tpu.dbs import plan_cache as pc
+
+    calls = []
+    real = pc._parameterize
+    monkeypatch.setattr(pc, "_parameterize", lambda text, q: calls.append(text) or real(text, q))
+    sql = "SELECT * FROM likes:{}abc2"
+    for i in range(13):  # four variants, then nine capacity evictions
+        ok(ds.execute(sql.format(i))[-1])
+    entry = ds.plan_cache._entries[fp_of(sql.format(0))]
+    assert len(calls) == 13 and entry.churn == 9 and not entry.bound
+    for i in range(13, 53):
+        ok(ds.execute(sql.format(i))[-1])
+    assert len(calls) == 13 and entry.refused == 40
+    # a shape that binds is never turned away, however it churned before
+    entry.bound = True
+    ok(ds.execute(sql.format(99))[-1])
+    assert len(calls) == 14
+
+
+def test_plan_fetch_span_says_how_the_plan_was_found(ds):
+    from surrealdb_tpu import tracing
+
+    def outcome(tid, sql):
+        with tracing.request("test", trace_id=tid):
+            ok(ds.execute(sql)[-1])
+        return [s["labels"]["outcome"] for s in tracing.get_trace(tid)["spans"] if s["name"] == "plan_fetch"]
+
+    ok(ds.execute("CREATE person:1 SET n = 1")[-1])
+    assert outcome("pf-parse", "SELECT * FROM person:1") == ["parse"]
+    assert outcome("pf-lexed", "SELECT * FROM person:2") == ["lexed"]
+    assert outcome("pf-digest", "SELECT * FROM person:2") == ["digest"]
