@@ -102,6 +102,7 @@ def gl001(modules: List[Module]) -> List[Finding]:
 GL002_KERNEL_DEF_MODULES = frozenset(
     {
         "surrealdb_tpu/ops/bm25.py",
+        "surrealdb_tpu/ops/column_agg.py",
         "surrealdb_tpu/ops/distances.py",
         "surrealdb_tpu/parallel/mesh.py",
     }

@@ -161,11 +161,6 @@ COLUMN_REBUILD_DEBOUNCE_SECS = _env_float("SURREAL_COLUMN_REBUILD_DEBOUNCE", 0.5
 # reference's condition-checker semantics; tests/test_knn_strategies.py
 # holds each strategy to it); only the txn overlay merge post-filters
 KNN_COLUMN_PREFILTER = _env_bool("SURREAL_KNN_COLUMN_PREFILTER", True)
-# vectorized SELECT pipeline (ops/pipeline.py): route large numeric masks /
-# sorts through a jitted device kernel. Off until the accelerator
-# re-measure (ROADMAP) proves the dispatch round-trip pays; the cost model
-# records the declined option in plan notes either way.
-COLUMN_DEVICE = _env_bool("SURREAL_COLUMN_DEVICE", False)
 
 # Bulk-ingest pipeline v2 (doc/bulk.py + kvs/ds.py GroupCommit).
 # Mirror delta-feed: a bulk statement's decoded column blocks append

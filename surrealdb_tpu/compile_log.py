@@ -59,6 +59,7 @@ KERNEL_SITES = {
     "graph_csc": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
     "graph_chain": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
     "bm25": "surrealdb_tpu.ops.bm25:graftcheck_sites",
+    "column_agg": "surrealdb_tpu.ops.column_agg:graftcheck_sites",
     "ml_forward": "surrealdb_tpu.ml.model:graftcheck_sites",
 }
 

@@ -255,20 +255,28 @@ class DispatchQueue:
         self._reported = sums
         return due
 
-    def _bucket(self, key: Hashable) -> _Bucket:
+    def _bucket(self, key: Hashable, depth: Optional[int]) -> _Bucket:
         with self._lock:
             # the queue counters + bucket map are one guarded unit
             # (sanitizer-declared: stats() diffs depend on their atomicity)
             _locks.assert_held(self._lock, "dispatch.counters")
             b = self._buckets.get(key)
             if b is None:
-                b = self._buckets[key] = _Bucket(self._depth())
+                b = self._buckets[key] = _Bucket(depth or self._depth())
             self.submitted += 1
             self._move(queued=1)
             return b
 
-    def submit(self, key: Hashable, payload: Any, runner: Callable[[Sequence[Any]], Sequence[Any]]) -> Any:
-        b = self._bucket(key)
+    def submit(
+        self, key: Hashable, payload: Any, runner: Callable[[Sequence[Any]], Sequence[Any]],
+        depth: Optional[int] = None,
+    ) -> Any:
+        """`depth`: the pipeline depth of `key`'s bucket where the family
+        knows better than the knob (fixed when the bucket is first touched).
+        A launch whose device cost does not grow with its riders (a sweep of
+        a whole table, ops/pipeline.py) asks for 1: a second sweep in flight
+        beside the first would only split between two what one serves."""
+        b = self._bucket(key, depth)
         req = _Req(payload, runner)
         with b.lock:
             b.queue.append(req)

@@ -44,6 +44,7 @@ path (rows the predicate compiler can't judge are re-checked per row).
 
 from __future__ import annotations
 
+import itertools
 import threading
 from surrealdb_tpu.utils import locks as _locks
 import time as _time
@@ -67,6 +68,7 @@ from surrealdb_tpu.ops.predicates import (
     CompiledPredicate,
 )
 from surrealdb_tpu.sql.value import Datetime, Thing, is_none, is_null
+from surrealdb_tpu.utils.num import path_slots as _path_slots
 from surrealdb_tpu.utils.ser import unpack
 
 
@@ -214,11 +216,69 @@ def _classify(v) -> Tuple[int, Optional[float], Optional[str], Optional[int]]:
     return TAG_OTHER, None, None, None
 
 
+# ------------------------------------------------------------------ device form
+_TAG_CELL = {TAG_NONE: "none", TAG_NULL: "null", TAG_FLOAT: "float", TAG_OTHER: "other"}
+_INT32 = np.iinfo(np.int32)
+_MIRROR_SERIAL = itertools.count(1)
+
+
+class DeviceColumn:
+    """One column's device form, as long-lived as the mirror object it
+    belongs to (one build version): `plane`, int32 [path_slots(rows)] in
+    HBM in record-key order, the pad 0. Form `value`: the cells themselves
+    (every cell an int inside int32; `lo` / `hi` their least and greatest).
+    Form `code`: dense codes of `values`, the host's sorted distinct cells
+    (ints, bools, strings, or datetime nanos: `tag` says which), so that a
+    comparison with a constant is a comparison of codes after a
+    `searchsorted` on the host, exact for any ordered type."""
+
+    __slots__ = ("form", "plane", "values", "tag", "lo", "hi")
+
+    def __init__(self, form: str, plane, values, tag: int, lo: int = 0, hi: int = 0):
+        self.form, self.plane, self.values, self.tag, self.lo, self.hi = form, plane, values, tag, lo, hi
+
+
+def _encode_device(col: Column, form: str, path: str):
+    """(form, int32 cells, sorted distinct values or None, tag, lo, hi) of
+    one column's device form (`form` asked: `value`, `code`, or `any`: the
+    values where they fit, else codes), or the reason (text) it has none:
+    the rule is by column, read once."""
+    tags = col.tags
+    if not len(tags):
+        return f"empty:{path}"
+    tag = int(tags[0])
+    if not (tags == tag).all():
+        odd = next((name for t, name in _TAG_CELL.items() if (tags == t).any()), "mixed")
+        return f"{odd}_cell:{path}"
+    if tag in _TAG_CELL:
+        return f"{_TAG_CELL[tag]}_cell:{path}"
+    if form != "code" and tag == TAG_INT:
+        lo, hi = int(col.nums.min()), int(col.nums.max())
+        if _INT32.min <= lo and hi <= _INT32.max:
+            return "value", col.nums.astype(np.int32), None, tag, lo, hi
+    if form == "value":
+        return f"{'int_range' if tag == TAG_INT else 'not_int'}:{path}"
+    if tag == TAG_STR:
+        cells = col.str_array().tolist()
+        values = np.asarray(sorted(set(cells)), dtype=object)
+        code_of = {v: i for i, v in enumerate(values.tolist())}
+        codes = np.fromiter(map(code_of.__getitem__, cells), dtype=np.int32, count=len(tags))
+    else:
+        cells = col.i64() if tag == TAG_DATETIME else col.nums.astype(np.int64)
+        values, codes = np.unique(cells, return_inverse=True)
+        codes = codes.reshape(-1).astype(np.int32)
+    return "code", codes, values, tag, 0, len(values) - 1
+
+
 # ------------------------------------------------------------------ mirror
 class ColumnMirror:
     """One table's columns, frozen at (built_version, build snapshot)."""
 
     __slots__ = (
+        "serial",
+        "_device",
+        "_device_lock",
+        "placements",
         "ids",
         "enc_keys",
         "columns",
@@ -256,6 +316,79 @@ class ColumnMirror:
         self._id_index: Optional[Dict[str, int]] = None
         # (id(rids list), n_slots) -> row permutation for the kNN prefilter
         self._slot_perm: Optional[Tuple[Tuple[int, int], int, np.ndarray]] = None
+        # the device forms of this build's columns: (path, form it has) ->
+        # DeviceColumn, or (path, form asked) -> the reason there is none.
+        # They belong to this object, so to one build version: a write
+        # installs another mirror, and a reader that may not serve this one
+        # never sees them
+        self.serial = next(_MIRROR_SERIAL)
+        self._device: Dict[Tuple[str, str], Any] = {}
+        # what ops/pipeline.py::grouped_route worked out over these planes (a
+        # statement shape -> its DevicePlan, or the reason it has none)
+        self.placements: Dict[Any, Any] = {}
+        self._device_lock = _locks.Lock("idx.column.device")
+
+    def device_columns(self, want) -> Tuple[Optional[Dict[Tuple[str, str], DeviceColumn]], Optional[str]]:
+        """The device forms of `want` ((path, form) pairs), encoded and
+        uploaded on first need, or (None, reason) where a column has none
+        (a NONE / NULL / float / OTHER cell in any row, a `value` form of a
+        column that is no int inside int32). The references are taken under
+        the lock; the caller computes outside it."""
+        from surrealdb_tpu import telemetry
+
+        want = sorted(set(want))
+        with self._device_lock:
+            new = [w for w in want if self._held(w) is None]
+            if new:
+                cols = self.columns_for({p for p, _ in new})
+                if cols is None:
+                    return None, "columns"
+                t0 = _time.perf_counter()
+                order, slots = self.key_order(), _path_slots(self.n)
+                made = {}
+                for path, form in new:
+                    enc = _encode_device(cols[path], form, path)
+                    if isinstance(enc, str):
+                        made[(path, form)] = enc
+                    elif (path, enc[0]) not in made:  # `any` and the form it resolves to, both new: one plane
+                        plane = np.zeros(slots, dtype=np.int32)
+                        plane[: self.n] = enc[1] if order is None else enc[1][order]
+                        made[(path, enc[0])] = (enc[0], plane) + enc[2:]  # DeviceColumn's fields, the plane still on the host
+                dt = _time.perf_counter() - t0
+                telemetry.observe("column_device_encode", dt)
+                telemetry.stage("column_device_encode", t0, dt, columns=len(made), rows=self.n)
+                encoded = [k for k, e in made.items() if not isinstance(e, str)]
+                if encoded:
+                    import jax.numpy as jnp
+
+                    t0 = _time.perf_counter()
+                    for k in encoded:
+                        form, plane, *rest = made[k]
+                        made[k] = DeviceColumn(form, jnp.asarray(plane), *rest)
+                    dt = _time.perf_counter() - t0
+                    telemetry.observe("column_device_upload", dt)
+                    telemetry.stage("column_device_upload", t0, dt, bytes=4 * slots * len(encoded))
+                self._device.update(made)
+            refs = {w: self._held(w) for w in want}
+        for d in refs.values():
+            if isinstance(d, str):
+                return None, d
+        return refs, None
+
+    def _held(self, w: Tuple[str, str]):
+        """What `_device` holds for one (path, form) asked: the DeviceColumn,
+        the reason there is none, or None where it is yet to be encoded. A
+        plane is kept under the form it HAS, so `any` (the values where
+        they fit, else codes) finds what `value` or `code` made and makes
+        no second copy."""
+        path, form = w
+        if form != "any":
+            return self._device.get(w)
+        for f in ("value", "code"):
+            d = self._device.get((path, f))
+            if isinstance(d, DeviceColumn):
+                return d
+        return self._device.get(w)  # the reason the column has no form at all
 
     def key_order(self) -> Optional[np.ndarray]:
         """Row indices in record-key order, or None when rows are already
@@ -735,21 +868,25 @@ class ColumnMirrors:
         cap = 1024
         row = 0
         for chunk in txn.batch(pre, prefix_end(pre), cnf.NORMAL_FETCH_SIZE):
-            for k, raw in chunk:
-                if row >= cap:
+            if row + len(chunk) > cap:
+                while row + len(chunk) > cap:
                     cap *= 2
-                    for b in builders.values():
-                        b.grow(cap)
+                for b in builders.values():
+                    b.grow(cap)
+            docs = []
+            for k, raw in chunk:
                 ids.append(keys.decode_thing_id(k, ns, db, tb))
                 enc_keys.append(k[npre:])
-                doc = unpack(raw)
-                if isinstance(doc, dict):
-                    for name, v in doc.items():
-                        _put_cell(
-                            builders, name, v, row, cap, max_fields,
-                            nested_depth, mirror, unsafe_rows,
-                        )
-                row += 1
+                docs.append(unpack(raw))
+            if not _put_block(builders, docs, row, cap, max_fields, mirror, unsafe_rows):
+                for j, doc in enumerate(docs):
+                    if isinstance(doc, dict):
+                        for name, v in doc.items():
+                            _put_cell(
+                                builders, name, v, row + j, cap, max_fields,
+                                nested_depth, mirror, unsafe_rows,
+                            )
+            row += len(docs)
         mirror.ids = ids
         mirror.enc_keys = enc_keys
         mirror.n = row
@@ -771,16 +908,84 @@ def _build_block(docs) -> Tuple[ColumnMirror, Dict[str, List[int]]]:
     builders: Dict[str, _ColBuilder] = {}
     unsafe_rows: Dict[str, List[int]] = {}
     cap = max(len(docs), 1)
-    for row, doc in enumerate(docs):
-        if isinstance(doc, dict):
-            for name, v in doc.items():
-                _put_cell(
-                    builders, name, v, row, cap, max_fields,
-                    nested_depth, blk, unsafe_rows,
-                )
+    if not _put_block(builders, docs, 0, cap, max_fields, blk, unsafe_rows):
+        for row, doc in enumerate(docs):
+            if isinstance(doc, dict):
+                for name, v in doc.items():
+                    _put_cell(
+                        builders, name, v, row, cap, max_fields,
+                        nested_depth, blk, unsafe_rows,
+                    )
     blk.n = len(docs)
     blk.columns = {p: b.finalize(blk.n) for p, b in builders.items()}
     return blk, unsafe_rows
+
+
+def _put_block(builders, docs, row0: int, cap: int, max_fields: int, mirror, unsafe_rows) -> bool:
+    """Classify a block of documents (rows row0 ...) a COLUMN at a time, by
+    whole-array conversion, where the block is homogeneous: every document a
+    dict of the first one's fields, every field's cells of one exact type
+    among int (inside the f64-exact window), float, bool, str, Datetime and
+    Thing (the record's own `id`: OTHER, and unsafe to look under).
+    The same builders in the same order and the same cells as `_put_cell` a
+    cell (tests/test_column_device.py holds the two to each other); any
+    other block returns False untouched, and the caller takes the per-cell
+    path for it. 26 -> ~10 us a row of 16 fields on the build's scan."""
+    first = docs[0] if docs else None
+    if type(first) is not dict or not first:
+        return False
+    names, k, m = first.keys(), len(first), len(docs)
+    for d in docs:
+        if type(d) is not dict or len(d) != k or d.keys() != names:
+            return False
+    if len(builders) + sum(1 for name in first if name not in builders) > max_fields:
+        return False
+    ready = []
+    for name in first:
+        vals = [d[name] for d in docs]
+        kinds = set(map(type, vals))
+        if len(kinds) != 1:
+            return False
+        kind = kinds.pop()
+        if kind is int:
+            try:
+                arr = np.fromiter(vals, dtype=np.int64, count=m)
+            except OverflowError:
+                return False
+            if int(arr.min()) < -F64_EXACT_INT or int(arr.max()) > F64_EXACT_INT:
+                return False
+            ready.append((name, TAG_INT, arr.astype(np.float64), None, None))
+        elif kind is float:
+            ready.append((name, TAG_FLOAT, np.fromiter(vals, dtype=np.float64, count=m), None, None))
+        elif kind is bool:
+            ready.append((name, TAG_BOOL, np.fromiter(vals, dtype=np.float64, count=m), None, None))
+        elif kind is str:
+            ready.append((name, TAG_STR, None, vals, None))
+        elif kind is Datetime:
+            ready.append((name, TAG_DATETIME, None, None, [v.nanos for v in vals]))
+        elif kind is Thing:
+            ready.append((name, TAG_OTHER, None, None, None))
+        else:
+            return False
+    rows = range(row0, row0 + m)
+    for name, tag, nums, strs, i64 in ready:
+        b = builders.get(name)
+        if b is None:
+            b = builders[name] = _ColBuilder(cap, row0)
+        b.tags[row0 : row0 + m] = tag
+        if nums is not None:
+            b.nums[row0 : row0 + m] = nums
+        if strs is not None:
+            b.str_rows.extend(rows)
+            b.str_vals.extend(strs)
+        if i64 is not None:
+            b.i64_rows.extend(rows)
+            b.i64_vals.extend(i64)
+        if tag == TAG_OTHER:
+            mirror.nested_unsafe.add(name)
+            unsafe_rows.setdefault(name, []).extend(rows)
+        b.n = row0 + m
+    return True
 
 
 def _put_cell(builders, name, v, row, cap, max_fields, nested_depth, mirror, unsafe_rows):

@@ -21,17 +21,38 @@ Iterator/group.rs contract:
   reproducing `aggregate_groups` byte-for-byte: int sums stay int (exact
   past-2^53 guard re-folds in python), min/max return the FIRST minimal
   member's value (int vs float tag preserved), NaN folds match python's
-  order-dependent min/max, empty aggregates yield NONE.
+  order-dependent min/max, empty aggregates yield NONE. `math::sum` and
+  `math::mean` also take an integer expression of columns and constants
+  (`+`, `-`, `*`: `math::sum(price * (100 - discount))`), evaluated a row
+  in int64 under interval bounds proved from the columns' cells.
+- **What runs where** (since PR 40). A grouped statement whose WHERE is a
+  conjunction of comparisons with constants, whose keys are low-cardinality
+  columns and whose aggregates are counts and integer sums runs ON THE
+  DEVICE: the columns it names stand in HBM as int32 planes
+  (idx/column_mirror.py `device_columns`) and the statement is one rider of
+  one dispatch of ops/column_agg.py::grouped_aggregate, which sweeps the
+  planes once for every rider of the launch and returns exact limb sums.
+  A summed expression is expanded here into a polynomial of its columns:
+  the launch sums the monomials and the rider folds its own coefficients
+  in, so no constant of a statement is in a program or a dispatch key, and
+  the key names the columns a launch reads, not their places among them.
+  `grouped_route` is the one rule; its outcome is on the statement's
+  `column_prepare` span and in `column_pipeline{outcome}`. What of the
+  rule a statement's constants cannot move is kept with the mirror build
+  (`placements`), and a sweep's dispatch bucket is one deep: statements
+  that arrive while a sweep is in flight ride the next one together. Every other
+  grouped statement, and every ordered one, runs on the HOST in NumPy over
+  the mirror's arrays; what does not lower at all keeps the row path. The
+  three return identical rows.
 - **Late materialization**: only the row ids surviving sort + START/LIMIT
   are decoded; plain-field projections are reconstructed straight off the
   columns (`id` from the row-id map) — a `SELECT VALUE id ... ORDER BY ...
   LIMIT k` touches ZERO documents. Any row whose projected cells include an
   OTHER tag decodes its document once and runs the ordinary row-path
   projection for exactness.
-- **Cost hook**: `choose_strategy` picks row vs columnar vs (when a device
-  kernel is enabled) device per statement from mirror presence/staleness,
-  table size, and pipeline shape; the decision + inputs land in plan notes
-  so EXPLAIN ANALYZE shows why a path was taken.
+- **Cost hook**: `choose_strategy` picks row vs columnar per statement from
+  mirror presence/staleness, table size, and pipeline shape; the decision +
+  inputs land in plan notes so EXPLAIN ANALYZE shows why a path was taken.
 - **Cluster partials**: `partial_aggregate` computes per-shard partial
   aggregates (count / exact int sums / min-max with NaN + int-float-tie
   exactness flags / mean as sum+count / first-member values keyed by the
@@ -54,6 +75,7 @@ import numpy as np
 
 from surrealdb_tpu import cnf
 from surrealdb_tpu.ops.predicates import (
+    F64_EXACT_INT,
     ORD_OF_TAG,
     TAG_BOOL,
     TAG_DATETIME,
@@ -64,10 +86,12 @@ from surrealdb_tpu.ops.predicates import (
     TAG_OTHER,
     TAG_STR,
     CompiledPredicate,
+    _const_value,
     _depth_limit,
+    _is_const,
     compile_where,
 )
-from surrealdb_tpu.sql.ast import FunctionCall
+from surrealdb_tpu.sql.ast import BinaryOp, FunctionCall
 from surrealdb_tpu.sql.path import Idiom, PField, get_path
 from surrealdb_tpu.sql.value import (
     NONE,
@@ -114,11 +138,16 @@ class OrderSpec:
 
 
 class AggSpec:
-    __slots__ = ("kind", "path")  # kind: count|count_arg|sum|min|max|mean
+    """One aggregate: `kind` count|count_arg|sum|min|max|mean over a plain
+    `path`, or (sum and mean) over `expr`, the AST of an integer expression
+    of columns and constants (`+`, `-`, `*`)."""
 
-    def __init__(self, kind: str, path: Optional[str]):
+    __slots__ = ("kind", "path", "expr")
+
+    def __init__(self, kind: str, path: Optional[str], expr=None):
         self.kind = kind
         self.path = path
+        self.expr = expr
 
 
 class GroupedField:
@@ -265,9 +294,86 @@ def resolve_plain_projection(stm) -> Optional[List[Tuple[Any, str]]]:
     return out
 
 
+def _int_expr_columns(e) -> Set[str]:
+    """The column paths of an integer expression (`+`, `-`, `*` of plain
+    paths and constants), empty where `e` is no such expression or names no
+    column. Pure AST: whether the cells and the constants ARE ints is read
+    a statement, from the columns and the bound values (`_bind_expr`)."""
+    if isinstance(e, BinaryOp) and e.op in ("+", "-", "*"):
+        l, r = _int_expr_columns(e.l), _int_expr_columns(e.r)
+        ok_l, ok_r = l or _is_const(e.l), r or _is_const(e.r)
+        return (l | r) if ok_l and ok_r else set()
+    p = _plain_path(e, allow_id=False)
+    return {p} if p is not None else set()
+
+
+def _bind_expr(ctx, e):
+    """An integer expression as a tree of ("col", path) / ("const", int) /
+    (op, a, b), its constants evaluated under `ctx`; None where one is no
+    int (a float, a decimal, a bool: the row path's arithmetic then)."""
+    if isinstance(e, BinaryOp) and e.op in ("+", "-", "*"):
+        a, b = _bind_expr(ctx, e.l), _bind_expr(ctx, e.r)
+        return None if a is None or b is None else (e.op, a, b)
+    p = _plain_path(e, allow_id=False)
+    if p is not None:
+        return ("col", p)
+    v = _const_value(ctx, e)
+    return ("const", v) if isinstance(v, int) and not isinstance(v, bool) else None
+
+
+def _expr_bound(tree, ranges: Dict[str, Tuple[int, int]]) -> Tuple[int, int]:
+    """(least, greatest) value the tree can take over columns inside
+    `ranges`, by interval arithmetic in Python ints."""
+    if tree[0] == "col":
+        return ranges[tree[1]]
+    if tree[0] == "const":
+        return tree[1], tree[1]
+    (alo, ahi), (blo, bhi) = _expr_bound(tree[1], ranges), _expr_bound(tree[2], ranges)
+    if tree[0] == "+":
+        return alo + blo, ahi + bhi
+    if tree[0] == "-":
+        return alo - bhi, ahi - blo
+    ends = (alo * blo, alo * bhi, ahi * blo, ahi * bhi)
+    return min(ends), max(ends)
+
+
+def _tree_columns(tree) -> Set[str]:
+    if tree[0] == "col":
+        return {tree[1]}
+    return set() if tree[0] == "const" else _tree_columns(tree[1]) | _tree_columns(tree[2])
+
+
+def _polynomial(tree) -> Optional[Dict[Tuple[str, ...], int]]:
+    """The tree as a polynomial of its columns: monomial (the sorted paths
+    whose product it is, () the constant term) -> coefficient. Which
+    monomials there are follows from the tree's shape alone (a coefficient
+    that comes out 0 keeps its monomial), only the coefficients from its
+    constants. None past MONOMIALS_MAX monomials."""
+    if tree[0] == "col":
+        return {(tree[1],): 1}
+    if tree[0] == "const":
+        return {(): tree[1]}
+    a, b = _polynomial(tree[1]), _polynomial(tree[2])
+    if a is None or b is None:
+        return None
+    out: Dict[Tuple[str, ...], int] = {}
+    if tree[0] == "*":
+        for ma, ca in a.items():
+            for mb, cb in b.items():
+                m = tuple(sorted(ma + mb))
+                out[m] = out.get(m, 0) + ca * cb
+    else:
+        sign = 1 if tree[0] == "+" else -1
+        out = dict(a)
+        for mb, cb in b.items():
+            out[mb] = out.get(mb, 0) + sign * cb
+    return out if len(out) <= MONOMIALS_MAX else None
+
+
 def grouped_shape(stm) -> Optional[GroupedShape]:
     """The statement's GROUP BY shape when every piece lowers: plain-path
-    group keys, aggregates from LOWERED_AGGREGATES over plain paths,
+    group keys, aggregates from LOWERED_AGGREGATES over plain paths (sum and
+    mean also over an integer expression of columns and constants),
     plain-path first-member projections. None otherwise."""
     from surrealdb_tpu.dbs.iterator import _AGGREGATES
 
@@ -293,7 +399,10 @@ def grouped_shape(stm) -> Optional[GroupedShape]:
                 return None
             ap = _plain_path(e.args[0])
             if ap is None:
-                return None
+                if kind not in ("sum", "mean") or not _int_expr_columns(e.args[0]):
+                    return None
+                fields.append(GroupedField(f, AggSpec(kind, None, e.args[0]), None))
+                continue
             fields.append(
                 GroupedField(f, AggSpec("count_arg" if kind == "count" else kind, ap), None)
             )
@@ -309,11 +418,13 @@ def grouped_shape(stm) -> Optional[GroupedShape]:
 
 # ------------------------------------------------------------------ cost model
 def choose_strategy(mirror, n_rows: int, shape: str) -> Tuple[str, dict]:
-    """Row vs columnar vs device for one lowerable statement. Inputs are the
-    mirror's state and the pipeline shape; the returned note lands in plan
-    notes so EXPLAIN ANALYZE names the decision. Device kernels are gated
-    behind SURREAL_COLUMN_DEVICE and route back to columnar until the
-    accelerator re-measure (ROADMAP) proves the dispatch pays."""
+    """Row vs columnar for one lowerable statement. Inputs are the mirror's
+    state and the pipeline shape; the returned note lands in plan notes so
+    EXPLAIN ANALYZE names the decision. Where a columnar grouped statement
+    then runs, on the device (one dispatch of ops/column_agg.py over the
+    columns' planes in HBM) or on the host (NumPy over the mirror's
+    arrays), is `grouped_route`'s one rule; ordered statements run on the
+    host."""
     note = {
         "shape": shape,
         "rows": n_rows,
@@ -337,10 +448,6 @@ def choose_strategy(mirror, n_rows: int, shape: str) -> Tuple[str, dict]:
             "declined_option": "columnar", "margin": col_cost - row_cost,
         }
         return "row", note
-    if cnf.COLUMN_DEVICE:
-        # a chip-backed mask/sort kernel would slot in here; today the
-        # columnar host path is the proven fastest option on every target
-        note["device"] = "declined: host columnar path (no measured win)"
     note["decision"] = "columnar"
     note["est_cost"] = {
         "unit": "row-visits", "chosen": col_cost, "declined": row_cost,
@@ -615,6 +722,56 @@ def factorize(
     return inv, len(key2gid)
 
 
+class _ExprDecline(Exception):
+    """An integer-expression aggregate the host arrays cannot answer
+    exactly (a cell that is no int, a constant that is no int, a value
+    past int64): the statement keeps the row path."""
+
+
+def _eval_tree(tree, cols, rows: np.ndarray) -> np.ndarray:
+    if tree[0] == "col":
+        return cols[tree[1]].nums[rows].astype(np.int64)
+    if tree[0] == "const":
+        return np.int64(tree[1])
+    a, b = _eval_tree(tree[1], cols, rows), _eval_tree(tree[2], cols, rows)
+    return a + b if tree[0] == "+" else a - b if tree[0] == "-" else a * b
+
+
+def _segment_expr(ctx, cols, agg: AggSpec, rows: np.ndarray, inv: np.ndarray, g: int) -> List[Any]:
+    """sum / mean of an integer expression a group, the row path's Python
+    ints: the expression a row in int64 (interval bounds from the columns'
+    least and greatest cells prove that no value wraps), the sums by float64
+    `bincount` inside the f64-exact window, by an int64 segmented add while
+    bound x rows stays under 2**63, in Python ints past that."""
+    tree = _bind_expr(ctx, agg.expr)
+    if tree is None:
+        raise _ExprDecline("constant")
+    ranges = {}
+    for p in _tree_columns(tree):
+        if (cols[p].tags[rows] != TAG_INT).any():
+            raise _ExprDecline("cells")
+        cells = cols[p].nums[rows]
+        ranges[p] = (int(cells.min()), int(cells.max())) if rows.size else (0, 0)
+    lo, hi = _expr_bound(tree, ranges)
+    bound = max(abs(lo), abs(hi))
+    if bound >= 1 << 63:
+        raise _ExprDecline("bound")
+    vals = np.broadcast_to(_eval_tree(tree, cols, rows), rows.shape)
+    counts = np.bincount(inv, minlength=g)
+    if bound * rows.size < F64_EXACT_INT:
+        sums = [int(x) for x in np.bincount(inv, weights=vals.astype(np.float64), minlength=g)]
+    else:
+        order = np.argsort(inv, kind="stable")
+        at = np.searchsorted(inv[order], np.arange(g + 1))
+        if bound * rows.size < 1 << 63:
+            sums = [int(x) for x in np.add.reduceat(vals[order], at[:-1])]  # no group is empty
+        else:
+            sums = [sum(vals[order[at[k] : at[k + 1]]].tolist()) for k in range(g)]
+    if agg.kind == "sum":
+        return sums
+    return [(s_ / int(c)) if c else NONE for s_, c in zip(sums, counts)]
+
+
 def _group_members(inv: np.ndarray, g: int) -> List[np.ndarray]:
     order = np.argsort(inv, kind="stable")
     bounds = np.searchsorted(inv[order], np.arange(g + 1))
@@ -632,6 +789,8 @@ def segment_aggregate(
     n = rows.size
     if agg.kind == "count":
         return [int(x) for x in np.bincount(inv, minlength=g)]
+    if agg.expr is not None:
+        return _segment_expr(ctx, cols, agg, rows, inv, g)
 
     col = cols[agg.path] if agg.path != "id" else None
     if agg.path == "id":
@@ -740,6 +899,309 @@ def segment_aggregate(
     return out
 
 
+# ------------------------------------------------------------------ the device route
+# The least table the device route takes: where a launch and a collect stop
+# costing more than the host's NumPy pass. One session on a v5e's host (my
+# chip runs, PR 40; PERF.md section 6): a device statement is 2.4-2.5 ms at
+# any table up to 128,000 rows (2.1 for Q6's shape); the host route's Q1 is
+# 2.50 ms at 964 rows, 4.24 at 2,007, 17.1 at 7,982, 336 at 127,998 (its
+# five expression sums and four groups: ~2.2 us a row), so Q1's shape crosses
+# at ~1,000 rows. A one-sum, one-group statement (Q6's shape) crosses only at
+# ~43,000 (host 1.77 ms at 32,082 rows, 5.08 at 127,998); between the two
+# floors the device costs such a statement up to 1.5 ms and the host would
+# cost a Q1 up to 90, so the lower floor stands.
+DEVICE_MIN_ROWS = 1024
+# A sweep costs the device the same whatever its riders (1.440 ms at 1 rider and at 8 over 3.0M rows: PERF.md
+# section 6, PR 40), so its bucket's pipeline is one deep: the statements that arrive while a sweep is in
+# flight ride the next one together, where a second sweep beside it would take some of them and leave the rest
+# a third. What a dispatch costs the host (a launch, a collect, a read-back under the interpreter lock) is then
+# shared by more riders.
+SWEEP_PIPELINE_DEPTH = 1
+PLACEMENTS_MAX = 64  # placements a mirror build keeps (grouped_route)
+MONOMIALS_MAX = 16  # distinct products of columns a launch sums: Q1's five expressions expand to six
+
+
+class DevicePlan:
+    """A grouped statement's shape placed on one mirror build: all of a
+    launch but its riders' constants. `columns` the (path, form) of each
+    plane it reads, in the planes' order, `static` the kernel's shape over
+    those planes (`pred`, `keys`, `exprs`: the monomials), `groups`,
+    `strides`, `serial` the mirror build the planes belong to and `key` the dispatch key they
+    all make; `compared` the column each predicate term compares (a rider's
+    constants are placed among ITS values); and how a reply folds into
+    rows: `polys`, a summed expression's coefficients (one a monomial of
+    `static["exprs"]`, then the constant term's), `slots`, which of
+    `polys` a field reads, `keys` the group keys' columns."""
+
+    __slots__ = ("planes", "columns", "static", "groups", "strides", "compared", "polys", "keys", "slots", "rows",
+                 "serial", "key")
+
+    def __init__(self, **kw):
+        for k, v in kw.items():
+            setattr(self, k, v)
+
+
+def _prepared(route: str, reason: str, t_enter: float, shape: Optional[GroupedShape] = None, rows: int = 0, groups: int = 0) -> None:
+    """One grouped statement sent to `route` (`device`, `host` or `row`)
+    for `reason`: the labels of its `column_prepare` span, which runs from
+    run_pipeline's entry to here (the dispatch submit of a `device`
+    statement, the start of the NumPy pass of a `host` one, the decline of
+    a `row` one). `column_pipeline{outcome}` counts the same three ways
+    out at run_pipeline's own exits."""
+    from surrealdb_tpu import tracing
+
+    aggregates = sum(1 for gf in shape.fields if gf.agg is not None) if shape is not None else 0
+    tracing.record_span_into(
+        tracing.current(), "column_prepare",
+        {"route": route, "reason": reason, "rows": rows, "groups": groups, "aggregates": aggregates},
+        t_enter, _time.perf_counter() - t_enter,
+    )
+
+
+def _code_constant(d, op: str, c) -> Optional[int]:
+    """The int32 a rider compares a plane with under `op`, so that `plane
+    op constant'` holds on exactly the rows where `cell op c` does; None
+    where `c` is not of the column's kind (the host mask has the cross-type
+    rules). A `value` plane takes an int inside int32 as it is; a `code`
+    plane takes the constant's place among the sorted distinct values."""
+    if d.form == "value":
+        if isinstance(c, bool) or not isinstance(c, int) or not -(1 << 31) <= c < (1 << 31):
+            return None
+        return c
+    if d.tag == TAG_STR:
+        if not (isinstance(c, str) and type(c) is str):
+            return None
+    elif d.tag == TAG_DATETIME:
+        if not isinstance(c, Datetime):
+            return None
+        c = c.nanos
+    elif d.tag == TAG_BOOL:
+        if not isinstance(c, bool):
+            return None
+        c = int(c)
+    elif isinstance(c, bool) or not isinstance(c, int):
+        return None
+    if op in ("<=", ">"):
+        return int(np.searchsorted(d.values, c, side="right")) - 1
+    left = int(np.searchsorted(d.values, c, side="left"))
+    if op == "=" and not (left < len(d.values) and d.values[left] == c):
+        return -1  # codes start at 0: -1 equals none
+    return left
+
+
+def grouped_route(ctx, shape: GroupedShape, compiled: Optional[CompiledPredicate], mirror) -> Tuple[str, str, Any]:
+    """THE rule that says where a lowered grouped statement over a serveable
+    mirror runs: (`device`, "", (plan, the rider's constants)) or (`host`,
+    reason, None).
+
+    `device` where the WHERE is absent or a conjunction of `<`, `<=`, `=`,
+    `>=`, `>` between device columns and constants of their kind; the group
+    keys are device columns whose distinct counts multiply to at most 256;
+    every aggregate is `count()`, or `math::sum` / `math::mean` of a column
+    or an integer expression (`+`, `-`, `*`) of int32 columns and integer
+    constants whose interval bound times the table's rows stays under
+    2**63 and which expand, all together, to at most MONOMIALS_MAX products
+    of columns; every other projection is a group key; the device is not
+    disabled; and the table has DEVICE_MIN_ROWS rows. A column has a device
+    form only if no cell of it is NONE, NULL, a float or OTHER
+    (idx/column_mirror.py). Everything else is `host`, today's NumPy route,
+    with the reason named.
+
+    What of the rule a statement's predicate constants cannot move (the
+    columns, their planes, the shape over them, the bounds) is worked out
+    once a (group keys, fields, compared columns and operators, summed
+    expressions) and kept with the mirror build it was worked out on
+    (`mirror.placements`, gone with the build as its planes are): `_place`.
+    A statement binds its expressions' constants, looks its placement up,
+    and places its predicate constants among the columns' values."""
+    if cnf.TPU_DISABLE:
+        return "host", "tpu_disable", None
+    if mirror.n < DEVICE_MIN_ROWS:
+        return "host", "rows", None
+    terms = compiled.device_terms() if compiled is not None else []
+    if terms is None:
+        return "host", "where", None
+    trees: List[Any] = []  # the distinct summed expressions
+    slots: List[Optional[int]] = []  # a field's expression, None for a key or the count
+    for gf in shape.fields:
+        if gf.agg is None:
+            if gf.path not in shape.group_paths:
+                return "host", "projection", None
+            slots.append(None)
+        elif gf.agg.kind == "count":
+            slots.append(None)
+        elif gf.agg.kind in ("sum", "mean"):
+            tree = ("col", gf.agg.path) if gf.agg.expr is None else _bind_expr(ctx, gf.agg.expr)
+            if tree is None:
+                return "host", "constant", None
+            if tree not in trees:
+                trees.append(tree)
+            slots.append(trees.index(tree))
+        else:
+            return "host", "aggregate", None
+    compared = tuple((p, op) for p, op, _ in terms)
+    asked = (tuple(shape.group_paths), tuple(slots), compared, tuple(trees))
+    plan = mirror.placements.get(asked)
+    if plan is None:
+        if len(mirror.placements) >= PLACEMENTS_MAX:
+            mirror.placements.clear()  # a client's constants inside summed expressions: each value is a placement
+        plan = mirror.placements[asked] = _place(shape, compared, trees, slots, mirror)
+    if isinstance(plan, str):
+        return "host", plan, None
+    consts = []
+    for d, (_, op, c) in zip(plan.compared, terms):
+        cc = _code_constant(d, op, c)
+        if cc is None:
+            return "host", "constant", None
+        consts.append(cc)
+    return "device", "", (plan, tuple(consts))
+
+
+def _place(shape: GroupedShape, compared, trees, slots, mirror):
+    """`grouped_route`'s rule past the statement's own constants: the
+    DevicePlan of (`compared` (path, operator) terms, summed `trees`,
+    `slots`) over `mirror`, or the reason (text) the host takes it."""
+    from surrealdb_tpu.ops.column_agg import GROUPS_MAX
+    from surrealdb_tpu.utils.num import next_pow2
+
+    summed = set().union(*(_tree_columns(t) for t in trees)) if trees else set()
+    polys = [_polynomial(t) for t in trees]
+    monos = list(dict.fromkeys(m for poly in polys if poly is not None for m in poly if m))
+    if None in polys or len(monos) > MONOMIALS_MAX:
+        return "expression"
+    want = {(p, "value") for p in summed} | {(p, "code") for p in shape.group_paths}
+    want |= {(p, "any") for p, _ in compared if p not in summed}
+    if not want:
+        return "no_column"  # a bare count(): the mirror's row count answers
+    dev, why = mirror.device_columns(want)
+    if dev is None:
+        return why
+    # an `any` column resolved to a form another asker named is that plane, once
+    held = {(p, d.form): d for (p, _), d in dev.items()}
+    columns = tuple(sorted(held))
+    at = {(p, form): columns.index((p, d.form)) for (p, form), d in dev.items()}
+    asked_as = [(p, "value") if p in summed else (p, "any") for p, _ in compared]
+    sizes = [len(dev[(p, "code")].values) for p in shape.group_paths]
+    total = int(np.prod(sizes, dtype=np.int64)) if sizes else 1
+    if total > GROUPS_MAX:
+        return "groups"
+    strides = tuple(int(np.prod(sizes[k + 1 :], dtype=np.int64)) for k in range(len(sizes)))
+    ranges = {p: (dev[(p, "value")].lo, dev[(p, "value")].hi) for p in summed}
+    for tree in trees:
+        lo, hi = _expr_bound(tree, ranges)
+        if max(abs(lo), abs(hi)) * mirror.n >= 1 << 63:
+            return "bound"
+    static = {
+        "pred": tuple((at[w], op) for w, (_, op) in zip(asked_as, compared)),
+        "keys": tuple(at[(p, "code")] for p in shape.group_paths),
+        "exprs": tuple(tuple(at[(p, "value")] for p in m) for m in monos),
+    }
+    groups = next_pow2(total)
+    return DevicePlan(
+        planes=tuple(held[w].plane for w in columns), columns=columns, static=static, groups=groups, strides=strides,
+        compared=[dev[w] for w in asked_as],
+        polys=[[poly.get(m, 0) for m in monos] + [poly.get((), 0)] for poly in polys],
+        keys=[dev[(p, "code")] for p in shape.group_paths], slots=list(slots), rows=mirror.n, serial=mirror.serial,
+        # everything a launch is apart from its riders' constants: the mirror build, the columns its planes
+        # hold (two statements of one shape over different columns are two launches), the shape over them
+        key=("colagg", mirror.serial, columns, static["pred"], static["keys"], static["exprs"], groups),
+    )
+
+
+def _device_runner(plan: DevicePlan):
+    """The dispatch runner of one bucket (mirror build, columns, statement
+    shape): every rider's constants as one [lanes, terms] operand of ONE
+    launch of ops/column_agg.py::grouped_aggregate, whatever the constants
+    are. The launch hands the device three small operands, the row count,
+    the strides and the constants, each its own transfer: one packed
+    operand made the launch phase shorter, the batches narrower and the
+    rate lower (PERF.md section 6, PR 40)."""
+
+    def run(payloads):
+        import zlib
+
+        from surrealdb_tpu import compile_log
+        from surrealdb_tpu.ops.column_agg import grouped_aggregate, unpack_results
+        from surrealdb_tpu.utils.num import count_lanes
+
+        static, groups = plan.static, plan.groups
+        n_rows, strides = np.int32(plan.rows), np.asarray(plan.strides or (0,), dtype=np.int32)
+        consts = np.zeros((count_lanes(len(payloads)), max(len(static["pred"]), 1)), dtype=np.int32)
+        for i, mine in enumerate(payloads):
+            consts[i, : len(mine)] = mine
+        sig = zlib.crc32(repr(sorted(static.items())).encode())
+        key = (plan.planes[0].shape[0], consts.shape[0], groups, len(plan.planes), sig)
+        with compile_log.tracked("column_agg", key):
+            out = grouped_aggregate(plan.planes, n_rows, consts, strides, groups=groups, **static)
+
+        def collect():
+            return unpack_results(np.asarray(out), len(payloads), groups, len(static["exprs"]))
+
+        collect.launch_labels = {"rows": plan.rows}
+        collect.outputs = (out,)
+        return collect
+
+    return run
+
+
+def _run_grouped_device(ctx, stm, shape: GroupedShape, plan: DevicePlan, consts, t_enter: float, stages) -> List[Any]:
+    """One grouped statement as ONE rider of one dispatch: submit its
+    constants under the plan's key, fold what comes back into the rows the
+    host route makes (groups in first-appearance order, Python-int sums,
+    mean = sum / count by the same division)."""
+    from surrealdb_tpu import tracing
+
+    _prepared("device", "", t_enter, shape, plan.rows, plan.groups)
+    t0 = _time.perf_counter()
+    counts, first, sums = ctx.ds().dispatch.submit(plan.key, consts, _device_runner(plan), depth=SWEEP_PIPELINE_DEPTH)
+    t_op = _time.perf_counter()  # the device operator has returned: the `materialise` span starts here
+    stages["device"] = {"rows": plan.rows, "ms": round((t_op - t0) * 1e3, 3)}
+    present = np.flatnonzero(counts > 0)
+    present = present[np.argsort(first[present], kind="stable")]
+    live, n = present.tolist(), counts.tolist()
+
+    def fold(poly) -> List[int]:
+        """An expression's sum a present group: its coefficients'
+        combination of the monomials' sums and the count, modulo 2**64,
+        read as signed (the route rule proved the true sum inside int64)."""
+        *coeffs, constant = poly
+        terms = [(c, mono) for c, mono in zip(coeffs, sums) if c]
+        out = []
+        for g in live:
+            v = (sum(c * mono[g] for c, mono in terms) + constant * n[g]) & ((1 << 64) - 1)
+            out.append(v - (1 << 64) if v >> 63 else v)
+        return out
+
+    totals = [fold(poly) for poly in plan.polys]
+    per_field: List[List[Any]] = []
+    for gf, slot in zip(shape.fields, plan.slots):
+        if gf.agg is None:
+            k = shape.group_paths.index(gf.path)
+            d, stride = plan.keys[k], plan.strides[k]
+            codes = (present // stride) % len(d.values)
+            per_field.append([_key_cell(d, int(c)) for c in codes])
+        elif gf.agg.kind == "count":
+            per_field.append([n[g] for g in live])
+        elif gf.agg.kind == "sum":
+            per_field.append(list(totals[slot]))
+        else:
+            per_field.append([t / n[g] for t, g in zip(totals[slot], live)])
+    stages["reduce"] = {"groups": int(present.size), "rows": int(counts.sum())}
+    out = _grouped_rows(ctx, stm, shape, per_field, int(present.size), stages)
+    tracing.record_span_into(tracing.current(), "materialise", {}, t_op, _time.perf_counter() - t_op)
+    return out
+
+
+def _key_cell(d, code: int):
+    """A group key's value from its code, typed as `cell_value` types it."""
+    v = d.values[code]
+    if d.tag == TAG_STR:
+        return v
+    if d.tag == TAG_DATETIME:
+        return Datetime(int(v))
+    return bool(v) if d.tag == TAG_BOOL else int(v)
+
+
 # ------------------------------------------------------------------ analysis ladder
 class Lowering:
     """One statement's resolved whole-pipeline lowering (grouped_shape OR
@@ -830,7 +1292,18 @@ def run_pipeline(ctx, stm, tb: str) -> Optional[Tuple[List[Any], dict]]:
 
     pc = active_plan_cache(ctx)
     cached = pc.lowering_for(ctx, stm) if pc is not None else None
-    t0 = _time.perf_counter()
+    t0 = t_enter = _time.perf_counter()
+    grouped = bool(getattr(stm, "group", None) or getattr(stm, "group_all", False))
+
+    def declined(reason: Optional[str]):
+        """The row path takes the statement: counted, and said on a grouped
+        statement's `column_prepare` span."""
+        if reason is not None:
+            _outcome(reason)
+            if grouped:
+                _prepared("row", reason, t_enter)
+        return None
+
     low = None
     if cached is not None:
         low = cached
@@ -852,9 +1325,7 @@ def run_pipeline(ctx, stm, tb: str) -> Optional[Tuple[List[Any], dict]]:
                 warm,
             )
         if low is None:
-            if reason is not None:
-                _outcome(reason)
-            return None
+            return declined(reason)
         if pc is not None:
             pc.install_pipeline(ctx, stm, low)
     else:
@@ -872,30 +1343,37 @@ def run_pipeline(ctx, stm, tb: str) -> Optional[Tuple[List[Any], dict]]:
         "grouped" if shape is not None else "ordered",
     )
     if mirror is None or strategy != "columnar":
-        _outcome("decline_mirror")
-        return None
+        return declined("decline_mirror")
 
     from surrealdb_tpu import telemetry
 
     doc_cache: dict = {}
     stages: Dict[str, dict] = {}
-    t0 = _time.perf_counter()
-    rows_idx = survivors(ctx, tb, mirror, compiled, cond, doc_cache)
-    if rows_idx is None:
-        _outcome("decline_columns")
-        return None
-    stages["mask"] = {
-        "rows": int(rows_idx.size), "ms": round((_time.perf_counter() - t0) * 1e3, 3),
-    }
-
+    route = "host"
     if shape is not None:
-        out = _run_grouped(ctx, stm, tb, mirror, shape, rows_idx, doc_cache, stages)
+        route, reason, placed = grouped_route(ctx, shape, compiled, mirror)
+    if route == "device":
+        out = _run_grouped_device(ctx, stm, shape, *placed, t_enter, stages)
     else:
-        out = _run_ordered(ctx, stm, tb, mirror, specs, ordered_proj, rows_idx, doc_cache, stages)
-    if out is None:
-        return None
+        if shape is not None:
+            _prepared("host", reason, t_enter, shape, mirror.n)
+        t0 = _time.perf_counter()
+        rows_idx = survivors(ctx, tb, mirror, compiled, cond, doc_cache)
+        if rows_idx is None:
+            _outcome("decline_columns")
+            return None
+        stages["mask"] = {
+            "rows": int(rows_idx.size), "ms": round((_time.perf_counter() - t0) * 1e3, 3),
+        }
+        if shape is not None:
+            out = _run_grouped(ctx, stm, tb, mirror, shape, rows_idx, doc_cache, stages)
+        else:
+            out = _run_ordered(ctx, stm, tb, mirror, specs, ordered_proj, rows_idx, doc_cache, stages)
+        if out is None:
+            return None
     telemetry.inc(
-        "column_pipeline", outcome="grouped" if shape is not None else "ordered"
+        "column_pipeline",
+        outcome="device" if route == "device" else "grouped" if shape is not None else "ordered",
     )
     # a columnar pipeline examines every mirrored row — it is a full scan
     # in columnar clothing, so the tenant meter sees the same rows_scanned
@@ -907,6 +1385,7 @@ def run_pipeline(ctx, stm, tb: str) -> Optional[Tuple[List[Any], dict]]:
         "table": tb,
         "plan": "ColumnPipeline",
         "strategy": "columnar-pipeline",
+        "route": route,
         "cost": cost_note,
         "stages": stages,
     }
@@ -981,15 +1460,7 @@ def _run_ordered(ctx, stm, tb, mirror, specs, proj, rows_idx, doc_cache, stages)
 
 
 def _run_grouped(ctx, stm, tb, mirror, shape, rows_idx, doc_cache, stages):
-    from surrealdb_tpu.dbs.iterator import apply_order, apply_start_limit
-
-    paths: Set[str] = set(shape.group_paths)
-    for gf in shape.fields:
-        if gf.agg is not None and gf.agg.path is not None:
-            paths.add(gf.agg.path)
-        elif gf.path is not None:
-            paths.add(gf.path)
-    cols = _columns_for(mirror, paths)
+    cols = _columns_for(mirror, _shape_paths(shape))
     if cols is None:
         _outcome("decline_columns")
         return None
@@ -1003,9 +1474,13 @@ def _run_grouped(ctx, stm, tb, mirror, shape, rows_idx, doc_cache, stages):
     per_field: List[List[Any]] = []
     for gf in shape.fields:
         if gf.agg is not None:
-            per_field.append(
-                segment_aggregate(ctx, tb, mirror, cols, gf.agg, rows_idx, inv, g, doc_cache)
-            )
+            try:
+                per_field.append(
+                    segment_aggregate(ctx, tb, mirror, cols, gf.agg, rows_idx, inv, g, doc_cache)
+                )
+            except _ExprDecline:
+                _outcome("decline_columns")
+                return None
         else:
             vals = []
             for k in range(g):
@@ -1016,6 +1491,16 @@ def _run_grouped(ctx, stm, tb, mirror, shape, rows_idx, doc_cache, stages):
         "groups": g, "rows": int(rows_idx.size),
         "ms": round((_time.perf_counter() - t0) * 1e3, 3),
     }
+    return _grouped_rows(ctx, stm, shape, per_field, g, stages)
+
+
+def _grouped_rows(ctx, stm, shape, per_field, g: int, stages):
+    """The result rows of a grouped statement from its per-field values (a
+    list a field, a value a group, groups in first-appearance order), then
+    the statement's ORDER BY and START / LIMIT: the one tail of the host and
+    the device route."""
+    from surrealdb_tpu.dbs.iterator import apply_order, apply_start_limit
+
     t0 = _time.perf_counter()
     out: List[Any] = []
     for k in range(g):
@@ -1030,6 +1515,19 @@ def _run_grouped(ctx, stm, tb, mirror, shape, rows_idx, doc_cache, stages):
         "rows": len(out), "ms": round((_time.perf_counter() - t0) * 1e3, 3),
     }
     return out
+
+
+def _shape_paths(shape: GroupedShape) -> Set[str]:
+    """Every column path a grouped shape reads."""
+    paths: Set[str] = set(shape.group_paths)
+    for gf in shape.fields:
+        if gf.agg is None:
+            paths.add(gf.path)
+        elif gf.agg.expr is not None:
+            paths |= _int_expr_columns(gf.agg.expr)
+        elif gf.agg.path is not None:
+            paths.add(gf.agg.path)
+    return paths
 
 
 def _assign(ctx, row: dict, f, v) -> None:
@@ -1054,7 +1552,7 @@ def explain_pipeline(ctx, stm, tb: str) -> Optional[dict]:
         detail["stages"] = ["mask", "factorize", "segment-reduce", "materialize"]
         detail["group"] = low.shape.group_paths or ["ALL"]
         detail["aggregates"] = [
-            f"{gf.agg.kind}({gf.agg.path or ''})"
+            f"{gf.agg.kind}({gf.agg.path or (repr(gf.agg.expr) if gf.agg.expr is not None else '')})"
             for gf in low.shape.fields
             if gf.agg
         ]
@@ -1166,8 +1664,8 @@ def partial_aggregate(
     sums, NaN min/max folds) reports exact=False and the coordinator falls
     back to the full gather-and-replay scatter. None = shape decline."""
     shape = grouped_shape(stm)
-    if shape is None:
-        return None
+    if shape is None or any(gf.agg is not None and gf.agg.expr is not None for gf in shape.fields):
+        return None  # an expression aggregate has no partial form: the full scatter answers
     out = _columnar_partials(ctx, tb, stm, shape, owner_ok)
     if out is not None:
         return out

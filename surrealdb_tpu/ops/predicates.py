@@ -33,7 +33,7 @@ from typing import Any, List, Optional, Set, Tuple
 
 import numpy as np
 
-from surrealdb_tpu.sql.ast import ArrayLit, BinaryOp, Expr, Literal, Param, UnaryOp
+from surrealdb_tpu.sql.ast import ArrayLit, BinaryOp, Cast, Expr, Literal, Param, UnaryOp
 from surrealdb_tpu.sql.path import Idiom, PField, PStart
 from surrealdb_tpu.sql.value import Datetime, is_none, is_null
 
@@ -69,6 +69,7 @@ def _depth_limit() -> int:
     return min(cnf.COLUMN_MIRROR_MAX_DEPTH, MATERIALIZED_DEPTH)
 
 _CMP_OPS = ("=", "!=", "<", "<=", ">", ">=")
+DEVICE_OPS = ("=", "<", "<=", ">", ">=")  # what ops/column_agg.py compares a plane with
 
 
 class _Node:
@@ -139,6 +140,21 @@ class CompiledPredicate:
 
         walk(self.root)
         return (self.source, tuple(consts))
+
+    def device_terms(self) -> Optional[List[Tuple[str, str, Any]]]:
+        """The predicate's device form: [(path, operator, constant)] where it
+        is a conjunction of comparisons (DEVICE_OPS) of a column with a
+        constant, the constants this binding's own VALUES; None for anything
+        else, which keeps the host mask."""
+        terms: List[Tuple[str, str, Any]] = []
+
+        def walk(n: _Node) -> bool:
+            if isinstance(n, _Bool):
+                return n.op == "and" and all(walk(k) for k in n.kids)
+            terms.append((n.path, n.op, n.const))
+            return n.op in DEVICE_OPS
+
+        return terms if walk(self.root) else None
 
     def evaluate(self, columns) -> Tuple[np.ndarray, np.ndarray]:
         """columns: {path: Column} covering self.paths (idx/column_mirror)."""
@@ -266,6 +282,10 @@ def _lower_path(e) -> Optional[str]:
 def _is_const(e) -> bool:
     if isinstance(e, (Literal, Param)):
         return True
+    if isinstance(e, Cast):
+        # a cast of a constant is a constant: `<datetime> $q.d`, what a JSON
+        # client writes for a date, is folded once a statement
+        return _is_const(e.expr)
     if isinstance(e, ArrayLit):
         return all(_is_const(x) for x in e.items)
     if isinstance(e, Idiom) and len(e.parts) > 1 and isinstance(e.parts[0], PStart):
@@ -280,8 +300,16 @@ def _is_const(e) -> bool:
     return False
 
 
+_UNFOLDABLE = object()  # a constant whose evaluation raised: no scalar, so the row path reports it
+
+
 def _const_value(ctx, e):
-    return e.compute(ctx)
+    from surrealdb_tpu.err import TypeError_
+
+    try:
+        return e.compute(ctx)
+    except TypeError_:  # a cast that fails fails on the row path, with its own error
+        return _UNFOLDABLE
 
 
 def _scalar_const(v) -> bool:

@@ -66,6 +66,7 @@ HIERARCHY: Dict[str, int] = {
     "idx.ft.state": 44,        # FT mirror state (RLock)
     "idx.ft.upload": 45,       # one FT generation's upload, once (never under idx.ft.state)
     "idx.column.registry": 46, # column-mirror registry (RLock)
+    "idx.column.device": 47,   # one column mirror's device forms: encode + upload, once a column
     "idx.graph.registry": 48,  # graph-mirror registry (RLock)
     "idx.graph.mirror": 50,    # one graph mirror's adjacency state
     "idx.graph.interner": 51,  # Thing <-> dense-int node mapping

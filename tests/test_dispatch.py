@@ -553,6 +553,46 @@ def test_pipeline_depth_bounds_inflight_batches():
     assert q.stats()["pipeline_wait_s"] > 0
 
 
+def test_a_family_names_its_own_pipeline_depth():
+    """`submit(..., depth=1)`: that key's bucket is one deep whatever the
+    knob says (a sweep whose cost does not grow with its riders), so the
+    riders that arrive while a batch is in flight ride the NEXT one
+    together; a key that names no depth keeps the knob's."""
+    from surrealdb_tpu.dbs.dispatch import DispatchQueue
+
+    q = DispatchQueue(pipeline_depth=4)
+    launched, release, widths = threading.Event(), threading.Event(), []
+
+    def runner(xs):
+        widths.append(len(xs))
+        launched.set()
+
+        def collect():
+            assert release.wait(10)
+            return [x * 10 for x in xs]
+
+        return collect
+
+    results = {}
+    ts = [threading.Thread(target=lambda: results.__setitem__(1, q.submit("sweep", 1, runner, depth=1)))]
+    ts[0].start()
+    assert launched.wait(5)
+    for i in (2, 3, 4):
+        ts.append(threading.Thread(target=lambda i=i: results.__setitem__(i, q.submit("sweep", i, runner, depth=1))))
+        ts[-1].start()
+    t_end = time.time() + 5
+    while q.stats()["submitted"] < 4 and time.time() < t_end:
+        time.sleep(0.001)
+    time.sleep(0.2)
+    assert widths == [1]  # the bucket is one deep: nothing launches beside the batch in flight
+    release.set()
+    for t in ts:
+        t.join(10)
+    assert widths == [1, 3] and results == {1: 10, 2: 20, 3: 30, 4: 40}
+    assert q.submit("other", 5, lambda xs: [x * 10 for x in xs]) == 50
+    assert {k: b.depth for k, b in q._buckets.items()} == {"sweep": 1, "other": 4}
+
+
 def test_collect_phase_transient_failure_split_retried(monkeypatch):
     """A transient failure in the COLLECT phase of a wide two-phase batch
     goes through the same bisection as a launch failure."""
