@@ -637,7 +637,7 @@ class _Loop:
         payload = bytes(buf[off:off + n])
         del buf[: off + n]
         if key:
-            payload = bytes(b ^ key[i % 4] for i, b in enumerate(payload))
+            payload = wsproto.apply_mask(payload, key)
         ws = conn.ws
         if op == wsproto.OP_CLOSE:
             self.enqueue_write(conn, wsproto.encode_frame(wsproto.OP_CLOSE, b""))

@@ -3,7 +3,9 @@
 The reference uses tokio-tungstenite (reference: src/rpc/connection.rs); the
 stdlib has no WebSocket support, so the handshake and frame codec live here.
 Only the features the RPC protocol needs: text/binary frames, ping/pong,
-close, client-side masking.
+close, client-side masking. `apply_mask` is the one place a payload meets
+its 4-byte masking key: `encode_frame`, `read_frame` and the event loop's
+`net/loop.py::_ws_frames` all call it.
 """
 
 from __future__ import annotations
@@ -15,6 +17,8 @@ import socket
 import struct
 from typing import Optional, Tuple
 
+import numpy as np
+
 GUID = "258EAFA5-E914-47DA-95CA-C5AB0DC85B11"
 
 OP_CONT, OP_TEXT, OP_BINARY, OP_CLOSE, OP_PING, OP_PONG = 0x0, 0x1, 0x2, 0x8, 0x9, 0xA
@@ -23,6 +27,17 @@ OP_CONT, OP_TEXT, OP_BINARY, OP_CLOSE, OP_PING, OP_PONG = 0x0, 0x1, 0x2, 0x8, 0x
 def accept_key(client_key: str) -> str:
     digest = hashlib.sha1((client_key + GUID).encode()).digest()
     return base64.b64encode(digest).decode()
+
+
+def apply_mask(payload, key: bytes) -> bytes:
+    """RFC 6455 §5.3: octet i of the payload XOR octet i mod 4 of the key,
+    in one pass over the whole buffer (masking and unmasking are the same
+    operation). The whole 32-bit words go through one vectorised XOR with
+    the key as a word of the same byte order; the 0-3 bytes left are
+    paired with the key's first bytes."""
+    n = len(payload)
+    words = np.frombuffer(payload, np.uint32, n >> 2) ^ np.frombuffer(key, np.uint32)
+    return words.tobytes() + bytes(map(int.__xor__, payload[n & ~3:], key))
 
 
 def encode_frame(opcode: int, payload: bytes, mask: bool = False) -> bytes:
@@ -40,7 +55,7 @@ def encode_frame(opcode: int, payload: bytes, mask: bool = False) -> bytes:
     if mask:
         key = os.urandom(4)
         head += key
-        payload = bytes(b ^ key[i % 4] for i, b in enumerate(payload))
+        payload = apply_mask(payload, key)
     return bytes(head) + payload
 
 
@@ -78,7 +93,7 @@ def read_frame(sock) -> Tuple[int, bytes]:
         key = _read_exact(sock, 4) if masked else None
         data = _read_exact(sock, n) if n else b""
         if key:
-            data = bytes(b ^ key[i % 4] for i, b in enumerate(data))
+            data = apply_mask(data, key)
         if op != OP_CONT:
             opcode = op
         payload += data
