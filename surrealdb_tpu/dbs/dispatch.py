@@ -7,9 +7,9 @@ queries IN — requests against the same index mirror coalesce into one
 batched kernel launch, amortizing the per-dispatch round trip (launch,
 execute, download) across every waiting query.
 
-Leader–follower protocol, no artificial batching window: the first request
-on an idle bucket becomes the leader and immediately dispatches everything
-queued (initially just itself). While its batch is on device, later arrivals
+Leader–follower protocol, no batching window (but see `gather` below): the
+first request on an idle bucket becomes the leader and immediately dispatches
+everything queued (initially just itself). While its batch is on device, later arrivals
 enqueue; when the leader finishes its launch phase it hands the bucket to
 the next queued request, which dispatches the accumulated batch. Batching
 therefore emerges exactly when dispatch latency exceeds arrival spacing — a
@@ -34,6 +34,16 @@ Throughput hardening (the scale-1.0 concurrent-kNN collapse fixes):
   launcher + unbounded-collect hand-off and removes convoying behind a
   slow leader under sustained multi-client load.
 
+- **A family's own depth, and gathering**: submit(depth=) fixes a bucket's
+  depth where the family knows better than the knob: a sweep, whose launch
+  costs the device the same whatever its riders, asks for SWEEP_DEPTH (1).
+  One deep, sessions in a closed loop ride alternate batches, and a rider
+  that arrives a moment after its group's launch waits a whole cycle for
+  the next. submit(gather=True) on a one-deep bucket lets the leader wait
+  for riders on their way: until the queue is as wide as the batch before
+  last, at most the time one launch phase takes (_gather). A lone session
+  never waits; a rider that has left costs the wait once.
+
 - **Memory-aware split-retry**: a batch that fails transiently
   (RESOURCE_EXHAUSTED and friends) is NOT re-executed at full width.
   Batches wider than cnf.DISPATCH_SPLIT_FLOOR are bisected and the halves
@@ -56,7 +66,8 @@ download). The bucket is handed to the next leader right after the launch
 phase returns, so the pipeline depth above is measured launch-to-collect.
 What a runner has to say about its launch rides on that callable, as
 attributes: a dict `launch_labels` (the graph count runners: `lanes`, the
-padded lane count) joins `batch` on every rider's `dispatch_launch` span,
+padded lane count, and for a sparse count `sweeps`, the hops its kernel
+swept) joins `batch` on every rider's `dispatch_launch` span,
 and `outputs`, the device arrays the collect will read, lets the queue wait
 for the device itself (each array's `copy_to_host_async()`, then each
 array's `block_until_ready()`) before it calls the closure: the collect then splits into `dispatch_ready_wait` (the
@@ -74,7 +85,7 @@ the host knows), else `launching_s` (a leader is in its launch phase:
 uploads and look-ups while the device waits, then the jitted call, after
 whose return the device may already run while the runner finishes), else
 `handoff_s` (requests wait and no leader runs: promotion, wake-up, the
-depth semaphore, the interpreter lock), else `empty_s` (no statement has
+depth semaphore, a gathering leader's wait, the interpreter lock), else `empty_s` (no statement has
 reached the queue). The four sums of two stats() snapshots differ by the
 wall time between them. A synchronous runner (its result is no callable)
 has its whole run counted as `launching_s`, and so has a split-retry's
@@ -151,16 +162,45 @@ class _Req:
         self.tenant = accounting.current_tenant()
 
 
-class _Bucket:
-    __slots__ = ("lock", "queue", "launching", "sem", "depth")
+# The pipeline depth a family names for a bucket whose launch costs the device
+# the same whatever its riders: a sweep of a whole table (ops/pipeline.py:
+# 1.440 ms at 1 rider and at 8 over 3.0M rows, PERF.md section 6, PR 40) or
+# of a graph operator (idx/graph_csr.py: PR 42). The statements that arrive
+# while a sweep is in flight ride the next one together, where a second sweep
+# beside it would take some of them, wait behind the first on the device
+# anyway, and cost the host a launch, a collect and a read-back of its own
+# under the interpreter lock.
+SWEEP_DEPTH = 1
 
-    def __init__(self, depth: int):
+
+class _Bucket:
+    __slots__ = ("lock", "queue", "launching", "sem", "depth",
+                 "gather", "arrived", "awaiting", "widths", "launches")
+
+    def __init__(self, depth: int, gather: bool = False):
         self.lock = _locks.Lock("dispatch.bucket")
         self.queue: List[_Req] = []
         self.launching = False  # exactly one leader in the launch phase
         self.depth = depth
         # bounds launched-but-not-collected batches (the pipeline depth)
         self.sem = threading.BoundedSemaphore(depth)
+        # a one-deep bucket that gathers (DispatchQueue._gather; all under
+        # `lock`): the widths of its last two batches, the seconds of its
+        # last five launch phases, and whether its leader waits for riders
+        self.gather = gather and depth == 1
+        self.arrived = threading.Condition(self.lock)
+        self.awaiting = False
+        self.widths: List[int] = []
+        self.launches: List[float] = []
+
+    def launch_s(self) -> float:
+        """What a launch phase of this bucket costs the host: the mean of
+        its last five without the longest and the shortest (a compile or a
+        collection inside one does not count), the shortest of fewer than
+        three, 0 before the first."""
+        xs = sorted(self.launches)
+        xs = xs[1:-1] if len(xs) >= 3 else xs[:1]
+        return sum(xs) / len(xs) if xs else 0.0
 
 
 class DispatchQueue:
@@ -255,34 +295,38 @@ class DispatchQueue:
         self._reported = sums
         return due
 
-    def _bucket(self, key: Hashable, depth: Optional[int]) -> _Bucket:
+    def _bucket(self, key: Hashable, depth: Optional[int], gather: bool) -> _Bucket:
         with self._lock:
             # the queue counters + bucket map are one guarded unit
             # (sanitizer-declared: stats() diffs depend on their atomicity)
             _locks.assert_held(self._lock, "dispatch.counters")
             b = self._buckets.get(key)
             if b is None:
-                b = self._buckets[key] = _Bucket(depth or self._depth())
+                b = self._buckets[key] = _Bucket(depth or self._depth(), gather)
             self.submitted += 1
             self._move(queued=1)
             return b
 
     def submit(
         self, key: Hashable, payload: Any, runner: Callable[[Sequence[Any]], Sequence[Any]],
-        depth: Optional[int] = None,
+        depth: Optional[int] = None, gather: bool = False,
     ) -> Any:
         """`depth`: the pipeline depth of `key`'s bucket where the family
         knows better than the knob (fixed when the bucket is first touched).
-        A launch whose device cost does not grow with its riders (a sweep of
-        a whole table, ops/pipeline.py) asks for 1: a second sweep in flight
-        beside the first would only split between two what one serves."""
-        b = self._bucket(key, depth)
+        A launch whose device cost does not grow with its riders (a sweep)
+        asks for SWEEP_DEPTH: a second sweep in flight beside the first
+        would only split between two what one serves. `gather` (of a
+        one-deep bucket, fixed with it): its leader waits a moment for the
+        riders of its group (_gather)."""
+        b = self._bucket(key, depth, gather)
         req = _Req(payload, runner)
         with b.lock:
             b.queue.append(req)
             leader = not b.launching
             if leader:
                 b.launching = True
+            elif b.awaiting:
+                b.arrived.notify()
         if not leader:
             req.event.wait()
             if not req.promoted:
@@ -320,8 +364,12 @@ class DispatchQueue:
         waited = _time.perf_counter() - t_sem
         try:
             with b.lock:
+                if b.gather:
+                    self._gather(b)
                 width = min(len(b.queue), self._max_width())
                 batch, b.queue = b.queue[:width], b.queue[width:]
+                if b.gather:
+                    b.widths = (b.widths + [width])[-2:]
             finish = self._launch(batch, b, waited) if batch else None
             with b.lock:
                 if b.queue:
@@ -337,6 +385,33 @@ class DispatchQueue:
                 finish()
         finally:
             b.sem.release()
+
+    @staticmethod
+    def _gather(b: _Bucket) -> None:
+        """The leader of a one-deep bucket, the device free, waits for the
+        riders of its group (caller holds b.lock). One deep, sessions in a
+        closed loop ride alternate batches: while one batch is in flight the
+        riders of the batch before it are on their way back, and one that
+        arrives a moment after the launch rides the next batch, a whole
+        cycle (launch, kernel, hand-over) later: the tail of a cell whose
+        kernel is shorter than its riders' way back. So the leader waits
+        until the queue is as wide as the batch before last was, at most one
+        launch phase (_Bucket.launch_s): where a batch costs the
+        same at any width, a rider is worth that wait, since the launch of
+        its own it would otherwise need costs the host as much. A rider
+        that does not come costs the wait once: the width that launched is
+        what the batch after next expects. A lone session never waits."""
+        want = b.widths[0] if len(b.widths) == 2 else 0
+        if len(b.queue) < want:
+            deadline = _time.perf_counter() + b.launch_s()
+            b.awaiting = True
+            try:
+                while len(b.queue) < want:
+                    left = deadline - _time.perf_counter()
+                    if left <= 0 or not b.arrived.wait(left):
+                        break
+            finally:
+                b.awaiting = False
 
     def _charge_batch(self, batch: List[_Req], elapsed: float, meter: str) -> None:
         """Tenant accounting: split one batch phase's elapsed time EQUALLY
@@ -445,6 +520,9 @@ class DispatchQueue:
             return None
         finally:
             elapsed = _time.perf_counter() - t0
+            if b.gather:
+                with b.lock:
+                    b.launches = (b.launches + [elapsed])[-5:]
             with self._lock:
                 _locks.assert_held(self._lock, "dispatch.counters")
                 self.launch_s += elapsed

@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from surrealdb_tpu import key as keys, telemetry
+from surrealdb_tpu.dbs.dispatch import SWEEP_DEPTH
 from surrealdb_tpu.key.encode import prefix_end
 from surrealdb_tpu.sql.value import Thing
 from surrealdb_tpu.utils.byte_cache import ByteBudgetCache
@@ -221,7 +222,8 @@ class PointerCsr:
 
 
 def _served(
-    form: str, t_enter: Optional[float], operand: Optional[str] = None, filter: str = "none"
+    form: str, t_enter: Optional[float], operand: Optional[str] = None, filter: str = "none",
+    first_hop: Optional[str] = None,
 ) -> None:
     """One count chain served by `form` (`dense`, `csc` or `host`): the
     `graph_count_form` counter and the `form` label of the statement's
@@ -229,7 +231,12 @@ def _served(
     disagree. A `csc` count also says which `operand` its kernel swept
     (`composed`: node->node operators in a table's compact id space;
     `records`: the record-level mirrors in the shared id space), to the
-    `graph_csc_operand` counter and the span's `operand` label alike; and
+    `graph_csc_operand` counter and the span's `operand` label alike; a
+    `csc` count that launches says where the frontier after its first hop
+    came from (`first_hop`, to the `graph_csc_first_hop` counter's `how` and
+    the span's label: `rows`, read on the host from the first operator's
+    source-sorted rows, so the kernel sweeps one hop less; `sweep`, the
+    kernel swept for it from the seeds); and
     every count says what became of a predicate on the chain (`filter`, to
     the `graph_count_filter` counter's `route` and the span's label: `none`,
     the chain has no WHERE; `fused`, the predicate on its final node part
@@ -238,7 +245,8 @@ def _served(
     span closes here, at the dispatch submit (for a count no dispatcher
     carries: where its fused chain starts): hop specs, frontier, work
     estimate, the dense form's refusal, operand look-ups (and, on a first
-    statement, the builds inside them) since chain_count's entry. A `host`
+    statement, the builds inside them), the first hop's rows since
+    chain_count's entry. A `host`
     count is known for one only when its walk has ended, so there the span
     holds the whole count; a reader of preparation time leaves it out."""
     telemetry.inc("graph_count_form", form=form)
@@ -247,6 +255,9 @@ def _served(
     if operand is not None:
         telemetry.inc("graph_csc_operand", operand=operand)
         labels["operand"] = operand
+    if first_hop is not None:
+        telemetry.inc("graph_csc_first_hop", how=first_hop)
+        labels["first_hop"] = first_hop
     if t_enter is not None:
         telemetry.stage(
             "graph_prepare", t_enter, _time.perf_counter() - t_enter, **labels
@@ -313,19 +324,21 @@ def _weights_into(cptr: np.ndarray, csrc: np.ndarray, passing: np.ndarray, size:
     return np.bincount(csrc[_slots(starts, cptr[passing + 1] - starts)], minlength=size).astype(np.int32)
 
 
-def _collect_counts(out, riders: int, lanes: int):
+def _collect_counts(out, riders: int, lanes: int, sweeps: Optional[int] = None):
     """The collect phase of a batched count launched at `lanes` lanes: the
     riders' counts off the device. The `graph_count_lanes` counter and the
     `lanes` label the dispatcher puts on every rider's `dispatch_launch`
     span come from this one argument (the pattern of _served): riders over
-    lanes is the fill. `outputs` hands the dispatcher the array to wait
-    for, so the collect's time splits into the device's and the read-back."""
+    lanes is the fill. A sparse count's launch also says how many hops its
+    kernel swept (`sweeps`). `outputs` hands the dispatcher the array to
+    wait for, so the collect's time splits into the device's and the
+    read-back."""
     telemetry.inc("graph_count_lanes", lanes=lanes)
 
     def collect():
         return np.asarray(out)[:riders].tolist()
 
-    collect.launch_labels = {"lanes": lanes}
+    collect.launch_labels = {"lanes": lanes} if sweeps is None else {"lanes": lanes, "sweeps": sweeps}
     collect.outputs = (out,)
     return collect
 
@@ -362,10 +375,51 @@ def _slots(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
     return np.repeat(starts - (np.cumsum(lens) - lens), lens) + np.arange(int(lens.sum()))
 
 
+# The longest pad a composed operator's rows are read at. The kernel densifies
+# a frontier with one scatter update a (lane, pad slot), ~9 ns each on a v5e
+# (PERF.md section 6, PR 42): at 4,096 that is 0.3 ms at 8 lanes beside the
+# 3.2 ms of the sweep it saves; a pad of 65,536 costs more than the sweep.
+ROW_PAD_MAX = 4096
+
+
+def _row_pad(longest: int) -> int:
+    """The size a composed operator's rows pad to when a count reads its
+    first hop from them: the power of two at or above the longest row, or
+    0 (no such read: every count sweeps from its seeds) where a hub's row
+    passes ROW_PAD_MAX."""
+    pad = _next_pow2(max(longest, 1))
+    return pad if pad <= ROW_PAD_MAX else 0
+
+
+def _seed_rows(op: dict, fr: np.ndarray, cw: np.ndarray):
+    """The weighted frontier one hop past the seeds `fr` (local ids, weights
+    `cw`), read from the composed operator `op`'s source-sorted rows
+    (`by_src`: `indptr`, `dst`; _csc_pair): every seed's row with the
+    seed's weight on each entry, row after row, as two [row_pad] int32
+    arrays like _local_seeds' (pad slots at the sentinel `n_pad`, weight
+    0). A destination two paths reach stands twice: the kernel's
+    densifying scatter adds them, and int32 sums wrap the same in any
+    order. Whole-array NumPy: one gather of the rows' slots. None where
+    the rows together are longer than `row_pad`."""
+    (indptr, dst), pad = op["by_src"], op["row_pad"]
+    starts = indptr[fr]
+    lens = indptr[fr + 1] - starts
+    total = int(lens.sum())
+    if total > pad:
+        return None
+    out = np.full(pad, op["n_pad"], dtype=np.int32)
+    w = np.zeros(pad, dtype=np.int32)
+    out[:total] = dst[_slots(starts, lens)]
+    w[:total] = np.repeat(cw, lens)
+    return out, w
+
+
 def _compose_coo(ip1, ix1, ip2, ix2, space_src: dict, space_dst: dict, max_paths: int):
     """src -> mid -> dst through two CSR mirrors over the shared id space, as
     COO `(local_src, local_dst)` in the two tables' compact ids: one entry a
-    2-hop path, a repeated path a repeated entry. Whole-array NumPy (the
+    2-hop path, a repeated path a repeated entry, in source order (the
+    first mirror's edges stand by global source id, and a table's compact
+    ids rise with its global ids: table_space). Whole-array NumPy (the
     second mirror's degrees repeat the first mirror's edges), so a million
     paths are a few array passes. None past `max_paths`, told from the
     degrees before any path is laid out."""
@@ -999,7 +1053,9 @@ class GraphMirrors:
         `->edge->node` OUT-pair over built mirrors, at every lane count the
         serving runners can return (utils/num.py::count_lane_set: 8, 16,
         32, 64 at the dispatcher's width cap of 64) and the frontier pad
-        they use — so a post-ingest burst of count-chain queries of any
+        they use (a sparse count of composed operators past its first pair:
+        the operator's row pad, and a sweep less) — so a post-ingest burst
+        of count-chain queries of any
         width starts on pre-compiled shapes (the r6 scale-1.0 log showed
         84.8s/26.4s first-query stalls that were exactly these compiles).
         Results are discarded; zero-weight lanes are harmless."""
@@ -1076,7 +1132,14 @@ class GraphMirrors:
                 if cop is not None:
                     n_cap, last_hop = cop["n_pad"], ((cop["indptr"],),)
                     hop = ((cop["cptr"], cop["csrc"]),)
-                    chains = [(hop,) * (c - 1) for c in range(1, max_pairs + 1)]
+                    # both entries of _csc_chain_count: from the seeds (one
+                    # pair; more where the seeds' rows pass the pad), and
+                    # from their rows of the first operator, at its pad and
+                    # a sweep less
+                    swept = [(fsz, c - 1) for c in range(1, max_pairs + 1)]
+                    if cop["row_pad"]:
+                        swept += [(cop["row_pad"], c - 2) for c in range(2, max_pairs + 1)]
+                    chains = [(pad, (hop,) * n) for pad, n in dict.fromkeys(swept)]
                 else:
                     m1 = self._hop_mirrors(ns, db, spec1)
                     m2 = self._hop_mirrors(ns, db, spec2)
@@ -1088,23 +1151,23 @@ class GraphMirrors:
                     # `->et->tb` repeated c times = 2c specs; the final
                     # spec is a degree reduction (no CSC)
                     chains = [
-                        tuple(
+                        (fsz, tuple(
                             ((csc1,) if i % 2 == 0 else (csc2,))
                             for i in range(2 * c - 1)
-                        )
+                        ))
                         for c in range(1, max_pairs + 1)
                     ]
                 # end weights ride the composed operand alone
                 no_end = None if cop is None else jnp.zeros(n_cap, dtype=jnp.int32)
                 for lanes in lane_set:
-                    frs = jnp.asarray(np.full((lanes, fsz), n_cap, dtype=np.int32))
-                    cws = jnp.asarray(np.zeros((lanes, fsz), dtype=np.int32))
                     endings = [(last_hop, None)] + ([((), (no_end,) * lanes)] if cop is not None else [])
                     for last, ends in endings:
-                        for csc_hops in chains:
+                        for pad, csc_hops in chains:
+                            frs = jnp.asarray(np.full((lanes, pad), n_cap, dtype=np.int32))
+                            cws = jnp.asarray(np.zeros((lanes, pad), dtype=np.int32))
                             with compile_log.tracked(
                                 "graph_csc",
-                                _csc_shape_key(lanes, fsz, n_cap, csc_hops, last, ends is not None),
+                                _csc_shape_key(lanes, pad, n_cap, csc_hops, last, ends is not None),
                                 prewarmed=True,
                             ):
                                 csc_kernel(csc_hops, last, frs, cws, n_cap=n_cap, end_weights=ends)
@@ -1296,7 +1359,15 @@ class GraphMirrors:
         where the record-level mirrors cost two hops over the shared id
         space (persons AND edge records: 2,097,152 slots for 24,328 persons
         at SNB SF3). A repeated path stays a repeated entry: int32 sums wrap
-        the same in any order. Cached a generation like the dense operator,
+        the same in any order. Beside them, on the host, the operator by
+        source (`by_src`: that `indptr` and the destinations in
+        _compose_coo's source order, so a node's row is one slice) and
+        `row_pad`, the power of two at or above its longest row (_row_pad:
+        0 where a hub's row passes ROW_PAD_MAX): what a
+        count that leaves from its seeds reads its first hop from
+        (_csc_chain_count). Rows and swept arrays are made together, one
+        generation's, so an acknowledged RELATE makes both anew. Cached a
+        generation like the dense operator,
         the refusal too. None when no single operator spans the pair, or
         when the operator would hold more entries than the two mirrors it
         composes (a hop through a NODE table multiplies in-degrees by
@@ -1338,6 +1409,8 @@ class GraphMirrors:
                 indptr=jnp.asarray(indptr),
                 space_src=sp_s,
                 by_dst=(cptr, csrc),
+                by_src=(indptr, ld.astype(np.int32)),
+                row_pad=_row_pad(int(np.diff(indptr).max())),
             )
             # asynchronous, as device_csc()'s: the host's hand-off
             telemetry.stage(
@@ -1502,9 +1575,21 @@ class GraphMirrors:
         One kernel either way, chosen from what the chain is. A predicate
         on the final node part (`end`) rides the composed operand alone, as
         _dense_chain_count's does: the end weights live in the node
-        table's compact ids."""
-        import jax.numpy as jnp
+        table's compact ids.
 
+        Over composed operators the first pair is not swept for where the
+        seeds' rows of it fit its `row_pad`: a sweep from a seed is a pass
+        over every slot of the operator for one adjacency row, which the
+        operator's source-sorted rows hold as a slice (_seed_rows). The
+        kernel then takes the frontier after one hop and sweeps the pairs
+        between the first and the last (none for a chain of two pairs: a
+        degree or end-weight reduction over the compact frontier). The
+        frontier pads to `row_pad`, which the operator fixes, so every rider
+        of a generation shares one bucket and one program whatever its
+        seed's degree. Seeds whose rows together pass the pad (a FROM of
+        many records), and every seed of an operator whose longest row is
+        too long to pad to (_row_pad), are swept from, as every
+        records-operand count is."""
         from surrealdb_tpu import cnf
 
         fsz = _next_pow2(max(frontier.size, cnf.TPU_GRAPH_FRONTIER_PAD))
@@ -1515,7 +1600,7 @@ class GraphMirrors:
         ):
             ops = None
         weighted = end is not None
-        endw, how = None, "fused" if weighted else "none"
+        endw, how, first_hop = None, "fused" if weighted else "none", "sweep"
         if ops is not None:
             operand, n_cap = "composed", ops[0]["n_pad"]
             fr, cw, seeded = _local_seeds(
@@ -1528,7 +1613,11 @@ class GraphMirrors:
                 endw = self._filtered(end, ops[-1], n_cap)
                 if endw is None:
                     return _UNFUSED
-            csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in ops[:-1])
+            swept = ops[:-1]
+            rows = _seed_rows(ops[0], fr[:seeded], cw[:seeded]) if swept and ops[0]["row_pad"] else None
+            if rows is not None:
+                (fr, cw), fsz, swept, first_hop = rows, ops[0]["row_pad"], swept[1:], "rows"
+            csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in swept)
             last_hop = () if weighted else ((ops[-1]["indptr"],),)
         elif weighted:
             return _UNFUSED
@@ -1549,10 +1638,11 @@ class GraphMirrors:
             last_hop = tuple((m.device_arrays()[0],) for m in hop_mirrors[-1])
         _kernels()
         batch_kernel = _JITTED["chain_count_batch"]
-        # the operands' ids: only chains over the same arrays coalesce (a
-        # weighted batch's riders bring their own ends, whatever they bound)
+        # the frontier's pad, what is swept and the operands' ids: only
+        # chains over the same arrays coalesce (a weighted batch's riders
+        # bring their own ends, whatever they bound)
         key = (
-            "gchain", fsz, n_cap, len(specs),
+            "gchain", fsz, n_cap, len(csc_hops),
             tuple(id(a) for hop in csc_hops for pair in hop for a in pair),
             "w" if weighted else tuple(id(p) for (p,) in last_hop),
         )
@@ -1566,15 +1656,25 @@ class GraphMirrors:
                 "graph_csc",
                 _csc_shape_key(len(frs), fsz, n_cap, csc_hops, last_hop, weighted),
             ):
-                out = batch_kernel(
-                    csc_hops, last_hop,
-                    jnp.asarray(frs), jnp.asarray(cws),
-                    n_cap=n_cap, end_weights=ends,
-                )
-            return _collect_counts(out, len(payloads), len(frs))
+                # the NumPy lanes as they are: the jitted call puts them on
+                # the device itself, where two jnp.asarray in front of it
+                # gave the interpreter lock up and took it back twice more
+                # (a launch phase 4.1 -> 2.7 ms under eight sessions)
+                out = batch_kernel(csc_hops, last_hop, frs, cws, n_cap=n_cap, end_weights=ends)
+            return _collect_counts(out, len(payloads), len(frs), sweeps=len(csc_hops))
 
-        _served("csc", t_enter, operand, filter=how)
-        return dispatch.submit(key, (fr, cw, endw) if weighted else (fr, cw), runner)
+        _served("csc", t_enter, operand, filter=how, first_hop=first_hop)
+        # from the rows, one sweep left: a kernel shorter than its riders'
+        # way back through the host, so the bucket is one deep and gathers
+        # (dbs/dispatch.py::SWEEP_DEPTH, _gather; PERF.md section 6, PR 42).
+        # Every count that keeps the sweep from its seeds keeps the queue's
+        # own depth, as does one with two sweeps left (the device clocks it:
+        # a second batch in flight keeps it fed) or none (a small program)
+        paced = first_hop == "rows" and len(csc_hops) == 1
+        return dispatch.submit(
+            key, (fr, cw, endw) if weighted else (fr, cw), runner,
+            depth=SWEEP_DEPTH if paced else None, gather=paced,
+        )
 
     def _device_chain(
         self, ns, db, frontier: np.ndarray, counts: np.ndarray, specs,
@@ -1696,10 +1796,16 @@ class GraphMirrors:
             and self._chain_work_estimate(ns, db, specs, counts)
             >= cnf.TPU_GRAPH_COUNT_EDGES
         ):
-            # big count chain: straight to device from the seed — the whole
-            # chain is one tiny-upload batched dispatch (no host hops means
-            # no GIL serialization across concurrent clients, and every
-            # query shares one compiled shape so they coalesce)
+            # big count chain: to the device in one tiny-upload batched
+            # dispatch, with no hop walked over the live `adj` dicts
+            # (_host_hop: Python an edge under the mirror's lock, which
+            # serializes concurrent clients, and frontiers whose padded size
+            # differs a rider, so they would not coalesce). The dense form
+            # and the record-level sweep leave from the seed itself; the
+            # sweep of composed operators leaves from the seeds' rows of the
+            # first operator, a whole-array slice of one generation's arrays
+            # padded to a size the operator fixes (_csc_chain_count): every
+            # rider still shares one compiled shape
             res = self._device_chain(
                 ns, db, frontier, counts, specs,
                 count_only=True, dispatch=dispatch, t_enter=t_enter, end=end,

@@ -74,6 +74,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from surrealdb_tpu import cnf
+from surrealdb_tpu.dbs.dispatch import SWEEP_DEPTH
 from surrealdb_tpu.ops.predicates import (
     F64_EXACT_INT,
     ORD_OF_TAG,
@@ -911,12 +912,6 @@ def segment_aggregate(
 # floors the device costs such a statement up to 1.5 ms and the host would
 # cost a Q1 up to 90, so the lower floor stands.
 DEVICE_MIN_ROWS = 1024
-# A sweep costs the device the same whatever its riders (1.440 ms at 1 rider and at 8 over 3.0M rows: PERF.md
-# section 6, PR 40), so its bucket's pipeline is one deep: the statements that arrive while a sweep is in
-# flight ride the next one together, where a second sweep beside it would take some of them and leave the rest
-# a third. What a dispatch costs the host (a launch, a collect, a read-back under the interpreter lock) is then
-# shared by more riders.
-SWEEP_PIPELINE_DEPTH = 1
 PLACEMENTS_MAX = 64  # placements a mirror build keeps (grouped_route)
 MONOMIALS_MAX = 16  # distinct products of columns a launch sums: Q1's five expressions expand to six
 
@@ -1153,7 +1148,7 @@ def _run_grouped_device(ctx, stm, shape: GroupedShape, plan: DevicePlan, consts,
 
     _prepared("device", "", t_enter, shape, plan.rows, plan.groups)
     t0 = _time.perf_counter()
-    counts, first, sums = ctx.ds().dispatch.submit(plan.key, consts, _device_runner(plan), depth=SWEEP_PIPELINE_DEPTH)
+    counts, first, sums = ctx.ds().dispatch.submit(plan.key, consts, _device_runner(plan), depth=SWEEP_DEPTH)
     t_op = _time.perf_counter()  # the device operator has returned: the `materialise` span starts here
     stages["device"] = {"rows": plan.rows, "ms": round((t_op - t0) * 1e3, 3)}
     present = np.flatnonzero(counts > 0)

@@ -498,7 +498,7 @@ def test_a_shape_is_placed_once_a_mirror_build_and_its_sweeps_are_one_deep(small
     later = small.ds.column_mirrors.get(("t", "t", "lineitem"))
     assert later is not mirror and len(later.placements) == 1
     depths = {k[0]: b.depth for k, b in small.ds.dispatch._buckets.items()}
-    assert depths == {"colagg": pipeline.SWEEP_PIPELINE_DEPTH} and pipeline.SWEEP_PIPELINE_DEPTH == 1
+    assert depths == {"colagg": pipeline.SWEEP_DEPTH} and pipeline.SWEEP_DEPTH == 1
 
 
 def test_a_reason_is_placed_too_and_expression_constants_cannot_fill_the_mirror(small, monkeypatch):

@@ -615,3 +615,101 @@ def test_collect_phase_transient_failure_split_retried(monkeypatch):
     results, errors = _coalesce_batch(q, 4, runner)
     assert errors == {} and results == {i: i * 10 for i in range(1, 5)}
     assert q.stats()["splits"] == 1
+
+
+# ------------------------------------------------------------------ a one-deep bucket that gathers
+def _gathering(launch_s: float):
+    """A queue, the widths its batches launched at, a submit into its one
+    gathering bucket, and the waits its leaders made for riders."""
+    from surrealdb_tpu.dbs.dispatch import SWEEP_DEPTH
+
+    q, widths, waits = DispatchQueue(), [], []
+
+    def runner(xs):
+        widths.append(len(xs))
+        time.sleep(launch_s)  # the launch phase: what a rider is worth waiting for
+        return lambda: [x * 10 for x in xs]
+
+    def submit(x):
+        got = q.submit("g", x, runner, depth=SWEEP_DEPTH, gather=True)
+        bucket = q._buckets["g"]
+        if not hasattr(bucket.arrived, "counted"):
+            wait = bucket.arrived.wait
+
+            def counted(timeout=None):
+                waits.append(timeout)
+                return wait(timeout)
+
+            bucket.arrived.wait = bucket.arrived.counted = counted
+        return got
+
+    return q, widths, waits, submit
+
+
+def _together(submit, xs, gap_s: float = 0.0):
+    """Submit `xs` from a thread each, `gap_s` apart; their results."""
+    results = {}
+    ts = []
+    for x in xs:
+        ts.append(threading.Thread(target=lambda x=x: results.__setitem__(x, submit(x))))
+        ts[-1].start()
+        time.sleep(gap_s)
+    for t in ts:
+        t.join(10)
+    return results
+
+
+def test_a_gathering_leader_waits_for_the_rider_of_the_batch_before_last():
+    q, widths, waits, submit = _gathering(0.1)
+    assert submit(1) == 10  # alone; the bucket exists from here, and its waits are counted
+    # 2 leads alone and holds the bucket through its launch; 3 and 4 queue behind it and ride together
+    assert _together(submit, [2, 3, 4], gap_s=0.03) == {2: 20, 3: 30, 4: 40}
+    assert widths == [1, 1, 2] and waits == []
+    bucket = q._buckets["g"]
+    assert bucket.widths == [1, 2] and bucket.launch_s() == pytest.approx(0.1, rel=0.5)
+    assert submit(5) == 50 and waits == []  # the batch before last was 1 wide: nobody to wait for
+    assert bucket.widths == [2, 1]
+    # the batch before last was 2 wide: 6 waits for a second rider, and 7, a moment later, rides with it
+    assert _together(submit, [6, 7], gap_s=0.03) == {6: 60, 7: 70}
+    assert widths == [1, 1, 2, 1, 2] and len(waits) >= 1 and 0 < waits[0] <= bucket.launch_s() * 1.5
+    assert q.stats()["dispatches"] == 5 and q.stats()["submitted"] == 7
+
+
+def test_a_rider_that_does_not_come_costs_one_launch_phase_once():
+    q, widths, waits, submit = _gathering(0.1)
+    submit(1)
+    _together(submit, [2, 3, 4], gap_s=0.03)
+    submit(5)
+    bucket = q._buckets["g"]
+    assert bucket.widths == [2, 1] and waits == []
+    t0 = time.perf_counter()
+    assert submit(6) == 60  # expects a second rider, as the batch before last had: none comes
+    waited = time.perf_counter() - t0 - 0.1
+    assert len(waits) == 1 and waited >= 0.5 * waits[0] and bucket.widths == [1, 1]
+    for x in (7, 8, 9):
+        assert submit(x) == x * 10  # and nobody waits again: the widths that launched are what is expected
+    assert len(waits) == 1 and widths == [1, 1, 2, 1, 1, 1, 1, 1]
+
+
+def test_a_lone_session_never_waits_and_only_a_one_deep_bucket_gathers():
+    q, widths, waits, submit = _gathering(0.0)
+    assert [submit(x) for x in range(6)] == [x * 10 for x in range(6)]
+    assert waits == [] and widths == [1] * 6
+    run = lambda xs: [x * 10 for x in xs]
+    assert q.submit("two_deep", 1, run, depth=2, gather=True) == 10
+    assert q.submit("the_knob_s", 1, run, gather=True) == 10
+    assert q.submit("plain", 1, run, depth=1) == 10
+    assert {k: (b.depth, b.gather) for k, b in q._buckets.items()} == {
+        "g": (1, True), "two_deep": (2, False), "the_knob_s": (cnf.DISPATCH_PIPELINE_DEPTH, False), "plain": (1, False)}
+
+
+@pytest.mark.parametrize("launches, cost", [
+    ([], 0.0), ([1.5], 1.5), ([1.5, 0.002], 0.002),  # a first launch that compiled: the shortest of fewer than three
+    ([1.5, 0.002, 0.004], 0.004), ([0.002, 0.003, 0.004, 0.9, 0.001], 0.003),  # neither the longest nor the shortest
+], ids=["none", "one", "two", "three", "five"])
+def test_what_a_launch_costs_a_gathering_bucket_leaves_out_its_longest_and_shortest(launches, cost):
+    from surrealdb_tpu.dbs.dispatch import _Bucket
+
+    b = _Bucket(1, gather=True)
+    b.launches = launches
+    assert b.launch_s() == pytest.approx(cost)

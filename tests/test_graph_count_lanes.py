@@ -58,14 +58,14 @@ class HeldQueue(DispatchQueue):
         super().__init__()
         self.started, self.release = threading.Event(), threading.Event()
 
-    def submit(self, key, payload, runner):
+    def submit(self, key, payload, runner, **bucket):
         def held(payloads):
             if not self.started.is_set():
                 self.started.set()
                 assert self.release.wait(30)
             return runner(payloads)
 
-        return super().submit(key, payload, held)
+        return super().submit(key, payload, held, **bucket)
 
     def queued(self) -> int:
         return sum(len(b.queue) for b in list(self._buckets.values()))
@@ -114,10 +114,12 @@ def lane_counter() -> dict:
     return {int(dict(k)["lanes"]): int(v) for k, v in telemetry.counters_matching("graph_count_lanes").items()}
 
 
+# `swept`: what a sparse count's launch says beside its lanes. Three seeds of 119 friends each pass the
+# composed operator's row pad (128), so the kernel sweeps from the seeds: two pairs, or five record-level specs
 ROUTES = {
-    "dense_limbs": {"form": "dense", "dense_max": None, "compose": True},
-    "csc_composed": {"form": "csc", "dense_max": 1, "compose": True},
-    "csc_records": {"form": "csc", "dense_max": 1, "compose": False},
+    "dense_limbs": {"form": "dense", "dense_max": None, "compose": True, "swept": {}},
+    "csc_composed": {"form": "csc", "dense_max": 1, "compose": True, "swept": {"sweeps": "2"}},
+    "csc_records": {"form": "csc", "dense_max": 1, "compose": False, "swept": {"sweeps": "5"}},
 }
 
 
@@ -145,9 +147,9 @@ def test_the_same_int32_at_every_lane_count_on_every_device_route(monkeypatch, g
     assert forms() == {how["form"]: riders + 1}
     # the lead alone, then the rest in one dispatch at the lanes they fill
     assert q.width_distribution() == ({1: 2} if riders == 1 else {1: 1, riders: 1})
-    assert launch_labels(0) == {"batch": "1", "lanes": "8"}
+    assert launch_labels(0) == {"batch": "1", "lanes": "8", **how["swept"]}
     for i in range(1, riders + 1):
-        assert launch_labels(i) == {"batch": str(riders), "lanes": str(count_lanes(riders))}
+        assert launch_labels(i) == {"batch": str(riders), "lanes": str(count_lanes(riders)), **how["swept"]}
     assert lane_counter() == Counter([8, count_lanes(riders)])  # one a dispatch, from the value the label has
     shapes = {int(e["shape"].split("x")[0]) for e in compile_log.events()}
     assert shapes == {8, count_lanes(riders)}

@@ -190,7 +190,8 @@ def test_span_and_counter_name_one_operand_and_the_form_stays_csc(ds, monkeypatc
     for i, start in enumerate((0, 3, 9)):
         got, spans = traced_count(ds, sess, start, f"operand-{i}")
         assert got == walk_count(n, edges, {start: 1}, 3)
-        assert [s["labels"] for s in named(spans, "graph_prepare")] == [{"form": "csc", "filter": "none", "operand": "composed"}]
+        assert [s["labels"] for s in named(spans, "graph_prepare")] == [
+            {"form": "csc", "filter": "none", "operand": "composed", "first_hop": "rows"}]
     # a person nobody relates from is not in the table's space: no seed, no dispatch, still a csc count
     assert forms() == {"csc": 3} and operands() == {"composed": 3}
     assert {e["subsystem"] for e in compile_log.events()} == {"graph_csc"}
@@ -249,7 +250,7 @@ def test_a_hop_over_two_edge_tables_sweeps_the_records_exactly(monkeypatch):
                                t_enter=0.0)
     assert got == walk_count(n, np.concatenate([knows, follows]), seeds, 2)
     (span,) = named(tracing.get_trace("two-tables")["spans"], "graph_prepare")
-    assert span["labels"] == {"form": "csc", "filter": "none", "operand": "records"}
+    assert span["labels"] == {"form": "csc", "filter": "none", "operand": "records", "first_hop": "sweep"}
     assert operands() == {"records": 1} and gm._csc == {}
 
 

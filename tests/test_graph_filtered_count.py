@@ -128,7 +128,8 @@ def test_a_filtered_count_is_the_walk_that_ends_at_a_passing_person(loaded, edge
     for start in (0, 7, 11):
         got, prepare, filters = ask(ds, sess, sql, {"p": start, **bound}, f"{route}-{predicate}-{start}")
         assert got == walk_ending(edges, start, pred)
-        assert prepare == {"form": FORM[route], "filter": "fused", **({"operand": "composed"} if route == "csc_composed" else {})}
+        assert prepare == {"form": FORM[route], "filter": "fused",
+                           **({"operand": "composed", "first_hop": "rows"} if route == "csc_composed" else {})}
         # the first statement makes the weights, the next two find them
         assert [f["outcome"] for f in filters] == ["build" if start == 0 else "hit"]
         assert int(filters[0]["rows"]) == sum(pred(person(i)) for i in range(N))
@@ -196,7 +197,9 @@ def test_riders_that_bind_different_values_share_one_dispatch(loaded, edges, mon
     assert q.width_distribution() == ({1: 2} if riders == 1 else {1: 1, riders: 1})
     launches = [[s["labels"] for s in tracing.get_trace(f"rider-{i}")["spans"] if s["name"] == "dispatch_launch"]
                 for i in range(1, riders + 1)]
-    assert launches == [[{"batch": str(riders), "lanes": "16" if riders == 9 else "8"}]] * riders
+    # the sparse count reads its first hop from the operator's rows: one swept hop of three pairs
+    swept = {"sweeps": "1"} if route == "csc_composed" else {}
+    assert launches == [[{"batch": str(riders), "lanes": "16" if riders == 9 else "8", **swept}]] * riders
     assert routes_counted() == {"fused": riders + 1}
 
 
@@ -377,7 +380,9 @@ def test_the_bare_count_runs_the_program_it_ran_with_the_inputs_it_had(loaded, e
     assert kwargs.get("end_weights") is None
     # the last pair's out-degrees (dense) / its source-side indptr (csc): what the count always ended in
     assert args[1] is not None and (route == "dense_limbs" or len(args[1]) == 1)
-    assert args[2].shape == args[3].shape == (8, 256)
+    # seeds at the frontier pad (dense); their rows at the pad the sparse operator fixes (person 0 has 300 friends)
+    assert args[2].shape == args[3].shape == ((8, 256) if route == "dense_limbs" else (8, 512))
+    assert route == "dense_limbs" or (len(args[0]) == 1 and prepare["first_hop"] == "rows")
     (event,) = served_compiles()
     assert not event["shape"].endswith("w")
     # and a filtered count of the same chain ends in weights, under a shape of its own

@@ -146,9 +146,10 @@ def test_a_record_level_operand_of_any_slot_count_counts_what_the_walk_and_the_d
 
 # ------------------------------------------------------------------ shapes
 def shape_key(op: dict, lanes: int = 8) -> tuple:
+    """As a count of three pairs from one start person is served: the seed's
+    row of the operator at the operator's row pad, and one swept hop."""
     hop = ((op["cptr"], op["csrc"]),)
-    fsz = next_pow2(cnf.TPU_GRAPH_FRONTIER_PAD)
-    return graph_csr._csc_shape_key(lanes, fsz, op["n_pad"], (hop, hop), ((op["indptr"],),))
+    return graph_csr._csc_shape_key(lanes, op["row_pad"], op["n_pad"], (hop,), ((op["indptr"],),))
 
 
 def served_compiles() -> list:
@@ -171,9 +172,11 @@ def test_a_relate_inside_a_quantum_keeps_the_compiled_shape_and_one_across_it_ch
         assert ask(ds, sess, BARE, {"p": 0}, f"after-{i}")[0] == bare_walk(edges, 0)
         keys_seen.append(shape_key(composed(ds)))
     distinct = sorted(set(keys_seen), key=keys_seen.index)
-    # (lanes, frontier, n_cap, (cptr, csrc) a hop, (indptr,)): only the csrc entries may move
-    assert [k[3] for k in distinct] == [(513, s, 513, s) for s in slots]
-    assert {k[:3] + k[4:] for k in distinct} == {(8, next_pow2(cnf.TPU_GRAPH_FRONTIER_PAD), 512, (513,))}
+    # (lanes, row pad, n_cap, (cptr, csrc) a hop, (indptr,)): only the csrc entries may move (these RELATEs
+    # lengthen no row past the power of two over the longest)
+    assert [k[3] for k in distinct] == [(513, s) for s in slots]
+    (rest,) = {k[:3] + k[4:] for k in distinct}
+    assert rest == (8, composed(ds)["row_pad"], 512, (513,)) and rest[1] < next_pow2(cnf.TPU_GRAPH_FRONTIER_PAD)
     assert keys_seen == sorted(keys_seen)  # and never back
     # what the served counts compiled: one program a slot count
     assert served_compiles() == ["x".join(str(s) for s in k) for k in distinct]
