@@ -973,176 +973,6 @@ def gl013(modules: List[Module]) -> List[Finding]:
     return out
 
 
-# ------------------------------------------------------------------ GL014
-# The advisor proposal store (surrealdb_tpu/advisor.py) has ONE
-# construction door: advisor.propose(). It owns the stable-id derivation,
-# the re-arm-vs-duplicate lifecycle, the kind/evidence validation and the
-# lock discipline (mutate under advisor.store, emit proposal events only
-# after release). The rule has two halves, mirroring GL012/GL013 on the
-# store side and GL009 on the call side: (a) outside advisor.py, touching
-# any private member of the advisor module is a finding; (b) every
-# propose() call site must name a STATIC kind that is registered in
-# advisor.KINDS (imported from the real module so the static and runtime
-# checks can never drift) and must pass a non-empty `evidence=` argument
-# — a proposal without a resolvable evidence chain is an opinion.
-GL014_ALLOWED_FILES = frozenset({"surrealdb_tpu/advisor.py"})
-GL014_ADVISOR_MODULE = "surrealdb_tpu.advisor"
-GL014_PRIVATE = frozenset(
-    {"_store", "_lock", "_expired_ring", "_evicted", "_sweeps",
-     "_last_sweep", "_counter_base", "_digest", "_expire_missing"}
-)
-
-
-def _gl014_advisor_aliases(m: Module) -> Set[str]:
-    """Every local NAME the advisor module is bound to in this file
-    (mirrors _gl013_acct_aliases)."""
-    out: Set[str] = set()
-    for node in ast.walk(m.tree):
-        if isinstance(node, ast.Import):
-            for a in node.names:
-                if a.name == GL014_ADVISOR_MODULE and a.asname:
-                    out.add(a.asname)
-        elif isinstance(node, ast.ImportFrom) and node.module:
-            for a in node.names:
-                if (
-                    f"{node.module}.{a.name}" == GL014_ADVISOR_MODULE
-                    or (a.name == "advisor" and node.module == "surrealdb_tpu")
-                ):
-                    out.add(a.asname or a.name)
-    return out
-
-
-def _gl014_propose_aliases(m: Module) -> Set[str]:
-    """Direct-import aliases of the door itself:
-    `from surrealdb_tpu.advisor import propose (as p)`."""
-    out: Set[str] = set()
-    for node in ast.walk(m.tree):
-        if (
-            isinstance(node, ast.ImportFrom)
-            and node.module == GL014_ADVISOR_MODULE
-        ):
-            for a in node.names:
-                if a.name == "propose":
-                    out.add(a.asname or a.name)
-    return out
-
-
-def _gl014_registry() -> Optional[Set[str]]:
-    """The declared proposal-kind registry, imported from the real module
-    (the GL009 pattern); None skips the kind check if the engine is
-    unimportable — lint must not require a working engine."""
-    try:
-        from surrealdb_tpu.advisor import KINDS
-
-        return set(KINDS)
-    except Exception:  # noqa: BLE001
-        return None
-
-
-@_rule("GL014", "advisor proposals constructed outside advisor.propose() "
-                "or with an unregistered kind / missing evidence")
-def gl014(modules: List[Module]) -> List[Finding]:
-    kinds = _gl014_registry()
-    out: List[Finding] = []
-    for m in modules:
-        if m.rel in GL014_ALLOWED_FILES:
-            continue
-        aliases = _gl014_advisor_aliases(m)
-        propose_names = _gl014_propose_aliases(m)
-        for node in ast.walk(m.tree):
-            # (a) private store access outside advisor.py
-            if isinstance(node, ast.Attribute):
-                if node.attr not in GL014_PRIVATE:
-                    continue
-                via_alias = (
-                    isinstance(node.value, ast.Name)
-                    and node.value.id in aliases
-                )
-                via_dotted = _gl012_dotted(node.value) == GL014_ADVISOR_MODULE
-                if not (via_alias or via_dotted):
-                    continue
-                out.append(
-                    Finding(
-                        "GL014", m.rel, node.lineno, node.col_offset,
-                        f"advisor.{node.attr} accessed outside advisor.py — "
-                        "proposals must go through advisor.propose() (the "
-                        "one door that keeps the stable-id lifecycle, the "
-                        "kind/evidence validation and the lock discipline "
-                        "honest)",
-                        f"GL014:{m.rel}:{m.enclosing_def(node)}:{node.attr}",
-                    )
-                )
-                continue
-            # (b) propose() call-site hygiene
-            if not isinstance(node, ast.Call):
-                continue
-            recv, attr = _call_name(node)
-            is_propose = (
-                attr == "propose" and recv in aliases
-            ) or (recv is None and attr in propose_names)
-            if not is_propose:
-                continue
-            kind_arg = node.args[0] if node.args else None
-            if kind_arg is None:
-                for kw in node.keywords:
-                    if kw.arg == "kind":
-                        kind_arg = kw.value
-            if not (
-                isinstance(kind_arg, ast.Constant)
-                and isinstance(kind_arg.value, str)
-            ):
-                out.append(
-                    Finding(
-                        "GL014", m.rel, node.lineno, node.col_offset,
-                        "advisor.propose with a DYNAMIC kind — proposal "
-                        "kinds are a closed registry (advisor.KINDS); use "
-                        "a static registered string and put the variable "
-                        "part in the subject",
-                        f"GL014:{m.rel}:{m.enclosing_def(node)}:dynamic-kind",
-                    )
-                )
-            elif kinds is not None and kind_arg.value not in kinds:
-                out.append(
-                    Finding(
-                        "GL014", m.rel, node.lineno, node.col_offset,
-                        f"advisor.propose kind {kind_arg.value!r} is not in "
-                        "the advisor.KINDS registry — register it (with a "
-                        "description) before proposing",
-                        f"GL014:{m.rel}:kind:{kind_arg.value}",
-                    )
-                )
-            evidence = None
-            for kw in node.keywords:
-                if kw.arg == "evidence":
-                    evidence = kw.value
-                if kw.arg is None:
-                    evidence = evidence or True  # **kwargs: can't see inside
-            if evidence is None:
-                out.append(
-                    Finding(
-                        "GL014", m.rel, node.lineno, node.col_offset,
-                        "advisor.propose without an evidence= argument — a "
-                        "proposal without a resolvable evidence chain is "
-                        "an opinion, not a proposal",
-                        f"GL014:{m.rel}:{m.enclosing_def(node)}:no-evidence",
-                    )
-                )
-            elif (
-                isinstance(evidence, (ast.List, ast.Tuple))
-                and not evidence.elts
-            ):
-                out.append(
-                    Finding(
-                        "GL014", m.rel, node.lineno, node.col_offset,
-                        "advisor.propose with an EMPTY evidence list — at "
-                        "least one {plane, metric, window, value, "
-                        "threshold} entry is required",
-                        f"GL014:{m.rel}:{m.enclosing_def(node)}:empty-evidence",
-                    )
-                )
-    return out
-
-
 # ------------------------------------------------------------------ GL015
 # The plan/pipeline cache (surrealdb_tpu/dbs/plan_cache.py) has ONE write
 # door: the PlanCache methods themselves (fetch/observe/install_*/
@@ -1151,7 +981,7 @@ def gl014(modules: List[Module]) -> List[Finding]:
 # events/counters only after release) and the validation-on-serve
 # contract — generation/epoch/scope stamps checked on every serve. An
 # ad-hoc writer reaching into the private tables (`_entries`, `_gen`,
-# route maps, the timing windows) would bypass both and could serve a
+# route maps) would bypass both and could serve a
 # stale plan, the one failure mode the cache is built to make impossible.
 # Outside plan_cache.py, touching any private member of the module OR of
 # a PlanCache INSTANCE (any attribute chain ending in `.plan_cache`, the
@@ -1160,9 +990,9 @@ GL015_ALLOWED_FILES = frozenset({"surrealdb_tpu/dbs/plan_cache.py"})
 GL015_PC_MODULE = "surrealdb_tpu.dbs.plan_cache"
 GL015_PRIVATE = frozenset(
     {"_entries", "_warm", "_by_stmt", "_index_defs", "_gen", "_inflight",
-     "_epoch", "_timing", "_hits", "_misses", "_invalidations", "_verifies",
+     "_epoch", "_hits", "_misses", "_invalidations", "_verifies",
      "_evlog", "_lock", "_caches", "_serve_digest", "_serve_lexed",
-     "_route_for", "_emit_evict", "_note_timing"}
+     "_route_for", "_emit_evict"}
 )
 
 

@@ -105,7 +105,7 @@ timeout -k 10 300 env JAX_PLATFORMS=cpu \
   tests/test_kvs.py tests/test_e2e_crud.py tests/test_cluster.py \
   tests/test_bulk_ingest_v2.py tests/test_faults.py \
   tests/test_cluster_obs.py tests/test_elastic.py \
-  tests/test_stats.py tests/test_accounting.py tests/test_advisor.py \
+  tests/test_stats.py tests/test_accounting.py \
   tests/test_tombstone_gc.py tests/test_plan_cache.py \
   -q -p no:cacheprovider -p no:xdist -p no:randomly >/tmp/_t1_sanitize.log 2>&1
 san_rc=$?

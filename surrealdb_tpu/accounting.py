@@ -29,13 +29,13 @@ Surfaces: system-gated ``GET /tenants`` (``?cluster=1`` federates
 node-tagged member stores), the debug bundle's ``tenants`` section
 and ``INFO FOR ROOT``.
 
-Budgets are observe-only (the advisor's observe->propose contract):
+Budgets are observe-only:
 ``SURREAL_TENANT_BUDGET_{CPU_S,DISPATCH_S,ROWS,BYTES}`` define soft
 limits — a plain float applies to every tenant, ``ns:limit[,ns:limit]``
 per namespace. A meter crossing its limit FROM BELOW emits one
 ``tenant.budget_exceeded`` event (trace-linked to the crossing
 statement, kept resolvable via force_keep) and bumps
-``tenant_budget_breaches{ns}`` — proposals, never enforcement.
+``tenant_budget_breaches{ns}`` — never enforcement.
 
 Lock discipline: ``accounting.store`` is a leaf in locks.HIERARCHY
 (mutate-and-release); events/telemetry side effects are emitted AFTER

@@ -52,7 +52,6 @@ KIND_DEADLINES: Dict[str, float] = {
     "index_build": 900.0,
     "cluster_read_repair": 60.0,
     "cluster_tombstone_gc": 120.0,
-    "advisor": 60.0,
 }
 
 _STATES = ("scheduled", "running", "done", "failed", "stalled")

@@ -57,15 +57,13 @@ versioned document — the artifact you attach to any perf report:
                      time, rows and bytes, bg-task and scatter cost —
                      with global conservation totals, store size and
                      eviction count (new in bundle/7).
-15. `advisor`      — the advisor plane (advisor.py): live evidence-
-                     chained tuning proposals (observe-only), the
-                     proposal-kind catalog, the expired ring and sweep
-                     health (new in bundle/8).
-16. `plan_cache`   — the fingerprint-keyed plan & pipeline cache
+15. `plan_cache`   — the fingerprint-keyed plan & pipeline cache
                      (dbs/plan_cache.py): hit/miss/invalidation
-                     counters by cause, entry/variant/route counts,
-                     per-fingerprint warm-vs-cold pre-kernel timings
+                     counters by cause, entry/variant/route counts
                      and the recent eviction log (new in bundle/9).
+16. `net`          — the network plane (net/loop.py, net/qos.py): live
+                     event-loop servers and the per-tenant weighted-fair
+                     admission state.
 
 Served by `GET /debug/bundle` (system-user-gated) and embedded via
 `INFO FOR ROOT` (`system.bundle`). Works
@@ -84,13 +82,13 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
-BUNDLE_SCHEMA = "surrealdb-tpu-bundle/10"
+BUNDLE_SCHEMA = "surrealdb-tpu-bundle/11"
 
 # the sections every consumer may rely on
 SECTIONS = (
     "traces", "slow_queries", "errors", "tasks", "compiles", "engine",
     "locks", "faults", "events", "kernel_audit", "flow_audit",
-    "statements", "profiler", "tenants", "advisor", "plan_cache", "net",
+    "statements", "profiler", "tenants", "plan_cache", "net",
 )
 
 
@@ -98,8 +96,8 @@ def debug_bundle(
     ds=None, trace_limit: int = 50, full_traces: int = 10
 ) -> Dict[str, Any]:
     from surrealdb_tpu import (
-        accounting, advisor, bg, compile_log, events, faults, profiler,
-        stats, telemetry, tracing,
+        accounting, bg, compile_log, events, faults, profiler, stats,
+        telemetry, tracing,
     )
     from surrealdb_tpu.utils import locks
 
@@ -131,7 +129,6 @@ def debug_bundle(
         "statements": stats.snapshot(),
         "profiler": profiler.report(),
         "tenants": accounting.snapshot(),
-        "advisor": advisor.snapshot(),
         "plan_cache": ds.plan_cache.snapshot()
         if ds is not None
         else {"enabled": False, "available": False},

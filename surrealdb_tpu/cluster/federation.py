@@ -183,48 +183,13 @@ def federated_tenants(ds, limit: int = 50, sort: str = "exec_s") -> list:
 def _unreachable_markers(gathered: Dict[str, Any], errors: Dict[str, str]) -> list:
     """One `{node, unreachable, error}` marker per member that produced
     no payload — the list-shaped twin of federated_bundle's per-node
-    marker, shared by /statements, /tenants and /advisor."""
+    marker, shared by /statements and /tenants."""
     return [
         {"node": nid, "unreachable": True,
          "error": errors.get(nid, "no payload")}
         for nid, payload in gathered.items()
         if payload is None
     ]
-
-
-def federated_advisor(ds, limit: int = 50) -> dict:
-    """`GET /advisor?cluster=1`: every member's live proposals, DEDUPED
-    by stable proposal id — the id is a digest of (kind, subject), so the
-    same condition observed from two nodes is ONE record tagged
-    `nodes=[...]` (evidence kept from the most-recently-seen reporter;
-    two nodes' evidence chains cite the same planes but each node's own
-    measurements, and fabricating a merged value would break the
-    resolve-in-artifact contract). Dead members are marked unreachable."""
-    gathered, errors = _gather(ds, "advisor", {"limit": limit})
-    by_id: Dict[str, dict] = {}
-    for nid in sorted(gathered.keys()):
-        entries = gathered[nid]
-        if not isinstance(entries, list):
-            continue
-        for e in entries:
-            if not isinstance(e, dict) or not e.get("id"):
-                continue
-            cur = by_id.get(e["id"])
-            if cur is None:
-                by_id[e["id"]] = dict(e, nodes=[nid])
-            else:
-                cur["nodes"].append(nid)
-                if (e.get("last_seen_ts") or 0) > (cur.get("last_seen_ts") or 0):
-                    nodes = cur["nodes"]
-                    by_id[e["id"]] = dict(e, nodes=nodes)
-    merged = sorted(
-        by_id.values(),
-        key=lambda r: (-(r.get("last_seen_ts") or 0), r["id"]),
-    )[: max(int(limit), 1)]
-    return {
-        "proposals": merged,
-        "unreachable": _unreachable_markers(gathered, errors),
-    }
 
 
 def federated_events(

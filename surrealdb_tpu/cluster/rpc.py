@@ -372,20 +372,6 @@ def _op_tenants(ds, req):
     return {"json": _json.dumps(out, default=str)}
 
 
-def _op_advisor(ds, req):
-    """This node's live advisor proposals for the federated
-    `/advisor?cluster=1` merge (advisor plane, advisor.py): records ride
-    node-UNtagged — the coordinator dedups by stable proposal id and
-    tags each merged record with the member ids that reported it."""
-    from surrealdb_tpu import advisor
-
-    limit = req.get("limit")
-    out = advisor.export_state(
-        limit=int(limit) if limit is not None else 100
-    )
-    return {"json": _json.dumps(out, default=str)}
-
-
 def _op_member_update(ds, req):
     """Elastic membership: prepare / commit / abort one epoch change
     (cluster/membership.py drives the two-phase flow)."""
@@ -485,7 +471,6 @@ _OPS = {
     "events": _op_events,
     "statements": _op_statements,
     "tenants": _op_tenants,
-    "advisor": _op_advisor,
     # elastic membership + convergent repair
     "member_update": _op_member_update,
     "membership": _op_membership,

@@ -315,7 +315,7 @@ PROFILE_MAX_STACKS = _env_int("SURREAL_PROFILE_MAX_STACKS", 512)
 # fingerprint drill-down entries per tenant). Budgets are OBSERVE-ONLY
 # soft limits: a plain float applies to every tenant, "ns:limit[,...]"
 # per namespace; a crossing emits tenant.budget_exceeded + bumps
-# tenant_budget_breaches{ns} — proposals, never enforcement.
+# tenant_budget_breaches{ns} — never enforcement.
 TENANT_ACCOUNTING = _env_bool("SURREAL_TENANT_ACCOUNTING", True)
 TENANT_STORE_SIZE = _env_int("SURREAL_TENANT_STORE_SIZE", 256)
 TENANT_FP_CAP = _env_int("SURREAL_TENANT_FP_CAP", 32)
@@ -323,27 +323,6 @@ TENANT_BUDGET_CPU_S = os.environ.get("SURREAL_TENANT_BUDGET_CPU_S", "")
 TENANT_BUDGET_DISPATCH_S = os.environ.get("SURREAL_TENANT_BUDGET_DISPATCH_S", "")
 TENANT_BUDGET_ROWS = os.environ.get("SURREAL_TENANT_BUDGET_ROWS", "")
 TENANT_BUDGET_BYTES = os.environ.get("SURREAL_TENANT_BUDGET_BYTES", "")
-
-# Advisor plane (advisor.py): the observe->propose half of a self-driving
-# engine. A supervised `bg:advisor` sweep re-derives evidence-chained
-# tuning proposals every ADVISOR_INTERVAL secs from the stats/accounting/
-# telemetry/vector/cluster planes — OBSERVE-ONLY, nothing is applied. A
-# proposal re-arms while its evidence persists and expires after
-# ADVISOR_EXPIRE_SWEEPS consecutive sweeps without it. The analyzer
-# thresholds: MIN_CALLS gates every per-fingerprint rule, SCAN_ROWS is
-# the per-call scanned-rows break-even floor for index.create,
-# DECLINE_MIN the per-sweep mirror-decline drift floor, SKEW_RATIO the
-# max/mean per-node scatter skew for cluster.rebalance, BREACH_MIN the
-# budget-breach recurrence floor.
-ADVISOR = _env_bool("SURREAL_ADVISOR", True)
-ADVISOR_INTERVAL_SECS = _env_float("SURREAL_ADVISOR_INTERVAL", 5.0)
-ADVISOR_STORE_SIZE = _env_int("SURREAL_ADVISOR_STORE_SIZE", 128)
-ADVISOR_EXPIRE_SWEEPS = _env_int("SURREAL_ADVISOR_EXPIRE_SWEEPS", 3)
-ADVISOR_MIN_CALLS = _env_int("SURREAL_ADVISOR_MIN_CALLS", 8)
-ADVISOR_SCAN_ROWS = _env_int("SURREAL_ADVISOR_SCAN_ROWS", 512)
-ADVISOR_DECLINE_MIN = _env_int("SURREAL_ADVISOR_DECLINE_MIN", 32)
-ADVISOR_SKEW_RATIO = _env_float("SURREAL_ADVISOR_SKEW_RATIO", 3.0)
-ADVISOR_BREACH_MIN = _env_int("SURREAL_ADVISOR_BREACH_MIN", 3)
 
 # Plan & pipeline cache (dbs/plan_cache.py): fingerprint-keyed cache of
 # the front-of-pipeline artifact chain (parsed AST template with literal

@@ -135,7 +135,6 @@ HTTP_ROUTES = frozenset(
         "export", "import", "rpc", "version", "sql", "signin", "signup", "key",
         "ml", "graphql", "health", "sync", "status", "metrics", "slow",
         "trace", "traces", "debug", "cluster", "events", "statements", "tenants",
-        "advisor",
     }
 )
 

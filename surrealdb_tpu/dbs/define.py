@@ -505,7 +505,7 @@ def remove_compute(ctx, stm) -> Any:
             return missing("model")
         txn.del_ml(ns, db, name, version)
         # GC the content-addressed weights blob unless another model version
-        # still references the same digest (advisor r2: orphaned blobs)
+        # still references the same digest (ADVICE r2: orphaned blobs)
         digest = entry.get("blob")
         if digest and not any(m.get("blob") == digest for m in txn.all_ml(ns, db)):
             from surrealdb_tpu.obs import del_blob

@@ -71,7 +71,6 @@ class Executor:
         # and the schema-generation token captured at statement start
         # (plan artifacts installed under a stale token are refused)
         self.slot_values: Optional[tuple] = None
-        self.cache_warm = False
         # when the statement's device operator (kNN search, graph count)
         # returned: Iterator.output() starts the `materialise` span there
         self.op_end: Optional[float] = None

@@ -99,7 +99,7 @@ def _system_info(ds=None) -> Dict[str, Any]:
     users, the same bar as the HTTP endpoints. Traces are the bounded
     store's summaries; fetch one in full by id via `traces` ->
     tracing.get_trace (or GET /trace/:id on a server)."""
-    from surrealdb_tpu import accounting, advisor, stats, telemetry, tracing
+    from surrealdb_tpu import accounting, stats, telemetry, tracing
     from surrealdb_tpu.bundle import debug_bundle
 
     return {
@@ -112,9 +112,6 @@ def _system_info(ds=None) -> Dict[str, Any]:
         # tenant cost-attribution plane: the top (ns, db) pairs by
         # cumulative execution time (accounting.py)
         "tenants": accounting.top(limit=20),
-        # advisor plane: live evidence-chained tuning proposals + sweep
-        # health (advisor.py; observe-only — nothing is ever applied)
-        "advisor": advisor.snapshot(limit=20),
         # the flight-recorder bundle for embedded users. full_traces=0: the
         # rings/summaries above already cover them, and re-materializing the
         # newest full span trees would double this (routine, root-gated)

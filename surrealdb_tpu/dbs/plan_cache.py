@@ -86,7 +86,7 @@ _VARIANT_CAP = 4  # arity/spelling variants kept per fingerprint entry
 _SCOPE_CAP = 8  # tenant/session scopes with routes per variant
 _VERIFY_TRUST = 4  # verified lex-serves before a variant skips the parse
 _REVALIDATE_EVERY = 64  # serves between forced cold re-resolutions
-_EVLOG_CAP = 64  # recent evictions kept for the advisor's thrash view
+_EVLOG_CAP = 64  # recent evictions kept for snapshot()'s recent_evictions
 
 
 class Served(NamedTuple):
@@ -462,7 +462,6 @@ class PlanCache:
         self._gen: Dict[Tuple, int] = {}  # (ns, db) -> schema generation
         self._inflight: Dict[Tuple, int] = {}  # (ns, db) -> DDLs in flight
         self._epoch: Any = None  # cluster membership epoch, None standalone
-        self._timing: "OrderedDict[str, Dict[str, float]]" = OrderedDict()
         self._hits = {"ast": 0, "route": 0}
         self._misses: Counter = Counter()
         self._invalidations: Counter = Counter()
@@ -478,7 +477,6 @@ class PlanCache:
             return None
         from surrealdb_tpu import stats
 
-        t0 = time.perf_counter()
         key = _stmt_key(text)
         if not key or ";" in key:
             return None  # empty or multi-statement: never cached
@@ -495,7 +493,6 @@ class PlanCache:
         if out is None and entry is not None:
             out = self._serve_lexed(entry, fp, key, dg)
         if out is not None:
-            self._note_timing(fp, "parse", (time.perf_counter() - t0) * 1e6, True)
             self._inc_hit("ast")
         elif entry is None:
             self._inc_miss("cold")
@@ -586,7 +583,7 @@ class PlanCache:
             self._inc_miss("verify")
         return ok
 
-    def observe(self, text: str, query, parse_us: float) -> None:
+    def observe(self, text: str, query) -> None:
         """The cold-parse report (ds.execute_local): counts the shape and,
         once it has been seen `_MIN_HITS` times, installs the parsed
         query as a shared template (parameterized in place — SlotLiteral
@@ -601,7 +598,6 @@ class PlanCache:
         if not key or ";" in key:
             return
         fp, _ = stats.fingerprint(key)
-        self._note_timing(fp, "parse", parse_us, False)
         with self._lock:
             n = self._warm.get(fp, 0) + 1
             self._warm[fp] = n
@@ -921,10 +917,9 @@ class PlanCache:
             self._index_defs.clear()
 
     def reset_window(self) -> None:
-        """Zero counters and timing but KEEP entries — a warm
-        measurement window starts here."""
+        """Zero counters but KEEP entries — a warm measurement window
+        starts here."""
         with self._lock:
-            self._timing.clear()
             self._hits = {"ast": 0, "route": 0}
             self._misses.clear()
             self._invalidations.clear()
@@ -932,81 +927,7 @@ class PlanCache:
             for e in self._entries.values():
                 e.hits = e.misses = e.route_hits = 0
 
-    # ------------------------------------------------------- timing
-    def _note_timing(self, fp: str, phase: str, us: float, warm: bool) -> None:
-        k = ("warm_" if warm else "cold_") + phase
-        with self._lock:
-            t = self._timing.get(fp)
-            if t is None:
-                t = self._timing[fp] = {}
-                while len(self._timing) > self._cap * 2:
-                    self._timing.popitem(last=False)
-            t[k + "_us"] = t.get(k + "_us", 0.0) + us
-            t[k + "_n"] = t.get(k + "_n", 0) + 1
-
-    def note_plan_time(self, fp: Optional[str], us: float, warm: bool) -> None:
-        """Pre-kernel plan/lower time attribution (planner + pipeline
-        analyze); `fp` is the active statement fingerprint."""
-        if fp and self.enabled:
-            self._note_timing(fp, "plan", us, warm)
-
     # ------------------------------------------------------- views
-    def _prekernel(self, t: Dict[str, float]) -> Dict[str, Any]:
-        def avg(pfx: str) -> Optional[float]:
-            n = t.get(pfx + "_parse_n", 0) + 0
-            us = t.get(pfx + "_parse_us", 0.0)
-            pn = t.get(pfx + "_plan_n", 0)
-            pus = t.get(pfx + "_plan_us", 0.0)
-            parse = us / n if n else None
-            plan = pus / pn if pn else None
-            if parse is None and plan is None:
-                return None
-            return round((parse or 0.0) + (plan or 0.0), 2)
-
-        return {"cold_us": avg("cold"), "warm_us": avg("warm")}
-
-    def window_stats(self, per_fp_limit: int = 20) -> dict:
-        """Window hit rates + per-fingerprint pre-kernel overhead, warm vs
-        cold, since `reset_window()`."""
-        with self._lock:
-            hits = dict(self._hits)
-            misses = sum(self._misses.values())
-            inv = dict(self._invalidations)
-            verifies = dict(self._verifies)
-            timing = {fp: dict(t) for fp, t in self._timing.items()}
-            entries = len(self._entries)
-            variants = sum(len(e.variants) for e in self._entries.values())
-        total = hits["ast"] + misses
-        fps = []
-        for fp, t in timing.items():
-            pk = self._prekernel(t)
-            if pk["cold_us"] is None and pk["warm_us"] is None:
-                continue
-            fps.append({"fingerprint": fp, **pk})
-        fps.sort(key=lambda r: (r["cold_us"] or 0.0), reverse=True)
-        colds = [r["cold_us"] for r in fps if r["cold_us"] is not None]
-        warms = [r["warm_us"] for r in fps if r["warm_us"] is not None]
-        return {
-            "enabled": self.enabled,
-            "entries": entries,
-            "variants": variants,
-            "hits": hits["ast"],
-            "route_hits": hits["route"],
-            "misses": misses,
-            "hit_rate": round(hits["ast"] / total, 4) if total else None,
-            "invalidations": inv,
-            "verifies": verifies,
-            "prekernel": {
-                "cold_avg_us": round(sum(colds) / len(colds), 2)
-                if colds
-                else None,
-                "warm_avg_us": round(sum(warms) / len(warms), 2)
-                if warms
-                else None,
-            },
-            "fingerprints": fps[: max(per_fp_limit, 1)],
-        }
-
     def snapshot(self, limit: int = 20) -> dict:
         """The debug bundle's `plan_cache` section."""
         with self._lock:
@@ -1085,47 +1006,6 @@ class PlanCache:
                     row["plan_cache"] = got
         return rows
 
-    def review_rows(self, min_calls: int = 8) -> List[dict]:
-        """The advisor's raw material: low-hit-rate entries and
-        thrash-evicted fingerprints (evicted 2+ times recently)."""
-        with self._lock:
-            out = []
-            for fp, e in self._entries.items():
-                total = e.hits + e.misses
-                if total >= min_calls and e.hits / total < 0.5:
-                    out.append(
-                        {
-                            "fingerprint": fp,
-                            "kind": "low_hit_rate",
-                            "hits": e.hits,
-                            "misses": e.misses,
-                            "hit_rate": round(e.hits / total, 3),
-                            "sql": e.variants[0].text
-                            if e.variants
-                            else None,
-                        }
-                    )
-            thrash = Counter(
-                ev["fp"] for ev in self._evlog if ev["fp"] is not None
-            )
-            for fp, n in thrash.items():
-                if n >= 2:
-                    out.append(
-                        {
-                            "fingerprint": fp,
-                            "kind": "thrash",
-                            "evictions": n,
-                            "causes": sorted(
-                                {
-                                    ev["cause"]
-                                    for ev in self._evlog
-                                    if ev["fp"] == fp
-                                }
-                            ),
-                        }
-                    )
-        return out
-
     # ------------------------------------------------------- emission
     # One helper per metric family so every emission site carries a STATIC
     # name and STATIC label keys (GL006: bounded series cardinality); the
@@ -1155,7 +1035,7 @@ class PlanCache:
 
 # ------------------------------------------------------------------ registry
 # every live PlanCache, so stats.record's flip hook (which has no ds
-# handle) can reach them all — the same weak registry shape advisor uses
+# handle) can reach them all
 _caches: "weakref.WeakSet[PlanCache]" = weakref.WeakSet()
 
 

@@ -70,9 +70,6 @@ KINDS: Dict[str, str] = {
     "net.backpressure_close": "a connection's write queue overflowed its bound and was closed",
     "net.overload_close": "ingress shed a connection (accept cap or header deadline)",
     "cluster.auth_reject": "an internal /cluster request failed per-node key auth",
-    # advisor plane (observe->propose; nothing is ever applied)
-    "advisor.proposal": "the advisor registered a new evidence-chained proposal",
-    "advisor.expired": "an advisor proposal's evidence decayed and it expired",
     # failpoints / chaos
     "fault.trip": "an armed failpoint site fired",
     # background machinery

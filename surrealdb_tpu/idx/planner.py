@@ -268,9 +268,6 @@ def plan_sources(ctx, stm, sources: List[Any]) -> List[Any]:
     from surrealdb_tpu import telemetry
 
     out: List[Any] = []
-    import time as _time
-
-    t0 = _time.perf_counter()
     with telemetry.span("plan"):
         for s in sources:
             if not isinstance(s, ITable):
@@ -306,19 +303,6 @@ def plan_sources(ctx, stm, sources: List[Any]) -> List[Any]:
                     }
                 telemetry.note_plan(note)
                 out.append(IIndex(s.tb, plan))
-    # plan-cache pre-kernel accounting: planner time per fingerprint,
-    # warm (template served from cache) vs cold
-    from surrealdb_tpu.dbs.plan_cache import active_plan_cache
-
-    pc = active_plan_cache(ctx)
-    if pc is not None:
-        from surrealdb_tpu import stats as _stats
-
-        pc.note_plan_time(
-            _stats.active_fingerprint(),
-            (_time.perf_counter() - t0) * 1e6,
-            bool(getattr(getattr(ctx, "executor", None), "cache_warm", False)),
-        )
     return out
 
 

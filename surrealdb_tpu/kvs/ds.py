@@ -348,7 +348,7 @@ class Datastore:
         self.index_builder = IndexBuilder(self)
         # serializes backend commit + mirror-delta application so two
         # concurrently committing transactions can't apply graph/vector
-        # deltas in the opposite order of their backend commits (advisor r2)
+        # deltas in the opposite order of their backend commits (ADVICE r2)
         self.commit_lock = _locks.Lock("kvs.commit")
         # bounded-latency write-commit coalescer (bulk-ingest group commit)
         self.group_commit = GroupCommit(self)
@@ -367,12 +367,6 @@ class Datastore:
         from surrealdb_tpu import profiler as _profiler
 
         _profiler.ensure_started()
-        # advisor plane (advisor.py): observe->propose sweeps over this
-        # instance's planes; same one-shot process-global service shape
-        # (SURREAL_ADVISOR=0 keeps it off), later instances just register
-        from surrealdb_tpu import advisor as _advisor
-
-        _advisor.ensure_started(self)
         # cluster mode (surrealdb_tpu/cluster/): when attach()ed, execute()
         # routes through the distributed scatter/gather executor; the
         # internal /cluster channel and the executor's own sub-queries run
@@ -468,13 +462,9 @@ class Datastore:
                     session or Session.owner(),
                     vars,
                     slot_values=served.slot_values,
-                    cache_warm=True,
                 )
-            t0 = _time.perf_counter()
             ast = parse_query(text)
-            self.plan_cache.observe(
-                text, ast, (_time.perf_counter() - t0) * 1e6
-            )
+            self.plan_cache.observe(text, ast)
             tracing.record_span_into(
                 at, "plan_fetch", {"outcome": "parse"},
                 t_fetch, _time.perf_counter() - t_fetch,
@@ -487,7 +477,6 @@ class Datastore:
         session,
         vars: Optional[Dict[str, Any]] = None,
         slot_values: Optional[tuple] = None,
-        cache_warm: bool = False,
     ) -> List[dict]:
         from surrealdb_tpu.dbs.executor import Executor
 
@@ -495,7 +484,6 @@ class Datastore:
         # plan-cache slot bindings ride the per-query executor (every
         # child Context shares it), never the shared template AST
         ex.slot_values = slot_values
-        ex.cache_warm = cache_warm
         return ex.execute(ast)
 
     def compute(self, expr, session, vars: Optional[Dict[str, Any]] = None):

@@ -476,36 +476,6 @@ class SurrealHandler(BaseHTTPRequestHandler):
                     200, federated_tenants(self.ds, limit=limit, sort=sort)
                 )
             return self._send(200, _accounting.top(limit=limit, sort=sort))
-        if path == "/advisor":
-            # advisor plane (advisor.py): evidence-chained tuning proposals
-            # (observe-only; nothing is ever applied). Proposals cite
-            # statement fingerprints and tenant namespaces, so system-gated
-            # like /statements and /tenants.
-            if not self._route_allowed("advisor"):
-                return
-            if self._system_gate() is None:
-                return
-            from urllib.parse import parse_qs
-
-            from surrealdb_tpu import advisor as _advisor
-
-            q = parse_qs(urlparse(self.path).query)
-            kind = q.get("kind", [None])[0]
-            try:
-                limit = int(q.get("limit", [None])[0]) if q.get("limit") else 50
-            except (TypeError, ValueError):
-                limit = 50
-            if self._cluster_query():
-                from surrealdb_tpu.cluster.federation import federated_advisor
-
-                return self._send(
-                    200, federated_advisor(self.ds, limit=limit)
-                )
-            if kind:
-                return self._send(
-                    200, {"proposals": _advisor.proposals(limit=limit, kind=kind)}
-                )
-            return self._send(200, _advisor.snapshot(limit=limit))
         if path == "/slow":
             # structured slow-query log (ring buffer; dbs/executor.py) — the
             # /metrics-adjacent debug endpoint. Entries carry raw statement

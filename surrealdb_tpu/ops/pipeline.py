@@ -417,7 +417,7 @@ def grouped_shape(stm) -> Optional[GroupedShape]:
     return GroupedShape(group_paths, fields)
 
 
-# ------------------------------------------------------------------ cost model
+# ------------------------------------------------------------------ row or columnar
 def choose_strategy(mirror, n_rows: int, shape: str) -> Tuple[str, dict]:
     """Row vs columnar for one lowerable statement. Inputs are the mirror's
     state and the pipeline shape; the returned note lands in plan notes so
@@ -432,28 +432,11 @@ def choose_strategy(mirror, n_rows: int, shape: str) -> Tuple[str, dict]:
         "mirrored": mirror is not None,
         "min_rows": cnf.COLUMN_MIRROR_MIN_ROWS,
     }
-    # modeled per-call costs in row-visit units: the row path touches
-    # every row; the columnar path amortizes to a fraction of a visit per
-    # row but pays a fixed vectorized-dispatch overhead. Both estimates
-    # ride the note — the DECLINED option's cost alongside the chosen
-    # one — so the stats store can accumulate the margin per fingerprint
-    # and the advisor's break-even math gets the delta, not just the
-    # decision.
-    row_cost = float(n_rows)
-    col_cost = float(n_rows) * 0.25 + 64.0
     if n_rows < cnf.COLUMN_MIRROR_MIN_ROWS and mirror is None:
         note["decision"] = "row"
         note["why"] = "below mirror floor"
-        note["est_cost"] = {
-            "unit": "row-visits", "chosen": row_cost, "declined": col_cost,
-            "declined_option": "columnar", "margin": col_cost - row_cost,
-        }
         return "row", note
     note["decision"] = "columnar"
-    note["est_cost"] = {
-        "unit": "row-visits", "chosen": col_cost, "declined": row_cost,
-        "declined_option": "row", "margin": row_cost - col_cost,
-    }
     return "columnar", note
 
 
@@ -1282,12 +1265,11 @@ def run_pipeline(ctx, stm, tb: str) -> Optional[Tuple[List[Any], dict]]:
     the shape/order/projection resolution and the duplicate index probe
     are skipped, and only the compiled mask program's CONSTANTS re-bind
     against the live context (predicates.CompiledPredicate.rebind)."""
-    from surrealdb_tpu import stats as _stats
     from surrealdb_tpu.dbs.plan_cache import active_plan_cache
 
     pc = active_plan_cache(ctx)
     cached = pc.lowering_for(ctx, stm) if pc is not None else None
-    t0 = t_enter = _time.perf_counter()
+    t_enter = _time.perf_counter()
     grouped = bool(getattr(stm, "group", None) or getattr(stm, "group_all", False))
 
     def declined(reason: Optional[str]):
@@ -1310,25 +1292,12 @@ def run_pipeline(ctx, stm, tb: str) -> Optional[Tuple[List[Any], dict]]:
                 low = cached = None
             else:
                 low = Lowering(low.shape, low.specs, low.proj, rb, low.cond)
-    warm = bool(getattr(getattr(ctx, "executor", None), "cache_warm", False))
     if low is None:
         low, reason = analyze_select(ctx, stm, tb)
-        if pc is not None:
-            pc.note_plan_time(
-                _stats.active_fingerprint(),
-                (_time.perf_counter() - t0) * 1e6,
-                warm,
-            )
         if low is None:
             return declined(reason)
         if pc is not None:
             pc.install_pipeline(ctx, stm, low)
-    else:
-        pc.note_plan_time(
-            _stats.active_fingerprint(),
-            (_time.perf_counter() - t0) * 1e6,
-            warm,
-        )
     shape, specs, ordered_proj = low.shape, low.specs, low.proj
     compiled, cond = low.compiled, low.cond
 

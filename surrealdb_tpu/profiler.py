@@ -221,17 +221,6 @@ def report(top: int = 50) -> dict:
     return out
 
 
-def summary(top: int = 5) -> dict:
-    """Compact per-window form of `report()`."""
-    full = report(top=top)
-    return {
-        "hz": full["hz"],
-        "samples": full["samples"],
-        "by_thread": dict(list(full["by_thread"].items())[:top]),
-        "by_fingerprint": full["by_fingerprint"],
-    }
-
-
 def folded_text() -> str:
     """Flamegraph collapsed format: `thread;frame;...;leaf count` lines
     (flamegraph.pl / speedscope open this directly)."""

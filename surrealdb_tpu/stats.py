@@ -158,7 +158,6 @@ class _Entry:
         "dur_max", "buckets", "rows_out", "rows_in", "plan_mix",
         "dispatch_splits", "dispatch_retries", "last_primary", "flips",
         "flip_log", "first_ts", "last_ts",
-        "cost_chosen", "cost_declined", "cost_margin", "cost_notes",
     )
 
     def __init__(self, fp: str, text: str, kind: str):
@@ -179,13 +178,6 @@ class _Entry:
         self.dispatch_splits = 0
         self.dispatch_retries = 0
         self.last_primary: Optional[str] = None
-        # planner cost-hook accumulators (choose_strategy's est_cost
-        # note): chosen AND declined modeled costs in row-visit units —
-        # the break-even margin the advisor's index math consumes
-        self.cost_chosen = 0.0
-        self.cost_declined = 0.0
-        self.cost_margin = 0.0
-        self.cost_notes = 0
         self.flips = 0
         self.flip_log: List[dict] = []  # bounded: newest _FLIP_LOG_CAP
         self.first_ts = time.time()
@@ -227,18 +219,6 @@ class _Entry:
             "rows_out": self.rows_out,
             "rows_in": self.rows_in,
             "plan_mix": dict(self.plan_mix),
-            "cost": {
-                "unit": "row-visits",
-                "chosen": round(self.cost_chosen, 2),
-                "declined": round(self.cost_declined, 2),
-                "margin": round(self.cost_margin, 2),
-                "notes": self.cost_notes,
-                "margin_per_call": round(self.cost_margin / self.calls, 3)
-                if self.calls
-                else None,
-            }
-            if self.cost_notes
-            else None,
             "primary": self.last_primary,
             "plan_flips": self.flips,
             "flip_log": list(self.flip_log),
@@ -374,19 +354,6 @@ def record(
     if extra_mix:
         for k, v in extra_mix.items():
             mix[k] = mix.get(k, 0) + int(v)
-    # planner cost-hook extraction (outside the lock): every plan note
-    # carrying choose_strategy's est_cost contributes its chosen AND
-    # declined modeled costs, so the entry accumulates the margin — the
-    # delta the advisor's break-even math needs, not just the decision
-    c_chosen = c_declined = c_margin = 0.0
-    c_notes = 0
-    for note in plan or ():
-        ec = note.get("cost", {}).get("est_cost") if isinstance(note, dict) else None
-        if isinstance(ec, dict):
-            c_chosen += float(ec.get("chosen") or 0.0)
-            c_declined += float(ec.get("declined") or 0.0)
-            c_margin += float(ec.get("margin") or 0.0)
-            c_notes += 1
     flip: Optional[Tuple[str, str]] = None
     evictions = 0
     now = time.time()
@@ -409,11 +376,6 @@ def record(
         if dispatch:
             e.dispatch_splits += int(dispatch.get("splits", 0) or 0)
             e.dispatch_retries += int(dispatch.get("retries", 0) or 0)
-        if c_notes:
-            e.cost_chosen += c_chosen
-            e.cost_declined += c_declined
-            e.cost_margin += c_margin
-            e.cost_notes += c_notes
         if primary is not None:
             if e.last_primary is not None and e.last_primary != primary:
                 flip = (e.last_primary, primary)

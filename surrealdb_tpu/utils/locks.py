@@ -129,13 +129,6 @@ HIERARCHY: Dict[str, int] = {
                                # telemetry are LOWER levels and must never
                                # nest inside); never nests with the other
                                # level-85 observability leaves
-    "advisor.store": 85,       # advisor proposal store (advisor.py):
-                               # leaf-style — propose() mutates and
-                               # releases; proposal/expired events and
-                               # counters emit AFTER release, and sweeps
-                               # snapshot the stats/accounting planes
-                               # BEFORE touching this lock (same-level
-                               # leaves never nest)
     "telemetry.registry": 86,  # metrics registry (the hottest leaf)
 }
 

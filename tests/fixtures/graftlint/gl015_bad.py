@@ -27,6 +27,6 @@ def sneak_module_state():
 
 
 def sneak_counters(ds):
-    # cooking the counters lies to the bench gate and the advisor
+    # cooking the counters lies to whoever reads snapshot()
     ds.plan_cache._misses.clear()
     ds.plan_cache._evlog.clear()

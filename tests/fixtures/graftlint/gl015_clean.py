@@ -31,6 +31,4 @@ def read_views(ds):
     return (
         ds.plan_cache.snapshot(limit=5),
         ds.plan_cache.describe(fp="0" * 16),
-        ds.plan_cache.window_stats(),
-        ds.plan_cache.review_rows(min_calls=8),
     )

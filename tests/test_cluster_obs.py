@@ -260,11 +260,11 @@ def test_federated_metrics_relabels_every_node(cluster2):
 def test_federated_bundle_marks_dead_node_unreachable(cluster2, monkeypatch):
     seed_items(cluster2, n=24)
     fb = federated_bundle(cluster2.coord)
-    assert fb["schema"] == "surrealdb-tpu-bundle/10" and fb["cluster"] is True
+    assert fb["schema"] == "surrealdb-tpu-bundle/11" and fb["cluster"] is True
     assert fb["coordinator"] == "n1" and set(fb["nodes"]) == {"n1", "n2"}
     for nid in ("n1", "n2"):
         b = fb["nodes"][nid]
-        assert b.get("schema") == "surrealdb-tpu-bundle/10"
+        assert b.get("schema") == "surrealdb-tpu-bundle/11"
         assert "events" in b and "traces" in b and "engine" in b
 
     monkeypatch.setattr(cnf, "CLUSTER_RPC_TIMEOUT_SECS", 1.5)
@@ -274,7 +274,24 @@ def test_federated_bundle_marks_dead_node_unreachable(cluster2, monkeypatch):
     fb2 = json.loads(body)
     assert fb2["nodes"]["n2"].get("unreachable") is True
     assert fb2["nodes"]["n2"].get("error")
-    assert fb2["nodes"]["n1"].get("schema") == "surrealdb-tpu-bundle/10"
+    assert fb2["nodes"]["n1"].get("schema") == "surrealdb-tpu-bundle/11"
+
+
+@pytest.mark.parametrize("route", ["/statements", "/tenants"])
+def test_killed_member_marks_unreachable_not_silent(cluster2, monkeypatch, route):
+    """A federated list view against a cluster that LOST a member answers
+    200 with the dead node marked unreachable, the live member's rows in
+    the same partial view: labeled partial, never silently shrunk."""
+    monkeypatch.setattr(cnf, "CLUSTER_RPC_TIMEOUT_SECS", 1.5)
+    ok(cluster2.coord.execute("CREATE k:1 SET v = 1", cluster2.s)[0])
+    cluster2.kill(1)
+    status, body = cluster2.http_get(route + "?cluster=1")
+    assert status == 200
+    entries = json.loads(body)
+    dead = [e for e in entries if e.get("unreachable")]
+    assert [e["node"] for e in dead] == ["n2"], entries
+    assert dead[0].get("error")
+    assert any(e.get("node") == "n1" and not e.get("unreachable") for e in entries)
 
 
 def test_events_endpoint_and_federation(cluster2):
@@ -351,7 +368,7 @@ def test_trace_complete_and_timeline_ordered_under_mid_scatter_kill(
     monkeypatch.setattr(cnf, "CLUSTER_RPC_TIMEOUT_SECS", 1.0)
     fb = federated_bundle(cluster3.coord)
     assert fb["nodes"]["n3"].get("unreachable") is True
-    assert fb["nodes"]["n1"].get("schema") == "surrealdb-tpu-bundle/10"
+    assert fb["nodes"]["n1"].get("schema") == "surrealdb-tpu-bundle/11"
 
 
 # ------------------------------------------------------------ profile store

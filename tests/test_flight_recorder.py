@@ -307,7 +307,7 @@ def test_bundle_has_all_six_sections(ds):
     b = debug_bundle(ds)
     for sec in SECTIONS:
         assert sec in b, sec
-    assert b["schema"] == "surrealdb-tpu-bundle/10"
+    assert b["schema"] == "surrealdb-tpu-bundle/11"
     assert b["engine"]["dispatch"]["stats"]["submitted"] >= 0
     assert "memory_bytes" in b["engine"]
     # a ds-less bundle (the tier-1 failure hook) still carries every section
@@ -329,21 +329,6 @@ def test_bundle_http_endpoint():
         b = json.loads(r.read())
         for sec in SECTIONS:
             assert sec in b, sec
-        conn.close()
-    finally:
-        srv.shutdown()
-
-
-def test_bundle_http_requires_system_user():
-    from surrealdb_tpu.net.server import serve
-
-    srv = serve("memory", port=0, auth_enabled=True).start_background()
-    try:
-        conn = http.client.HTTPConnection(srv.host, srv.port)
-        conn.request("GET", "/debug/bundle")
-        r = conn.getresponse()
-        r.read()
-        assert r.status == 401
         conn.close()
     finally:
         srv.shutdown()

@@ -198,7 +198,7 @@ def test_bundle_embeds_flow_audit_section():
     from surrealdb_tpu import bundle
 
     b = bundle.debug_bundle()
-    assert b["schema"] == "surrealdb-tpu-bundle/10"
+    assert b["schema"] == "surrealdb-tpu-bundle/11"
     fa = b["flow_audit"]
     assert fa["available"] is True
     assert fa["callgraph"]["nodes"] > 0

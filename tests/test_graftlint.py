@@ -25,7 +25,7 @@ def rule_ids(findings):
 
 
 # ------------------------------------------------------------------ per rule
-@pytest.mark.parametrize("rule", ["GL001", "GL002", "GL003", "GL004", "GL005", "GL006", "GL007", "GL008", "GL009", "GL010", "GL011", "GL012", "GL013", "GL014", "GL015", "GL016"])
+@pytest.mark.parametrize("rule", ["GL001", "GL002", "GL003", "GL004", "GL005", "GL006", "GL007", "GL008", "GL009", "GL010", "GL011", "GL012", "GL013", "GL015", "GL016"])
 def test_rule_fires_on_bad_fixture_and_not_on_clean(rule):
     bad = lint(f"{rule.lower()}_bad.py", rules=[rule])
     assert rule in rule_ids(bad), f"{rule} failed to fire on its fixture"
@@ -148,13 +148,12 @@ def test_cli_exit_codes():
             os.path.join(FIXTURES, "gl011_bad.py"),
             os.path.join(FIXTURES, "gl012_bad.py"),
             os.path.join(FIXTURES, "gl013_bad.py"),
-            os.path.join(FIXTURES, "gl014_bad.py"),
             os.path.join(FIXTURES, "gl016_bad.py"),
         ],
         cwd=REPO, capture_output=True, text=True, env=env,
     )
     assert bad.returncode == 1, bad.stdout + bad.stderr
-    for rule in ("GL001", "GL002", "GL003", "GL004", "GL005", "GL006", "GL007", "GL008", "GL009", "GL011", "GL012", "GL013", "GL014", "GL016"):
+    for rule in ("GL001", "GL002", "GL003", "GL004", "GL005", "GL006", "GL007", "GL008", "GL009", "GL011", "GL012", "GL013", "GL016"):
         assert rule in bad.stdout, f"{rule} missing from CLI output"
     # --update-baseline refuses a restricted scope (it would silently drop
     # every grandfathered entry the restricted run can't see)
@@ -226,27 +225,6 @@ def test_gl013_flags_private_access_under_any_alias():
     assert lint("gl013_clean.py", rules=["GL013"]) == []
 
 
-def test_gl014_flags_store_pokes_and_call_site_hygiene():
-    keys = {f.key for f in lint("gl014_bad.py", rules=["GL014"])}
-    # store-poke half: all three import spellings are caught, per member
-    assert any(":sneak_dotted:_store" in k for k in keys), keys
-    assert any(k.endswith(":_store") for k in keys), keys
-    assert any(k.endswith(":_lock") for k in keys), keys
-    assert any(k.endswith(":_evicted") for k in keys), keys
-    assert any(k.endswith(":_expired_ring") for k in keys), keys
-    # call-site half: dynamic kind, unregistered kind, missing evidence,
-    # empty evidence (via the aliased direct import)
-    assert any(k.endswith(":sneak_dynamic_kind:dynamic-kind") for k in keys), keys
-    assert any(":kind:fixture.made_up_kind" in k for k in keys), keys
-    assert any(k.endswith(":sneak_no_evidence:no-evidence") for k in keys), keys
-    assert any(
-        k.endswith(":sneak_empty_evidence:empty-evidence") for k in keys
-    ), keys
-    # the public doors — propose with registered kind + evidence, and
-    # every read surface — stay clean
-    assert lint("gl014_clean.py", rules=["GL014"]) == []
-
-
 def test_gl016_flags_blocking_sockets_and_sleep_only_when_marked(tmp_path):
     keys = {f.key for f in lint("gl016_bad.py", rules=["GL016"])}
     assert any(k.endswith(":drain:recv") for k in keys), keys
@@ -286,14 +264,6 @@ def test_gl016_loop_module_is_marked_and_clean():
     assert engine.lint_paths([path], rules=["GL016"]) == []
 
 
-def test_gl014_registry_matches_runtime():
-    # the rule checks against the REAL registry, so the static and runtime
-    # halves can never drift
-    from surrealdb_tpu.advisor import KINDS
-
-    assert rules_mod._gl014_registry() == set(KINDS)
-
-
 def test_gl011_hierarchy_matches_runtime():
     # the rule checks against the REAL declared hierarchy, so the static
     # and runtime halves can never drift
@@ -313,8 +283,8 @@ def test_gl009_registry_matches_runtime():
 def test_every_rule_has_doc_and_registration():
     assert set(rules_mod.RULES) == {
         "GL001", "GL002", "GL003", "GL004", "GL005", "GL006", "GL007",
-        "GL008", "GL009", "GL010", "GL011", "GL012", "GL013", "GL014",
-        "GL015", "GL016",
+        "GL008", "GL009", "GL010", "GL011", "GL012", "GL013", "GL015",
+        "GL016",
     }
     for rid, (fn, doc) in rules_mod.RULES.items():
         assert callable(fn) and doc

@@ -232,7 +232,7 @@ def test_csr_remove_database_drops_mirrors(ds, jax8):
 def test_graph_multiplicity_parallel_edges(ds, jax8):
     """Parallel edges yield duplicate results on BOTH the exact KV walk and
     the mirror path — matching the reference's flatten-without-dedup
-    semantics (sql/value/get.rs:404-446; advisor r2 high finding)."""
+    semantics (sql/value/get.rs:404-446; ADVICE r2 high finding)."""
     from surrealdb_tpu import cnf
 
     ds.execute(

@@ -151,7 +151,7 @@ def test_ml_sdk_and_cli(tmp_path):
 
 def test_ml_remove_model_gcs_blob(ml_ds):
     """REMOVE MODEL deletes the content-addressed weights blob when no other
-    model version references it (advisor r2: orphaned blobs)."""
+    model version references it (ADVICE r2: orphaned blobs)."""
     from surrealdb_tpu import key as keys
     from surrealdb_tpu.key.encode import prefix_end
 
@@ -171,7 +171,7 @@ def test_ml_remove_model_gcs_blob(ml_ds):
 
 def test_ml_remove_database_clears_compiled_cache(ml_ds):
     """A recreated database must not serve the removed database's compiled
-    weights from the cache (advisor r2 medium)."""
+    weights from the cache (ADVICE r2 medium)."""
     assert ml_ds.execute("RETURN ml::house<1.0.0>([1.0, 2.0]);")[0]["status"] == "OK"
     ml_ds.execute("REMOVE DATABASE test;")
     out = ml_ds.execute("RETURN ml::house<1.0.0>([1.0, 2.0]);")

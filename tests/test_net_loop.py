@@ -352,7 +352,7 @@ def test_metrics_and_bundle_expose_net_plane(srv):
     from surrealdb_tpu import bundle
 
     b = bundle.debug_bundle(srv.httpd.RequestHandlerClass.ds)
-    assert b["schema"] == "surrealdb-tpu-bundle/10"
+    assert b["schema"] == "surrealdb-tpu-bundle/11"
     assert "net" in b and b["net"]["enabled"]
     assert b["net"]["servers"], "live server missing from bundle net section"
     assert b["net"]["servers"][0]["conns"] >= 1

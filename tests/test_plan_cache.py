@@ -465,32 +465,28 @@ def test_statements_annotation_and_bundle_section(ds):
     from surrealdb_tpu.bundle import debug_bundle
 
     b = debug_bundle(ds)
-    assert b["schema"] == "surrealdb-tpu-bundle/10"
+    assert b["schema"] == "surrealdb-tpu-bundle/11"
     assert b["plan_cache"]["enabled"] is True
     assert b["plan_cache"]["hits"]["ast"] >= 1, b["plan_cache"]
 
 
-def test_advisor_review_rows_flow_through_propose(ds):
-    from surrealdb_tpu import advisor
-
-    # manufacture a thrashing fingerprint: warm, then flip-evict twice
+def test_snapshot_recent_evictions_name_fingerprint_and_cause(ds):
+    # a thrashing fingerprint: warm, flip-evict, warm again, flip-evict
     sql = "SELECT * FROM adv WHERE x > 1"
     for i in range(6):
         ok(ds.execute(f"CREATE adv:{i} SET x = {i}")[-1])
     fp = fp_of(sql)
-    for _ in range(3):
-        ok(ds.execute(sql)[-1])
-    ds.plan_cache.on_plan_flip(fp)
-    for _ in range(3):
-        ok(ds.execute(sql)[-1])
-    ds.plan_cache.on_plan_flip(fp)
-    rows = ds.plan_cache.review_rows(min_calls=1)
-    assert any(r["kind"] == "thrash" and r["fingerprint"] == fp for r in rows)
-    rep = advisor.sweep_once(ds)
-    assert rep["errors"] == 0 if "errors" in rep else True, rep
-    props = [p for p in advisor.proposals(limit=50)
-             if p["kind"] == "plan_cache.review"]
-    assert props and any(fp in (p.get("fingerprints") or []) for p in props)
+    for _ in range(2):
+        for _ in range(3):
+            ok(ds.execute(sql)[-1])
+        ds.plan_cache.on_plan_flip(fp)
+    mine = [
+        ev for ev in ds.plan_cache.snapshot()["recent_evictions"]
+        if ev["fp"] == fp
+    ]
+    assert len(mine) == 2, mine
+    assert {ev["cause"] for ev in mine} == {"flip"}
+    assert all(ev["ts"] > 0 for ev in mine)
 
 
 # ============================================================ record ids

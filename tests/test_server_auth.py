@@ -83,6 +83,48 @@ def test_key_route_escapes_ids(authed_server):
     c.close()
 
 
+# every observability route that serves statement text, tenant names or
+# span trees, on both front ends: the threaded handler and the event loop
+# (the default ingress, the one a deployment is served from)
+_GATED = ("/slow", "/traces", "/trace/abcd", "/debug/bundle", "/events",
+          "/statements", "/tenants")
+
+
+@pytest.mark.parametrize("front", ["threaded", "loop"])
+@pytest.mark.parametrize("route", _GATED)
+def test_observability_route_needs_a_system_user(ds, monkeypatch, front, route):
+    from surrealdb_tpu import cnf
+    from surrealdb_tpu.dbs.session import Session
+    from surrealdb_tpu.net.server import Server
+
+    monkeypatch.setattr(cnf, "NET_LOOP", front == "loop")
+    ds.execute(
+        "DEFINE USER nsu ON NAMESPACE PASSWORD 'pw' ROLES EDITOR;",
+        Session.owner("test", None),
+    )
+    srv = Server(ds, port=0, auth_enabled=True).start_background()
+    try:
+        assert srv.loop_mode is (front == "loop")
+        c = _conn(srv)
+        c.request("GET", route)
+        r = c.getresponse(); r.read()
+        assert r.status == 401, (front, route)
+        # a system user passes the gate (no trace has the id `abcd`)
+        c.request("GET", route, headers={
+            "Authorization": "Basic " + base64.b64encode(b"nsu:pw").decode(),
+            "surreal-ns": "test",
+        })
+        r = c.getresponse(); r.read()
+        assert r.status == (404 if route.startswith("/trace/") else 200), (front, route)
+        # /metrics carries no statement text and stays open
+        c.request("GET", "/metrics")
+        r = c.getresponse(); r.read()
+        assert r.status == 200
+        c.close()
+    finally:
+        srv.shutdown()
+
+
 def test_insert_ignore_relation(ds):
     ds.execute("CREATE a:1; CREATE b:1; RELATE a:1->likes->b:1;")
     edge = ds.execute("SELECT VALUE id FROM likes;")[0]["result"][0]

@@ -234,26 +234,3 @@ def test_metrics_and_slow_endpoints_roundtrip(monkeypatch):
         conn.close()
     finally:
         srv.shutdown()
-
-
-def test_slow_endpoint_requires_system_user():
-    """/slow serves raw statement text, so with auth enabled an anonymous
-    client gets 401 (same posture as /export); /metrics stays open."""
-    import http.client
-
-    from surrealdb_tpu.net.server import serve
-
-    srv = serve("memory", port=0, auth_enabled=True).start_background()
-    try:
-        conn = http.client.HTTPConnection(srv.host, srv.port)
-        conn.request("GET", "/slow")
-        r = conn.getresponse()
-        r.read()
-        assert r.status == 401
-        conn.request("GET", "/metrics")
-        r = conn.getresponse()
-        r.read()
-        assert r.status == 200
-        conn.close()
-    finally:
-        srv.shutdown()
