@@ -57,6 +57,7 @@ KERNEL_SITES = {
     "knn_subset_sharded": "surrealdb_tpu.parallel.mesh:graftcheck_sites",
     "graph_dense": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
     "graph_csc": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
+    "graph_reach": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
     "graph_chain": "surrealdb_tpu.idx.graph_csr:graftcheck_sites",
     "bm25": "surrealdb_tpu.ops.bm25:graftcheck_sites",
     "column_agg": "surrealdb_tpu.ops.column_agg:graftcheck_sites",

@@ -74,6 +74,10 @@ class Executor:
         # when the statement's device operator (kNN search, graph count)
         # returned: Iterator.output() starts the `materialise` span there
         self.op_end: Optional[float] = None
+        # the rings the statement's `array::distinct(<graph chain>)`
+        # expressions share (idx/graph_csr.py chain_distinct); emptied
+        # before every statement
+        self.reach_memo: Dict[tuple, dict] = {}
         self.plan_gen: Optional[tuple] = None
         self._ddl_open: List[tuple] = []  # DDL brackets held to COMMIT/CANCEL
         self._buffered: List[dict] = []  # responses inside the explicit txn
@@ -258,6 +262,7 @@ class Executor:
                 at, "stmt_accounting", {"phase": "begin"},
                 t_begin, time.perf_counter() - t_begin,
             )
+        self.reach_memo, self.op_end = {}, None
         try:
             resp = self._execute_statement(ctx, stm)
         finally:

@@ -67,6 +67,17 @@ class IThing:
         self.t = t
 
 
+class IThings:
+    """Record ids alone, in order (what a graph traversal hands a FROM,
+    hundreds to thousands at a time): one source, read id by id until the
+    statement is full, not an iterable an id."""
+
+    __slots__ = ("ts",)
+
+    def __init__(self, ts: List[Thing]):
+        self.ts = ts
+
+
 class IDefer:
     """A record id for CREATE — existence checked at write time."""
 
@@ -131,17 +142,20 @@ def target_value(ctx, e: Expr):
     return e.compute(ctx)
 
 
-def classify_sources(ctx, what_exprs: List[Expr], verb: str) -> List[Any]:
+def classify_sources(ctx, what_exprs: List[Expr], verb: str, parallel: bool = False) -> List[Any]:
     """Evaluate FROM/target expressions into Iterables
-    (reference: statements/select.rs what-loop + iterator.rs ingest)."""
+    (reference: statements/select.rs what-loop + iterator.rs ingest).
+    `parallel`: the statement is a SELECT ... PARALLEL, whose sources run
+    side by side (Iterator._iterate_parallel): an array of record ids is
+    then a source an id, so that their device dispatches overlap."""
     out: List[Any] = []
     for e in what_exprs:
         v = target_value(ctx, e)
-        _classify_value(ctx, v, verb, out)
+        _classify_value(ctx, v, verb, out, parallel)
     return out
 
 
-def _classify_value(ctx, v, verb: str, out: List[Any]) -> None:
+def _classify_value(ctx, v, verb: str, out: List[Any], parallel: bool = False) -> None:
     if isinstance(v, Table):
         if verb == "create":
             out.append(IDefer(Thing(str(v))))
@@ -157,8 +171,11 @@ def _classify_value(ctx, v, verb: str, out: List[Any]) -> None:
     elif isinstance(v, ThingRange):
         out.append(IRange(v.tb, v.rng))
     elif isinstance(v, (list, tuple)):
+        if verb != "create" and not parallel and all(type(x) is Thing and type(x.id) is not Range for x in v):
+            out.append(IThings(v))
+            return
         for item in v:
-            _classify_value(ctx, item, verb, out)
+            _classify_value(ctx, item, verb, out, parallel)
     elif isinstance(v, str) and verb != "select":
         # string record id like "person:1" used as a write target
         try:
@@ -311,8 +328,10 @@ class Iterator:
         t_op = getattr(ctx.executor, "op_end", None)
         if t_op is not None:
             # the rows' fetch and projection since the device operator
-            # returned, up to the statement's result
-            ctx.executor.op_end = None
+            # returned, up to the statement's result; a statement inside
+            # another (a subquery) hands the clock on, and the enclosing
+            # statement's span takes up where this one ends
+            ctx.executor.op_end = None if ctx.depth == 0 else time.perf_counter()
             tracing.record_span_into(
                 tracing.current(), "materialise", {}, t_op, time.perf_counter() - t_op
             )
@@ -352,6 +371,11 @@ class Iterator:
             self._process_value(it.v)
         elif isinstance(it, IThing):
             self._process_thing(it.t)
+        elif isinstance(it, IThings):
+            for t in it.ts:
+                self._process_thing(t)
+                if self._full():
+                    return
         elif isinstance(it, IDefer):
             self._process_defer(it.t)
         elif isinstance(it, IRange):

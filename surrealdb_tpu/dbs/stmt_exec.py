@@ -61,7 +61,7 @@ def _only(stm, rows: List[Any]):
 def select_compute(ctx, stm) -> Any:
     t_setup = time.perf_counter()
     with _with_timeout(ctx, stm) as c:
-        sources = classify_sources(c, stm.what, "select")
+        sources = classify_sources(c, stm.what, "select", parallel=bool(getattr(stm, "parallel", False)))
 
         if stm.explain:
             from surrealdb_tpu.idx.planner import explain

@@ -9,6 +9,7 @@ from surrealdb_tpu.err import InvalidArgumentsError, TypeError_
 from surrealdb_tpu.sql.value import (
     NONE,
     Closure,
+    Thing,
     is_nullish,
     sort_key,
     truthy,
@@ -157,8 +158,14 @@ def difference(ctx, a, b):
 
 @register("array::distinct")
 def distinct(ctx, a):
+    a = _arr(a)
+    if all(type(x) is Thing for x in a):
+        # record ids alone (what a graph traversal hands over, thousands at
+        # a time): two are `=` exactly where they are equal and hash alike,
+        # so first occurrences in order are one pass, not a scan a value
+        return list(dict.fromkeys(a))
     out: list = []
-    for x in _arr(a):
+    for x in a:
         if not any(value_eq(x, y) for y in out):
             out.append(x)
     return out

@@ -21,6 +21,7 @@ the exact KV walk (sql/path.py graph_hop).
 
 from __future__ import annotations
 
+import contextvars
 import math
 import threading
 import time as _time
@@ -305,12 +306,13 @@ def _stack_lanes(payloads, fsz: int, pad: int):
     return frs, cws
 
 
-def _lane_end_weights(payloads, lanes: int) -> tuple:
+def _lane_end_weights(payloads, lanes: int, at: int = 2) -> tuple:
     """The riders' end weights (a payload's third member: one device array
     a rider, cached a predicate binding by GraphMirrors._end_weights), one a
-    lane. A padding lane carries no seed weight, so whatever it ends in
-    counts nothing: it borrows the first rider's array."""
-    ws = [p[2] for p in payloads]
+    lane; for a set chain the riders' masks (`at` 1: _reach_mask). A padding
+    lane carries no seed, so whatever it ends in counts nothing: it borrows
+    the first rider's array."""
+    ws = [p[at] for p in payloads]
     return tuple(ws + [ws[0]] * (lanes - len(ws)))
 
 
@@ -339,6 +341,66 @@ def _collect_counts(out, riders: int, lanes: int, sweeps: Optional[int] = None):
         return np.asarray(out)[:riders].tolist()
 
     collect.launch_labels = {"lanes": lanes} if sweeps is None else {"lanes": lanes, "sweeps": sweeps}
+    collect.outputs = (out,)
+    return collect
+
+
+def _reach_shape_key(lanes: int, fsz: int, n_cap: int, csc_hops) -> tuple:
+    """Compile-cache key of the batched set kernel (chain_reach_batch): the
+    lanes, the frontier's pad, the node space and each swept operand's
+    paddings. Every rider is masked, so the key has no ending."""
+    return (lanes, fsz, n_cap, tuple(int(a.shape[0]) for hop in csc_hops for pair in hop for a in pair))
+
+
+def _pack_mask(mask: np.ndarray, n_cap: int) -> np.ndarray:
+    """pred(node) over a table's compact ids as chain_reach_batch reads it:
+    uint32 [ceil(n_cap / 32)], bit v % 32 of word v // 32 for node v."""
+    bits = np.zeros(-(-n_cap // 32) * 32, dtype=bool)
+    bits[: mask.size] = mask
+    return np.packbits(bits, bitorder="little").view("<u4")
+
+
+def _ring_ids(words: np.ndarray) -> np.ndarray:
+    """The nodes of one bit-packed ring (_pack_mask's layout), ascending."""
+    return np.flatnonzero(np.unpackbits(np.ascontiguousarray(words, dtype="<u4").view(np.uint8), bitorder="little"))
+
+
+def _reached(
+    form: str, t_enter: float, t_ready: float, filter: str = "none", operand: Optional[str] = None,
+    depth: int = 0, memo: str = "fill", ids: int = 0, rings: int = 0,
+) -> None:
+    """One `array::distinct(<chain>)` served, as _served says it of a count:
+    the `graph_reach` counter (`form`: `csc`, the device's sweep of composed
+    operators, or `host`, the hop-by-hop set walk or the KV walk; `filter`:
+    `none`, `fused` or `host` as a count's; `operand`: `composed` for `csc`)
+    and the labels of the expression's `graph_prepare` span come from this
+    one argument list. The span runs from the expression's entry to
+    `t_ready` (the dispatch submit of the expression that fills the
+    statement's ring memo; the look-up's end for one the memo serves; the
+    walk's end for `host`) and is written when the rings are back, so it can
+    say `ids` (the nodes this expression returned) and `rings` (the hops the
+    statement's one program kept) beside `depth` (this chain's pairs) and
+    `memo` (`fill`: this expression ran the chain, for itself and for the
+    statement's others; `hit`: it read their rings)."""
+    telemetry.inc("graph_reach", form=form, filter=filter, operand=operand or "none")
+    labels = {"form": form, "filter": filter, "depth": depth, "memo": memo, "ids": ids, "rings": rings}
+    if operand is not None:
+        labels["operand"] = operand
+    telemetry.stage("graph_prepare", t_enter, t_ready - t_enter, **labels)
+
+
+def _collect_rings(out, riders: int, lanes: int, slots: int):
+    """The collect phase of a batched set chain launched at `lanes` lanes:
+    each rider's rings off the device, uint32 [hops, words] a rider.
+    `lanes` and `slots` (the operand slots the kernel swept a lane, all
+    hops together) join the riders' `dispatch_launch` spans, as
+    _collect_counts' labels do; `outputs` is what the dispatcher waits
+    for."""
+
+    def collect():
+        return list(np.asarray(out)[:riders])
+
+    collect.launch_labels = {"lanes": lanes, "slots": slots}
     collect.outputs = (out,)
     return collect
 
@@ -555,6 +617,21 @@ def _kernels():
         _, out = jax.lax.scan(step, before, slabs)
         return out.transpose(1, 2, 0).reshape(lanes, rows * row)[:, :slots]
 
+    def sweep(x, mirrors, n_cap):
+        """One hop of a dense frontier `x` [lanes, n_cap + 1] (sentinel
+        column n_cap) over the destination-sorted operands `mirrors`
+        ((cptr, csrc), ...): y[v] = the sum of x over the sources of the
+        edges into v, as a gather at the sources, a prefix sum and the
+        differences at the bin bounds. [lanes, n_cap]."""
+        x = x.at[:, n_cap].set(0)
+        zcol = jnp.zeros((x.shape[0], 1), dtype=jnp.int32)
+        y = 0
+        for cptr, csrc in mirrors:
+            vals = x[:, csrc]  # sentinel src reads the zeroed column
+            s = jnp.concatenate([zcol, prefix_sum_rows(vals)], axis=1)
+            y = y + (s[:, cptr[1:]] - s[:, cptr[:-1]])
+        return y
+
     @partial(jax.jit, static_argnames=("n_cap",))
     def chain_count_batch(csc_hops, last_hop, frontiers, weights, n_cap, end_weights=None):
         """Batched count-only chains for B concurrent queries over the SAME
@@ -610,13 +687,7 @@ def _kernels():
         )
         zcol = jnp.zeros((B, 1), dtype=jnp.int32)
         for mirrors in csc_hops:
-            x = x.at[:, n_cap].set(0)
-            y = 0
-            for cptr, csrc in mirrors:
-                vals = x[:, csrc]  # sentinel src reads the zeroed column
-                s = jnp.concatenate([zcol, prefix_sum_rows(vals)], axis=1)
-                y = y + (s[:, cptr[1:]] - s[:, cptr[:-1]])
-            x = jnp.concatenate([y, zcol], axis=1)
+            x = jnp.concatenate([sweep(x, mirrors, n_cap), zcol], axis=1)
         xr = x[:, :n_cap]
         if ends is not None:
             return (xr * ends).sum(axis=1)
@@ -625,6 +696,41 @@ def _kernels():
             deg = ptr[1 : n_cap + 1] - ptr[:n_cap]
             total = total + (xr * deg[None, :]).sum(axis=1)
         return total
+
+    @partial(jax.jit, static_argnames=("n_cap",))
+    def chain_reach_batch(csc_hops, frontiers, masks, n_cap):
+        """Batched SET chains for B concurrent statements over the same
+        composed operators (`array::distinct(<chain>)`: the nodes a walk of
+        exactly h pairs reaches, each once), the set form of
+        chain_count_batch: the frontiers densify to {0, 1}, every hop is
+        the count's sweep with the result clamped back to {0, 1} (so no sum
+        passes the slots of an operator, whatever the walks number), and
+        EVERY hop's frontier is kept, where a count keeps a sum of the last
+        alone. `frontiers` [B, pad] local ids (pad slots at the sentinel
+        n_cap); `masks`: one uint32 [ceil(n_cap / 32)] array a lane, bit
+        v % 32 of word v // 32 set where node v passes the rider's
+        predicate (all of them for a chain without one). Returns uint32
+        [B, hops, words]: the nodes after each hop that pass, bit-packed
+        the same way, so a rider reads back n_cap / 8 bytes a hop whatever
+        it reached."""
+        B = frontiers.shape[0]
+        lane_off = (jnp.arange(B) * (n_cap + 1))[:, None]
+        x = (
+            jnp.zeros(B * (n_cap + 1), dtype=jnp.int32)
+            .at[(lane_off + jnp.clip(frontiers, 0, n_cap)).reshape(-1)]
+            .set(1)
+            .reshape(B, n_cap + 1)
+        )
+        zcol = jnp.zeros((B, 1), dtype=jnp.int32)
+        words = -(-n_cap // 32)
+        shifts = jnp.arange(32, dtype=jnp.uint32)
+        rings = []
+        for mirrors in csc_hops:
+            reached = sweep(x, mirrors, n_cap) > 0
+            bits = jnp.pad(reached, ((0, 0), (0, words * 32 - n_cap))).reshape(B, words, 32)
+            rings.append((bits.astype(jnp.uint32) << shifts).sum(axis=2, dtype=jnp.uint32))
+            x = jnp.concatenate([reached.astype(jnp.int32), zcol], axis=1)
+        return jnp.stack(rings, axis=1) & jnp.stack(masks)[:, None, :]
 
     @partial(jax.jit, static_argnames=("n0",))
     def chain_count_batch_dense(As, outdeg, frontiers, weights, n0, end_weights=None):
@@ -667,6 +773,7 @@ def _kernels():
 
     _JITTED["chain"] = chain_kernel
     _JITTED["chain_count_batch"] = chain_count_batch
+    _JITTED["chain_reach_batch"] = chain_reach_batch
     _JITTED["prefix_sum_rows"] = jax.jit(prefix_sum_rows)
     _JITTED["chain_count_batch_dense"] = chain_count_batch_dense
     return chain_kernel
@@ -760,6 +867,7 @@ class GraphMirrors:
         self._prewarm_deadline: Dict[Tuple[str, str, str], float] = {}
         self._prewarm_running: Set[Tuple[str, str, str]] = set()
         self._warmed_pairs: Set[tuple] = set()
+        self._warmed_reach: Set[tuple] = set()  # set-kernel shapes warmed at every lane count
         # flight-recorder task ids of armed prewarms (bg.py lifecycle)
         self._task_ids: Dict[Tuple[str, str, str], int] = {}
         self._owner = None  # id(ds), for bg teardown scoping
@@ -1490,6 +1598,19 @@ class GraphMirrors:
             ops.append(op)
         return ops
 
+    def _csc_ops(self, ns, db, specs):
+        """The chain's composed sparse operators, one a pair, where one
+        kernel can sweep them in a row: each pair's destination table the
+        next pair's source table, all padded to one node space. None
+        otherwise (_chain_pairs)."""
+        ops = self._chain_pairs(ns, db, specs, self._csc_pair)
+        if ops is not None and any(
+            a["dst_tb"] != b["src_tb"] or a["n_pad"] != b["n_pad"]
+            for a, b in zip(ops, ops[1:])
+        ):
+            return None
+        return ops
+
     def _filtered(self, end: "_EndFilter", op: dict, size: int):
         """The end weights of a count's last pair `op` under `end`, with the
         statement's `graph_filter` span; None where the predicate cannot
@@ -1593,12 +1714,7 @@ class GraphMirrors:
         from surrealdb_tpu import cnf
 
         fsz = _next_pow2(max(frontier.size, cnf.TPU_GRAPH_FRONTIER_PAD))
-        ops = self._chain_pairs(ns, db, specs, self._csc_pair)
-        if ops is not None and any(
-            a["dst_tb"] != b["src_tb"] or a["n_pad"] != b["n_pad"]
-            for a, b in zip(ops, ops[1:])
-        ):
-            ops = None
+        ops = self._csc_ops(ns, db, specs)
         weighted = end is not None
         endw, how, first_hop = None, "fused" if weighted else "none", "sweep"
         if ops is not None:
@@ -1752,6 +1868,20 @@ class GraphMirrors:
         keep = c > 0
         return u[keep].astype(np.int32), c[keep].astype(np.int32)
 
+    def _chain_specs(self, ctx, tables: Set[str], parts: List) -> list:
+        """The hop specs (source tables, directions, foreign tables) of a
+        chain that leaves from `tables`, each source table's mirrors built:
+        a hop filtered on foreign-table ft lands entirely in table ft, so
+        the next hop's sources are exactly p.what."""
+        dir_map = {"out": [keys.DIR_OUT], "in": [keys.DIR_IN], "both": [keys.DIR_IN, keys.DIR_OUT]}
+        specs = []
+        for p in parts:
+            for tb in tables:
+                self.ensure_table(ctx, tb)
+            specs.append((sorted(tables), dir_map[p.dir], p.what))
+            tables = set(p.what)
+        return specs
+
     def _chain_frontier(self, ctx, start: List[Thing], parts: List, count_only: bool = False, where=None):
         """Shared frontier machinery for chain()/chain_count(): returns
         (frontier int32[], counts int32[], interner) — or the scalar path
@@ -1765,16 +1895,7 @@ class GraphMirrors:
         t_enter = _time.perf_counter()
         ns, db = ctx.ns_db()
         it = self.interner(ns, db)
-        dir_map = {"out": [keys.DIR_OUT], "in": [keys.DIR_IN], "both": [keys.DIR_IN, keys.DIR_OUT]}
-        # pre-resolve hop specs; a hop filtered on foreign-table ft lands
-        # entirely in table ft, so the next hop's sources are exactly p.what
-        tables = {t.tb for t in start}
-        specs = []
-        for p in parts:
-            for tb in tables:
-                self.ensure_table(ctx, tb)
-            specs.append((sorted(tables), dir_map[p.dir], p.what))
-            tables = set(p.what)
+        specs = self._chain_specs(ctx, {t.tb for t in start}, parts)
         cmap: Dict[int, int] = {}
         for t in start:
             i = it.lookup(t)
@@ -1899,11 +2020,288 @@ class GraphMirrors:
         _served("host", t_enter, filter="host")
 
 
+    # ------------------------------------------------ the set a chain reaches
+    def chain_distinct(
+        self, ctx, start: Thing, parts: List, where=None, deepest: Optional[List] = None,
+        memo: Optional[dict] = None,
+    ) -> Optional[List[Thing]]:
+        """`array::distinct(<chain>)` from the record `start`: the nodes a
+        walk of the chain's parts ends at, each once, in ascending order of
+        their ids in the mirrors (a table's compact ids rise with the
+        interner's), without the multiset chain() lays out. `where`: the
+        compiled predicate of the final part, as chain_count takes it; the
+        set is then of the nodes that pass. None where the predicate cannot
+        ride the mirrors (the caller walks the KV and says so:
+        reach_walked).
+
+        `deepest`: the parts of the longest chain among the statement's
+        `array::distinct` expressions that this chain is a prefix of, under
+        the same predicate (sql/path.py::mark_chain_families), and `memo`
+        the statement's ring memo. The expression that comes first runs
+        `deepest` ONCE and keeps the set after each of its hops (ring h: a
+        walk of exactly h pairs) under (start, hop specs, predicate
+        binding, operand generations); the statement's other expressions
+        read their ring there. So `array::concat` of the 1-, 2- and 3-pair
+        chains from one record is one device dispatch, not three.
+
+        Where the chain's `->edge->node` pairs have composed sparse
+        operators that line up (_csc_ops), the rings come from the device
+        for every start whose row fits the operator's row pad: no work
+        estimate and no threshold, so a statement's dispatches are fixed by
+        its text. Ring 1 is the first operator's row of the start, read on
+        the host; the later rings are chain_reach_batch's, one sweep a
+        pair, with the rider's predicate as a bit mask kept on the device a
+        binding (_reach_mask).
+        A chain of one pair makes no dispatch. Anything else (TPU_DISABLE,
+        a part over several tables, an odd number of parts, a pair no
+        operator spans, a start whose row of the first operator passes the
+        pad rows are read at: ROW_PAD_MAX): the host's hop-by-hop walk over
+        the mirrors with every frontier a set (_host_reach)."""
+        from surrealdb_tpu import cnf
+
+        t_enter = _time.perf_counter()
+        ns, db = ctx.ns_db()
+        deepest = parts if deepest is None else deepest
+        memo = {} if memo is None else memo
+        specs = self._chain_specs(ctx, {start.tb}, deepest)
+        end = None
+        if where is not None:
+            end = _EndFilter(self, ctx, parts[-1].what[0], where)
+            if end.mirror() is None:
+                return None
+        dispatch = getattr(ctx.ds(), "dispatch", None)
+        ops = None if cnf.TPU_DISABLE or dispatch is None else self._csc_ops(ns, db, specs)
+        key = (
+            start.tb, repr(start.id), tuple((tuple(s), tuple(d), tuple(f)) for s, d, f in specs),
+            None if end is None else (where.binding_key(), id(end.mirror())),
+            tuple(op["gen"] for op in ops) if ops is not None
+            else tuple(m.version for sp in specs for m in self._hop_mirrors(ns, db, sp)),
+        )
+        got = memo.get(key)
+        fill = got is None
+        if fill:
+            got = (
+                self._device_reach(ns, db, start, specs, ops, end, dispatch) if ops is not None
+                else self._host_reach(ns, db, start, specs, end)
+            )
+            if got is None:
+                return None
+            memo[key] = got
+            ctx.executor.op_end = _time.perf_counter()  # the `materialise` span starts here
+        ring = got["rings"].get(len(parts) - 1)
+        if ring is None:  # a ring the deepest chain's program does not keep for this chain
+            return None if deepest is parts else self.chain_distinct(ctx, start, parts, where=where, memo=memo)
+        t_ready = (got["t_ready"] if fill else None) or _time.perf_counter()
+        things = self._things(ns, db, ring)
+        _reached(
+            got["form"], t_enter, t_ready, "none" if end is None else "fused", got["operand"],
+            depth=len(parts) // 2, memo="fill" if fill else "hit", ids=len(things), rings=len(got["rings"]),
+        )
+        return things
+
+    def _device_reach(self, ns, db, start: Thing, specs, ops: List[dict], end, dispatch) -> Optional[dict]:
+        """chain_distinct's rings from the composed operators `ops`: ring h
+        (a chain part's index 2h - 1) as (table, ascending compact ids),
+        kept for the hops that land in the last pair's table (the mask is
+        over that table, and no chain of the statement reads another).
+        None where the predicate's mask cannot be made for this reader."""
+        n_cap, tb = ops[0]["n_pad"], ops[-1]["dst_tb"]
+        got = {"form": "csc", "operand": "composed", "rings": {}, "t_ready": None}  # stamped at the dispatch submit
+        g = self.interner(ns, db).lookup(start)
+        seed = None if g is None else ops[0]["space_src"]["inv"].get(g)
+        indptr, dst = ops[0]["by_src"]
+        row = dst[:0] if seed is None else dst[indptr[seed] : indptr[seed + 1]]
+        if not row.size:  # a record no edge leaves reaches nothing
+            got["rings"] = {2 * h + 1: (tb, np.empty(0, dtype=np.int64)) for h in range(len(ops))}
+            return got
+        swept, fsz = ops[1:], ops[0]["row_pad"]
+        if swept and row.size > fsz:
+            # a hub's row passes the pad its operator reads rows at
+            # (ROW_PAD_MAX; 0: the operator has such a row): the host's walk
+            return self._host_reach(ns, db, start, specs, end)
+        masked = self._reach_mask(end, ops[-1], n_cap)
+        if masked is None:
+            return None
+        words, mask = masked
+        # the first operator's row IS the first hop: no sweep for it
+        if ops[0]["dst_tb"] == tb:
+            first = np.unique(row)
+            got["rings"][1] = (tb, first if mask is None else first[mask[first]])
+        if not swept:
+            return got
+        fr = np.full(fsz, n_cap, dtype=np.int32)
+        fr[: row.size] = row
+        _kernels()
+        kernel = _JITTED["chain_reach_batch"]
+        csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in swept)
+        slots = sum(int(op["csrc"].shape[0]) for op in swept)
+        # the frontier's pad and the operands' ids: riders of any start and
+        # any bound value share a batch (the mask rides as payload)
+        key = ("greach", fsz, n_cap, tuple(id(a) for hop in csc_hops for pair in hop for a in pair))
+
+        def runner(payloads):
+            from surrealdb_tpu import compile_log
+
+            lanes = count_lanes(len(payloads))
+            frs = np.full((lanes, fsz), n_cap, dtype=np.int32)
+            for i, p in enumerate(payloads):
+                frs[i] = p[0]
+            with compile_log.tracked("graph_reach", _reach_shape_key(lanes, fsz, n_cap, csc_hops)):
+                out = kernel(csc_hops, frs, _lane_end_weights(payloads, lanes, at=1), n_cap=n_cap)
+            return _collect_rings(out, len(payloads), lanes, slots)
+
+        self._warm_reach(csc_hops, fsz, n_cap)
+        # the bucket is one batch deep and gathers, as the count's with one
+        # sweep left (_csc_chain_count), at TWO sweeps left too: a set
+        # statement's way back through the host is long (hundreds of record
+        # ids to fetch from), so at the queue's own depth its batches stay
+        # under 2 wide (1,728 dispatches in 14 s, 215 stmt/s, p50 36.3 ms;
+        # one deep and gathering 3.8 wide, 837, 223-229, 34.3-34.5: PERF.md
+        # section 6, PR 44)
+        got["t_ready"] = _time.perf_counter()
+        out = dispatch.submit(key, (fr, words), runner, depth=SWEEP_DEPTH, gather=True)
+        for h, (op, ring) in enumerate(zip(swept, out), start=1):
+            if op["dst_tb"] == tb:
+                got["rings"][2 * h + 1] = (tb, _ring_ids(ring))
+        return got
+
+    def _warm_reach(self, csc_hops, fsz: int, n_cap: int) -> None:
+        """Compile chain_reach_batch at every lane count the runner can
+        return (count_lane_set) for the shapes a statement is being served
+        at, once a shape and in the background (cnf.GRAPH_PREWARM), as
+        idx/ivf.py warms a search's other tiles: the first burst wider than
+        this statement's batch then starts on compiled programs. Not with
+        warm_count_kernels' shapes: a deployment that never asks for a set
+        composes and compiles nothing for one."""
+        from surrealdb_tpu import bg, cnf
+
+        shape = _reach_shape_key(0, fsz, n_cap, csc_hops)
+        with self._lock:
+            if not cnf.GRAPH_PREWARM or shape in self._warmed_reach:
+                return
+            self._warmed_reach.add(shape)
+        kernel = _JITTED["chain_reach_batch"]
+
+        def warm():
+            from surrealdb_tpu import compile_log
+
+            words = np.zeros(-(-n_cap // 32), dtype=np.uint32)
+            for lanes in count_lane_set():
+                try:
+                    with compile_log.tracked(
+                        "graph_reach", _reach_shape_key(lanes, fsz, n_cap, csc_hops), prewarmed=True
+                    ):
+                        kernel(csc_hops, np.full((lanes, fsz), n_cap, dtype=np.int32), (words,) * lanes, n_cap=n_cap)
+                except Exception:
+                    telemetry.inc("prewarm_errors", subsystem="graph_reach")
+
+        # under the arming statement's context, as idx/ft_mirror.py's warms:
+        # a wait behind the statement's own compile lands in that trace
+        bg.spawn(
+            "shape_warm", f"graph_reach:f{fsz}:n{n_cap}:h{len(csc_hops)}",
+            contextvars.copy_context().run, warm, owner=self._owner,
+        )
+
+    def _reach_mask(self, end: Optional["_EndFilter"], op: dict, n_cap: int):
+        """The rider's predicate over the last pair `op`'s destination
+        table as chain_reach_batch reads it (_pack_mask, on the device) and
+        as the host applies it to a first hop's row (bool [n_cap], None:
+        every node passes), kept a (pair, predicate binding) while the
+        operator's generation and the table's column mirror are the ones it
+        was made from, as _end_weights keeps a count's end weights: an
+        acknowledged UPDATE is seen by the next statement. With the
+        statement's `graph_filter` span. None where the column mirror
+        cannot answer for this reader."""
+        import jax.numpy as jnp
+
+        t0 = _time.perf_counter()
+        mirror = None if end is None else end.mirror()
+        key = (op["key"] + ("mask", n_cap), None if end is None else end.compiled.binding_key())
+        with self._lock:
+            got = self._endw.get(key)
+        built = got is None or got["gen"] != op["gen"] or got["mirror"] is not mirror
+        if built:
+            mask = None
+            if end is not None:
+                passing = end.local_mask()
+                if passing is None:
+                    return None
+                mask = np.zeros(n_cap, dtype=bool)
+                mask[: min(passing.size, n_cap)] = passing[:n_cap]
+            words = _pack_mask(np.ones(n_cap, dtype=bool) if mask is None else mask, n_cap)
+            t1 = _time.perf_counter()
+            telemetry.stage("graph_filter_build", t0, t1 - t0, bytes=words.nbytes)
+            got = {"gen": op["gen"], "mirror": mirror, "w": jnp.asarray(words), "mask": mask,
+                   "rows": n_cap if mask is None else int(mask.sum())}
+            telemetry.stage("graph_filter_upload", t1, _time.perf_counter() - t1, bytes=words.nbytes)
+            with self._lock:
+                self._endw.put(key, got, words.nbytes + n_cap)
+        if end is not None:
+            end.span(t0, built, got["rows"])
+        return got["w"], got["mask"]
+
+    def _host_reach(self, ns, db, start: Thing, specs, end) -> Optional[dict]:
+        """chain_distinct's rings by the host's walk over the mirrors, every
+        frontier a set (_host_hop with the counts held at 1): the set after
+        every part. Without a predicate the rings are in the interner's
+        ids, whatever tables a part names; with one, they are kept for the
+        parts that land in the predicate's table, in its compact ids, and
+        masked. None where the mask cannot be made for this reader."""
+        t0 = _time.perf_counter()
+        mask = inv = None
+        if end is not None:
+            mask = end.local_mask()
+            if mask is None:
+                return None
+            inv = self.table_space(ns, db, end.tb)["inv"]
+        g = self.interner(ns, db).lookup(start)
+        frontier = np.array([] if g is None else [g], dtype=np.int32)
+        rings = {}
+        for j, spec in enumerate(specs):
+            frontier, _ = self._host_hop(ns, db, frontier, np.ones(frontier.size, dtype=np.int32), spec)
+            if end is None:
+                rings[j] = (None, frontier)
+            elif list(spec[2]) == [end.tb]:
+                local = np.array([inv.get(int(g), -1) for g in frontier.tolist()], dtype=np.int64)
+                local = local[(local >= 0) & (local < mask.size)]
+                rings[j] = (end.tb, local[mask[local]])
+        if end is not None:
+            end.span(t0, True, int(mask.sum()))
+        return {"form": "host", "operand": None, "rings": rings, "t_ready": None}
+
+    def _things(self, ns, db, ring) -> List[Thing]:
+        """The records of one ring. Compact ids of a table are one take
+        from the table's records as an object array, kept with the table's
+        id space and extended as it grows; ids of the interner (a host
+        walk without a predicate) are looked up one by one."""
+        tb, ids = ring
+        node_of = self.interner(ns, db).node_of
+        if tb is None:
+            return [node_of[g] for g in ids.tolist()]
+        sp = self.table_space(ns, db, tb)
+        with self._lock:
+            arr, globals_ = sp.get("things"), sp["globals"]
+            have = 0 if arr is None else arr.size
+            if arr is None or have < len(globals_):
+                grown = np.empty(len(globals_), dtype=object)
+                grown[:have] = arr
+                for i in range(have, len(globals_)):
+                    grown[i] = node_of[globals_[i]]
+                arr = sp["things"] = grown
+        return arr[ids].tolist()
+
+    @staticmethod
+    def reach_walked(t_enter: float, depth: int, ids: int) -> None:
+        """An `array::distinct(<chain>)` with a WHERE that the KV walk
+        served, from `t_enter` to now: `form=host`, `filter=host`."""
+        _reached("host", t_enter, _time.perf_counter(), filter="host", depth=depth, ids=ids)
+
+
 def graftcheck_sites():
-    """Audit contracts of the three graph count/expand kernels (compile_log
-    subsystems `graph_dense` / `graph_csc` / `graph_chain`): representative
-    2-hop chains over pow2-padded adjacencies at the dispatch lane widths
-    the batched count paths serve."""
+    """Audit contracts of the four graph count/set/expand kernels
+    (compile_log subsystems `graph_dense` / `graph_csc` / `graph_reach` /
+    `graph_chain`): representative 2-hop chains over pow2-padded
+    adjacencies at the dispatch lane widths the batched paths serve."""
     import jax
     import jax.numpy as jnp
 
@@ -1950,6 +2348,21 @@ def graftcheck_sites():
         return (
             lambda ch, lh, fr, cw: kernel(ch, lh, fr, cw, n_cap=n_cap),
             (csc_hops, last_hop, lanes_fsz, lanes_fsz),
+        )
+
+    def build_reach(shape):
+        _kernels()
+        kernel = _JITTED["chain_reach_batch"]
+        lanes = shape["lanes"]
+        csc_hops = tuple(
+            ((jax.ShapeDtypeStruct((n_cap + 1,), jnp.int32),
+              jax.ShapeDtypeStruct((path_slots(E),), jnp.int32)),)
+            for _ in range(shape["hops"])
+        )
+        masks = (jax.ShapeDtypeStruct((n_cap // 32,), jnp.uint32),) * lanes
+        return (
+            lambda ch, fr, ms: kernel(ch, fr, ms, n_cap=n_cap),
+            (csc_hops, jax.ShapeDtypeStruct((lanes, fsz), jnp.int32), masks),
         )
 
     def build_chain(shape):
@@ -2006,6 +2419,20 @@ def graftcheck_sites():
                 {"label": f"l8_f{fsz}_n{n0}_h2_p{E + 1}", "lanes": 8, "hops": 2, "weighted": False, "paths": E + 1}
             ],
             "build": build_csc,
+        },
+        {
+            "subsystem": "graph_reach",
+            "module": __name__,
+            "kind": "single",
+            "allowed_collectives": (),
+            "out_dtypes": ("uint32",),
+            # the rings after two swept hops (a chain of three pairs from
+            # the first operator's rows), bit-packed, at every lane count
+            "shapes": [
+                {"label": f"l{lanes}_f{fsz}_n{n_cap}_h2", "lanes": lanes, "hops": 2}
+                for lanes in count_lane_set()
+            ],
+            "build": build_reach,
         },
         {
             "subsystem": "graph_chain",

@@ -672,6 +672,7 @@ def explain(ctx, stm, sources: List[Any], full: bool = False) -> List[dict]:
         IRange,
         ITable,
         IThing,
+        IThings,
         IValue,
     )
 
@@ -686,6 +687,8 @@ def explain(ctx, stm, sources: List[Any], full: bool = False) -> List[dict]:
             out.append({"detail": {"table": s.tb}, "operation": "Iterate Range"})
         elif isinstance(s, IThing):
             out.append({"detail": {"thing": s.t}, "operation": "Iterate Thing"})
+        elif isinstance(s, IThings):
+            out.extend({"detail": {"thing": t}, "operation": "Iterate Thing"} for t in s.ts)
         elif isinstance(s, IValue):
             out.append({"detail": {"value": s.v}, "operation": "Iterate Value"})
     if getattr(stm, "parallel", False) and len(planned) > 1:

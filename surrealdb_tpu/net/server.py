@@ -1127,8 +1127,11 @@ class Server:
         tls_key: Optional[str] = None,
         cors_origins="*",
     ):
-        from surrealdb_tpu import cnf, device
+        from surrealdb_tpu import cnf, device, telemetry
 
+        # this process holds a datastore to serve it: what survives a long
+        # full collection is that data, and is not walked again
+        telemetry.freeze_long_lived()
         # initialise the JAX backend NOW, not inside the first kNN
         # statement: a process whose accelerator failed to come up serves
         # every kernel on JAX's CPU backend, and start-up is where that
@@ -1166,8 +1169,6 @@ class Server:
         try:
             ds.bootstrap()
         except Exception:  # noqa: BLE001 — single-node boot must not die
-            from surrealdb_tpu import telemetry
-
             # counted, not silent: a boot that skipped node registration
             # serves fine single-node but is a membership-protocol gap
             telemetry.inc("bootstrap_errors")

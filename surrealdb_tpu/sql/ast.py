@@ -643,11 +643,15 @@ class KnnOp(Expr):
 
 # ------------------------------------------------------------------ calls
 class FunctionCall(Expr):
-    __slots__ = ("name", "args")
+    __slots__ = ("name", "args", "reach")
 
     def __init__(self, name: str, args: List[Expr]):
         self.name = name
         self.args = args
+        # the parser's note on `array::distinct(<graph chain>)`: the longest
+        # chain among the statement's calls of that kind that this call's
+        # chain is a prefix of (sql/path.py::mark_chain_families)
+        self.reach = None
 
     def compute(self, ctx):
         from surrealdb_tpu import fnc
@@ -661,6 +665,14 @@ class FunctionCall(Expr):
             n = graph_chain_count(ctx, self.args[0])
             if n is not None:
                 return n
+        # array::distinct(->graph->chain) reads the set off the mirrors hop
+        # by hop instead of shrinking the expanded multiset
+        if self.reach is not None:
+            from surrealdb_tpu.sql.path import graph_chain_distinct
+
+            found = graph_chain_distinct(ctx, self.args[0], self.reach)
+            if found is not None:
+                return found
         args = [a.compute(ctx) for a in self.args]
         return fnc.run(ctx, self.name, args, exprs=self.args)
 

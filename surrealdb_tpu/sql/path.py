@@ -415,6 +415,35 @@ def _prefix_end(p: bytes) -> bytes:
     return prefix_end(p)
 
 
+def _chain_over_record(ctx, expr):
+    """(the current record's id, the parts) where `expr`, the one argument
+    of an aggregate (`count`, `array::distinct`), is an idiom of graph
+    parts alone over the current record; None for anything else."""
+    if not is_graph_chain(expr):
+        return None
+    doc = ctx.doc
+    rid = doc.rid if doc is not None else None
+    return (rid, expr.parts) if isinstance(rid, Thing) else None
+
+
+def _riding_where(ctx, parts):
+    """The compiled predicate (ops/predicates.py) of a chain whose final
+    part alone has a WHERE, names one node table there and lowers onto
+    that table's column mirror, every part eligible for the mirrors
+    (_mirror_eligible): what can ride an aggregate of the chain as a
+    mask. None where the chain or the predicate is not of that kind."""
+    last = parts[-1]
+    if last.cond is None or len(last.what) != 1:
+        return None
+    if not all(_mirror_eligible(ctx, p) for p in parts[:-1]):
+        return None
+    if not _mirror_eligible(ctx, last, cond_ok=True):
+        return None
+    from surrealdb_tpu.ops.predicates import compile_where
+
+    return compile_where(ctx, last.cond)
+
+
 def graph_chain_count(ctx, expr) -> "int | None":
     """count(->a->b->c) fast path: when the argument is a graph-chain idiom
     over the current record whose parts name their tables, sum the path
@@ -431,25 +460,21 @@ def graph_chain_count(ctx, expr) -> "int | None":
     uncommitted edge writes, a column mirror this reader may not use) is
     walked here as the caller would walk it, so that its `graph_prepare`
     span can say `filter=host`."""
-    if not isinstance(expr, Idiom) or not expr.parts:
+    chain = _chain_over_record(ctx, expr)
+    if chain is None:
         return None
-    if not all(isinstance(p, PGraph) for p in expr.parts):
-        return None
-    doc = ctx.doc
-    rid = doc.rid if doc is not None else None
-    if not isinstance(rid, Thing):
-        return None
-    if all(p.cond is None for p in expr.parts):
-        for p in expr.parts:
+    rid, parts = chain
+    if all(p.cond is None for p in parts):
+        for p in parts:
             if not _mirror_eligible(ctx, p):
                 return None
         # no exception guard: deadline/internal errors must propagate, not
         # silently re-run the whole traversal on the slow path
-        n = ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(expr.parts))
+        n = ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(parts))
         ctx.executor.op_end = time.perf_counter()
         return n
     t_enter = time.perf_counter()
-    n = _filtered_chain_count(ctx, rid, expr.parts)
+    n = _filtered_chain_count(ctx, rid, parts)
     if n is None:
         from surrealdb_tpu import fnc
 
@@ -464,19 +489,93 @@ def graph_chain_count(ctx, expr) -> "int | None":
 def _filtered_chain_count(ctx, rid: Thing, parts) -> "int | None":
     """The count of a chain whose final part alone has a WHERE, off the
     mirrors; None where the chain or the predicate is not of that kind."""
-    last = parts[-1]
-    if last.cond is None or len(last.what) != 1:
-        return None
-    if not all(_mirror_eligible(ctx, p) for p in parts[:-1]):
-        return None
-    if not _mirror_eligible(ctx, last, cond_ok=True):
-        return None
-    from surrealdb_tpu.ops.predicates import compile_where
-
-    where = compile_where(ctx, last.cond)
+    where = _riding_where(ctx, parts)
     if where is None:
         return None
     return ctx.ds().graph_mirrors.chain_count(ctx, [rid], list(parts), where=where)
+
+
+def graph_chain_distinct(ctx, expr, deepest) -> "list | None":
+    """array::distinct(->a->b->c) fast path: the set form of
+    graph_chain_count, for the same chains by the same test (no WHERE, or
+    one on the final node part that rides as a mask), served off the
+    mirrors as sets hop by hop (idx/graph_csr.py chain_distinct) and never
+    as the multiset chain() lays out for `array::distinct` to shrink.
+    `deepest` is what the parser found for this call
+    (mark_chain_families): the longest chain among the statement's
+    `array::distinct` calls that this one is a prefix of. One run of it
+    serves them all through the statement's ring memo, where it is still
+    this chain under this predicate as the statement is bound NOW (a
+    cached template's literals are slots: two that were one text when
+    parsed may be bound apart). None when ineligible, and the caller
+    evaluates as it always did; a chain with a WHERE that cannot ride is
+    evaluated here, as the caller would, so that its `graph_prepare` span
+    can say `filter=host`."""
+    chain = _chain_over_record(ctx, expr)
+    if chain is None:
+        return None
+    rid, parts = chain
+    cond_free = all(p.cond is None for p in parts)
+    if cond_free and not all(_mirror_eligible(ctx, p) for p in parts):
+        return None
+    t_enter = time.perf_counter()
+    where = None if cond_free else _riding_where(ctx, parts)
+    found = None
+    if cond_free or where is not None:
+        far = deepest.parts if _rides_alike(ctx, deepest.parts, parts, where) else parts
+        found = ctx.ds().graph_mirrors.chain_distinct(
+            ctx, rid, parts, where=where, deepest=far, memo=ctx.executor.reach_memo
+        )
+    if found is None:
+        from surrealdb_tpu import fnc
+
+        found = fnc.run(ctx, "array::distinct", [expr.compute(ctx)], exprs=[expr])
+        mirrors = getattr(ctx.ds(), "graph_mirrors", None)
+        if mirrors is not None:
+            mirrors.reach_walked(t_enter, len(parts) // 2, len(found))
+    return found
+
+
+def _rides_alike(ctx, far: List[Part], parts: List[Part], where) -> bool:
+    """Can the chain `far`, of which `parts` was a prefix when the
+    statement was parsed (chain_prefix), stand in for it as the statement
+    is bound now: eligible for the mirrors, and under `where`'s very
+    binding (the predicate's text and the constants bound into it)?"""
+    if far is parts:
+        return True
+    if where is None:
+        return all(_mirror_eligible(ctx, p) for p in far)
+    their = _riding_where(ctx, far)
+    return their is not None and their.binding_key() == where.binding_key()
+
+
+def chain_prefix(a: List[Part], b: List[Part]) -> bool:
+    """Is the graph chain `a` a prefix of the graph chain `b` under one
+    predicate: the same direction and tables part for part, no WHERE
+    before either's final part, and on the final parts the same WHERE text
+    (or none on both)? The set `a` reaches is then the set `b` reaches
+    after len(a) parts."""
+    if len(a) > len(b) or repr(a[-1].cond) != repr(b[-1].cond):
+        return False
+    if any(p.cond is not None for p in a[:-1] + b[:-1]):
+        return False
+    return all(p.dir == q.dir and p.what == q.what for p, q in zip(a, b))
+
+
+def mark_chain_families(calls: List) -> None:
+    """The parser's note on each `array::distinct(<graph chain>)` call of
+    one statement (ast.FunctionCall.reach): the argument of the call with
+    the longest chain that this call's chain is a prefix of (chain_prefix;
+    its own where there is no other). Kept with the statement's AST, so
+    with the plan cache's template of it."""
+    for call in calls:
+        mine = call.args[0]
+        family = [c.args[0] for c in calls if chain_prefix(mine.parts, c.args[0].parts)]
+        call.reach = max(family, key=lambda idiom: len(idiom.parts), default=mine)
+
+
+def is_graph_chain(expr) -> bool:
+    return isinstance(expr, Idiom) and bool(expr.parts) and all(isinstance(p, PGraph) for p in expr.parts)
 
 
 def _mirror_eligible(ctx, p: PGraph, cond_ok: bool = False) -> bool:

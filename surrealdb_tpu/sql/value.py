@@ -141,6 +141,8 @@ class Thing:
         )
 
     def __hash__(self):
+        if type(self.id) is int or type(self.id) is str:
+            return hash((self.tb, self.id))
         try:
             return hash((self.tb, _hashable(self.id)))
         except TypeError:

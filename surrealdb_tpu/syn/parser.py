@@ -72,6 +72,7 @@ class Parser:
         self.i = 0
         self._no_graph = 0  # >0: don't consume ->/<- as idiom parts (RELATE)
         self._depth = 0  # expression nesting, bounded by _MAX_PARSE_DEPTH
+        self.chain_sets: List[A.FunctionCall] = []  # the statement's array::distinct(<graph chain>) calls
 
     # ------------------------------------------------------------- helpers
     def peek(self, off: int = 0) -> Token:
@@ -149,7 +150,9 @@ class Parser:
             if self.peek().kind == "EOF":
                 break
             start = self.peek().pos
+            self.chain_sets = []
             stmts.append(self.parse_statement())
+            P.mark_chain_families(self.chain_sets)
             spans.append((start, self.peek().pos))
             if self.peek().kind == "EOF":
                 break
@@ -2069,6 +2072,8 @@ class Parser:
                         break
                 self.expect_op(")")
                 call = A.FunctionCall(full, args)
+                if full.lower() == "array::distinct" and len(args) == 1 and P.is_graph_chain(args[0]):
+                    self.chain_sets.append(call)
                 parts5: List[P.Part] = [P.PStart(call)]
                 self._idiom_tail(parts5)
                 if len(parts5) > 1:

@@ -11,7 +11,9 @@ environment has no OTLP collector, so the equivalent surface is:
   execution, device dispatches, RPC methods and HTTP requests;
 - the interpreter's collections (one `gc.callbacks` hook): the
   `gc_collections{gen}` counter, the `gc_pause{gen}` duration histogram,
-  and a `gc_pause` span in the trace active on the collecting thread;
+  and a `gc_pause` span in the trace active on the collecting thread; in a
+  server's process the hook also freezes the survivors of a long full
+  collection (freeze_long_lived; the `gc_frozen_objects` gauge);
 - a structured slow-query ring buffer (sql, duration, plan summary,
   dispatch stats, error) drained via `snapshot()` or GET /slow;
 - span recording around statement execution and device dispatches,
@@ -187,6 +189,27 @@ def _gc_zero() -> list:
 _gc_cells: Dict[int, list] = {g: _gc_zero() for g in range(3)}
 _gc_t0 = 0.0
 
+# A full collection walks every tracked object with every thread stopped:
+# 2.2-2.4 s at the 9.3 million objects of a loaded SF3 graph, 39 times
+# during its load and again whenever a quarter as many objects have grown old
+# since (PERF.md section 6, PR 44). Where the process is a server's
+# (freeze_long_lived), the survivors of a full collection that stopped it
+# this long are the data it holds: they are moved out of the collector's
+# sight (gc.freeze), and the next full collection walks what came since.
+# What is frozen is still freed when its last reference goes; a reference
+# cycle that forms among frozen objects and is dropped is not.
+GC_FREEZE_OVER_S = 0.1
+_gc_freeze = False
+
+
+def freeze_long_lived() -> None:
+    """From now on, freeze what survives a full collection that took
+    GC_FREEZE_OVER_S or longer (net/server.py::Server: a process that holds
+    a datastore to serve it; a library user's process keeps its collector
+    as it is). `gc_frozen_objects` on /metrics says how many that is."""
+    global _gc_freeze
+    _gc_freeze = True
+
 
 def _gc_hook(phase: str, info: dict) -> None:
     global _gc_t0
@@ -196,6 +219,8 @@ def _gc_hook(phase: str, info: dict) -> None:
     t0 = _gc_t0
     dur = time.perf_counter() - t0
     gen = info["generation"]
+    if gen == 2 and _gc_freeze and dur >= GC_FREEZE_OVER_S:
+        gc.freeze()
     h = _gc_cells[gen]
     h[bisect_left(DURATION_BUCKETS, dur)] += 1
     h[-3] += dur
@@ -417,6 +442,7 @@ def collect_node_metrics(ds=None) -> None:
         )
     except (OSError, ValueError, IndexError):
         pass
+    gauge_set("gc_frozen_objects", gc.get_freeze_count())
     if ds is not None and getattr(ds, "notifications", None) is not None:
         gauge_set("live_queries", ds.notifications.live_count())
     # workload statistics plane: how many statement shapes the bounded

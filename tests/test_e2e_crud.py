@@ -401,3 +401,49 @@ def test_remove_table(ds):
 def test_mock_source(ds):
     r = ds.execute("CREATE |m:5|;")
     assert len(ok(r[0])) == 5
+
+
+@pytest.mark.parametrize("sql, want", [
+    ("SELECT VALUE n FROM [t:3, t:1, t:9, t:2]", [3, 1, 2]),
+    ("SELECT VALUE n FROM [t:3, t:1, t:9, t:2] LIMIT 2", [3, 1]),
+    ("SELECT VALUE n FROM [t:3, t:9, t:1, t:2] LIMIT 1 START 1", [1]),
+    ("SELECT VALUE n FROM (SELECT VALUE id FROM t WHERE n > 1) ORDER BY n DESC", [3, 2]),
+    ("SELECT VALUE n FROM [t:1, t:1]", [1, 1]),
+    ("SELECT VALUE n FROM [t:2, 7, t:1]", [2, NONE, 1]),
+])
+def test_an_array_of_record_ids_is_one_source_read_in_order(ds, sql, want):
+    """`FROM <array of record ids>` (what a graph traversal hands over) is
+    one IThings source: the records in the array's order, a missing one
+    skipped, read until the statement is full; a mixed array keeps the
+    per-value path."""
+    ds.execute("CREATE t:1 SET n = 1; CREATE t:2 SET n = 2; CREATE t:3 SET n = 3;")
+    assert ok(ds.execute(sql)[0]) == want
+
+
+def test_an_array_of_record_ids_explains_updates_and_deletes_an_id_at_a_time(ds):
+    ds.execute("CREATE t:1 SET n = 1; CREATE t:2 SET n = 2; CREATE t:3 SET n = 3;")
+    plan = ok(ds.execute("SELECT * FROM [t:2, t:1] EXPLAIN")[0])
+    assert [(p["operation"], p["detail"]["thing"]) for p in plan] == [
+        ("Iterate Thing", Thing("t", 2)), ("Iterate Thing", Thing("t", 1))]
+    assert [r["n"] for r in ok(ds.execute("UPDATE [t:1, t:3] SET n += 10")[0])] == [11, 13]
+    ds.execute("DELETE [t:1, t:2]")
+    assert ok(ds.execute("SELECT VALUE n FROM t")[0]) == [13]
+
+
+@pytest.mark.parametrize("parallel, sources", [(False, ["IThings"]), (True, ["IThing", "IThing", "IThing"])])
+def test_a_parallel_select_keeps_a_source_an_id_so_their_dispatches_overlap(ds, monkeypatch, parallel, sources):
+    """PARALLEL runs the statement's sources side by side
+    (Iterator._iterate_parallel), which is where per-record device
+    dispatches coalesce: there an array of record ids stays a source an id."""
+    from surrealdb_tpu.dbs import iterator
+
+    ds.execute("CREATE t:1 SET n = 1; CREATE t:2 SET n = 2; CREATE t:3 SET n = 3;")
+    seen, side_by_side = [], []
+    ingest = iterator.classify_sources
+    run = iterator.Iterator._iterate_parallel
+    monkeypatch.setattr("surrealdb_tpu.dbs.stmt_exec.classify_sources",
+                        lambda *a, **k: seen.append([type(s).__name__ for s in ingest(*a, **k)]) or ingest(*a, **k))
+    monkeypatch.setattr(iterator.Iterator, "_iterate_parallel", lambda self: side_by_side.append(len(self.entries)) or run(self))
+    rows = ok(ds.execute("SELECT VALUE n FROM [t:3, t:1, t:2]" + (" PARALLEL" if parallel else ""))[0])
+    assert rows == [3, 1, 2] and seen == [sources]
+    assert side_by_side == ([3] if parallel else [])

@@ -300,6 +300,45 @@ def test_a_collection_outside_any_trace_is_counted_and_takes_no_lock():
     assert telemetry.snapshot()["counters"]['gc_collections{gen="2"}'] >= 1
 
 
+@pytest.mark.parametrize("armed, over_s, frozen", [(True, 0.0, True), (True, 3600.0, False), (False, 0.0, False)])
+def test_a_server_s_process_freezes_what_survives_a_long_full_collection(monkeypatch, armed, over_s, frozen):
+    """A full collection walks every tracked object with every thread
+    stopped. Armed (a process that serves a datastore: net/server.py), the
+    hook moves the survivors of one that took GC_FREEZE_OVER_S or longer out
+    of the collector's sight; a short one, a young one, and a process that
+    only imports the library leave the collector as it is."""
+    monkeypatch.setattr(telemetry, "_gc_freeze", armed)
+    monkeypatch.setattr(telemetry, "GC_FREEZE_OVER_S", over_s)
+    gc.unfreeze()
+    try:
+        gc.collect(0), gc.collect(1)
+        assert gc.get_freeze_count() == 0
+        held = [{"k": [i]} for i in range(1000)]
+        gc.collect()
+        assert (gc.get_freeze_count() > 1000) is frozen
+        if frozen:
+            before = gc.get_freeze_count()
+            del held  # what is frozen is still freed when its last reference goes
+            assert gc.get_freeze_count() <= before - 2000
+            telemetry.collect_node_metrics()
+            # (other threads free frozen objects meanwhile)
+            assert abs(telemetry.snapshot()["gauges"]["gc_frozen_objects"] - gc.get_freeze_count()) < 1000
+    finally:
+        gc.unfreeze()
+
+
+def test_starting_a_server_arms_the_freeze(monkeypatch):
+    from surrealdb_tpu.net.server import serve
+
+    monkeypatch.setattr(telemetry, "_gc_freeze", False)
+    srv = serve("memory", port=0, auth_enabled=False)
+    try:
+        assert telemetry._gc_freeze is True
+    finally:
+        srv.shutdown()
+        srv.ds.close()
+
+
 # ------------------------------------------------------------------ durations
 def test_durations_are_read_off_the_histograms():
     assert not hasattr(telemetry, "_durations")
