@@ -42,7 +42,14 @@ Throughput hardening (the scale-1.0 concurrent-kNN collapse fixes):
   the next. submit(gather=True) on a one-deep bucket lets the leader wait
   for riders on their way: until the queue is as wide as the batch before
   last, at most the time one launch phase takes (_gather). A lone session
-  never waits; a rider that has left costs the wait once.
+  never waits; a rider that has left costs the wait once. Who asks: the
+  column sweep (ops/pipeline.py: the depth alone), and with the gathering
+  the sparse count from the rows with one sweep left, the set chain from
+  the rows and the dense count with an operator product
+  (idx/graph_csr.py). stats() counts how the gathering fares:
+  `gather_waits` (a leader found its queue narrower than the batch before
+  last, and waited), `gather_met` (of those: the riders came before the
+  deadline) and `gather_wait_s` (the seconds waited).
 
 - **Memory-aware split-retry**: a batch that fails transiently
   (RESOURCE_EXHAUSTED and friends) is NOT re-executed at full width.
@@ -164,8 +171,9 @@ class _Req:
 
 # The pipeline depth a family names for a bucket whose launch costs the device
 # the same whatever its riders: a sweep of a whole table (ops/pipeline.py:
-# 1.440 ms at 1 rider and at 8 over 3.0M rows, PERF.md section 6, PR 40) or
-# of a graph operator (idx/graph_csr.py: PR 42). The statements that arrive
+# 1.440 ms at 1 rider and at 8 over 3.0M rows, PERF.md section 6, PR 40), of
+# a graph operator (idx/graph_csr.py: PR 42) or a dense count's operator
+# products at its 8 lanes (the same file: PR 45). The statements that arrive
 # while a sweep is in flight ride the next one together, where a second sweep
 # beside it would take some of them, wait behind the first on the device
 # anyway, and cost the host a launch, a collect and a read-back of its own
@@ -241,6 +249,9 @@ class DispatchQueue:
         self.ready_wait_s = 0.0  # of collect_s: until the outputs were ready on the device
         self.fetch_s = 0.0  # of collect_s: from ready to the results as host values
         self.pipeline_wait_s = 0.0  # leaders blocked on the depth semaphore
+        self.gather_waits = 0  # leaders that found their queue narrower than the batch before last, and waited (_gather)
+        self.gather_met = 0  # of those: the riders came before the deadline
+        self.gather_wait_s = 0.0  # the seconds those leaders waited
         self.width_counts: Dict[int, int] = {}  # batch width -> dispatch count
         # the state clock (module docstring): three counts, the stamp of
         # their last change, and the seconds spent in each state
@@ -364,13 +375,12 @@ class DispatchQueue:
         waited = _time.perf_counter() - t_sem
         try:
             with b.lock:
-                if b.gather:
-                    self._gather(b)
+                gathered = self._gather(b) if b.gather else None
                 width = min(len(b.queue), self._max_width())
                 batch, b.queue = b.queue[:width], b.queue[width:]
                 if b.gather:
                     b.widths = (b.widths + [width])[-2:]
-            finish = self._launch(batch, b, waited) if batch else None
+            finish = self._launch(batch, b, waited, gathered) if batch else None
             with b.lock:
                 if b.queue:
                     nxt = b.queue[0]
@@ -387,7 +397,7 @@ class DispatchQueue:
             b.sem.release()
 
     @staticmethod
-    def _gather(b: _Bucket) -> None:
+    def _gather(b: _Bucket) -> Optional[tuple]:
         """The leader of a one-deep bucket, the device free, waits for the
         riders of its group (caller holds b.lock). One deep, sessions in a
         closed loop ride alternate batches: while one batch is in flight the
@@ -400,18 +410,24 @@ class DispatchQueue:
         same at any width, a rider is worth that wait, since the launch of
         its own it would otherwise need costs the host as much. A rider
         that does not come costs the wait once: the width that launched is
-        what the batch after next expects. A lone session never waits."""
+        what the batch after next expects. A lone session never waits.
+        Returns (the seconds waited, whether the riders came) for the
+        queue's `gather_*` counters, None where there was nothing to wait
+        for."""
         want = b.widths[0] if len(b.widths) == 2 else 0
-        if len(b.queue) < want:
-            deadline = _time.perf_counter() + b.launch_s()
-            b.awaiting = True
-            try:
-                while len(b.queue) < want:
-                    left = deadline - _time.perf_counter()
-                    if left <= 0 or not b.arrived.wait(left):
-                        break
-            finally:
-                b.awaiting = False
+        if len(b.queue) >= want:
+            return None
+        t0 = _time.perf_counter()
+        deadline = t0 + b.launch_s()
+        b.awaiting = True
+        try:
+            while len(b.queue) < want:
+                left = deadline - _time.perf_counter()
+                if left <= 0 or not b.arrived.wait(left):
+                    break
+        finally:
+            b.awaiting = False
+        return _time.perf_counter() - t0, len(b.queue) >= want
 
     def _charge_batch(self, batch: List[_Req], elapsed: float, meter: str) -> None:
         """Tenant accounting: split one batch phase's elapsed time EQUALLY
@@ -442,12 +458,14 @@ class DispatchQueue:
             tracing.record_span_into(r.trace_ctx, name, labels, start, dur, error)
 
     def _launch(
-        self, batch: List[_Req], b: _Bucket, pipeline_wait: float
+        self, batch: List[_Req], b: _Bucket, pipeline_wait: float,
+        gathered: Optional[tuple],
     ) -> Optional[Callable[[], None]]:
         """Phase 1: run the leader's runner. Sync runners finish here;
         two-phase runners return the collect closure to run after the
         bucket hand-off. A transient launch failure also returns a closure
-        (the split-retry), so the hand-off never waits on re-execution."""
+        (the split-retry), so the hand-off never waits on re-execution.
+        `gathered`: what this batch's leader waited for its group (_gather)."""
         from surrealdb_tpu import telemetry, tracing
 
         with self._lock:
@@ -455,6 +473,10 @@ class DispatchQueue:
             self.dispatches += 1
             self.batched += len(batch) - 1
             self.pipeline_wait_s += pipeline_wait
+            if gathered is not None:
+                self.gather_waits += 1
+                self.gather_wait_s += gathered[0]
+                self.gather_met += gathered[1]
             self.width_counts[len(batch)] = self.width_counts.get(len(batch), 0) + 1
             self._move(queued=-len(batch), launching=1)
         payloads = [r.payload for r in batch]
@@ -764,6 +786,9 @@ class DispatchQueue:
                 "launch_s": round(self.launch_s, 4),
                 "collect_s": round(self.collect_s, 4),
                 "pipeline_wait_s": round(self.pipeline_wait_s, 4),
+                "gather_waits": self.gather_waits,
+                "gather_met": self.gather_met,
+                "gather_wait_s": self.gather_wait_s,
                 "ready_wait_s": self.ready_wait_s,
                 "fetch_s": self.fetch_s,
                 "fed_s": self.fed_s,

@@ -1683,7 +1683,21 @@ class GraphMirrors:
             return _collect_counts(out, len(payloads), len(frs))
 
         _served("dense", t_enter, filter=how)
-        return dispatch.submit(key, (fr, cw, endw) if weighted else (fr, cw), runner)
+        # with an operator product the program runs its 8 lanes in the same
+        # 0.54 ms at any width (snbsf1.hop3_c8: the operator's 194 MB read),
+        # far shorter than its riders' way back through the host, and a
+        # launch costs the host the same whatever it carries: one deep and
+        # gathering, as the sparse count from the rows is (dbs/dispatch.py::
+        # SWEEP_DEPTH, _gather). On the chip, eight sessions, one deep
+        # WITHOUT the gathering: 2.68 riders a launch against 1.91, 486.8
+        # stmt/s at p50 16.54 against 429-449 at 17.5 (PERF.md section 7 w,
+        # PR 42); as shipped: section 6, PR 45. A one-pair count (a dot
+        # product, no cell) keeps the queue's own depth
+        paced = bool(As)
+        return dispatch.submit(
+            key, (fr, cw, endw) if weighted else (fr, cw), runner,
+            depth=SWEEP_DEPTH if paced else None, gather=paced,
+        )
 
     def _csc_chain_count(self, ns, db, frontier, counts, specs, dispatch, t_enter=None, end=None):
         """Count chain in the scatter-free CSC prefix-sum form

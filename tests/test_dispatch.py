@@ -713,3 +713,103 @@ def test_what_a_launch_costs_a_gathering_bucket_leaves_out_its_longest_and_short
     b = _Bucket(1, gather=True)
     b.launches = launches
     assert b.launch_s() == pytest.approx(cost)
+
+
+# ------------------------------------------------------------------ the gathering's own counters
+def _gather_counts(q) -> tuple:
+    s = q.stats()
+    return s["gather_waits"], s["gather_met"], s["gather_wait_s"]
+
+
+def test_a_rider_that_comes_is_a_wait_that_was_met():
+    q, widths, waits, submit = _gathering(0.1)
+    submit(1)
+    _together(submit, [2, 3, 4], gap_s=0.03)
+    submit(5)
+    assert _gather_counts(q) == (0, 0, 0.0)  # nobody has had anyone to wait for yet
+    _together(submit, [6, 7], gap_s=0.03)  # 6 expects a second rider and 7 comes
+    n, met, wait_s = _gather_counts(q)
+    assert (n, met) == (1, 1) and widths[-1] == 2
+    assert 0.0 < wait_s < q._buckets["g"].launch_s() * 1.5  # it came before the deadline: less than the launch phase
+
+
+def test_a_rider_that_has_left_is_a_wait_that_was_not_met():
+    q, widths, waits, submit = _gathering(0.1)
+    submit(1)
+    _together(submit, [2, 3, 4], gap_s=0.03)
+    submit(5)
+    bound = q._buckets["g"].launch_s()
+    submit(6)  # expects a second rider: none comes
+    n, met, wait_s = _gather_counts(q)
+    assert (n, met) == (1, 0) and wait_s >= 0.5 * bound
+    for x in (7, 8, 9):
+        submit(x)
+    assert _gather_counts(q) == (n, met, wait_s)  # the wait is paid once
+
+
+@pytest.mark.parametrize("kwargs", [
+    {"depth": 1, "gather": True}, {"depth": 1}, {"depth": 2, "gather": True}, {},
+], ids=["gathering", "one_deep", "two_deep", "the_knob_s"])
+def test_a_lone_session_counts_no_wait_in_any_bucket(kwargs):
+    q = DispatchQueue()
+    run = lambda xs: (lambda: [x * 10 for x in xs])
+    assert [q.submit("k", x, run, **kwargs) for x in range(6)] == [x * 10 for x in range(6)]
+    assert _gather_counts(q) == (0, 0, 0.0)
+
+
+def test_stats_with_the_gather_counters_still_diff_numerically():
+    q, widths, waits, submit = _gathering(0.05)
+    s0 = q.stats()
+    submit(1)
+    _together(submit, [2, 3, 4], gap_s=0.02)
+    submit(5)
+    submit(6)
+    s1 = q.stats()
+    assert set(s0) == set(s1) and {"gather_waits", "gather_met", "gather_wait_s"} <= set(s1)
+    d = {k: s1[k] - s0[k] for k in s1}  # what benchmarks/run.py and the slow-query record do
+    assert all(isinstance(v, (int, float)) and not isinstance(v, bool) for v in s1.values())
+    assert isinstance(s1["gather_waits"], int) and isinstance(s1["gather_met"], int)
+    assert d["gather_waits"] == 1 and d["gather_met"] == 0 and d["gather_wait_s"] > 0
+    assert d["gather_met"] <= d["gather_waits"] <= d["dispatches"]
+
+
+def test_the_gather_counters_lose_no_update_under_many_sessions():
+    """Twenty-four closed-loop sessions on one gathering bucket, the
+    interpreter switching threads every 10 us: every leader's wait is
+    counted once, under the lock the other counters share."""
+    import sys
+
+    from surrealdb_tpu.dbs.dispatch import SWEEP_DEPTH
+
+    q, gathered = DispatchQueue(), []
+    real = DispatchQueue._gather
+
+    def spy(b):
+        got = real(b)
+        gathered.append(got)
+        return got
+
+    q._gather = spy
+    run = lambda xs: (lambda: [x + 1 for x in xs])
+    sessions, rounds, out = 24, 25, {}
+
+    def session(i):
+        out[i] = [q.submit("g", i * rounds + r, run, depth=SWEEP_DEPTH, gather=True) for r in range(rounds)]
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ts = [threading.Thread(target=session, args=(i,)) for i in range(sessions)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts)
+    assert out == {i: [i * rounds + r + 1 for r in range(rounds)] for i in range(sessions)}
+    s, waited = q.stats(), [g for g in gathered if g is not None]
+    assert s["submitted"] == sessions * rounds == sum(w * n for w, n in q.width_distribution().items())
+    assert len(gathered) == s["dispatches"]
+    assert s["gather_waits"] == len(waited) and s["gather_met"] == sum(met for _, met in waited)
+    assert s["gather_wait_s"] == pytest.approx(sum(t for t, _ in waited))

@@ -39,8 +39,9 @@ def mirrors_of(n: int, edges: np.ndarray):
     return gm, np.asarray(persons, dtype=np.int32)
 
 
-def walk_count(n: int, edges: np.ndarray, seeds: dict, pairs: int) -> int:
-    """Walks of `pairs` knows records from the weighted seeds, in int64."""
+def walk_count(n: int, edges: np.ndarray, seeds: dict, pairs: int, passing=None) -> int:
+    """Walks of `pairs` knows records from the weighted seeds, in int64;
+    with `passing` (a boolean mask over the persons) those that end there."""
     x = np.zeros(n, dtype=np.int64)
     for s, w in seeds.items():
         x[s] += w
@@ -48,7 +49,7 @@ def walk_count(n: int, edges: np.ndarray, seeds: dict, pairs: int) -> int:
         y = np.zeros(n, dtype=np.int64)
         np.add.at(y, edges[:, 1], x[edges[:, 0]])
         x = y
-    return int(x.sum())
+    return int(x.sum() if passing is None else x[passing].sum())
 
 
 def as_int32(v: int) -> int:
@@ -247,6 +248,94 @@ def test_concurrent_served_counts_all_answer_the_walk(ds, monkeypatch, form):
     assert got == {j: walk_count(n, edges, {starts[j]: 1}, 3) for j in range(len(starts))}
     assert ds.dispatch.stats()["submitted"] - before == len(starts)
     assert forms() == {form: len(starts) + 1}
+
+
+# ------------------------------------------------------------------ the bucket (ISSUE 45)
+@pytest.mark.parametrize("pairs, paced", [
+    (1, False),  # no operator product: a dot product, the queue's own depth
+    (2, True), (3, True), (4, True),  # a product or more: the same 8 lanes at any width, so one deep, and it gathers
+])
+def test_a_dense_count_with_an_operator_product_is_one_deep_and_gathers(built, pairs, paced):
+    from surrealdb_tpu.dbs import dispatch
+
+    n, edges, start, gm, persons = built("lognormal_hub_900")
+    q = DispatchQueue()
+    frontier, counts = np.asarray([persons[start]], dtype=np.int32), np.asarray([1], dtype=np.int32)
+    got = gm._device_chain(NS, DB, frontier, counts, PAIR * pairs, count_only=True, dispatch=q)
+    assert got == as_int32(walk_count(n, edges, {start: 1}, pairs)) and forms() == {"dense": 1}
+    ((key, bucket),) = q._buckets.items()
+    assert key[0] == "gdense" and len(key[3]) == pairs - 1
+    assert (bucket.depth, bucket.gather) == ((dispatch.SWEEP_DEPTH, True) if paced else (q._depth(), False))
+    assert dispatch.SWEEP_DEPTH == 1 and q._depth() == cnf.DISPATCH_PIPELINE_DEPTH == 2
+
+
+ENDING = {  # the final part, and which persons pass it (None: all)
+    "bare": ("person", None),
+    "by_name": ("(person WHERE firstName = $fn)", lambda n: np.arange(n) % 3 == 1),
+}
+
+
+@pytest.mark.parametrize("pairs", [1, 3])
+@pytest.mark.parametrize("ending", sorted(ENDING))
+def test_eight_sessions_of_dense_counts_get_the_walk_s_counts_from_the_family_s_bucket(ds, monkeypatch, ending, pairs):
+    """Eight threads in a closed loop, each its own starts, bare and ending
+    in a predicate: every count is the int64 walk's, all rode the one
+    `gdense` bucket of their form, whose depth is the family's (one deep
+    and gathering with an operator product, the queue's own without), and
+    some batch was more than two wide."""
+    import threading
+
+    monkeypatch.setattr(cnf, "TPU_GRAPH_COUNT_EDGES", 1)
+    monkeypatch.setattr(cnf, "GRAPH_PREWARM", False)
+    n, threads, rounds = 400, 8, 6
+    edges = lognormal_hub(n, hub_degree=120, seed=13)
+    sess = serve_graph(ds, n, edges)
+    ds.execute("UPDATE person SET firstName = ['Anna', 'Bo', 'Chen'][id.id() % 3] RETURN NONE", sess)
+    part, passes = ENDING[ending]
+    passing = passes(n) if passes else None
+    sql = f"SELECT count({'->knows->person' * (pairs - 1)}->knows->{part}) AS c FROM type::thing('person', $p)"
+
+    def walk(start: int) -> int:
+        return walk_count(n, edges, {start: 1}, pairs, passing)
+
+    def ask(start: int) -> int:
+        (res,) = ds.execute(sql, sess, {"p": start, "fn": "Bo"})
+        assert res["status"] == "OK", res
+        return res["result"][0]["c"]
+
+    assert ask(0) == walk(0)  # builds the mirrors and the end weights, compiles the shape
+    assert passing is None or 0 < walk(0) < walk_count(n, edges, {0: 1}, pairs)  # the predicate cuts the count
+    starts = [(i * 37 + 5) % n for i in range(threads * rounds)]
+    got, errors = {}, []
+    barrier = threading.Barrier(threads)
+
+    def client(i):
+        barrier.wait()
+        for j in range(i * rounds, (i + 1) * rounds):
+            try:
+                got[j] = ask(starts[j])
+            except BaseException as e:  # noqa: BLE001 - reported below
+                errors.append(e)
+
+    w0, s0 = ds.dispatch.width_distribution(), ds.dispatch.stats()
+    ts = [threading.Thread(target=client, args=(i,)) for i in range(threads)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(60)
+    assert not errors, errors[:1]
+    assert got == {j: walk(starts[j]) for j in range(len(starts))}
+    assert len(set(starts)) == len(starts) and forms() == {"dense": len(starts) + 1}
+    w1, s1 = ds.dispatch.width_distribution(), ds.dispatch.stats()
+    widths = {w: c - w0.get(w, 0) for w, c in w1.items() if c != w0.get(w, 0)}
+    assert sum(w * c for w, c in widths.items()) == s1["submitted"] - s0["submitted"] == len(starts)
+    assert max(widths) > 2, widths
+    ((key, bucket),) = [(k, b) for k, b in ds.dispatch._buckets.items() if k[0] == "gdense"]
+    assert isinstance(key[4], tuple) == (ending == "by_name")  # ("w", the weights' length), or the id of the last pair's degrees
+    paced = pairs > 1
+    assert (bucket.depth, bucket.gather) == ((1, True) if paced else (cnf.DISPATCH_PIPELINE_DEPTH, False))
+    # only a gathering bucket counts a wait, and no wait is met more than once
+    assert s1["gather_met"] - s0["gather_met"] <= s1["gather_waits"] - s0["gather_waits"] <= (s1["dispatches"] - s0["dispatches"]) * paced
 
 
 def test_the_dense_entry_keeps_the_module_name_trace_readers_match():

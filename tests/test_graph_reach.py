@@ -693,11 +693,15 @@ def test_the_manifest_has_the_deployment_its_cell_and_its_seven_readers(cfg):
     assert len(manifest["configs"]) == 8 == len(manifest["workloads"])
     names = ["graph_reach_roofline", "graph.reach_device_share", "graph.reach_prepare_ms", "graph.reach_ids_mean",
              "graph.reach_lane_fill", "graph.reach_filter_prepare_ms", "graph.reach_filter_build_share"]
-    assert [m["name"] for m in manifest["per_layer"][-7:]] == names
-    assert all(m["workloads"] == [CELL] for m in manifest["per_layer"][-7:])
-    assert [m["moves"] for m in manifest["per_layer"][-7:]] == ["p50_ms"] * 6 + ["p95_ms"]
-    # nobody else's list names the new cell: the readers without a list cover it as they are
-    assert [m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", [])] == names
+    # together and in order after those that were there (not "at the end": the next PR appends)
+    at = [m["name"] for m in manifest["per_layer"]].index(names[0])
+    mine = manifest["per_layer"][at:at + 7]
+    assert [m["name"] for m in mine] == names and at > 40
+    assert all(m["workloads"] == [CELL] for m in mine)
+    assert [m["moves"] for m in mine] == ["p50_ms"] * 6 + ["p95_ms"]
+    # of the older lists none names the new cell (the readers without a list cover it as they are); of the
+    # later ones PR 45's does: the set chain's bucket gathers
+    assert [m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", [])] == names + ["dispatch.gather_met_share"]
     assert {m["name"] for m in mf.metrics_of(manifest, "end_to_end", CELL)} == {"setup_s", "stmt_per_s", "p50_ms", "p95_ms"}
 
 
