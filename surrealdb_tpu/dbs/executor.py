@@ -74,9 +74,12 @@ class Executor:
         # when the statement's device operator (kNN search, graph count)
         # returned: Iterator.output() starts the `materialise` span there
         self.op_end: Optional[float] = None
-        # the rings the statement's `array::distinct(<graph chain>)`
-        # expressions share (idx/graph_csr.py chain_distinct); emptied
-        # before every statement
+        # what the statement's `array::distinct(<graph chain>)` expressions
+        # share: one run of a family's deepest chain, its rings and what its
+        # spans say, under (the deepest idiom node, the start record, the
+        # WHERE's constants as bound); asked before an expression prepares
+        # anything (sql/path.py graph_chain_distinct); emptied before every
+        # statement
         self.reach_memo: Dict[tuple, dict] = {}
         self.plan_gen: Optional[tuple] = None
         self._ddl_open: List[tuple] = []  # DDL brackets held to COMMIT/CANCEL

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import random
 import time
-from typing import Any, Iterable as PyIterable, List, Optional, Tuple
+from typing import Any, Iterable as PyIterable, List, Optional, Sequence, Tuple
 
 from surrealdb_tpu import cnf, tracing
 from surrealdb_tpu import key as keys
@@ -272,12 +272,12 @@ class Iterator:
         # a per-row forward (BASELINE config 5; reference runs Model::compute
         # per document, core/src/sql/model.rs). Guests / record-access
         # sessions keep the per-row path so per-doc model PERMISSIONS hold.
-        self.ml_calls: List[Any] = []
+        self.ml_calls: Sequence[Any] = ()
         if verb == "select" and not self.grouping:
             from surrealdb_tpu.iam.check import perms_apply
 
             if not perms_apply(ctx):
-                self.ml_calls = find_model_calls(getattr(stm, "fields", None))
+                self.ml_calls = find_model_calls(stm)
         self.defer_projection = bool(self.ml_calls)
         # set when the (single) planned source already yields rows in the
         # statement's ORDER BY order (IndexOrderPlan) — skips the post-sort
@@ -710,19 +710,20 @@ class Iterator:
         return rows
 
 # ------------------------------------------------------------------ ml detection
-def find_model_calls(fields) -> List[Any]:
-    """ModelCall nodes evaluated directly in a projection (not inside
-    subquery scope boundaries — those bind a different document)."""
-    from surrealdb_tpu.sql.ast import ModelCall, walk_exprs
+def find_model_calls(stm) -> Sequence[Any]:
+    """ModelCall nodes evaluated directly in `stm`'s projection (not inside
+    subquery scope boundaries — those bind a different document). The
+    statement's text fixes them, so the parser found them and the answer
+    is kept with the AST (SelectStatement.ml_calls, so with the plan
+    cache's template): a text without `ml::` walks nothing here. Only a
+    statement no parser made, or one whose field list other code swapped,
+    has no note and is walked (ast.model_calls)."""
+    noted = getattr(stm, "ml_calls", None)
+    if noted is not None:
+        return noted
+    from surrealdb_tpu.sql.ast import model_calls
 
-    found: List[Any] = []
-
-    def visit(node):
-        if isinstance(node, ModelCall):
-            found.append(node)
-
-    walk_exprs(fields, visit)
-    return found
+    return model_calls(getattr(stm, "fields", None))
 
 
 # ------------------------------------------------------------------ projection

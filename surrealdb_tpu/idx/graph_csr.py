@@ -27,7 +27,7 @@ import threading
 import time as _time
 from surrealdb_tpu.utils import locks as _locks
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -2037,7 +2037,7 @@ class GraphMirrors:
     # ------------------------------------------------ the set a chain reaches
     def chain_distinct(
         self, ctx, start: Thing, parts: List, where=None, deepest: Optional[List] = None,
-        memo: Optional[dict] = None,
+        keep: Optional[Tuple[dict, Any]] = None,
     ) -> Optional[List[Thing]]:
         """`array::distinct(<chain>)` from the record `start`: the nodes a
         walk of the chain's parts ends at, each once, in ascending order of
@@ -2050,13 +2050,16 @@ class GraphMirrors:
 
         `deepest`: the parts of the longest chain among the statement's
         `array::distinct` expressions that this chain is a prefix of, under
-        the same predicate (sql/path.py::mark_chain_families), and `memo`
-        the statement's ring memo. The expression that comes first runs
-        `deepest` ONCE and keeps the set after each of its hops (ring h: a
-        walk of exactly h pairs) under (start, hop specs, predicate
-        binding, operand generations); the statement's other expressions
-        read their ring there. So `array::concat` of the 1-, 2- and 3-pair
-        chains from one record is one device dispatch, not three.
+        the same predicate as bound now (sql/path.py::graph_chain_distinct,
+        which finds both out without a second predicate), and `keep` the
+        statement's ring memo with the family's key in it. This runs
+        `deepest` ONCE and keeps there the set after each of its hops (ring
+        h: a walk of exactly h pairs) with what a span says of the run; the
+        statement's other expressions never come here: they read their
+        ring off the memo (ring), before any of the preparation below. So
+        `array::concat` of the 1-, 2- and 3-pair chains from one record is
+        one device dispatch, one compiled predicate and one look-up of the
+        operators, not three.
 
         Where the chain's `->edge->node` pairs have composed sparse
         operators that line up (_csc_ops), the rings come from the device
@@ -2076,7 +2079,6 @@ class GraphMirrors:
         t_enter = _time.perf_counter()
         ns, db = ctx.ns_db()
         deepest = parts if deepest is None else deepest
-        memo = {} if memo is None else memo
         specs = self._chain_specs(ctx, {start.tb}, deepest)
         end = None
         if where is not None:
@@ -2085,30 +2087,38 @@ class GraphMirrors:
                 return None
         dispatch = getattr(ctx.ds(), "dispatch", None)
         ops = None if cnf.TPU_DISABLE or dispatch is None else self._csc_ops(ns, db, specs)
-        key = (
-            start.tb, repr(start.id), tuple((tuple(s), tuple(d), tuple(f)) for s, d, f in specs),
-            None if end is None else (where.binding_key(), id(end.mirror())),
-            tuple(op["gen"] for op in ops) if ops is not None
-            else tuple(m.version for sp in specs for m in self._hop_mirrors(ns, db, sp)),
+        got = (
+            self._device_reach(ns, db, start, specs, ops, end, dispatch) if ops is not None
+            else self._host_reach(ns, db, start, specs, end)
         )
-        got = memo.get(key)
-        fill = got is None
-        if fill:
-            got = (
-                self._device_reach(ns, db, start, specs, ops, end, dispatch) if ops is not None
-                else self._host_reach(ns, db, start, specs, end)
-            )
-            if got is None:
-                return None
+        if got is None:
+            return None
+        got["filter"] = "none" if end is None else "fused"
+        if keep is not None:
+            memo, key = keep
             memo[key] = got
-            ctx.executor.op_end = _time.perf_counter()  # the `materialise` span starts here
+        ctx.executor.op_end = _time.perf_counter()  # the `materialise` span starts here
+        things = self.ring(ctx, got, parts, t_enter, fill=True)
+        if things is None and deepest is not parts:
+            # a ring the deepest chain's program does not keep for this chain
+            return self.chain_distinct(ctx, start, parts, where=where)
+        return things
+
+    def ring(self, ctx, got: dict, parts: List, t_enter: float, fill: bool = False) -> Optional[List[Thing]]:
+        """The records of the ring that `got`, one run of chain_distinct as
+        the statement's memo keeps it, holds for the chain `parts` (a
+        prefix of the chain that ran, under its predicate as bound now:
+        the memo's key says so), with the expression's `graph_prepare`
+        span and `graph_reach` count; `fill`: for the expression that ran
+        it. None where the run kept no ring for this chain."""
         ring = got["rings"].get(len(parts) - 1)
-        if ring is None:  # a ring the deepest chain's program does not keep for this chain
-            return None if deepest is parts else self.chain_distinct(ctx, start, parts, where=where, memo=memo)
+        if ring is None:
+            return None
         t_ready = (got["t_ready"] if fill else None) or _time.perf_counter()
+        ns, db = ctx.ns_db()
         things = self._things(ns, db, ring)
         _reached(
-            got["form"], t_enter, t_ready, "none" if end is None else "fused", got["operand"],
+            got["form"], t_enter, t_ready, got["filter"], got["operand"],
             depth=len(parts) // 2, memo="fill" if fill else "hit", ids=len(things), rings=len(got["rings"]),
         )
         return things

@@ -33,7 +33,7 @@ from typing import Any, List, Optional, Set, Tuple
 
 import numpy as np
 
-from surrealdb_tpu.sql.ast import ArrayLit, BinaryOp, Cast, Expr, Literal, Param, UnaryOp
+from surrealdb_tpu.sql.ast import ArrayLit, BinaryOp, Cast, Expr, Literal, Param, UnaryOp, walk_exprs
 from surrealdb_tpu.sql.path import Idiom, PField, PStart
 from surrealdb_tpu.sql.value import Datetime, is_none, is_null
 
@@ -183,6 +183,33 @@ def compile_where(ctx, cond: Expr) -> Optional[CompiledPredicate]:
         return None
     telemetry.inc("predicate_compile_outcome", outcome="lowered")
     return CompiledPredicate(root, paths, repr(cond))
+
+
+def bound_constants(ctx, cond: Optional[Expr]) -> tuple:
+    """What can tell apart, as the statement stands NOW, two WHEREs that
+    were one text when parsed: the values of their constants (_is_const:
+    what _cmp_leaf folds, so one list of what a constant is) in the tree's
+    order, by type and repr as binding_key has a predicate's. A parameter
+    is rebound inside one statement (FOR, LET in a block, a function's
+    argument) and a literal of a cached template is a slot of this serve
+    (ast.SlotLiteral); a plain literal is the text's and left out. No
+    predicate is compiled to tell, and nothing is judged here: whether
+    the tree lowers is compile_where's to say. () for no WHERE."""
+    if cond is None:
+        return ()
+    found: List[Tuple[str, str]] = []
+
+    def visit(e) -> bool:
+        if type(e) is Literal:
+            return True
+        if not _is_const(e):
+            return False
+        v = _const_value(ctx, e)
+        found.append((type(v).__name__, repr(v)))
+        return True
+
+    walk_exprs(cond, visit)
+    return tuple(found)
 
 
 def _compile_node(ctx, e: Expr, paths: Set[str]) -> Optional[_Node]:

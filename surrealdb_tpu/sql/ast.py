@@ -892,7 +892,8 @@ _SCOPE_BOUNDARIES = ("Subquery", "Block", "ClosureLit", "FutureLit")
 def walk_exprs(node, visit, _depth: int = 0) -> None:
     """Generic pre-order walk over an AST fragment (exprs, idiom parts,
     field lists). `visit` is called for every surrealdb_tpu node; descent
-    stops at subquery-like scope boundaries."""
+    stops at subquery-like scope boundaries, and below a node whose `visit`
+    returned True (it answered for the whole of it)."""
     if node is None or _depth > 80:
         return
     if isinstance(node, (list, tuple)):
@@ -906,8 +907,7 @@ def walk_exprs(node, visit, _depth: int = 0) -> None:
     cls = type(node)
     if not cls.__module__.startswith("surrealdb_tpu"):
         return
-    visit(node)
-    if cls.__name__ in _SCOPE_BOUNDARIES:
+    if visit(node) is True or cls.__name__ in _SCOPE_BOUNDARIES:
         return
     seen = set()
     for klass in cls.__mro__:
@@ -922,3 +922,20 @@ def walk_exprs(node, visit, _depth: int = 0) -> None:
             walk_exprs(v, visit, _depth + 1)
     for v in getattr(node, "__dict__", {}).values():
         walk_exprs(v, visit, _depth + 1)
+
+
+def model_calls(fields) -> tuple:
+    """The ModelCall nodes a projection evaluates against the scanned
+    record: those of its field list, not those inside a scope boundary
+    (they bind a different document). What a SELECT's batched scoring runs
+    once a statement (dbs/iterator.py). The parser calls this where it
+    read an `ml::` call in the field list and keeps the answer on the
+    statement (SelectStatement.ml_calls)."""
+    found = []
+
+    def visit(node):
+        if isinstance(node, ModelCall):
+            found.append(node)
+
+    walk_exprs(fields, visit)
+    return tuple(found)

@@ -426,22 +426,32 @@ def _chain_over_record(ctx, expr):
     return (rid, expr.parts) if isinstance(rid, Thing) else None
 
 
+def _chain_rides(ctx, parts) -> bool:
+    """Is the chain one the mirrors can serve an aggregate of: every part
+    eligible (_mirror_eligible), with no WHERE but on its final part, which
+    then names one node table that this transaction has not written (the
+    column mirror refuses such a reader: ColumnMirrors.serveable)? Asked of
+    the statement as it runs (this transaction's uncommitted writes), so
+    of every expression, also of one that the statement's memo answers."""
+    last = parts[-1]
+    if last.cond is not None and len(last.what) != 1:
+        return False
+    if not (all(_mirror_eligible(ctx, p) for p in parts[:-1]) and _mirror_eligible(ctx, last, cond_ok=True)):
+        return False
+    return last.cond is None or (*ctx.ns_db(), last.what[0]) not in getattr(ctx.txn(), "touched_tables", ())
+
+
 def _riding_where(ctx, parts):
     """The compiled predicate (ops/predicates.py) of a chain whose final
     part alone has a WHERE, names one node table there and lowers onto
     that table's column mirror, every part eligible for the mirrors
-    (_mirror_eligible): what can ride an aggregate of the chain as a
-    mask. None where the chain or the predicate is not of that kind."""
-    last = parts[-1]
-    if last.cond is None or len(last.what) != 1:
-        return None
-    if not all(_mirror_eligible(ctx, p) for p in parts[:-1]):
-        return None
-    if not _mirror_eligible(ctx, last, cond_ok=True):
+    (_chain_rides): what can ride an aggregate of the chain as a mask.
+    None where the chain or the predicate is not of that kind."""
+    if parts[-1].cond is None or not _chain_rides(ctx, parts):
         return None
     from surrealdb_tpu.ops.predicates import compile_where
 
-    return compile_where(ctx, last.cond)
+    return compile_where(ctx, parts[-1].cond)
 
 
 def graph_chain_count(ctx, expr) -> "int | None":
@@ -504,49 +514,70 @@ def graph_chain_distinct(ctx, expr, deepest) -> "list | None":
     `deepest` is what the parser found for this call
     (mark_chain_families): the longest chain among the statement's
     `array::distinct` calls that this one is a prefix of. One run of it
-    serves them all through the statement's ring memo, where it is still
-    this chain under this predicate as the statement is bound NOW (a
-    cached template's literals are slots: two that were one text when
-    parsed may be bound apart). None when ineligible, and the caller
-    evaluates as it always did; a chain with a WHERE that cannot ride is
-    evaluated here, as the caller would, so that its `graph_prepare` span
-    can say `filter=host`."""
+    serves them all, and the statement's memo (Executor.reach_memo) stands
+    IN FRONT of the preparation: under (that idiom node, the start record,
+    the WHERE's constants as bound now: ops/predicates.py bound_constants)
+    it keeps what the family's first expression ran, and a sibling reads
+    its ring there without compiling a predicate or looking an operator
+    up. The constants are in the key because the family is the parser's,
+    of the text, and the memo lives a top-level statement: a cached
+    template's literals are slots, and two that were one text when parsed
+    may be bound apart; a FOR, a LET in a block or a function's argument
+    rebinds a parameter between two evaluations of one node. Such an
+    expression finds nothing under its key and runs as one alone does,
+    under its own predicate. So does one whose ring the family's program
+    did not keep. What the statement's own transaction wrote since is
+    _chain_rides' to see, before the memo is asked. None when ineligible,
+    and the caller evaluates as it always did; a chain with a WHERE that
+    cannot ride is evaluated here, as the caller would, so that its
+    `graph_prepare` span can say `filter=host`."""
     chain = _chain_over_record(ctx, expr)
     if chain is None:
         return None
     rid, parts = chain
-    cond_free = all(p.cond is None for p in parts)
-    if cond_free and not all(_mirror_eligible(ctx, p) for p in parts):
+    rides = _chain_rides(ctx, parts)
+    if not rides and all(p.cond is None for p in parts):
         return None
+    from surrealdb_tpu.ops.predicates import bound_constants, compile_where
+
     t_enter = time.perf_counter()
-    where = None if cond_free else _riding_where(ctx, parts)
+    mirrors = getattr(ctx.ds(), "graph_mirrors", None)
+    cond = parts[-1].cond
     found = None
-    if cond_free or where is not None:
-        far = deepest.parts if _rides_alike(ctx, deepest.parts, parts, where) else parts
-        found = ctx.ds().graph_mirrors.chain_distinct(
-            ctx, rid, parts, where=where, deepest=far, memo=ctx.executor.reach_memo
-        )
+    if rides:
+        bound = bound_constants(ctx, cond)
+        memo, key = ctx.executor.reach_memo, (id(deepest), rid.tb, repr(rid.id), bound)  # person:1 is not person:1.0
+        family = memo.get(key)
+        if family is not None:
+            found = mirrors.ring(ctx, family, parts, t_enter)
+        if found is None:
+            where = None if cond is None else compile_where(ctx, cond)
+            if cond is None or where is not None:
+                # the family's chain where nobody ran it yet and it is this
+                # chain as bound; else this chain alone, and nobody reads that
+                far = deepest.parts if family is None and _rides_alike(ctx, deepest.parts, parts, bound) else parts
+                found = mirrors.chain_distinct(
+                    ctx, rid, parts, where=where, deepest=far, keep=(memo, key) if far is deepest.parts else None
+                )
     if found is None:
         from surrealdb_tpu import fnc
 
         found = fnc.run(ctx, "array::distinct", [expr.compute(ctx)], exprs=[expr])
-        mirrors = getattr(ctx.ds(), "graph_mirrors", None)
         if mirrors is not None:
             mirrors.reach_walked(t_enter, len(parts) // 2, len(found))
     return found
 
 
-def _rides_alike(ctx, far: List[Part], parts: List[Part], where) -> bool:
+def _rides_alike(ctx, far: List[Part], parts: List[Part], bound: tuple) -> bool:
     """Can the chain `far`, of which `parts` was a prefix when the
-    statement was parsed (chain_prefix), stand in for it as the statement
-    is bound now: eligible for the mirrors, and under `where`'s very
-    binding (the predicate's text and the constants bound into it)?"""
+    statement was parsed (chain_prefix: one WHERE text), stand in for it
+    as the statement is bound now: eligible for the mirrors, and its WHERE's
+    constants bound as `bound`, which are those of `parts`' own?"""
     if far is parts:
         return True
-    if where is None:
-        return all(_mirror_eligible(ctx, p) for p in far)
-    their = _riding_where(ctx, far)
-    return their is not None and their.binding_key() == where.binding_key()
+    from surrealdb_tpu.ops.predicates import bound_constants
+
+    return _chain_rides(ctx, far) and bound_constants(ctx, far[-1].cond) == bound
 
 
 def chain_prefix(a: List[Part], b: List[Part]) -> bool:

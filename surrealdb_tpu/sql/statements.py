@@ -377,11 +377,23 @@ class SelectStatement(Statement):
         "explain_full",
         "explain_analyze",
         "value_mode",
+        "ml_calls",
     )
 
     def __init__(self, fields, what, **kw):
         self.fields = fields
         self.what = what
+        # the parser's note (syn/parser.py::_stmt_select): the ml:: calls
+        # that `fields` evaluates against the scanned record, as
+        # ast.model_calls finds them, a tuple. `()` and None are two
+        # answers: `()` is the parser's "none in this text", and nothing is
+        # walked at execution; None is "nobody looked", and the iterator
+        # walks the field list (dbs/iterator.py::find_model_calls). That
+        # walk exists only for the SELECTs no parser made (cluster/
+        # executor.py's two post-merge SELECTs) and for _replay there,
+        # which swaps a parsed statement's field list and sets None while
+        # it does
+        self.ml_calls = kw.get("ml_calls")
         self.omit = kw.get("omit")
         self.only = kw.get("only", False)
         self.with_ = kw.get("with_")

@@ -73,6 +73,7 @@ class Parser:
         self._no_graph = 0  # >0: don't consume ->/<- as idiom parts (RELATE)
         self._depth = 0  # expression nesting, bounded by _MAX_PARSE_DEPTH
         self.chain_sets: List[A.FunctionCall] = []  # the statement's array::distinct(<graph chain>) calls
+        self.ml_seen = 0  # the ml:: calls made so far: a field list that raised it is walked for them, once, here
 
     # ------------------------------------------------------------- helpers
     def peek(self, off: int = 0) -> Token:
@@ -326,6 +327,7 @@ class Parser:
         self.next()
         value_mode = False
         fields: List[S.Field] = []
+        ml_seen = self.ml_seen
         if self.eat_kw("VALUE"):
             value_mode = True
             expr = self.parse_expr()
@@ -346,6 +348,10 @@ class Parser:
                     fields.append(S.Field(expr, alias))
                 if not self.eat_op(","):
                     break
+        # the projection's own ml:: calls (ast.model_calls), noted where the
+        # text is read: a field list without one is walked by nobody, here
+        # or at any execution (dbs/iterator.py reads the note)
+        ml_calls = A.model_calls(fields) if self.ml_seen != ml_seen else ()
         omit = None
         if self.eat_kw("OMIT"):
             omit = self._idiom_list()
@@ -354,7 +360,7 @@ class Parser:
         what = [self.parse_expr()]
         while self.eat_op(","):
             what.append(self.parse_expr())
-        kw: dict = {"omit": omit, "only": only, "value_mode": value_mode}
+        kw: dict = {"omit": omit, "only": only, "value_mode": value_mode, "ml_calls": ml_calls}
         if self.eat_kw("WITH"):
             if self.eat_kw("NOINDEX"):
                 kw["with_"] = S.With(True)
@@ -2052,6 +2058,7 @@ class Parser:
                 if not self.eat_op(","):
                     break
             self.expect_op(")")
+            self.ml_seen += 1
             return A.ModelCall(mname, version, args)
         # namespaced function / constant: math::pi, array::len(...)
         if self.is_op("::"):

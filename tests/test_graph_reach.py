@@ -486,6 +486,198 @@ def test_literal_names_bound_apart_in_a_cached_template_do_not_share_rings(serve
         assert made == (1 if a == b == c else 2)  # bound apart: the 2- and the 3-pair chain each run their own
 
 
+def prepared_once(served, monkeypatch) -> dict:
+    """Spies on what a family's preparation is made of: the operators' look-up and the chain's hop specs."""
+    seen = {"csc_ops": 0, "chain_specs": 0}
+    gm = served.graph_mirrors
+    for name in ("_csc_ops", "_chain_specs"):
+        real = getattr(gm, name)
+
+        def spy(*a, _real=real, _name=name.lstrip("_"), **kw):
+            seen[_name] += 1
+            return _real(*a, **kw)
+
+        monkeypatch.setattr(gm, name, spy)
+    return seen
+
+
+@pytest.mark.parametrize("statement", ["primary", "probe"])
+def test_a_statement_s_family_is_prepared_once_and_its_siblings_read_the_memo(served, cfg, kind, world, monkeypatch, statement):
+    """ISSUE 46: one compiled predicate and one look-up of the operators a
+    statement, where each of its three expressions made their own (and two
+    more predicates to compare bindings); three `graph_prepare` spans, one
+    `fill`, as before; the reply is the reference's."""
+    data, ref, pool = world
+    sql = cfg["statements"][statement]["sql"]
+    seen = prepared_once(served, monkeypatch)
+    for q in range(6):
+        seen.update(csc_ops=0, chain_specs=0)
+        tid = f"once-{statement}-{q}"
+        before = served.dispatch.stats()["submitted"]
+        with tracing.request("reach", trace_id=tid):
+            (res,) = execute_ok(served, sql, {"q": pool[q]})
+        spans = tracing.get_trace(tid)["spans"]
+        assert served.dispatch.stats()["submitted"] - before == 1
+        assert len([s for s in spans if s["name"] == "predicate_compile"]) == 1
+        assert seen == {"csc_ops": 1, "chain_specs": 1}
+        prepares = [s["labels"] for s in spans if s["name"] == "graph_prepare"]
+        assert [(p["memo"], p["depth"]) for p in prepares] == [("fill", "1"), ("hit", "2"), ("hit", "3")]
+        if statement == "probe":
+            assert [ids_of(res["result"][f"d{h + 1}"]) for h in range(3)] == [ref["rings"][q][h].tolist() for h in range(3)]
+        else:
+            ids = [int(r["id"].id) for r in res["result"]]
+            assert kind.judge(ids, ref["ball"][q], 20) == dict.fromkeys(kind.NUMBERS, 0)
+
+
+def name_rings(data, start: int, fn: str) -> list:
+    """The three rings of `start` among the persons named `fn`, by boolean steps over the edge list."""
+    named = np.asarray(data["names"])[data["first"]] == fn
+    frontier = np.zeros(data["nodes"], dtype=bool)
+    frontier[start] = True
+    rings = []
+    for _ in range(3):
+        nxt = np.zeros(data["nodes"], dtype=bool)
+        nxt[data["pairs"][frontier[data["pairs"][:, 0]], 1]] = True
+        frontier = nxt
+        rings.append(np.flatnonzero(frontier & named).tolist())
+    return rings
+
+
+@pytest.mark.parametrize("bound,dispatches,compiles", [
+    ("aaa", 1, 1),  # one family as bound: one run, the siblings read it
+    ("abb", 1, 2),  # the first is alone (one pair: no dispatch), the second runs the family's chain for the third
+    ("aba", 2, 2),  # the first runs the family's chain for the third; the second is alone
+    ("aab", 2, 3),  # nobody's binding is the deepest chain's but its own
+    ("abc", 2, 3),
+])
+def test_one_family_s_slots_bound_apart_give_each_ring_its_own_predicate_s_set(served, world, monkeypatch, bound, dispatches, compiles):
+    """A cached template's literals are slots: three WHEREs that were one
+    text when parsed, so one family of the parser's, are bound as `bound`
+    says on this serve. The memo's key holds the slots' values, so no ring
+    is read under another name, and no predicate is compiled to find out."""
+    from surrealdb_tpu.sql.ast import Literal, SlotLiteral
+
+    data, _, pool = world
+    start = pool[0]["p"]
+    names = dict(zip("abc", sorted({e["fn"] for e in pool})[:3]))
+    query = parse_query(three_fields("(person WHERE firstName = 'x')").replace(FROM, f" FROM ONLY person:{start}"))
+    calls = [f.expr for f in query.statements[0].fields]
+    assert all(c.reach is calls[2].args[0] for c in calls)
+    for slot, call in enumerate(calls):  # what plan_cache._parameterize does to a text whose three names differ
+        cond = call.args[0].parts[-1].cond
+        assert type(cond.r) is Literal
+        cond.r = SlotLiteral(slot, cond.r.value)
+    before = served.dispatch.stats()["submitted"]
+    with tracing.request("reach", trace_id=f"apart-{bound}"):
+        (res,) = served.process(query, Session.owner("bench", "bench"), slot_values=tuple(names[b] for b in bound))
+    assert res["status"] == "OK", res
+    for h, b in enumerate(bound):
+        assert ids_of(res["result"][f"d{h + 1}"]) == name_rings(data, start, names[b])[h]
+    assert served.dispatch.stats()["submitted"] - before == dispatches
+    spans = tracing.get_trace(f"apart-{bound}")["spans"]
+    assert len([s for s in spans if s["name"] == "predicate_compile"]) == compiles
+
+
+RING2 = "array::distinct(->knows->person->knows->(person WHERE firstName = $n))"
+REBOUND = {
+    # a FOR binds the name anew every turn, inside ONE top-level statement: one memo, one family node
+    "for": ("FOR $n IN $names {{ UPSERT type::thing('ring', $n) SET got = (SELECT VALUE {ring} FROM ONLY $start) }}",
+            "SELECT VALUE got FROM [type::thing('ring', $names[0]), type::thing('ring', $names[1]), type::thing('ring', $names[2])]"),
+    # two expressions of one text are one family of the parser's; a LET between them binds the name anew
+    "lets": ("RETURN {{ LET $n = $names[0]; LET $a = (SELECT VALUE {ring} FROM ONLY $start); LET $n = $names[1]; "
+             "LET $b = (SELECT VALUE {ring} FROM ONLY $start); LET $n = $names[2]; "
+             "RETURN [$a, $b, (SELECT VALUE {ring} FROM ONLY $start)] }}", None),
+    # a closure's argument
+    "closure": ("RETURN array::map($names, |$n| (SELECT VALUE {ring} FROM ONLY $start))", None),
+}
+
+
+@pytest.mark.parametrize("how", list(REBOUND))
+def test_a_name_bound_anew_inside_one_statement_gives_each_ring_its_own_name_s_set(served, world, how):
+    """The memo lives a top-level statement and its key holds the WHERE's
+    constants as bound at each evaluation: a parameter that a FOR, a LET or
+    a closure rebinds between two evaluations of one family reads no ring
+    of the name before (REVIEW, PR 46: the slots alone were in the key)."""
+    data, _, pool = world
+    start = pool[0]["p"]
+    names = sorted({e["fn"] for e in pool})[:3]
+    want = [name_rings(data, start, fn)[1] for fn in names]
+    assert len({tuple(w) for w in want}) > 1  # the names' rings differ: a stale one shows
+    sql, read = REBOUND[how]
+    vars = {"names": names, "start": Thing("person", start)}
+    before = served.dispatch.stats()["submitted"]
+    out = execute_ok(served, sql.format(ring=RING2), vars)
+    got = out[-1]["result"] if read is None else execute_ok(served, read, vars)[-1]["result"]
+    assert [ids_of(g) for g in got] == want
+    assert served.dispatch.stats()["submitted"] - before == 3  # each name its own run
+    # and one name three times is one run: the memo still answers where the binding is the same
+    before = served.dispatch.stats()["submitted"]
+    same = {"names": [names[0]] * 3, "start": Thing("person", start)}
+    out = execute_ok(served, sql.format(ring=RING2), same)
+    got = out[-1]["result"] if read is None else execute_ok(served, read, same)[-1]["result"]
+    assert [ids_of(g) for g in got] == [want[0]] * 3
+    assert served.dispatch.stats()["submitted"] - before == 1  # (the FOR's own write is to another table)
+
+
+@pytest.mark.parametrize("write", ["update", "relate"])
+def test_a_write_of_the_statement_s_own_between_two_evaluations_is_seen_by_the_second(served, world, write):
+    """One FOR, the same name both turns, and between the two evaluations
+    the statement's own transaction renames a person of the ring, or adds
+    an edge: the second turn is no reading of the memo (the column mirror
+    refuses a reader that wrote its table, the mirrors one with edge
+    deltas: _chain_rides asks both before the memo is asked)."""
+    data, ref, pool = world
+    q = next(i for i, e in enumerate(pool) if ref["rings"][i][1].size >= 2)
+    entry, ring2 = pool[q], ref["rings"][q][1].tolist()
+    gone = next(i for i in ring2 if i != entry["p"])
+    change = ("UPDATE type::thing('person', $gone) SET firstName = 'Renamed'" if write == "update"
+              else "RELATE $start->knows->person:10001")
+    sql = ("FOR $turn IN [0, 1] { LET $n = $fn; UPDATE type::thing('seen', $turn) SET got = (SELECT VALUE "
+           + RING2 + " FROM ONLY $start); " + change + " }")
+    execute_ok(served, "CREATE seen:0, seen:1; CREATE person:10001 SET firstName = 'Far'; "
+                       "CREATE person:10002 SET firstName = $fn; RELATE person:10001->knows->person:10002", {"fn": entry["fn"]})
+    execute_ok(served, sql, {"fn": entry["fn"], "start": Thing("person", entry["p"]), "gone": gone})
+    first, second = (r["got"] for r in execute_ok(served, "SELECT got FROM seen:0, seen:1")[-1]["result"])
+    assert ids_of(first) == ring2
+    assert ids_of(second) == (sorted(set(ring2) - {gone}) if write == "update" else sorted(ring2 + [10002]))
+
+
+ACCEPTED = [  # WHEREs that compile_where lowers: the vars that bind them, one var changed, the constants in the key
+    ("firstName = $n", {"n": "Ann"}, {"n": "Bob"}, 1),
+    ("firstName = $q.fn", {"q": {"fn": "Ann", "p": 1}}, {"q": {"fn": "Bob", "p": 1}}, 1),
+    ("$n = firstName AND lastName != $m AND gender = 'Zed'", {"n": "Ann", "m": "X"}, {"n": "Ann", "m": "Y"}, 2),
+    ("firstName IN [$n, $m]", {"n": "Ann", "m": "Cy"}, {"n": "Bob", "m": "Cy"}, 1),  # an array is one constant
+    ("firstName IN $ns", {"ns": ["Ann"]}, {"ns": ["Ann", "Bob"]}, 1),
+    ("!(birthday < <datetime> $d) OR firstName CONTAINS $n", {"d": "2000-01-01T00:00:00Z", "n": "A"},
+     {"d": "2001-01-01T00:00:00Z", "n": "A"}, 2),
+    ("age > $a", {"a": 1}, {"a": 1.0}, 1),  # by type and repr: `1` and `1.0` are different constants
+]
+
+
+@pytest.mark.parametrize("text,vars,other,constants", ACCEPTED, ids=[a[0] for a in ACCEPTED])
+def test_every_where_that_lowers_has_a_key_of_its_constants_as_bound(ds, text, vars, other, constants):
+    """ops/predicates.py bound_constants and compile_where agree on what a
+    constant is (one _is_const): two bindings have one key where the
+    compiled predicates have one binding_key, and different keys where
+    they differ. A plain literal is the text's and in no key."""
+    from surrealdb_tpu.dbs.context import Context
+    from surrealdb_tpu.dbs.executor import Executor
+    from surrealdb_tpu.ops.predicates import bound_constants, compile_where
+
+    cond = parse_query(f"SELECT * FROM person WHERE {text}").statements[0].cond
+    ex = Executor(ds, Session.owner("bench", "bench"))
+
+    def bind(vs):
+        ctx = Context(ex, ex.session)
+        for k, v in vs.items():
+            ctx.set_param(k, v)
+        return compile_where(ctx, cond).binding_key(), bound_constants(ctx, cond)
+
+    (ck, bk), (ck2, bk2), (ck3, bk3) = bind(vars), bind(dict(vars)), bind(other)
+    assert ck == ck2 and bk == bk2 and ck != ck3 and bk != bk3
+    assert len(bk) == constants and "Zed" not in repr(bk)
+
+
 # ------------------------------------------------------------------ warm-up and audit
 def test_the_first_statement_warms_every_lane_count_in_the_background(ds, cfg, kind, world, monkeypatch):
     from surrealdb_tpu import bg
@@ -700,8 +892,9 @@ def test_the_manifest_has_the_deployment_its_cell_and_its_seven_readers(cfg):
     assert all(m["workloads"] == [CELL] for m in mine)
     assert [m["moves"] for m in mine] == ["p50_ms"] * 6 + ["p95_ms"]
     # of the older lists none names the new cell (the readers without a list cover it as they are); of the
-    # later ones PR 45's does: the set chain's bucket gathers
-    assert [m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", [])] == names + ["dispatch.gather_met_share"]
+    # later ones PR 45's does (the set chain's bucket gathers) and PR 46's (a statement's compiled predicates)
+    assert [m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", [])] == (
+        names + ["dispatch.gather_met_share", "exec.predicate_compiles"])
     assert {m["name"] for m in mf.metrics_of(manifest, "end_to_end", CELL)} == {"setup_s", "stmt_per_s", "p50_ms", "p95_ms"}
 
 

@@ -167,3 +167,95 @@ def test_columnar_scan_key_order_after_mixed_inserts(ds):
     fast = ds.execute("SELECT VALUE ml::score<1>(f) FROM h;")[-1]["result"]
     slow = ds.execute("SELECT VALUE ml::score<1>(f) FROM h WHERE f[0] >= 0;")[-1]["result"]
     assert fast == slow  # positionally identical, key order
+
+
+# ------------------------------------------------------------------ the parser's note of a projection's ml:: calls
+# (ISSUE 46) {shape: (the statement with a literal to vary, the calls its OUTER projection batches)}
+NOTED = {
+    "plain": ("SELECT id, ml::score<1>(f) AS s FROM h WHERE n >= {n} ORDER BY id", 1),
+    "in_arguments": ("SELECT id, math::max([ml::score<1>(f), 0]) AS s FROM h WHERE n >= {n} ORDER BY id", 1),
+    "in_subquery": ("SELECT id, (SELECT VALUE ml::score<1>(f) FROM g) AS s FROM h WHERE n >= {n} ORDER BY id", 0),
+    "none": ("SELECT id, n AS s FROM h WHERE n >= {n} ORDER BY id", 0),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(NOTED))
+def test_the_parser_s_note_is_what_the_walk_finds_however_the_statement_is_served(ds, monkeypatch, shape):
+    """The calls the batched scoring runs are the nodes `ast.model_calls`
+    finds in the field list (not those of a subquery, which binds another
+    document; those in a function's arguments, yes), whether the statement
+    was parsed, bound into its template (`lexed`) or found by its text
+    (`digest`); and once the text is parsed nothing walks a field list."""
+    from surrealdb_tpu import tracing
+    from surrealdb_tpu.dbs import iterator
+    from surrealdb_tpu.sql import ast
+
+    _import(ds)
+    ds.execute(";".join(f"CREATE h:{i} SET f = [{i}.0, {i}.0], n = {i}" for i in range(6)))
+    ds.execute("CREATE g:1 SET f = [1.0, 1.0];")
+    sql, outer = NOTED[shape]
+    real_walk = ast.walk_exprs
+    found, walks, real_find = [], [], iterator.find_model_calls
+    monkeypatch.setattr(iterator, "find_model_calls", lambda stm: found.append((stm, real_find(stm))) or found[-1][1])
+
+    def walk_spy(node, visit, _depth=0):
+        if not _depth:  # the walk recurses through the module's name
+            walks.append(node)
+        return real_walk(node, visit, _depth)
+
+    monkeypatch.setattr(ast, "walk_exprs", walk_spy)
+    answers = {}
+    for how, n in (("parse", 3), ("parse", 3), ("digest", 3), ("lexed", 4)):  # the second parse installs the template
+        del found[:], walks[:]
+        with tracing.request("test", trace_id=f"noted-{shape}-{how}"):
+            (res,) = ds.execute(sql.format(n=n))
+        assert res["status"] == "OK", res
+        spans = tracing.get_trace(f"noted-{shape}-{how}")["spans"]
+        assert [s["labels"]["outcome"] for s in spans if s["name"] == "plan_fetch"] == [how]
+        # the walk runs where a parser has read an `ml::` inside a field list (the subquery's is inside the outer
+        # SELECT's too), so in a parse, and in the one a `lexed` serve is verified by; no execution walks
+        assert len(walks) == (0 if how == "digest" else {"none": 0, "in_subquery": 2}.get(shape, 1))
+        # the outer SELECT's iterator is made first; the others are the subquery's, one a row
+        assert [len(calls) for _, calls in found] == [outer] + [1] * (len(found) - 1)
+        for stm, calls in found:
+            assert stm.ml_calls is not None and list(map(id, calls)) == list(map(id, ast.model_calls(stm.fields)))
+        del walks[:]
+        answers[how] = res["result"]
+    assert answers["parse"] == answers["digest"] and len(answers["parse"]) == 3 and answers["lexed"] == answers["parse"][1:]
+    if shape in ("plain", "in_arguments"):
+        assert [r["s"] for r in answers["parse"]] == pytest.approx([25.0, 30.0, 35.0])
+        assert _compiled_model(ds).dispatches == 4  # one batch a statement
+    if shape == "in_subquery":
+        assert all(r["s"] == pytest.approx([15.0]) for r in answers["parse"])
+
+
+def test_a_select_no_parser_made_has_no_note_and_is_walked(ds):
+    """The cluster coordinator builds its post-merge SELECT in code, and
+    swaps a statement's field list for the replay: no note, so the walk."""
+    from surrealdb_tpu.dbs.iterator import find_model_calls
+    from surrealdb_tpu.sql import ast
+    from surrealdb_tpu.sql.statements import Field, SelectStatement
+    from surrealdb_tpu.syn.parser import parse_query
+
+    stm = parse_query("SELECT ml::score<1>(f) AS s, n FROM h").statements[0]
+    (call,) = stm.ml_calls
+    assert isinstance(call, ast.ModelCall) and find_model_calls(stm) is stm.ml_calls
+    built = SelectStatement([Field(call, alias=None)], stm.what)
+    assert built.ml_calls is None and find_model_calls(built) == (call,)
+    assert parse_query("SELECT n FROM h").statements[0].ml_calls == ()
+
+
+def test_the_note_opens_no_second_way_to_an_unaliased_projection_s_literals():
+    """An unaliased `ml::m(5)` is a column named by its own text, so its
+    literal stays a fixed token of the template (plan_cache); the note holds
+    the same ModelCall and must not hand that literal to the slots."""
+    from surrealdb_tpu.dbs import plan_cache as pc
+    from surrealdb_tpu.sql import ast
+    from surrealdb_tpu.syn.parser import parse_query
+
+    text = "SELECT ml::score<1>([7.5, n]), ml::score<1>([8.5, n]) AS s FROM h WHERE n > 3"
+    query = parse_query(text)
+    variant = pc._parameterize(text, query)
+    named, aliased = query.statements[0].ml_calls
+    assert [type(x) for x in named.args[0].items[:1] + aliased.args[0].items[:1]] == [ast.Literal, ast.SlotLiteral]
+    assert variant.defaults == (8.5, 3)
