@@ -1938,19 +1938,19 @@ class ClusterExecutor:
         WHERE already ran on the shards (and the kNN/BM25 merge decided
         membership), so the cond drops; score/distance functions resolve
         from the carrier fields instead of a per-statement query executor."""
-        saved = (stm.what, stm.cond, stm.fields, stm.order, stm.ml_calls)
+        saved = (stm.what, stm.cond, stm.fields, stm.order, stm.ml_calls, stm.reach_calls)
         try:
             stm.what = [Param(_ROWS)]
             stm.cond = None
             stm.fields = [_rewrite_field(f) for f in stm.fields]
-            stm.ml_calls = None  # the parser's note is of the field list it read
+            stm.ml_calls, stm.reach_calls = None, ()  # the parser's notes are of the field list it read
             if stm.order:
                 stm.order = [_rewrite_order(o) for o in stm.order]
             out = self.ds.process(
                 Query([stm]), session, dict(vars or {}, **{_ROWS: rows})
             )
         finally:
-            stm.what, stm.cond, stm.fields, stm.order, stm.ml_calls = saved
+            stm.what, stm.cond, stm.fields, stm.order, stm.ml_calls, stm.reach_calls = saved
         resp = {"status": out[0]["status"], "result": out[0]["result"]}
         if resp["status"] == "OK":
             resp["result"] = _merge.strip_cluster_fields(resp["result"])

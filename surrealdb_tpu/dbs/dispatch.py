@@ -145,8 +145,8 @@ def _retry_cause(e: BaseException) -> str:
 
 class _Req:
     __slots__ = (
-        "payload", "runner", "event", "result", "error", "promoted", "done",
-        "t_submit", "t_done", "trace_ctx", "tenant",
+        "payload", "runner", "event", "result", "error", "done",
+        "t_submit", "t_done", "trace_ctx", "tenant", "dispatch_no",
     )
 
     def __init__(self, payload, runner):
@@ -155,8 +155,8 @@ class _Req:
         self.event = threading.Event()
         self.result = None
         self.error: BaseException | None = None
-        self.promoted = False  # woken to take over bucket leadership
-        self.done = False
+        self.done = False  # its event set while it is not: woken to take over bucket leadership
+        self.dispatch_no = 0  # `dispatches` as the launch that drew it counted itself
         self.t_submit = _time.perf_counter()  # queue-wait accounting
         self.t_done: float | None = None  # when the leader handed the result out
         # the submitting request's trace position: whoever LEADS the batch
@@ -306,7 +306,7 @@ class DispatchQueue:
         self._reported = sums
         return due
 
-    def _bucket(self, key: Hashable, depth: Optional[int], gather: bool) -> _Bucket:
+    def _bucket(self, key: Hashable, depth: Optional[int], gather: bool, riders: int = 1) -> _Bucket:
         with self._lock:
             # the queue counters + bucket map are one guarded unit
             # (sanitizer-declared: stats() diffs depend on their atomicity)
@@ -314,8 +314,8 @@ class DispatchQueue:
             b = self._buckets.get(key)
             if b is None:
                 b = self._buckets[key] = _Bucket(depth or self._depth(), gather)
-            self.submitted += 1
-            self._move(queued=1)
+            self.submitted += riders
+            self._move(queued=riders)
             return b
 
     def submit(
@@ -329,39 +329,66 @@ class DispatchQueue:
         would only split between two what one serves. `gather` (of a
         one-deep bucket, fixed with it): its leader waits a moment for the
         riders of its group (_gather)."""
-        b = self._bucket(key, depth, gather)
-        req = _Req(payload, runner)
+        return self.submit_many(key, (payload,), runner, depth=depth, gather=gather)[0]
+
+    def submit_many(
+        self, key: Hashable, payloads: Sequence[Any], runner: Callable[[Sequence[Any]], Sequence[Any]],
+        depth: Optional[int] = None, gather: bool = False, rode: Optional[List[int]] = None,
+    ) -> List[Any]:
+        """submit() for one caller's `payloads` under one key: each a rider
+        of its own (`submitted` rises by their number, a batch's width
+        counts each), all queued at one instant and next to each other, so
+        that an idle bucket launches them together and a busy one's next
+        leader draws them together; the caller waits for all of them. More
+        riders than a batch may hold leave in consecutive batches by the
+        queue's own rule (_lead). Returns the results in the payloads'
+        order; raises the first rider's error once every rider is done.
+        `rode`: a list that receives the number of the dispatch each rider
+        rode (`dispatches` as the launch counted it), for a caller that
+        tells how many launches its group took."""
+        if not payloads:
+            return []
+        b = self._bucket(key, depth, gather, riders=len(payloads))
+        reqs = [_Req(p, runner) for p in payloads]
         with b.lock:
-            b.queue.append(req)
+            b.queue.extend(reqs)
             leader = not b.launching
             if leader:
                 b.launching = True
             elif b.awaiting:
                 b.arrived.notify()
-        if not leader:
-            req.event.wait()
-            if not req.promoted:
-                return self._outcome(req)
-            # promoted: the previous leader handed the bucket over; our own
-            # request is still queued and rides the batch we now dispatch
-        self._lead(b)
-        return self._outcome(req)
+        if leader:
+            self._lead(b)
+        for req in reqs:
+            # promoted: the previous leader handed the bucket over with this
+            # rider at the queue's head; it rides the batch we now dispatch
+            while not req.done:
+                req.event.wait()
+                if not req.done:
+                    req.event.clear()
+                    self._lead(b)
+        if rode is not None:
+            rode.extend(r.dispatch_no for r in reqs)
+        self._woken(reqs)
+        for req in reqs:
+            if req.error is not None:
+                raise req.error
+        return [r.result for r in reqs]
 
     @staticmethod
-    def _outcome(req: _Req) -> Any:
-        """The submitter's way out: its result, and in its trace the
-        `dispatch_wake` span from the leader's hand-out to this thread's
-        running again (a rider's wake-up; for a leader, its bucket chores)."""
-        if req.error is not None:
-            raise req.error
-        if req.t_done is not None and req.trace_ctx is not None:
+    def _woken(reqs: List[_Req]) -> None:
+        """The submitter's way out: in its trace the `dispatch_wake` span
+        from the leader's hand-out of the last of its riders to this
+        thread's running again (a rider's wake-up; for a leader, its bucket
+        chores)."""
+        last = max(reqs, key=lambda r: r.t_done or 0.0)
+        if last.t_done is not None and last.trace_ctx is not None:
             from surrealdb_tpu import tracing
 
             tracing.record_span_into(
-                req.trace_ctx, "dispatch_wake", {},
-                req.t_done, _time.perf_counter() - req.t_done,
+                last.trace_ctx, "dispatch_wake", {},
+                last.t_done, _time.perf_counter() - last.t_done,
             )
-        return req.result
 
     def _lead(self, b: _Bucket) -> None:
         """Dispatch ONE width-capped batch (containing this leader's
@@ -384,7 +411,6 @@ class DispatchQueue:
             with b.lock:
                 if b.queue:
                     nxt = b.queue[0]
-                    nxt.promoted = True
                     nxt.event.set()  # launching stays True; nxt owns the bucket
                 else:
                     b.launching = False
@@ -450,12 +476,14 @@ class DispatchQueue:
     ) -> None:
         """Stamp one kernel-phase span onto EVERY rider's trace, parented
         at the span each request was in when it submitted — a query that
-        rode someone else's launch still shows its dispatch level."""
+        rode someone else's launch still shows its dispatch level. Once a
+        trace position: a statement's riders that share a batch
+        (submit_many) share the span."""
         from surrealdb_tpu import tracing
 
         labels = {"batch": len(batch), **extra}
-        for r in batch:
-            tracing.record_span_into(r.trace_ctx, name, labels, start, dur, error)
+        for ctx in {id(r.trace_ctx): r.trace_ctx for r in batch}.values():
+            tracing.record_span_into(ctx, name, labels, start, dur, error)
 
     def _launch(
         self, batch: List[_Req], b: _Bucket, pipeline_wait: float,
@@ -471,6 +499,8 @@ class DispatchQueue:
         with self._lock:
             _locks.assert_held(self._lock, "dispatch.counters")
             self.dispatches += 1
+            for r in batch:
+                r.dispatch_no = self.dispatches
             self.batched += len(batch) - 1
             self.pipeline_wait_s += pipeline_wait
             if gathered is not None:
@@ -494,12 +524,15 @@ class DispatchQueue:
             )
         from surrealdb_tpu import accounting
 
+        traced = set()
         for r in batch:
             telemetry.observe("dispatch_queue_wait", t0 - r.t_submit)
-            tracing.record_span_into(
-                r.trace_ctx, "dispatch_queue_wait", {"batch": len(batch)},
-                r.t_submit, t0 - r.t_submit,
-            )
+            if id(r.trace_ctx) not in traced:  # a statement's riders of one batch queued together
+                traced.add(id(r.trace_ctx))
+                tracing.record_span_into(
+                    r.trace_ctx, "dispatch_queue_wait", {"batch": len(batch)},
+                    r.t_submit, t0 - r.t_submit,
+                )
             ns, db = r.tenant if r.tenant is not None else (None, None)
             accounting.charge(
                 ns, db,

@@ -273,12 +273,18 @@ class Iterator:
         # per document, core/src/sql/model.rs). Guests / record-access
         # sessions keep the per-row path so per-doc model PERMISSIONS hold.
         self.ml_calls: Sequence[Any] = ()
+        # and so do SELECTs whose projection asks every row for the set a
+        # graph chain reaches (`array::distinct(<chain>)`, the parser's
+        # note): the rows' chains then ride ONE device launch together
+        # instead of a dispatch round trip a row (BASELINE config 4)
+        self.reach_calls: Sequence[Any] = ()
         if verb == "select" and not self.grouping:
             from surrealdb_tpu.iam.check import perms_apply
 
             if not perms_apply(ctx):
                 self.ml_calls = find_model_calls(stm)
-        self.defer_projection = bool(self.ml_calls)
+                self.reach_calls = getattr(stm, "reach_calls", None) or ()
+        self.defer_projection = bool(self.ml_calls or self.reach_calls)
         # set when the (single) planned source already yields rows in the
         # statement's ORDER BY order (IndexOrderPlan) — skips the post-sort
         # and re-enables the LIMIT fast path
@@ -591,7 +597,7 @@ class Iterator:
                 "plan_candidates", n, buckets=telemetry.COUNT_BUCKETS
             )
 
-    # -------------------------------------------------------------- ml batching
+    # -------------------------------------------------------------- row batching
     def _batched_projection(self, rows: List[Any]) -> List[Any]:
         """Deferred projection for SELECTs containing ml:: calls: every
         scanned row's model input is collected host-side, each distinct call
@@ -600,10 +606,21 @@ class Iterator:
 
         Rows whose argument expression fails to evaluate fall back to the
         inline per-row path (the call may sit under a conditional branch
-        that never reaches it for that row)."""
+        that never reaches it for that row).
+
+        And for SELECTs whose projection holds `array::distinct(<graph
+        chain>)` calls: with two or more rows, every chain family runs once
+        for all of them before the projection
+        (sql/path.py::fill_reach_groups) and parks each row's rings in the
+        statement's ring memo, where the projection's expressions look
+        first. One row is projected as it always was."""
         from surrealdb_tpu.ml.exec import run_model_batch
 
         ctx, stm = self.ctx, self.stm
+        if self.reach_calls and len(rows) >= 2:
+            from surrealdb_tpu.sql.path import fill_reach_groups
+
+            fill_reach_groups(ctx, self.reach_calls, rows)
         outputs: dict = {}  # id(call) -> {row_index: value}
         ex = ctx.executor
         # save/restore: a nested deferred SELECT (subquery with its own ml::

@@ -265,11 +265,11 @@ def _collect_literal_sites(root) -> List[Tuple[Any, Any, Any]]:
             for v in o:
                 walk(v)
         elif _is_sql_node(o):
-            if type(o) is S.SelectStatement and o.ml_calls:
-                # the parser's note repeats nodes of the field list: their
+            if type(o) is S.SelectStatement:
+                # the parser's notes repeat nodes of the field list: their
                 # literals are sites where the walk meets them THERE, or not
                 # at all (the unaliased projections below)
-                seen.add(id(o.ml_calls))
+                seen.update(id(note) for note in (o.ml_calls, o.reach_calls) if note)
             if type(o) is S.Output or (
                 type(o) is S.SelectStatement and not o.value_mode
             ):

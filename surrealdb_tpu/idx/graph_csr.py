@@ -389,6 +389,30 @@ def _reached(
     telemetry.stage("graph_prepare", t_enter, t_ready - t_enter, **labels)
 
 
+def _grouped(
+    t_enter: float, t_ready: float, rows: int, riders: int, families: int, launches: int, filter: str, depth: int,
+) -> None:
+    """One chain family of a statement served for all its rows together
+    (GraphMirrors.reach_group): the `graph_reach_group` counter and the
+    statement's `graph_reach_group` span come from this one argument list,
+    as _reached's do. The span runs from the group's entry to its submit
+    (`t_ready`: operators, mask, every row's frontier; what follows is the
+    dispatcher's) and is written when the rings are back, so it can say
+    `riders` (the rows that brought a frontier to the sweep) and `launches`
+    (the dispatches those riders rode: 1 with the bucket idle) beside
+    `rows` and `families` (the chains the statement's fill serves this way,
+    a span each). `rows` and `riders` grow with a statement's result, so they
+    are labels of the span alone; the counter keeps what is bounded. The
+    family's `graph_prepare` span says `memo=fill` once here, not once a
+    row: the rows' expressions then say `hit`."""
+    telemetry.inc("graph_reach_group", form="csc", filter=filter, depth=depth, launches=min(launches, 4))
+    telemetry.stage(
+        "graph_reach_group", t_enter, t_ready - t_enter, rows=rows, riders=riders, families=families,
+        launches=launches, form="csc", filter=filter, depth=depth,
+    )
+    _reached("csc", t_enter, t_ready, filter, "composed", depth=depth, memo="fill", ids=0, rings=0)
+
+
 def _collect_rings(out, riders: int, lanes: int, slots: int):
     """The collect phase of a batched set chain launched at `lanes` lanes:
     each rider's rings off the device, uint32 [hops, words] a rider.
@@ -2129,39 +2153,135 @@ class GraphMirrors:
         kept for the hops that land in the last pair's table (the mask is
         over that table, and no chain of the statement reads another).
         None where the predicate's mask cannot be made for this reader."""
-        n_cap, tb = ops[0]["n_pad"], ops[-1]["dst_tb"]
-        got = {"form": "csc", "operand": "composed", "rings": {}, "t_ready": None}  # stamped at the dispatch submit
-        g = self.interner(ns, db).lookup(start)
-        seed = None if g is None else ops[0]["space_src"]["inv"].get(g)
-        indptr, dst = ops[0]["by_src"]
-        row = dst[:0] if seed is None else dst[indptr[seed] : indptr[seed + 1]]
+        row = self._start_row(ns, db, start, ops[0])
         if not row.size:  # a record no edge leaves reaches nothing
-            got["rings"] = {2 * h + 1: (tb, np.empty(0, dtype=np.int64)) for h in range(len(ops))}
-            return got
-        swept, fsz = ops[1:], ops[0]["row_pad"]
-        if swept and row.size > fsz:
+            return self._no_rings(ops)
+        if len(ops) > 1 and row.size > ops[0]["row_pad"]:
             # a hub's row passes the pad its operator reads rows at
             # (ROW_PAD_MAX; 0: the operator has such a row): the host's walk
             return self._host_reach(ns, db, start, specs, end)
+        plan = self._reach_plan(ops, end)
+        if plan is None:
+            return None
+        got = self._first_ring(plan, row)
+        if plan["swept"]:
+            got["t_ready"] = _time.perf_counter()  # stamped at the dispatch submit
+            out = dispatch.submit(plan["key"], self._rider(plan, row), plan["runner"], depth=SWEEP_DEPTH, gather=True)
+            self._swept_rings(plan, got, out)
+        return got
+
+    def reach_group(
+        self, ctx, starts: List[Thing], parts: List, where=None, families: int = 1,
+    ) -> Optional[List[Optional[dict]]]:
+        """chain_distinct's run of the chain `parts` for MANY start records
+        of one table, as one group: the rows of a SELECT whose field list
+        asks every row for `array::distinct(<chain>)` (sql/path.py::
+        fill_reach_groups, which keeps each start's run in the statement's
+        ring memo, where the rows' expressions then find their rings).
+        The operators are looked up, the predicate's mask made and the
+        runner built ONCE; each start whose row of the first operator fits
+        the pad brings that row as its frontier, and all of them go to the
+        dispatch queue in one call (DispatchQueue.submit_many: a rider a
+        start, `submitted` up by their number), so that with the bucket
+        idle a statement's rows ride one launch and, with other sessions
+        at work, share it with theirs. A start no edge leaves has empty
+        rings, a hub's row past the pad takes the host's walk, as
+        chain_distinct serves them; a start the walk cannot serve either
+        has None. None for the whole group where the chain is not one the
+        composed operators serve (TPU_DISABLE, no dispatcher, a pair no
+        operator spans, a predicate whose mask cannot be made for this
+        reader): every row then evaluates as it does alone. With the
+        statement's `graph_reach_group` span and counter (_grouped);
+        `families`: how many chains the statement's fill serves this way,
+        for the span to say."""
+        from surrealdb_tpu import cnf
+
+        t_enter = _time.perf_counter()
+        ns, db = ctx.ns_db()
+        dispatch = getattr(ctx.ds(), "dispatch", None)
+        if cnf.TPU_DISABLE or dispatch is None or not starts:
+            return None
+        specs = self._chain_specs(ctx, {starts[0].tb}, parts)
+        ops = self._csc_ops(ns, db, specs)
+        if ops is None:
+            return None
+        end = None
+        if where is not None:
+            end = _EndFilter(self, ctx, parts[-1].what[0], where)
+            if end.mirror() is None:
+                return None
+        rows = [self._start_row(ns, db, start, ops[0]) for start in starts]
+        pad = ops[0]["row_pad"] if len(ops) > 1 else None  # one pair: the row is the answer, nothing is swept
+        riding = [i for i, row in enumerate(rows) if row.size and (pad is None or row.size <= pad)]
+        plan = self._reach_plan(ops, end) if riding else None
+        if riding and plan is None:
+            return None
+        gots: List[Optional[dict]] = [None] * len(starts)
+        for i in riding:
+            gots[i] = self._first_ring(plan, rows[i])
+        for i, (start, row) in enumerate(zip(starts, rows)):
+            if gots[i] is None:
+                gots[i] = self._host_reach(ns, db, start, specs, end) if row.size else self._no_rings(ops)
+        rode: List[int] = []
+        t_ready = _time.perf_counter()
+        if plan is not None and plan["swept"]:
+            outs = dispatch.submit_many(
+                plan["key"], [self._rider(plan, rows[i]) for i in riding], plan["runner"],
+                depth=SWEEP_DEPTH, gather=True, rode=rode,
+            )
+            for i, out in zip(riding, outs):
+                gots[i]["t_ready"] = t_ready
+                self._swept_rings(plan, gots[i], out)
+        for got in gots:
+            if got is not None:
+                got["filter"] = "none" if end is None else "fused"
+        ctx.executor.op_end = _time.perf_counter()  # the `materialise` span starts here
+        _grouped(
+            t_enter, t_ready, rows=len(starts), riders=len(rode), families=families, launches=len(set(rode)),
+            filter="none" if end is None else "fused", depth=len(parts) // 2,
+        )
+        return gots
+
+    def _start_row(self, ns, db, start: Thing, op: dict) -> np.ndarray:
+        """The record `start`'s row of the composed operator `op`, a
+        destination once a path: the first hop of a set chain from it.
+        Empty for a record that is no source of the operator."""
+        g = self.interner(ns, db).lookup(start)
+        seed = None if g is None else op["space_src"]["inv"].get(g)
+        indptr, dst = op["by_src"]
+        return dst[:0] if seed is None else dst[indptr[seed] : indptr[seed + 1]]
+
+    @staticmethod
+    def _no_rings(ops: List[dict]) -> dict:
+        """_device_reach's answer for a record no edge leaves."""
+        tb = ops[-1]["dst_tb"]
+        return {"form": "csc", "operand": "composed", "t_ready": None,
+                "rings": {2 * h + 1: (tb, np.empty(0, dtype=np.int64)) for h in range(len(ops))}}
+
+    def _reach_plan(self, ops: List[dict], end) -> Optional[dict]:
+        """What every rider of the set chain over `ops` under the predicate
+        `end` shares: the node space, the mask (_reach_mask: on the device
+        and as the host applies it to a first hop), and for the pairs after
+        the first the kernel's operands, the dispatch key and the runner.
+        Made once a statement's chain, for one start (_device_reach) or for
+        a group of them (reach_group). None where the mask cannot be made
+        for this reader."""
+        n_cap, tb = ops[0]["n_pad"], ops[-1]["dst_tb"]
         masked = self._reach_mask(end, ops[-1], n_cap)
         if masked is None:
             return None
         words, mask = masked
-        # the first operator's row IS the first hop: no sweep for it
-        if ops[0]["dst_tb"] == tb:
-            first = np.unique(row)
-            got["rings"][1] = (tb, first if mask is None else first[mask[first]])
+        swept, fsz = ops[1:], ops[0]["row_pad"]
+        plan = {"ops": ops, "tb": tb, "n_cap": n_cap, "fsz": fsz, "words": words, "mask": mask, "swept": swept}
         if not swept:
-            return got
-        fr = np.full(fsz, n_cap, dtype=np.int32)
-        fr[: row.size] = row
+            return plan
         _kernels()
         kernel = _JITTED["chain_reach_batch"]
         csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in swept)
         slots = sum(int(op["csrc"].shape[0]) for op in swept)
         # the frontier's pad and the operands' ids: riders of any start and
         # any bound value share a batch (the mask rides as payload)
-        key = ("greach", fsz, n_cap, tuple(id(a) for hop in csc_hops for pair in hop for a in pair))
+        plan["key"] = ("greach", fsz, n_cap, tuple(id(a) for hop in csc_hops for pair in hop for a in pair))
 
         def runner(payloads):
             from surrealdb_tpu import compile_log
@@ -2174,20 +2294,42 @@ class GraphMirrors:
                 out = kernel(csc_hops, frs, _lane_end_weights(payloads, lanes, at=1), n_cap=n_cap)
             return _collect_rings(out, len(payloads), lanes, slots)
 
+        plan["runner"] = runner
         self._warm_reach(csc_hops, fsz, n_cap)
-        # the bucket is one batch deep and gathers, as the count's with one
-        # sweep left (_csc_chain_count), at TWO sweeps left too: a set
-        # statement's way back through the host is long (hundreds of record
-        # ids to fetch from), so at the queue's own depth its batches stay
-        # under 2 wide (1,728 dispatches in 14 s, 215 stmt/s, p50 36.3 ms;
-        # one deep and gathering 3.8 wide, 837, 223-229, 34.3-34.5: PERF.md
-        # section 6, PR 44)
-        got["t_ready"] = _time.perf_counter()
-        out = dispatch.submit(key, (fr, words), runner, depth=SWEEP_DEPTH, gather=True)
-        for h, (op, ring) in enumerate(zip(swept, out), start=1):
-            if op["dst_tb"] == tb:
-                got["rings"][2 * h + 1] = (tb, _ring_ids(ring))
+        return plan
+
+    @staticmethod
+    def _first_ring(plan: dict, row: np.ndarray) -> dict:
+        """A rider's answer before the sweep: the first operator's row IS
+        the first hop, masked on the host."""
+        got = {"form": "csc", "operand": "composed", "rings": {}, "t_ready": None}
+        if plan["ops"][0]["dst_tb"] == plan["tb"]:
+            first, mask = np.unique(row), plan["mask"]
+            got["rings"][1] = (plan["tb"], first if mask is None else first[mask[first]])
         return got
+
+    @staticmethod
+    def _rider(plan: dict, row: np.ndarray) -> tuple:
+        """A rider's payload: its first hop as the sweep's frontier, padded
+        at the node space's sentinel, and the mask's words on the device.
+        The bucket is one batch deep and gathers, as the count's with one
+        sweep left (_csc_chain_count), at TWO sweeps left too: a set
+        statement's way back through the host is long (hundreds of record
+        ids to fetch from), so at the queue's own depth its batches stay
+        under 2 wide (1,728 dispatches in 14 s, 215 stmt/s, p50 36.3 ms;
+        one deep and gathering 3.8 wide, 837, 223-229, 34.3-34.5: PERF.md
+        section 6, PR 44)."""
+        fr = np.full(plan["fsz"], plan["n_cap"], dtype=np.int32)
+        fr[: row.size] = row
+        return fr, plan["words"]
+
+    @staticmethod
+    def _swept_rings(plan: dict, got: dict, out) -> None:
+        """The swept hops' rings of one rider (chain_reach_batch's words)
+        into its answer, for the hops that land in the mask's table."""
+        for h, (op, ring) in enumerate(zip(plan["swept"], out), start=1):
+            if op["dst_tb"] == plan["tb"]:
+                got["rings"][2 * h + 1] = (plan["tb"], _ring_ids(ring))
 
     def _warm_reach(self, csc_hops, fsz: int, n_cap: int) -> None:
         """Compile chain_reach_batch at every lane count the runner can

@@ -924,6 +924,22 @@ def walk_exprs(node, visit, _depth: int = 0) -> None:
         walk_exprs(v, visit, _depth + 1)
 
 
+def chain_set_calls(fields, marked) -> tuple:
+    """Of the parser's `array::distinct(<graph chain>)` calls `marked`,
+    those a projection evaluates against the scanned record: the ones in
+    its field list outside any scope boundary, as model_calls finds a
+    projection's `ml::` calls. Kept on the statement
+    (SelectStatement.reach_calls)."""
+    mine, found = {id(c) for c in marked}, []
+
+    def visit(node):
+        if id(node) in mine:
+            found.append(node)
+
+    walk_exprs(fields, visit)
+    return tuple(found)
+
+
 def model_calls(fields) -> tuple:
     """The ModelCall nodes a projection evaluates against the scanned
     record: those of its field list, not those inside a scope boundary

@@ -14,7 +14,7 @@ large frontiers; the semantics here are the per-record reference behavior.
 from __future__ import annotations
 
 import time
-from typing import Any, List, Optional, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 from surrealdb_tpu import key as keys
 from surrealdb_tpu.err import TypeError_
@@ -566,6 +566,56 @@ def graph_chain_distinct(ctx, expr, deepest) -> "list | None":
         if mirrors is not None:
             mirrors.reach_walked(t_enter, len(parts) // 2, len(found))
     return found
+
+
+def fill_reach_groups(ctx, calls: Sequence, rows: Sequence) -> None:
+    """A SELECT's collected `rows` ((rid, doc, ir), two or more:
+    dbs/iterator.py defers their projection) and the `array::distinct(<graph
+    chain>)` calls of its field list (`calls`: the parser's note,
+    SelectStatement.reach_calls): for each chain family among them (the
+    deepest chain, as mark_chain_families found it), ONE test that the
+    chain rides (_chain_rides), ONE compiled predicate, and one run of the
+    chain for every row's record together (idx/graph_csr.py::reach_group:
+    the rows' frontiers in one call to the dispatch queue). Each row's
+    rings land in the statement's memo under that row's own key, as
+    graph_chain_distinct keeps one row's: the projection's expressions then
+    read their rings there (`memo=hit`), and nothing of the per-expression
+    logic changes. A family that cannot ride as a whole (a WHERE on a
+    middle part, a predicate that does not lower, this transaction's own
+    edge writes, TPU_DISABLE), a row that is no record of the first row's
+    table or a start the group could not serve is not filled, and
+    evaluates as it does alone."""
+    mirrors = getattr(ctx.ds(), "graph_mirrors", None)
+    families = list({id(c.reach): c.reach for c in calls}.values())
+    rid0, doc0, ir0 = rows[0]
+    if mirrors is None or not isinstance(rid0, Thing):
+        return
+    from surrealdb_tpu.ops.predicates import bound_constants, compile_where
+
+    memo = ctx.executor.reach_memo
+    for deepest in families:
+        parts = deepest.parts
+        if not _chain_rides(ctx, parts):
+            continue
+        cond, where = parts[-1].cond, None
+        with ctx.with_doc_value(doc0, rid=rid0, ir=ir0) as c:
+            bound = bound_constants(c, cond)
+            if cond is not None:
+                where = compile_where(c, cond)
+                if where is None:
+                    continue
+        todo = {}
+        for rid, _, _ in rows:
+            if isinstance(rid, Thing) and rid.tb == rid0.tb:
+                key = (id(deepest), rid.tb, repr(rid.id), bound)
+                if key not in memo:
+                    todo[key] = rid
+        if len(todo) < 2:
+            continue
+        gots = mirrors.reach_group(ctx, list(todo.values()), parts, where=where, families=len(families))
+        for key, got in zip(todo, gots or ()):
+            if got is not None:
+                memo[key] = got
 
 
 def _rides_alike(ctx, far: List[Part], parts: List[Part], bound: tuple) -> bool:

@@ -378,6 +378,7 @@ class SelectStatement(Statement):
         "explain_analyze",
         "value_mode",
         "ml_calls",
+        "reach_calls",
     )
 
     def __init__(self, fields, what, **kw):
@@ -394,6 +395,13 @@ class SelectStatement(Statement):
         # which swaps a parsed statement's field list and sets None while
         # it does
         self.ml_calls = kw.get("ml_calls")
+        # the parser's note of the same kind: the `array::distinct(<graph
+        # chain>)` calls that `fields` evaluates against the scanned record
+        # (ast.chain_set_calls), a tuple. With two or more rows the
+        # iterator runs each chain once for all of them
+        # (sql/path.py::fill_reach_groups). `()` where no parser looked:
+        # every row then evaluates its own, as one row does
+        self.reach_calls = kw.get("reach_calls", ())
         self.omit = kw.get("omit")
         self.only = kw.get("only", False)
         self.with_ = kw.get("with_")

@@ -327,7 +327,7 @@ class Parser:
         self.next()
         value_mode = False
         fields: List[S.Field] = []
-        ml_seen = self.ml_seen
+        ml_seen, sets_seen = self.ml_seen, len(self.chain_sets)
         if self.eat_kw("VALUE"):
             value_mode = True
             expr = self.parse_expr()
@@ -352,6 +352,8 @@ class Parser:
         # text is read: a field list without one is walked by nobody, here
         # or at any execution (dbs/iterator.py reads the note)
         ml_calls = A.model_calls(fields) if self.ml_seen != ml_seen else ()
+        # and its own array::distinct(<graph chain>) calls, the same way
+        reach_calls = A.chain_set_calls(fields, self.chain_sets[sets_seen:]) if len(self.chain_sets) != sets_seen else ()
         omit = None
         if self.eat_kw("OMIT"):
             omit = self._idiom_list()
@@ -360,7 +362,8 @@ class Parser:
         what = [self.parse_expr()]
         while self.eat_op(","):
             what.append(self.parse_expr())
-        kw: dict = {"omit": omit, "only": only, "value_mode": value_mode, "ml_calls": ml_calls}
+        kw: dict = {"omit": omit, "only": only, "value_mode": value_mode, "ml_calls": ml_calls,
+                    "reach_calls": reach_calls}
         if self.eat_kw("WITH"):
             if self.eat_kw("NOINDEX"):
                 kw["with_"] = S.With(True)

@@ -17,6 +17,8 @@ WIRE_BEFORE = ["ws_decode", "ws_admit_wait", "ws_exec_wait"]
 WIRE_AFTER = ["ws_encode", "ws_write"]
 KNN_SQL = "SELECT id, vector::distance::knn() AS d FROM item WHERE emb <|5,16|> $q"
 COUNT_SQL = "SELECT count(->knows->person->knows->person) AS c FROM person:1"
+HYBRID_SQL = ("SELECT id, vector::distance::knn() AS d, array::distinct(->refs->item->refs->item) AS ctx "
+              "FROM item WHERE emb <|5,16|> $q")
 
 
 def _complete(tid, timeout=5.0):
@@ -40,8 +42,9 @@ def _end(span):
 
 @pytest.fixture(scope="module")
 def served():
-    """One loop-served WS session: `use`, then three tagged frames (the first
-    kNN statement after a load, the same statement again, a graph count)."""
+    """One loop-served WS session: `use`, then four tagged frames (the first
+    kNN statement after a load, the same statement again, a graph count, a
+    search whose every hit carries the set two steps away)."""
     from surrealdb_tpu.dbs.session import Session
     from surrealdb_tpu.net import ws as wsproto
     from surrealdb_tpu.net.server import serve
@@ -67,6 +70,9 @@ def served():
                  for i in range(40) for j in range(1, 4)]
         out = srv.ds.execute("INSERT RELATION INTO knows $rows RETURN NONE", s, vars={"rows": edges})
         assert out[-1]["status"] == "OK", out
+        refs = [{"in": Thing("item", i), "out": Thing("item", (i * 5 + j) % 256)} for i in range(256) for j in range(1, 4)]
+        out = srv.ds.execute("INSERT RELATION INTO refs $rows RETURN NONE", s, vars={"rows": refs})
+        assert out[-1]["status"] == "OK", out
 
         sock = socket.create_connection((srv.host, srv.port))
         bs = wsproto.BufferedSocket(sock, wsproto.client_handshake(sock, f"{srv.host}:{srv.port}", "/rpc"))
@@ -78,7 +84,8 @@ def served():
         first = rpc({"id": 1, "method": "use", "params": ["t", "t"], "trace": "wire-use"})
         q = rng.normal(size=8).tolist()
         replies = {"wire-use": first}
-        for i, (tid, sql) in enumerate([("wire-first", KNN_SQL), ("wire-second", KNN_SQL), ("wire-count", COUNT_SQL)]):
+        for i, (tid, sql) in enumerate([("wire-first", KNN_SQL), ("wire-second", KNN_SQL), ("wire-count", COUNT_SQL),
+                                       ("wire-hybrid", HYBRID_SQL)]):
             replies[tid] = rpc({"id": 2 + i, "method": "query", "params": [sql, {"q": q}], "trace": tid})
             assert replies[tid]["trace"] == tid
             assert all(r["status"] == "OK" for r in replies[tid]["result"]), replies[tid]
@@ -93,6 +100,27 @@ def served():
 
 
 # ------------------------------------------------------------------ wire spans
+def test_a_hybrid_statement_s_tree_holds_one_search_one_group_and_launches_of_both_families(served):
+    doc = served["docs"]["wire-hybrid"]
+    rows = served["replies"]["wire-hybrid"]["result"][-1]["result"]
+    assert len(rows) == 5 and all(len(r["ctx"]) > 0 for r in rows)
+    assert len(_named(doc, "knn_prepare")) == 1
+    (group,) = _named(doc, "graph_reach_group")
+    assert group["labels"] == {**group["labels"], "rows": "5", "riders": "5", "launches": "1", "filter": "none", "depth": "2"}
+    launches = _named(doc, "dispatch_launch")
+    swept = [l for l in launches if "slots" in l["labels"]]
+    assert len(launches) == 2 and len(swept) == 1 and swept[0]["labels"]["batch"] == "5"
+    search = next(l for l in launches if "slots" not in l["labels"])
+    assert _end(search) <= group["start_ms"] + 0.002 <= swept[0]["start_ms"] + 0.004  # the hits, then the group, then its launch
+    # each phase of the one launch is on the statement once, whatever riders it carried
+    for name in ("dispatch_queue_wait", "dispatch_wake"):
+        assert len(_named(doc, name)) == 2, name
+    (collect,) = _named(doc, "dispatch_collect")  # the sweep's: this small search's runner has one phase
+    assert collect["labels"]["batch"] == "5"
+    memo = [s["labels"]["memo"] for s in _named(doc, "graph_prepare")]
+    assert memo.count("fill") == 1 and memo.count("hit") == 5
+
+
 def test_ws_rpc_is_the_only_parentless_span_with_its_labels(served):
     for tid, method in (("wire-use", "use"), ("wire-second", "query")):
         doc = served["docs"][tid]
