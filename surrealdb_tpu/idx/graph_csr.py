@@ -345,11 +345,14 @@ def _collect_counts(out, riders: int, lanes: int, sweeps: Optional[int] = None):
     return collect
 
 
-def _reach_shape_key(lanes: int, fsz: int, n_cap: int, csc_hops) -> tuple:
+def _reach_shape_key(lanes: int, fsz: int, n_cap: int, csc_hops, walk_pad: int = 0) -> tuple:
     """Compile-cache key of the batched set kernel (chain_reach_batch): the
     lanes, the frontier's pad, the node space and each swept operand's
-    paddings. Every rider is masked, so the key has no ending."""
-    return (lanes, fsz, n_cap, tuple(int(a.shape[0]) for hop in csc_hops for pair in hop for a in pair))
+    paddings. Every rider is masked, so the key has no ending. With a
+    `walk_pad` it is the key of the program that reads the one hop from
+    the operator's rows (`csc_hops` then holds its `(indptr, dst)`)."""
+    key = (lanes, fsz, n_cap, tuple(int(a.shape[0]) for hop in csc_hops for pair in hop for a in pair))
+    return key + ("rows", walk_pad) if walk_pad else key
 
 
 def _pack_mask(mask: np.ndarray, n_cap: int) -> np.ndarray:
@@ -360,14 +363,20 @@ def _pack_mask(mask: np.ndarray, n_cap: int) -> np.ndarray:
     return np.packbits(bits, bitorder="little").view("<u4")
 
 
-def _ring_ids(words: np.ndarray) -> np.ndarray:
-    """The nodes of one bit-packed ring (_pack_mask's layout), ascending."""
-    return np.flatnonzero(np.unpackbits(np.ascontiguousarray(words, dtype="<u4").view(np.uint8), bitorder="little"))
+def _ring_ids(ring: np.ndarray) -> np.ndarray:
+    """The nodes of one ring as chain_reach_batch hands it back, ascending:
+    bit-packed words (uint32, _pack_mask's layout) from a sweep, or a walk
+    read from the rows (int32: a destination a slot, a node once a walk
+    that ends at it, -1 in the slots that hold none)."""
+    if ring.dtype == np.int32:
+        ids = np.unique(ring)
+        return ids[ids >= 0]
+    return np.flatnonzero(np.unpackbits(np.ascontiguousarray(ring, dtype="<u4").view(np.uint8), bitorder="little"))
 
 
 def _reached(
     form: str, t_enter: float, t_ready: float, filter: str = "none", operand: Optional[str] = None,
-    depth: int = 0, memo: str = "fill", ids: int = 0, rings: int = 0,
+    depth: int = 0, memo: str = "fill", ids: int = 0, rings: int = 0, last_hop: Optional[str] = None,
 ) -> None:
     """One `array::distinct(<chain>)` served, as _served says it of a count:
     the `graph_reach` counter (`form`: `csc`, the device's sweep of composed
@@ -381,16 +390,24 @@ def _reached(
     say `ids` (the nodes this expression returned) and `rings` (the hops the
     statement's one program kept) beside `depth` (this chain's pairs) and
     `memo` (`fill`: this expression ran the chain, for itself and for the
-    statement's others; `hit`: it read their rings)."""
-    telemetry.inc("graph_reach", form=form, filter=filter, operand=operand or "none")
+    statement's others; `hit`: it read their rings). The expression that
+    fills a `csc` run which launches also says where its last hop came
+    from (`last_hop`, as a count's `first_hop`: `rows`, the kernel read the
+    frontier's rows of the operator, bounded by the operators' walk pad;
+    `sweep`, it swept every slot of the operators; the counter says `none`
+    for every other expression)."""
+    telemetry.inc("graph_reach", form=form, filter=filter, operand=operand or "none", last_hop=last_hop or "none")
     labels = {"form": form, "filter": filter, "depth": depth, "memo": memo, "ids": ids, "rings": rings}
     if operand is not None:
         labels["operand"] = operand
+    if last_hop is not None:
+        labels["last_hop"] = last_hop
     telemetry.stage("graph_prepare", t_enter, t_ready - t_enter, **labels)
 
 
 def _grouped(
     t_enter: float, t_ready: float, rows: int, riders: int, families: int, launches: int, filter: str, depth: int,
+    last_hop: Optional[str] = None,
 ) -> None:
     """One chain family of a statement served for all its rows together
     (GraphMirrors.reach_group): the `graph_reach_group` counter and the
@@ -410,16 +427,17 @@ def _grouped(
         "graph_reach_group", t_enter, t_ready - t_enter, rows=rows, riders=riders, families=families,
         launches=launches, form="csc", filter=filter, depth=depth,
     )
-    _reached("csc", t_enter, t_ready, filter, "composed", depth=depth, memo="fill", ids=0, rings=0)
+    _reached("csc", t_enter, t_ready, filter, "composed", depth=depth, memo="fill", ids=0, rings=0, last_hop=last_hop)
 
 
 def _collect_rings(out, riders: int, lanes: int, slots: int):
     """The collect phase of a batched set chain launched at `lanes` lanes:
-    each rider's rings off the device, uint32 [hops, words] a rider.
+    each rider's rings off the device, uint32 [hops, words] a rider (int32
+    [1, walk_pad], its walk's destinations, where the kernel read the rows).
     `lanes` and `slots` (the operand slots the kernel swept a lane, all
-    hops together) join the riders' `dispatch_launch` spans, as
-    _collect_counts' labels do; `outputs` is what the dispatcher waits
-    for."""
+    hops together; the walk pad's slots where it read the rows) join the
+    riders' `dispatch_launch` spans, as _collect_counts' labels do;
+    `outputs` is what the dispatcher waits for."""
 
     def collect():
         return list(np.asarray(out)[:riders])
@@ -475,6 +493,26 @@ def _row_pad(longest: int) -> int:
     passes ROW_PAD_MAX."""
     pad = _next_pow2(max(longest, 1))
     return pad if pad <= ROW_PAD_MAX else 0
+
+
+def _walk_pad(first: dict, second: dict) -> int:
+    """The pad of the longest two-step walk through the composed operators
+    `first` then `second`: over `first`'s sources, the lengths of the
+    `second` rows of its row's destinations summed, a destination once a
+    path, padded as a row is (_row_pad: 0 where a walk passes
+    ROW_PAD_MAX, or where `first` has a row too long to be a frontier).
+    A property of the two operators and of no rider: every set rider of
+    a generation reads its last hop at this one size. Kept on `first`
+    while `second` is the generation it was reckoned from."""
+    gen, got = (second["key"], second["gen"]), first.get("walk_pad")
+    if got is None or got[0] != gen:
+        pad = 0
+        if first["row_pad"]:
+            (indptr, dst), rows = first["by_src"], second["by_src"][0]
+            run = np.concatenate([[0], np.cumsum(np.diff(rows)[dst], dtype=np.int64)])
+            pad = _row_pad(int((run[indptr[1:]] - run[indptr[:-1]]).max()))
+        first["walk_pad"] = got = (gen, pad)
+    return got[1]
 
 
 def _seed_rows(op: dict, fr: np.ndarray, cw: np.ndarray):
@@ -721,8 +759,43 @@ def _kernels():
             total = total + (xr * deg[None, :]).sum(axis=1)
         return total
 
-    @partial(jax.jit, static_argnames=("n_cap",))
-    def chain_reach_batch(csc_hops, frontiers, masks, n_cap):
+    def reach_rows(indptr, dst, frontiers, masks, n_cap, walk_pad):
+        """chain_reach_batch's ONE swept hop read from the operator's
+        source-sorted rows (`indptr` [n_cap + 1], `dst` in source order,
+        padded past the last row: _reach_plan), where the pair of operators
+        bounds every rider's walk by `walk_pad` slots (_walk_pad): the
+        frontier's rows laid end to end, a destination a slot. A lane: the
+        rows' first slots and lengths (0 at the sentinel n_cap), their
+        running sum over the frontier; for output slot t the row it falls
+        in is the last whose first running slot is at or below t, so the
+        slot of `dst` to read is t plus `starts - before` of that row,
+        which telescopes into one compare-and-sum over the frontier (the
+        differences of `starts - before`, each where its row starts at or
+        below t; int32 sums wrap back whatever the order) with the frontier
+        on the major axis and the slots on the lanes: no search, no scatter,
+        no prefix sum over the operator's slots, no pass over the node
+        space. Then the destination and its mask bit. Returns int32 [B,
+        walk_pad]: the destination where the slot lies inside the walk and
+        its node passes, else -1, a destination once a walk (the collect
+        makes the set: _ring_ids)."""
+        fr = jnp.clip(frontiers, 0, n_cap)
+        starts = indptr[fr]
+        lens = indptr[jnp.minimum(fr + 1, n_cap)] - starts
+        run = jnp.cumsum(lens, axis=1)
+        before = run - lens
+        base = starts - before
+        step = jnp.concatenate([base[:, :1], base[:, 1:] - base[:, :-1]], axis=1)
+        t = jnp.arange(walk_pad, dtype=jnp.int32)
+        at = t[None, :] + jnp.where(before[:, :, None] <= t[None, None, :], step[:, :, None], 0).sum(axis=1)
+        ids = dst[jnp.clip(at, 0, dst.shape[0] - 1)]
+        inside = t[None, :] < run[:, -1:]
+        safe = jnp.where(inside, ids, 0)
+        word = jnp.take_along_axis(jnp.stack(masks), safe >> 5, axis=1)
+        passes = (word >> (safe & 31).astype(jnp.uint32)) & 1
+        return jnp.where(inside & (passes > 0), ids, -1)
+
+    @partial(jax.jit, static_argnames=("n_cap", "walk_pad"))
+    def chain_reach_batch(csc_hops, frontiers, masks, n_cap, walk_pad=0):
         """Batched SET chains for B concurrent statements over the same
         composed operators (`array::distinct(<chain>)`: the nodes a walk of
         exactly h pairs reaches, each once), the set form of
@@ -736,7 +809,15 @@ def _kernels():
         predicate (all of them for a chain without one). Returns uint32
         [B, hops, words]: the nodes after each hop that pass, bit-packed
         the same way, so a rider reads back n_cap / 8 bytes a hop whatever
-        it reached."""
+        it reached. With a `walk_pad` (static: another program under the
+        same XLA module name, so trace readers find whichever served the
+        set) `csc_hops` is the ONE hop's operator by source, `(((indptr,
+        dst),),)`, and the hop is read from the frontier's rows
+        (reach_rows: int32 [B, 1, walk_pad] comes back, the one ring a
+        walk)."""
+        if walk_pad:
+            (((indptr, dst),),) = csc_hops
+            return reach_rows(indptr, dst, frontiers, masks, n_cap, walk_pad)[:, None, :]
         B = frontiers.shape[0]
         lane_off = (jnp.arange(B) * (n_cap + 1))[:, None]
         x = (
@@ -2091,8 +2172,10 @@ class GraphMirrors:
         estimate and no threshold, so a statement's dispatches are fixed by
         its text. Ring 1 is the first operator's row of the start, read on
         the host; the later rings are chain_reach_batch's, one sweep a
-        pair, with the rider's predicate as a bit mask kept on the device a
-        binding (_reach_mask).
+        pair (of a chain of two pairs whose operators bound every walk by a
+        pad: the frontier's rows of the second, _walk_pad), with the
+        rider's predicate as a bit mask kept on the device a binding
+        (_reach_mask).
         A chain of one pair makes no dispatch. Anything else (TPU_DISABLE,
         a part over several tables, an odd number of parts, a pair no
         operator spans, a start whose row of the first operator passes the
@@ -2144,6 +2227,7 @@ class GraphMirrors:
         _reached(
             got["form"], t_enter, t_ready, got["filter"], got["operand"],
             depth=len(parts) // 2, memo="fill" if fill else "hit", ids=len(things), rings=len(got["rings"]),
+            last_hop=got.get("last_hop") if fill else None,
         )
         return things
 
@@ -2239,6 +2323,7 @@ class GraphMirrors:
         _grouped(
             t_enter, t_ready, rows=len(starts), riders=len(rode), families=families, launches=len(set(rode)),
             filter="none" if end is None else "fused", depth=len(parts) // 2,
+            last_hop=plan["last_hop"] if rode else None,
         )
         return gots
 
@@ -2262,10 +2347,13 @@ class GraphMirrors:
         """What every rider of the set chain over `ops` under the predicate
         `end` shares: the node space, the mask (_reach_mask: on the device
         and as the host applies it to a first hop), and for the pairs after
-        the first the kernel's operands, the dispatch key and the runner.
-        Made once a statement's chain, for one start (_device_reach) or for
-        a group of them (reach_group). None where the mask cannot be made
-        for this reader."""
+        the first the kernel's operands, the dispatch key and the runner,
+        and where the last hop comes from (`last_hop`: `rows` where ONE
+        pair follows the first and the two operators bound every walk by a
+        pad, _walk_pad: one bucket and one program whatever the rider;
+        else `sweep`). Made once a statement's chain, for one start
+        (_device_reach) or for a group of them (reach_group). None where
+        the mask cannot be made for this reader."""
         n_cap, tb = ops[0]["n_pad"], ops[-1]["dst_tb"]
         masked = self._reach_mask(end, ops[-1], n_cap)
         if masked is None:
@@ -2276,12 +2364,22 @@ class GraphMirrors:
         if not swept:
             return plan
         _kernels()
+        # ONE hop after the first, every walk of the two operators inside
+        # the pad: the kernel reads the frontier's rows of the second
+        # operator (its `indptr`, and its destinations in source order,
+        # uploaded for this) where it would sweep every slot of it
+        walk_pad = _walk_pad(ops[0], ops[1]) if len(swept) == 1 else 0
         kernel = _JITTED["chain_reach_batch"]
-        csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in swept)
-        slots = sum(int(op["csrc"].shape[0]) for op in swept)
-        # the frontier's pad and the operands' ids: riders of any start and
-        # any bound value share a batch (the mask rides as payload)
-        plan["key"] = ("greach", fsz, n_cap, tuple(id(a) for hop in csc_hops for pair in hop for a in pair))
+        if walk_pad:
+            csc_hops, slots = (((swept[0]["indptr"], self._rows_on_device(swept[0])),),), walk_pad
+        else:
+            csc_hops = tuple(((op["cptr"], op["csrc"]),) for op in swept)
+            slots = sum(int(op["csrc"].shape[0]) for op in swept)
+        plan["last_hop"] = "rows" if walk_pad else "sweep"
+        # the frontier's pad, the walk's (0: swept) and the operands' ids:
+        # riders of any start and any bound value share a batch (the mask
+        # rides as payload)
+        plan["key"] = ("greach", fsz, walk_pad, n_cap, tuple(id(a) for hop in csc_hops for pair in hop for a in pair))
 
         def runner(payloads):
             from surrealdb_tpu import compile_log
@@ -2290,13 +2388,36 @@ class GraphMirrors:
             frs = np.full((lanes, fsz), n_cap, dtype=np.int32)
             for i, p in enumerate(payloads):
                 frs[i] = p[0]
-            with compile_log.tracked("graph_reach", _reach_shape_key(lanes, fsz, n_cap, csc_hops)):
-                out = kernel(csc_hops, frs, _lane_end_weights(payloads, lanes, at=1), n_cap=n_cap)
+            with compile_log.tracked("graph_reach", _reach_shape_key(lanes, fsz, n_cap, csc_hops, walk_pad)):
+                out = kernel(csc_hops, frs, _lane_end_weights(payloads, lanes, at=1), n_cap=n_cap, walk_pad=walk_pad)
             return _collect_rings(out, len(payloads), lanes, slots)
 
         plan["runner"] = runner
-        self._warm_reach(csc_hops, fsz, n_cap)
+        self._warm_reach(csc_hops, fsz, n_cap, walk_pad)
         return plan
+
+    def _rows_on_device(self, op: dict):
+        """The composed operator `op`'s destinations in source order
+        (`by_src`) on the device, padded as its swept arrays are
+        (path_slots, the sentinel `n_pad` past the last row: an edge more
+        keeps the program's shape): what chain_reach_batch gathers its walks
+        from. Uploaded once a generation, with the first set chain that
+        reads rows, and never for an operator whose walks pass the pad."""
+        import jax.numpy as jnp
+
+        rows = op.get("dst_rows")
+        if rows is None:
+            t0 = _time.perf_counter()
+            dst = op["by_src"][1]
+            padded = np.full(path_slots(dst.size), op["n_pad"], dtype=np.int32)
+            padded[: dst.size] = dst
+            rows = jnp.asarray(padded)
+            telemetry.stage("graph_csc_upload", t0, _time.perf_counter() - t0, bytes=padded.nbytes)
+            # two first statements at once: one array wins, so that every
+            # rider's dispatch key names the same operand
+            with self._lock:
+                rows = op.setdefault("dst_rows", rows)
+        return rows
 
     @staticmethod
     def _first_ring(plan: dict, row: np.ndarray) -> dict:
@@ -2325,23 +2446,27 @@ class GraphMirrors:
 
     @staticmethod
     def _swept_rings(plan: dict, got: dict, out) -> None:
-        """The swept hops' rings of one rider (chain_reach_batch's words)
-        into its answer, for the hops that land in the mask's table."""
+        """The swept hops' rings of one rider (chain_reach_batch's words, a
+        row a hop; its walk where the one hop was read from the rows) into
+        its answer, for the hops that land in the mask's table."""
+        got["last_hop"] = plan["last_hop"]
         for h, (op, ring) in enumerate(zip(plan["swept"], out), start=1):
             if op["dst_tb"] == plan["tb"]:
                 got["rings"][2 * h + 1] = (plan["tb"], _ring_ids(ring))
 
-    def _warm_reach(self, csc_hops, fsz: int, n_cap: int) -> None:
-        """Compile chain_reach_batch at every lane count the runner can
-        return (count_lane_set) for the shapes a statement is being served
-        at, once a shape and in the background (cnf.GRAPH_PREWARM), as
-        idx/ivf.py warms a search's other tiles: the first burst wider than
-        this statement's batch then starts on compiled programs. Not with
-        warm_count_kernels' shapes: a deployment that never asks for a set
-        composes and compiles nothing for one."""
+    def _warm_reach(self, csc_hops, fsz: int, n_cap: int, walk_pad: int = 0) -> None:
+        """Compile chain_reach_batch (the program a plan launches: the sweep
+        of `csc_hops`, or at a `walk_pad` the read of the one hop's rows) at
+        every lane count the runner can return (count_lane_set) for the
+        shapes a statement is being served at, once a shape and in the
+        background (cnf.GRAPH_PREWARM), as idx/ivf.py warms a search's
+        other tiles: the first burst wider than this statement's batch then
+        starts on compiled programs. Not with warm_count_kernels' shapes: a
+        deployment that never asks for a set composes and compiles nothing
+        for one."""
         from surrealdb_tpu import bg, cnf
 
-        shape = _reach_shape_key(0, fsz, n_cap, csc_hops)
+        shape = _reach_shape_key(0, fsz, n_cap, csc_hops, walk_pad)
         with self._lock:
             if not cnf.GRAPH_PREWARM or shape in self._warmed_reach:
                 return
@@ -2355,16 +2480,19 @@ class GraphMirrors:
             for lanes in count_lane_set():
                 try:
                     with compile_log.tracked(
-                        "graph_reach", _reach_shape_key(lanes, fsz, n_cap, csc_hops), prewarmed=True
+                        "graph_reach", _reach_shape_key(lanes, fsz, n_cap, csc_hops, walk_pad), prewarmed=True
                     ):
-                        kernel(csc_hops, np.full((lanes, fsz), n_cap, dtype=np.int32), (words,) * lanes, n_cap=n_cap)
+                        kernel(
+                            csc_hops, np.full((lanes, fsz), n_cap, dtype=np.int32), (words,) * lanes,
+                            n_cap=n_cap, walk_pad=walk_pad,
+                        )
                 except Exception:
                     telemetry.inc("prewarm_errors", subsystem="graph_reach")
 
         # under the arming statement's context, as idx/ft_mirror.py's warms:
         # a wait behind the statement's own compile lands in that trace
         bg.spawn(
-            "shape_warm", f"graph_reach:f{fsz}:n{n_cap}:h{len(csc_hops)}",
+            "shape_warm", f"graph_reach:f{fsz}:n{n_cap}:h{len(csc_hops)}" + (f":w{walk_pad}" if walk_pad else ""),
             contextvars.copy_context().run, warm, owner=self._owner,
         )
 
@@ -2520,14 +2648,18 @@ def graftcheck_sites():
         _kernels()
         kernel = _JITTED["chain_reach_batch"]
         lanes = shape["lanes"]
+        # a swept hop's (cptr, csrc) and, at a walk pad, the one hop's
+        # operator by source (its row bounds, its destinations padded as
+        # the swept arrays are) have the same shapes
         csc_hops = tuple(
             ((jax.ShapeDtypeStruct((n_cap + 1,), jnp.int32),
               jax.ShapeDtypeStruct((path_slots(E),), jnp.int32)),)
             for _ in range(shape["hops"])
         )
         masks = (jax.ShapeDtypeStruct((n_cap // 32,), jnp.uint32),) * lanes
+        walk_pad = shape.get("walk_pad", 0)
         return (
-            lambda ch, fr, ms: kernel(ch, fr, ms, n_cap=n_cap),
+            lambda ch, fr, ms: kernel(ch, fr, ms, n_cap=n_cap, walk_pad=walk_pad),
             (csc_hops, jax.ShapeDtypeStruct((lanes, fsz), jnp.int32), masks),
         )
 
@@ -2591,11 +2723,16 @@ def graftcheck_sites():
             "module": __name__,
             "kind": "single",
             "allowed_collectives": (),
-            "out_dtypes": ("uint32",),
+            "out_dtypes": ("uint32", "int32"),
             # the rings after two swept hops (a chain of three pairs from
-            # the first operator's rows), bit-packed, at every lane count
+            # the first operator's rows), bit-packed, at every lane count;
+            # and the one hop of a chain of two pairs read from the rows,
+            # a destination a slot of the walk pad (int32)
             "shapes": [
                 {"label": f"l{lanes}_f{fsz}_n{n_cap}_h2", "lanes": lanes, "hops": 2}
+                for lanes in count_lane_set()
+            ] + [
+                {"label": f"l{lanes}_f{fsz}_n{n_cap}_h1_w512", "lanes": lanes, "hops": 1, "walk_pad": 512}
                 for lanes in count_lane_set()
             ],
             "build": build_reach,

@@ -147,7 +147,11 @@ def test_the_three_rings_are_the_reference_s_sets_and_one_dispatch(served, kind,
         assert [int(p["ids"]) for p in prepares] == [len(row[f"d{h}"]) for h in (1, 2, 3)]
         assert launches[0]["lanes"] == "8" and int(launches[0]["slots"]) > 0
     how = "fused" if where == "named" else "none"
-    assert reached() == {(("filter", how), ("form", "csc"), ("operand", "composed")): 3 * len(pool)}
+    # the expression that ran the chain says where its last hop came from: three pairs sweep
+    assert reached() == {
+        (("filter", how), ("form", "csc"), ("last_hop", "sweep"), ("operand", "composed")): len(pool),
+        (("filter", how), ("form", "csc"), ("last_hop", "none"), ("operand", "composed")): 2 * len(pool),
+    }
 
 
 @pytest.mark.parametrize("depth", [1, 2, 3])
@@ -321,7 +325,7 @@ def test_a_where_that_cannot_ride_is_walked_and_says_filter_host(served, world, 
     assert sorted(ids_of(got)) == sorted(want) and len(got) == len(want)
     assert launches == [] and served.dispatch.stats()["submitted"] == before
     assert [(p["form"], p["filter"], p["memo"]) for p in prepares] == [("host", "host", "fill")]
-    assert reached() == {(("filter", "host"), ("form", "host"), ("operand", "none")): 1}
+    assert reached() == {(("filter", "host"), ("form", "host"), ("last_hop", "none"), ("operand", "none")): 1}
 
 
 def test_with_the_device_off_the_host_walks_sets_and_never_the_multiset(served, world, monkeypatch):
@@ -699,7 +703,9 @@ def test_graftcheck_audits_the_set_kernel_as_served():
 
     assert compile_log.KERNEL_SITES["graph_reach"] == "surrealdb_tpu.idx.graph_csr:graftcheck_sites"
     (contract,) = registry.resolve_contracts(["graph_reach"])
-    assert tuple(s["lanes"] for s in contract["shapes"]) == (8, 16, 32, 64)
+    # every lane count, swept (two hops) and read from the rows (one hop at a walk pad)
+    assert [(s["lanes"], s.get("walk_pad", 0)) for s in contract["shapes"]] == [
+        (lanes, pad) for pad in (0, 512) for lanes in (8, 16, 32, 64)]
     for shape in contract["shapes"]:
         low = lowering.lower_site(contract, shape)
         assert rules.check(contract, shape, low) == [] and low.collectives == {}
@@ -723,6 +729,132 @@ def test_the_kernel_packs_a_bit_a_node_and_a_padding_lane_reaches_nothing():
     assert [graph_csr._ring_ids(out[0, h]).tolist() for h in (0, 1)] == [[1, 31], [2, 32]]
     assert [graph_csr._ring_ids(out[1, h]).tolist() for h in (0, 1)] == [[], [7]]  # 6 is even: masked
     assert not out[2:].any()
+
+
+# ------------------------------------------------------------------ the one hop read from the rows (ISSUE 48)
+ROWS_N, ROWS_CAP = 200, 256
+
+
+def rows_graph():
+    """A seeded graph over 200 nodes (node space 256) as the composed
+    operator's arrays: up to 6 records a node among the first 180, drawn with
+    replacement (duplicate records; two of a node's neighbours reach the same
+    third: diamonds), and by hand: 190 with no record (a start with an empty
+    row); 191 -> 192, 193 and 192 with none (a frontier node with an empty
+    row); 194 -> 32 nodes, the longest row (a frontier at exactly the pad);
+    195 -> 100..115, each of which has 16 records: a walk of 256, the longest
+    (a walk at exactly the walk pad); 196 -> 197, 198 -> 199 (a diamond)."""
+    rng = np.random.default_rng(48)
+    src = np.repeat(np.arange(180), rng.integers(0, 7, 180))
+    src = src[(src < 100) | (src > 115)]
+    pairs = [(int(a), int(rng.integers(0, 180))) for a in src]
+    pairs += [(a, int(b)) for a in range(100, 116) for b in rng.integers(0, 100, 16)]
+    pairs += [(191, 192), (191, 193), (193, 5), (193, 6), (193, 5)]
+    pairs += [(194, int(b)) for b in rng.integers(0, 100, 32)] + [(195, b) for b in range(100, 116)]
+    pairs += [(196, 197), (196, 198), (197, 199), (198, 199)]
+    pairs = np.array(pairs)[rng.permutation(len(pairs))]
+    order = np.argsort(pairs[:, 0], kind="stable")
+    ls, ld = pairs[order, 0], pairs[order, 1]
+    indptr = np.zeros(ROWS_CAP + 1, dtype=np.int32)
+    np.cumsum(np.bincount(ls, minlength=ROWS_CAP), out=indptr[1:])
+    op = {"by_src": (indptr, ld.astype(np.int32)), "row_pad": graph_csr._row_pad(int(np.diff(indptr).max())),
+          "key": ("op",), "gen": (1,), "by_dst": graph_csr._csc_arrays(ls, ld, ROWS_CAP)}
+    return op, pairs
+
+
+def host_walk2(pairs, start: int, mask) -> list:
+    out: dict = {}
+    for a, b in pairs.tolist():
+        out.setdefault(a, []).append(b)
+    return sorted({w for v in out.get(start, ()) for w in out.get(v, ()) if mask[w]})
+
+
+ROWS_CASES = {
+    # the case's own starts (every batch also carries a few random ones) and its mask
+    "duplicate_records": ([3, 17, 42], "half"),
+    "a_diamond": ([196], "all"),
+    "a_start_with_an_empty_row": ([190], "all"),
+    "a_frontier_node_with_an_empty_row": ([191], "all"),
+    "a_frontier_at_exactly_the_pad": ([194], "half"),
+    "a_walk_at_exactly_the_walk_pad": ([195], "all"),
+    "a_mask_that_passes_nothing": ([195, 194, 7], "none"),
+    "a_mask_that_passes_all": ([195, 194, 7], "all"),
+}
+
+
+@pytest.mark.parametrize("lanes", [8, 64])
+@pytest.mark.parametrize("case", sorted(ROWS_CASES))
+def test_the_hop_read_from_the_rows_is_the_sweep_s_ring_and_the_host_s_walk(case, lanes):
+    op, pairs = rows_graph()
+    (indptr, dst), (cptr, csrc), fsz = op["by_src"], op["by_dst"], op["row_pad"]
+    walk_pad = graph_csr._walk_pad(op, op)
+    deg = np.diff(indptr)
+    longest = max(int(deg[dst[indptr[s]:indptr[s + 1]]].sum()) for s in range(ROWS_N))
+    assert (fsz, walk_pad, longest) == (32, 256, 256) and int(deg[194]) == 32
+    assert len({tuple(p) for p in pairs.tolist()}) < len(pairs)  # duplicate records
+    starts, how = ROWS_CASES[case]
+    rng = np.random.default_rng(lanes)
+    starts = starts + rng.integers(0, 180, (lanes - 3) - len(starts)).tolist()  # three lanes stay empty
+    mask = {"all": np.ones(ROWS_CAP, dtype=bool), "none": np.zeros(ROWS_CAP, dtype=bool),
+            "half": np.arange(ROWS_CAP) % 2 == 0}[how]
+    frs = np.full((lanes, fsz), ROWS_CAP, dtype=np.int32)
+    for i, s in enumerate(starts):
+        row = dst[indptr[s]:indptr[s + 1]]
+        frs[i, : row.size] = row
+    graph_csr._kernels()
+    kernel = graph_csr._JITTED["chain_reach_batch"]
+    padded = np.full(graph_csr.path_slots(dst.size), ROWS_CAP, dtype=np.int32)
+    padded[: dst.size] = dst
+    masks = (graph_csr._pack_mask(mask, ROWS_CAP),) * lanes
+    walked = np.asarray(kernel((((indptr, padded),),), frs, masks, n_cap=ROWS_CAP, walk_pad=walk_pad))
+    swept = np.asarray(kernel((((cptr, csrc),),), frs, masks, n_cap=ROWS_CAP))
+    assert walked.shape == (lanes, 1, walk_pad) and walked.dtype == np.int32 and swept.dtype == np.uint32
+    for i, s in enumerate(starts):
+        ring = graph_csr._ring_ids(walked[i, 0])
+        assert ring.tolist() == graph_csr._ring_ids(swept[i, 0]).tolist() == host_walk2(pairs, s, mask), (case, s)
+    assert (walked[len(starts):] == -1).all() and not swept[len(starts):].any()  # an empty lane reaches nothing
+    if how == "none":
+        assert (walked == -1).all()
+    if case == "a_walk_at_exactly_the_walk_pad":
+        assert (walked[0, 0] >= 0).all()  # every slot of the pad holds a destination
+
+
+def test_the_walk_pad_is_the_operators_longest_two_step_walk_padded_or_zero(monkeypatch):
+    op, _ = rows_graph()
+    assert graph_csr._walk_pad(op, op) == 256 and op["walk_pad"] == ((("op",), (1,)), 256)
+    # a walk longer than the pad rows are read at: every rider of the pair sweeps
+    monkeypatch.setattr(graph_csr, "ROW_PAD_MAX", 255)
+    later = {**op, "gen": (2,)}
+    assert graph_csr._walk_pad(op, later) == 0 and op["walk_pad"][0] == (("op",), (2,))
+    # an operator whose longest row passes the pad reads no row at all
+    assert graph_csr._walk_pad({**op, "row_pad": 0}, op) == 0
+
+
+def knows_operator(ds) -> dict:
+    (op,) = [op for key, op in ds.graph_mirrors._csc.items() if key[2] == "person" and key[4] == "knows"]
+    return op
+
+
+def test_a_three_pair_chain_and_sf3_s_operator_keep_the_sweep_and_its_shape_key(served, cfg, kind, world):
+    data, _, pool = world
+    compile_log.reset()
+    _, prepares, launches = ask(served, three_fields(NAMED), pool[0], "three-pairs")
+    # two hops after the first operator's row: no pad bounds them, whatever the operators' walks
+    assert prepares[0]["last_hop"] == "sweep" and all("last_hop" not in p for p in prepares[1:])
+    op = knows_operator(served)
+    hops = (((op["cptr"], op["csrc"]),),) * 2
+    assert int(launches[0]["slots"]) == 2 * int(op["csrc"].shape[0])
+    key = (8, op["row_pad"], op["n_pad"], (int(op["cptr"].shape[0]), int(op["csrc"].shape[0])) * 2)
+    assert graph_csr._reach_shape_key(8, op["row_pad"], op["n_pad"], hops) == key  # as before the walk pad
+    assert {e["shape"] for e in compile_log.events() if e["subsystem"] == "graph_reach"} == {"x".join(map(str, key))}
+    # SNB SF3's own `knows` (24,328 persons, ~46 a row): a two-step walk is far past the pad, so the
+    # benchmark's graph cells would sweep a chain of two pairs too
+    sf3 = kind.generate(cfg, cfg["sizes"], SEED)["pairs"]
+    order = np.argsort(sf3[:, 0], kind="stable")
+    indptr = np.concatenate([[0], np.cumsum(np.bincount(sf3[:, 0], minlength=32768))]).astype(np.int32)
+    knows = {"by_src": (indptr, sf3[order, 1].astype(np.int32)), "key": ("knows",), "gen": (1,),
+             "row_pad": graph_csr._row_pad(int(np.diff(indptr).max()))}
+    assert knows["row_pad"] == 1024 and graph_csr._walk_pad(knows, knows) == 0
 
 
 # ------------------------------------------------------------------ the program lowered for a TPU
@@ -749,6 +881,32 @@ def test_the_tpu_lowering_at_sf3_holds_no_reduce_window_over_the_slots(lanes):
     operands = re.findall(r"stablehlo\.reduce_window.*?\((tensor<[^>]*>)", text, flags=re.S)
     dims = [[int(d) for d in re.findall(r"(\d+)x", t)] for t in operands]
     assert all(max(d) <= SF3_SLOTS // 128 for d in dims), dims
+
+
+@pytest.mark.parametrize("lanes", [8, 64])
+def test_the_tpu_lowering_of_the_rows_read_at_magcite_s_shapes_scatters_nothing_and_passes_no_operator(lanes):
+    import jax
+    import jax.numpy as jnp
+    from jax import export
+
+    graph_csr._kernels()
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    slots, n_cap, fsz, walk_pad = 1_703_936, 262_144, 256, 4096
+    masks = (jax.ShapeDtypeStruct((n_cap // 32,), jnp.uint32),) * lanes
+    try:
+        exported = export.export(graph_csr._JITTED["chain_reach_batch"], platforms=("tpu",))(
+            (((i32(n_cap + 1), i32(slots)),),), i32(lanes, fsz), masks, n_cap=n_cap, walk_pad=walk_pad)
+    except Exception as e:  # a JAX that cannot lower for a platform it does not run on
+        pytest.skip(f"no TPU lowering without a chip here: {e!r}"[:200])
+    text = exported.mlir_module()
+    assert f"tensor<{lanes}x1x{walk_pad}xi32>" in text and "stablehlo.gather" in text
+    assert "stablehlo.scatter" not in text and "stablehlo.sort" not in text
+    # the one running sum is over the frontier's pad
+    operands = re.findall(r"stablehlo\.reduce_window.*?\((tensor<[^>]*>)", text, flags=re.S)
+    assert operands and all(t.startswith(f"tensor<{lanes}x{fsz}xi32") for t in operands), operands
+    # the operator's slots and the node space are operands to gather from, never an axis of a lane's work
+    assert not re.findall(rf"tensor<{lanes}x(?:{slots}|{n_cap}|{n_cap + 1})x", text)
+    assert not re.findall(rf"tensor<{lanes}x\d+x(?:{slots}|{n_cap}|{n_cap + 1})x", text)
 
 
 # ------------------------------------------------------------------ the benchmark's side
@@ -894,9 +1052,10 @@ def test_the_manifest_has_the_deployment_its_cell_and_its_seven_readers(cfg):
     assert all(m["workloads"] == [CELL] for m in mine)
     assert [m["moves"] for m in mine] == ["p50_ms"] * 6 + ["p95_ms"]
     # of the older lists none names the new cell (the readers without a list cover it as they are); of the
-    # later ones PR 45's does (the set chain's bucket gathers) and PR 46's (a statement's compiled predicates)
+    # later ones PR 45's does (the set chain's bucket gathers), PR 46's (a statement's compiled predicates)
+    # and PR 48's (where a set's last hop came from: swept here, 0.0)
     assert [m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", [])] == (
-        names + ["dispatch.gather_met_share", "exec.predicate_compiles"])
+        names + ["dispatch.gather_met_share", "exec.predicate_compiles", "graph.reach_rows_share"])
     assert {m["name"] for m in mf.metrics_of(manifest, "end_to_end", CELL)} == {"setup_s", "stmt_per_s", "p50_ms", "p95_ms"}
 
 

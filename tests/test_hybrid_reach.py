@@ -407,6 +407,97 @@ def test_an_acknowledged_update_of_year_and_a_relate_change_the_next_answer(serv
     assert len(sweeps(spans)) == 1 and len(named(spans, "graph_reach_group")) == 1
 
 
+# ------------------------------------------------------------------ the last hop from the rows, at the operators' pad (ISSUE 48)
+def cites_operator(ds) -> dict:
+    (op,) = [op for key, op in ds.graph_mirrors._csc.items() if key[2] == "paper" and key[4] == "cites"]
+    return op
+
+
+def longest_walk(cites) -> int:
+    deg = np.diff(cites.indptr)
+    return max(int(deg[cites.row(p)].sum()) for p in range(len(deg)))
+
+
+def test_every_rider_reads_its_last_hop_from_the_rows_at_the_pad_the_operators_fix(served, cfg, world):
+    data, ref, pool = world
+    pad = graph_csr._row_pad(longest_walk(ref["cites"]))
+    assert 0 < pad <= graph_csr.ROW_PAD_MAX and cites_operator(served)["walk_pad"][1] == pad
+    sql = cfg["statements"]["primary"]["sql"]
+    for q in range(4):
+        before = served.dispatch.stats()["submitted"]
+        rows, spans = ask(served, sql, pool[q], f"rows-{q}")
+        assert served.dispatch.stats()["submitted"] - before in (10, 11)  # ten set riders and the search's own
+        for row in rows:
+            assert ids_of(row["ctx"]) == ref["cites"].reach2(int(row["id"].id)).tolist() and row["n"] == len(row["ctx"])
+        (launch,) = sweeps(spans)  # whatever the hits' walks number: one bucket, one program
+        assert launch["lanes"] == "16" and launch["slots"] == str(pad) and launch["batch"] == "10"
+        fills = [p for p in named(spans, "graph_prepare") if p["memo"] == "fill"]
+        assert [(p["form"], p["last_hop"]) for p in fills] == [("csc", "rows")]
+        assert all("last_hop" not in p for p in named(spans, "graph_prepare") if p["memo"] == "hit")
+    shapes = {e["shape"] for e in compile_log.events() if e["subsystem"] == "graph_reach"}
+    assert shapes and all(s.endswith(f"xrowsx{pad}") for s in shapes), shapes
+    reached = {tuple(sorted(dict(k).items())): int(v) for k, v in telemetry.counters_matching("graph_reach").items()}
+    assert reached[(("filter", "fused"), ("form", "csc"), ("last_hop", "rows"), ("operand", "composed"))] == 4
+    # the rows the kernel gathers from went to the device once, with the operator's generation
+    assert cites_operator(served)["dst_rows"].shape[0] == graph_csr.path_slots(len(data["pairs"]))
+
+
+def test_the_deployment_s_own_graph_bounds_every_walk_by_4096(full_cfg, kind):
+    """`magcite150k` at full size (the generator's one fixed graph: reference
+    lists capped at 256): the longest two-step walk, duplicates counted, is
+    3,095 records over all papers and 2,867 over those that can be a hit."""
+    papers, cites = full_cfg["sizes"]["papers"], full_cfg["sizes"]["cites"]
+    pairs = kind.citations(full_cfg["generator"], papers, cites, papers // 2)
+    order = np.argsort(pairs[:, 0], kind="stable")
+    indptr = np.concatenate([[0], np.cumsum(np.bincount(pairs[:, 0], minlength=262144))]).astype(np.int32)
+    op = {"by_src": (indptr, pairs[order, 1].astype(np.int32)), "key": ("cites",), "gen": (1,),
+          "row_pad": graph_csr._row_pad(int(np.diff(indptr).max()))}
+    walks = np.bincount(pairs[:, 0], weights=np.diff(indptr)[pairs[:, 1]], minlength=papers)
+    assert (int(walks.max()), int(walks[papers // 2:].max()), int(np.median(walks[papers // 2:]))) == (3095, 2867, 75)
+    assert op["row_pad"] == 256 and graph_csr._walk_pad(op, op) == 4096 == graph_csr.ROW_PAD_MAX
+
+
+def test_one_relate_that_lengthens_a_walk_past_the_pad_makes_the_next_statement_sweep(served, cfg, world):
+    data, ref, pool = world
+    cites, sql = ref["cites"], cfg["statements"]["primary"]["sql"]
+    one = "SELECT VALUE array::distinct(" + CHAIN + ") FROM ONLY type::thing('paper', $q.p)"
+    hit = int(ref["ids"][0, 0])
+    found, spans = ask(served, one, {"p": hit, "y": data["y"]}, "alone")
+    assert ids_of(found) == cites.reach2(hit).tolist()
+    assert [p["last_hop"] for p in named(spans, "graph_prepare")] == ["rows"]
+    # one RELATE: the hit cites the most-citing papers until its walk passes ROW_PAD_MAX
+    deg = np.diff(cites.indptr)
+    more, walk = [], int(deg[cites.row(hit)].sum())
+    for p in np.argsort(-deg, kind="stable").tolist():
+        if walk > graph_csr.ROW_PAD_MAX:
+            break
+        more.append(p)
+        walk += int(deg[p])
+    assert walk > graph_csr.ROW_PAD_MAX and len(more) < 200
+    execute_ok(served, "RELATE $a->cites->$b", {"a": Thing("paper", hit), "b": [Thing("paper", p) for p in more]})
+    passes = data["year"] >= data["y"]
+    want = np.unique(np.concatenate([cites.row(int(m)) for m in cites.row(hit).tolist() + more]))
+    want = want[passes[want]].tolist()
+    compile_log.reset()
+    found, spans = ask(served, one, {"p": hit, "y": data["y"]}, "longer")
+    assert ids_of(found) == want and cites_operator(served)["walk_pad"][1] == 0
+    (launch,) = sweeps(spans)
+    assert int(launch["slots"]) == int(cites_operator(served)["csrc"].shape[0]) > graph_csr.ROW_PAD_MAX
+    assert [p["last_hop"] for p in named(spans, "graph_prepare")] == ["sweep"]
+    assert "dst_rows" not in cites_operator(served)  # no rows uploaded for a generation that sweeps
+    # and the timed statement, every row of it, through the same sweep
+    before = served.dispatch.stats()["submitted"]
+    rows, spans = ask(served, sql, pool[0], "group-swept")
+    assert served.dispatch.stats()["submitted"] - before in (10, 11)
+    by_id = {int(r["id"].id): r for r in rows}
+    assert ids_of(by_id[hit]["ctx"]) == want and by_id[hit]["n"] == len(want)
+    for p, row in by_id.items():
+        assert p == hit or ids_of(row["ctx"]) == cites.reach2(p).tolist()
+    assert [p["last_hop"] for p in named(spans, "graph_prepare") if p["memo"] == "fill"] == ["sweep"]
+    shapes = {e["shape"] for e in compile_log.events() if e["subsystem"] == "graph_reach"}
+    assert shapes and not any("rows" in s for s in shapes), shapes  # the sweep's shape key, as before the pad
+
+
 def test_uncommitted_edge_writes_take_the_kv_walk_for_every_row(served, world):
     data, ref, pool = world
     hit = int(ref["ids"][0, 0])
@@ -620,12 +711,16 @@ def test_the_manifest_has_the_deployment_its_cell_and_its_seven_readers(full_cfg
         "setup_s", "stmt_per_s", "p50_ms", "p95_ms", "recall_at_10"}
     (recall,) = [m for m in manifest["end_to_end"] if m["name"] == "recall_at_10"]
     assert recall["workloads"] == ["vec1m768.knn_c1", "vec500k768f.knn99p_c1", CELL]
-    assert [m["name"] for m in manifest["per_layer"]][-7:] == READERS
-    for m in manifest["per_layer"][-7:]:
+    # together and in order after those that were there (PR 48 appends the share of sets read from the rows)
+    assert [m["name"] for m in manifest["per_layer"]][-8:] == READERS + ["graph.reach_rows_share"]
+    for m in manifest["per_layer"][-8:-1]:
         assert m["workloads"] == [CELL] and m["moves"] == "p50_ms"
+    assert manifest["per_layer"][-1] == {
+        "name": "graph.reach_rows_share", "unit": "ratio", "better": "higher", "source": "program_span",
+        "layer": "kernels", "moves": "p50_ms", "workloads": [CELL, "snbsf3ic1d.near20_c8"]}
     mine = {m["name"] for m in mf.metrics_of(manifest, "per_layer", CELL)}
     theirs = {m["name"] for m in mf.metrics_of(manifest, "per_layer", "vec1m768.knn_c1")}
-    assert mine - theirs == set(READERS) and theirs - mine == {"ivf_roofline", "ivf.longest_list"}
+    assert mine - theirs == set(READERS) | {"graph.reach_rows_share"} and theirs - mine == {"ivf_roofline", "ivf.longest_list"}
 
 
 def test_the_configuration_states_the_source_s_shapes_and_cuts_only_the_rows(full_cfg):
