@@ -180,6 +180,14 @@ class _Req:
 # under the interpreter lock.
 SWEEP_DEPTH = 1
 
+# A launch phase reads its leader's CPU clock where the leader's own trace is
+# tagged, and otherwise at most this often a queue: the read is a system call
+# on the chip's host, and two round every launch cost a one-session cell 5%
+# of its p50 (PERF.md section 6, PR 49). `launch_cpu_s` over `launch_cpu_of_s`
+# is the sampled phases' share of work; ten samples a second tell it as well
+# as a clock of 10 ms ticks lets anything.
+CPU_SAMPLE_EVERY_S = 0.1
+
 
 class _Bucket:
     __slots__ = ("lock", "queue", "launching", "sem", "depth",
@@ -245,6 +253,10 @@ class DispatchQueue:
         self.splits = 0  # transiently-failed batches bisected for retry
         self.failures = 0  # batches that failed permanently (every rider errored)
         self.launch_s = 0.0  # time in runner launch phases (upload + enqueue)
+        # the leaders' own CPU (time.thread_time) in the launch phases that
+        # were SAMPLED, and those phases' wall time (_launch: CPU_SAMPLE_EVERY_S)
+        self.launch_cpu_s = self.launch_cpu_of_s = 0.0
+        self._t_cpu_sample = float("-inf")  # when a launch phase last read the CPU clock
         self.collect_s = 0.0  # time in collect phases (the device's wait and the download)
         self.ready_wait_s = 0.0  # of collect_s: until the outputs were ready on the device
         self.fetch_s = 0.0  # of collect_s: from ready to the results as host values
@@ -472,18 +484,29 @@ class DispatchQueue:
 
     def _trace_batch(
         self, batch: List[_Req], name: str, start: float, dur: float,
-        error=None, **extra,
+        error=None, cpu: Optional[float] = None, **extra,
     ) -> None:
         """Stamp one kernel-phase span onto EVERY rider's trace, parented
         at the span each request was in when it submitted — a query that
         rode someone else's launch still shows its dispatch level. Once a
         trace position: a statement's riders that share a batch
-        (submit_many) share the span."""
+        (submit_many) share the span. `cpu`: the CPU seconds the calling
+        thread, the batch's leader, burned in the phase: they go onto the
+        copy in the leader's own trace, if that trace is tagged, and onto no
+        rider's (a copy without `cpu_ms` reads "slept through it"). The
+        leader's own `dispatch_ready_wait` carries what of the device's wait
+        its thread ran after all (a backend that runs the program on the
+        waiting thread: all of it)."""
         from surrealdb_tpu import tracing
 
         labels = {"batch": len(batch), **extra}
+        own = tracing.current() if cpu is not None else None
+        if own is not None and not own.trace.explicit:
+            own = None
         for ctx in {id(r.trace_ctx): r.trace_ctx for r in batch}.values():
-            tracing.record_span_into(ctx, name, labels, start, dur, error)
+            tracing.record_span_into(
+                ctx, name, labels, start, dur, error, cpu=cpu if ctx is own else None
+            )
 
     def _launch(
         self, batch: List[_Req], b: _Bucket, pipeline_wait: float,
@@ -508,11 +531,16 @@ class DispatchQueue:
                 self.gather_wait_s += gathered[0]
                 self.gather_met += gathered[1]
             self.width_counts[len(batch)] = self.width_counts.get(len(batch), 0) + 1
-            self._move(queued=-len(batch), launching=1)
+            now = self._move(queued=-len(batch), launching=1)
+            own = tracing.current()  # the leader's own trace position: its riders' too
+            sampled = (own is not None and own.trace.explicit) or now - self._t_cpu_sample >= CPU_SAMPLE_EVERY_S
+            if sampled:
+                self._t_cpu_sample = now
         payloads = [r.payload for r in batch]
         runner = batch[0].runner
 
         t0 = _time.perf_counter()
+        cpu0 = _time.thread_time() if sampled else None  # the leader's CPU clock, beside launch_s's
         telemetry.observe_hist("dispatch_batch_size", len(batch))
         telemetry.observe("dispatch_pipeline_wait", pipeline_wait)
         if pipeline_wait >= 0.001:
@@ -575,12 +603,16 @@ class DispatchQueue:
             return None
         finally:
             elapsed = _time.perf_counter() - t0
+            cpu = _time.thread_time() - cpu0 if sampled else None
             if b.gather:
                 with b.lock:
                     b.launches = (b.launches + [elapsed])[-5:]
             with self._lock:
                 _locks.assert_held(self._lock, "dispatch.counters")
                 self.launch_s += elapsed
+                if sampled:
+                    self.launch_cpu_s += cpu
+                    self.launch_cpu_of_s += elapsed
                 # a collect closure came back: the batch is on the device.
                 # Anything else (results, a failure) ends the dispatch here
                 landed = callable(res)
@@ -593,7 +625,7 @@ class DispatchQueue:
             # (success and failure paths both) — conservation holds exactly
             self._charge_batch(batch, elapsed, "dispatch_s")
         self._trace_batch(
-            batch, "dispatch_launch", t0, _time.perf_counter() - t0,
+            batch, "dispatch_launch", t0, _time.perf_counter() - t0, cpu=cpu,
             **getattr(res, "launch_labels", {}),
         )
         if not callable(res):
@@ -604,7 +636,8 @@ class DispatchQueue:
 
         def collect() -> None:
             t1 = _time.perf_counter()
-            t_ready = None
+            cpu1 = tracing.cpu_now()  # the leader's own trace, if tagged
+            t_ready = cpu_ready = None
             try:
                 try:
                     with tracing.detached(), compile_log.attribution(
@@ -628,6 +661,7 @@ class DispatchQueue:
                             with self._lock:
                                 _locks.assert_held(self._lock, "dispatch.counters")
                                 t_ready = self._move(inflight=-1)
+                            cpu_ready = tracing.cpu_since(cpu1)
                         results = res()
                 finally:
                     # before any triage below: a split-retry's re-execution
@@ -660,10 +694,14 @@ class DispatchQueue:
             except BaseException as e:
                 self._fail(batch, e, t1)
                 return
-            self._trace_batch(batch, "dispatch_ready_wait", t1, t_ready - t1)
+            cpu = tracing.cpu_since(cpu1)
+            # the whole collect was the wait: so was its CPU
+            self._trace_batch(
+                batch, "dispatch_ready_wait", t1, t_ready - t1, cpu=cpu_ready if fetched else cpu
+            )
             if fetched:
                 self._trace_batch(batch, "dispatch_fetch", t_ready, t2 - t_ready)
-            self._trace_batch(batch, "dispatch_collect", t1, _time.perf_counter() - t1)
+            self._trace_batch(batch, "dispatch_collect", t1, _time.perf_counter() - t1, cpu=cpu)
             self._distribute(batch, results)
 
         return collect
@@ -806,7 +844,22 @@ class DispatchQueue:
         query records, the benchmark's `dispatch.*` counter readers). The
         state clock's running stretch is closed up to now first, so the
         four `*_s` state sums of two snapshots differ by the wall time
-        between them."""
+        between them. Beside what the device was given stands what the
+        interpreter was given, in CPU seconds of `time.thread_time()`:
+        `launch_cpu_s` (what the leaders ran themselves in the launch phases
+        that read the clock: those of tagged requests and one in
+        CPU_SAMPLE_EVERY_S; `launch_cpu_of_s` is those phases' wall time, and
+        the rest of it their leaders slept or waited for the interpreter)
+        and, process-wide, `cpu_exec_s` (the bg:net_exec
+        workers) and `cpu_loop_s` (the bg:net_loop threads), summed from the
+        slots those threads write (telemetry.cpu_seconds). The executor
+        calls this twice a statement, so it reads no CPU clock: what a
+        worker or a loop has burned since its last reading (at most a
+        quarter second and one task or pass: telemetry.CPU_SLOT_EVERY_S) is
+        missing from the sums until its next."""
+        from surrealdb_tpu import telemetry
+
+        cpu = telemetry.cpu_seconds()
         with self._lock:
             self._move()
             return {
@@ -817,6 +870,10 @@ class DispatchQueue:
                 "splits": self.splits,
                 "failures": self.failures,
                 "launch_s": round(self.launch_s, 4),
+                "launch_cpu_s": self.launch_cpu_s,
+                "launch_cpu_of_s": self.launch_cpu_of_s,
+                "cpu_exec_s": cpu.get("exec", 0.0),
+                "cpu_loop_s": cpu.get("loop", 0.0),
                 "collect_s": round(self.collect_s, 4),
                 "pipeline_wait_s": round(self.pipeline_wait_s, 4),
                 "gather_waits": self.gather_waits,

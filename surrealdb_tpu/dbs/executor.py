@@ -283,6 +283,9 @@ class Executor:
                     pc.ddl_end(self.session.ns, self.session.db)
         dt = time.perf_counter() - t0
         cpu_s = time.thread_time() - cpu0
+        # the `statement` span ran between these two readings: in a tagged
+        # trace it takes them as its `cpu_ms`, and the clock is not read again
+        tracing.note_cpu(at, "statement", cpu_s)
         # drained ONCE per statement: the stats record and the slow-query
         # ring read the same plan-note list
         notes = telemetry.drain_plan_notes()

@@ -212,16 +212,23 @@ class _ExecPool:
 
         from surrealdb_tpu import telemetry
 
-        while True:
-            item = self._q.get()
-            if item is None:
-                return
-            fn, cvctx = item
-            try:
-                cvctx.run(fn)
-            except Exception:  # noqa: BLE001 — tasks answer their own errors
-                # through response bytes; count the escape regardless
-                telemetry.inc("net_exec_task_errors")
+        # this worker's CPU clock, left when a task ends for
+        # DispatchQueue.stats() to sum (`cpu_exec_s`)
+        cpu = telemetry.cpu_slot("exec")
+        try:
+            while True:
+                item = self._q.get()
+                if item is None:
+                    return
+                fn, cvctx = item
+                try:
+                    cvctx.run(fn)
+                except Exception:  # noqa: BLE001 — tasks answer their own errors
+                    # through response bytes; count the escape regardless
+                    telemetry.inc("net_exec_task_errors")
+                telemetry.cpu_slot_note(cpu)
+        finally:
+            telemetry.cpu_slot_end("exec", cpu)
 
     def submit(self, fn: Callable[[], None]) -> None:
         import contextvars as _cv
@@ -305,10 +312,17 @@ class _Loop:
 
     # ------------------------------------------------------------ main loop
     def run(self) -> None:
+        from surrealdb_tpu import telemetry
+
+        # this loop's CPU clock, left when a pass over the ready sockets
+        # ends for DispatchQueue.stats() to sum (`cpu_loop_s`)
+        cpu = telemetry.cpu_slot("loop")
         try:
             while not self._stop.is_set():
                 self._tick()
+                telemetry.cpu_slot_note(cpu)
         finally:
+            telemetry.cpu_slot_end("loop", cpu)
             self._close_all()
 
     def _tick(self) -> None:
@@ -962,7 +976,7 @@ def _wire_spans(tr, stamps: tuple) -> float:
     from surrealdb_tpu import tracing
 
     idle_from, t_frame, t_decoded, t_submit = stamps
-    sid, _, t_root, dur = tr.root
+    sid, _, t_root, dur, cpu_root = tr.root
     at = tracing.SpanCtx(tr, sid)
     now = time.perf_counter()
     if idle_from is not None and idle_from <= t_frame:
@@ -971,9 +985,13 @@ def _wire_spans(tr, stamps: tuple) -> float:
         ("ws_decode", t_frame, t_decoded),
         ("ws_admit_wait", t_decoded, t_submit),
         ("ws_exec_wait", t_submit, tr.t0),
-        ("ws_encode", t_root + dur, now),
     ):
         tracing.record_span_into(at, name, {}, t0, t1 - t0)
+    # the encode ran on this thread since the root closed: of the wire's
+    # spans the one with a CPU reading (the others are the loop thread's)
+    tracing.record_span_into(
+        at, "ws_encode", {}, t_root + dur, now - (t_root + dur), cpu=tracing.cpu_since(cpu_root)
+    )
     return now
 
 

@@ -176,6 +176,65 @@ def stage(name: str, start: float, seconds: float, **labels) -> None:
         bg.note_stage(name, seconds, labels)
 
 
+# ------------------------------------------------------------------ thread CPU
+# What the interpreter was given, by the role of the thread that took it
+# (`exec`: the bg:net_exec workers; `loop`: the bg:net_loop threads): each
+# thread leaves `time.thread_time()`, its own CPU clock, in its slot when a
+# task or a pass ends (cpu_slot_note). A slot has ONE writer, its thread,
+# and the store of a float into a list cell is atomic under the interpreter
+# lock, so the writers take no lock and share no `+=`; `_lock` guards only
+# the table (a thread's first and last act) and the readers' sum.
+# Process-wide, as the interpreter is; monotone, and reset() leaves it alone.
+_cpu_slots: Dict[str, Dict[int, list]] = {}  # role -> {id(slot): slot}
+_cpu_ended: Dict[str, float] = {}  # role -> the last readings of threads that ended
+# A thread reads its clock at most this often. On the host of the chip the
+# call is a system call (5.6 us back to back, far more from a cold start:
+# two a dispatch cost a one-session cell 0.2 ms a statement) where
+# `perf_counter` is 0.12 us, and the clock it reads ticks every 10 ms
+# (PERF.md section 6, PR 49): one read a task and a pass cost 3-5% of
+# `snbsf1.hop3_c8`'s rate. Nine threads at four reads a second cost nothing,
+# and a 30 s window's edge is off by a quarter second of a thread's CPU.
+CPU_SLOT_EVERY_S = 0.25
+
+
+def cpu_slot(role: str) -> list:
+    """A slot of the calling thread under `role`: [its CPU clock as last
+    read, the `perf_counter` of that read]. The thread calls cpu_slot_note()
+    whenever a piece of work ends, and cpu_slot_end() when it ends itself."""
+    slot = [0.0, float("-inf")]
+    with _lock:
+        _cpu_slots.setdefault(role, {})[id(slot)] = slot
+    return slot
+
+
+def cpu_slot_note(slot: list) -> None:
+    """A piece of the calling thread's work has ended: leave its CPU clock in
+    its slot, if the last reading is CPU_SLOT_EVERY_S old."""
+    now = time.perf_counter()
+    if now - slot[1] >= CPU_SLOT_EVERY_S:
+        slot[0], slot[1] = time.thread_time(), now
+
+
+def cpu_slot_end(role: str, slot: list) -> None:
+    """The calling thread ends: its last reading, kept in the role's sum."""
+    slot[0] = max(slot[0], time.thread_time())
+    with _lock:
+        if _cpu_slots.get(role, {}).pop(id(slot), None) is not None:
+            _cpu_ended[role] = _cpu_ended.get(role, 0.0) + slot[0]
+
+
+def cpu_seconds() -> Dict[str, float]:
+    """{role: CPU seconds its threads have burned since the process
+    started}, as they last wrote them: no clock is read here, so what a
+    thread has burned since its last reading (at most CPU_SLOT_EVERY_S and
+    one task or pass) is not in its role's sum yet."""
+    with _lock:
+        return {
+            role: _cpu_ended.get(role, 0.0) + sum(s[0] for s in slots.values())
+            for role, slots in _cpu_slots.items()
+        }
+
+
 # ------------------------------------------------------------------ collections
 def _gc_zero() -> list:
     return [0] * (len(DURATION_BUCKETS) + 1) + [0.0, 0, 0.0]

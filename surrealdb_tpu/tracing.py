@@ -18,6 +18,21 @@ Mechanics:
 - every `telemetry.span()` that runs under an active trace becomes a node
   (name, labels, start, duration, error class) instead of only feeding the
   duration histograms; with no active trace the cost is one ContextVar read;
+- a TAGGED trace (its ingress was handed the id: `Trace.explicit`) also
+  reads `time.thread_time()`, the calling thread's CPU clock, at the ends
+  of a FEW spans: the root, `ws_encode`, `statement` (the executor's two
+  readings, which were there) and the `dispatch_launch` / `dispatch_collect`
+  (with the device's wait inside it) its own thread leads. A stored span then has `cpu_ms` beside `dur_ms`,
+  and what lies between them the thread spent asleep or waiting for the
+  interpreter. `cpu_ms` is on a span only in the trace of the request whose
+  own thread ran it: of the copies a leader stamps onto every rider, the
+  leader's own carries it and the riders' do not ("I slept through
+  somebody's launch"). Where it was not measured it is absent, never 0. The
+  read is a system call, dear where the host's kernel is a sandbox's (PERF.md
+  section 6, PR 49), and a tagged request is the sample every span metric is
+  taken from: a site that wants `cpu_ms` asks for it (cpu_now(), cpu_since(),
+  record_span_into(cpu=)), and push() / pop() read no clock. No other trace
+  reads it at all;
 - the dispatch queue re-parents kernel spans onto EVERY rider of a
   coalesced batch (`record_span_into`), so a query that rode someone
   else's kernel launch still shows its own dispatch/kernel levels;
@@ -51,8 +66,10 @@ from collections import OrderedDict
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
-# (span_id, parent_id, name, labels, start_perf, dur_s, error)
-_SpanRec = Tuple[int, Optional[int], str, Dict[str, Any], float, float, Optional[str]]
+# (span_id, parent_id, name, labels, start_perf, dur_s, error, cpu_s)
+_SpanRec = Tuple[
+    int, Optional[int], str, Dict[str, Any], float, float, Optional[str], Optional[float]
+]
 
 _HEX32 = re.compile(r"^[0-9a-f]{32}$")
 _SAFE_ID = re.compile(r"[^0-9a-zA-Z._-]")
@@ -79,8 +96,9 @@ class Trace:
         self.dropped = 0
         self.meta: Dict[str, Any] = {}  # session info (ns/db/auth level)
         self.client_parent = client_parent  # inbound traceparent span id
-        # (span id, name, start, dur) of a closed root whose ingress deferred
-        # the store to finish() (the reply is still to be written)
+        # (span id, name, start, dur, the CPU clock at its close or None) of
+        # a closed root whose ingress deferred the store to finish() (the
+        # reply is still to be written)
         self.root: Optional[tuple] = None
         self.sampled: Optional[str] = None  # retention class, once stored
 
@@ -96,11 +114,12 @@ class Trace:
         start: float,
         dur: float,
         error: Optional[str],
+        cpu: Optional[float] = None,
     ) -> None:
         if len(self.spans) >= cnf.TRACE_MAX_SPANS:
             self.dropped += 1
             return
-        self.spans.append((span_id, parent_id, name, labels, start, dur, error))
+        self.spans.append((span_id, parent_id, name, labels, start, dur, error, cpu))
 
 
 class SpanCtx:
@@ -214,6 +233,38 @@ def force_keep() -> None:
         ctx.trace.force = True
 
 
+# ------------------------------------------------------------------ CPU clock
+def cpu_now() -> Optional[float]:
+    """The calling thread's CPU clock if the active trace is tagged, else
+    None: the one door through which a span site reads that clock. Take it
+    where the span starts, on the thread that runs the span, and hand
+    `cpu_since()` of it to record_span_into(cpu=) where the span ends."""
+    ctx = _current.get()
+    if ctx is None or not ctx.trace.explicit:
+        return None
+    return time.thread_time()
+
+
+def cpu_since(cpu0: Optional[float]) -> Optional[float]:
+    """CPU seconds of the calling thread since its cpu_now() reading
+    `cpu0`; None for None."""
+    return None if cpu0 is None else time.thread_time() - cpu0
+
+
+def note_cpu(ctx: Optional[SpanCtx], name: str, cpu: float) -> None:
+    """Give the newest span `name` under `ctx`, in a tagged trace, the CPU
+    seconds its caller measured round it anyway (the executor's two
+    readings a statement, which the tenant meters had): no read here."""
+    if ctx is None or not ctx.trace.explicit:
+        return
+    spans = ctx.trace.spans
+    for i in range(len(spans) - 1, -1, -1):
+        s = spans[i]
+        if s[2] == name and s[1] == ctx.span_id:
+            spans[i] = s[:7] + (cpu,)
+            return
+
+
 def push() -> Optional[tuple]:
     """Open a child span under the active trace. Returns an opaque token
     for pop(), or None when no trace is active (the no-op fast path)."""
@@ -276,15 +327,19 @@ def record_span_into(
     start: float,
     dur: float,
     error: Any = None,
+    cpu: Optional[float] = None,
 ) -> None:
     """Record a completed span into ANOTHER request's trace, parented at
     the span that was active when that request captured `ctx` (dispatch
-    fan-out: the leader stamps launch/collect onto every rider)."""
+    fan-out: the leader stamps launch/collect onto every rider), or, after
+    the fact, into the caller's own. `cpu`: the CPU seconds the thread that
+    ran the span burned in it (cpu_since() of a cpu_now() taken where the
+    span started), given only for the trace of that thread's own request."""
     if ctx is None:
         return
     tr = ctx.trace
     err = error if (error is None or isinstance(error, str)) else _error_name(error)
-    tr.add(tr.next_id(), ctx.span_id, name, labels, start, dur, err)
+    tr.add(tr.next_id(), ctx.span_id, name, labels, start, dur, err, cpu)
 
 
 # ------------------------------------------------------------- cross-node
@@ -309,7 +364,7 @@ def export_spans() -> List[dict]:
             "dur": dur,
             "error": err,
         }
-        for (sid, parent, name, labels, start, dur, err) in list(tr.spans)
+        for (sid, parent, name, labels, start, dur, err, _cpu) in list(tr.spans)
     ]
 
 
@@ -386,6 +441,7 @@ def request(
     sid = tr.next_id()
     token = _current.set(SpanCtx(tr, sid))
     t0 = time.perf_counter()
+    cpu0 = time.thread_time() if explicit else None
     err: Optional[BaseException] = None
     try:
         yield tr
@@ -394,10 +450,11 @@ def request(
         raise
     finally:
         dur = time.perf_counter() - t0
+        cpu1 = time.thread_time() if explicit else None
         _current.reset(token)
-        tr.add(sid, None, name, labels, t0, dur, _error_name(err))
+        tr.add(sid, None, name, labels, t0, dur, _error_name(err), cpu1 - cpu0 if explicit else None)
         if defer:
-            tr.root = (sid, name, t0, dur)
+            tr.root = (sid, name, t0, dur, cpu1)
         else:
             _finish(tr, name, dur)
 
@@ -413,7 +470,7 @@ def finish(tr: Trace, last: Optional[tuple] = None) -> None:
     root = tr.root
     if root is None:
         return
-    sid, name, _, dur = root
+    sid, name, _, dur, _ = root
     if last is not None:
         tr.root = None
         tr.add(tr.next_id(), sid, last[0], {}, last[1], last[2] - last[1], None)
@@ -430,7 +487,7 @@ _RANK = {"probabilistic": 0, "client": 1, "pinned": 2}
 
 def _finish(tr: Trace, name: str, dur: float) -> bool:
     """Store the trace's doc if its retention class keeps it; says whether."""
-    first_error = next((e for (_, _, _, _, _, _, e) in tr.spans if e), None)
+    first_error = next((s[6] for s in tr.spans if s[6]), None)
     if tr.force or first_error is not None or dur >= cnf.SLOW_QUERY_THRESHOLD_SECS:
         sampled = "pinned"
     elif tr.explicit:
@@ -459,8 +516,9 @@ def _finish(tr: Trace, name: str, dur: float) -> bool:
                 "start_ms": round((start - tr.t0) * 1e3, 3),
                 "dur_ms": round(d * 1e3, 3),
                 "error": e,
+                **({} if cpu is None else {"cpu_ms": round(cpu * 1e3, 3)}),
             }
-            for (sid, parent, n, labels, start, d, e) in sorted(
+            for (sid, parent, n, labels, start, d, e, cpu) in sorted(
                 tr.spans, key=lambda s: s[4]
             )
         ],
@@ -567,6 +625,7 @@ def to_chrome(doc: dict) -> dict:
                     "parent": s["parent"],
                     **s["labels"],
                     **({"error": s["error"]} if s["error"] else {}),
+                    **({"cpu_ms": s["cpu_ms"]} if "cpu_ms" in s else {}),
                 },
             }
         )

@@ -711,11 +711,14 @@ def test_the_manifest_has_the_deployment_its_cell_and_its_seven_readers(full_cfg
         "setup_s", "stmt_per_s", "p50_ms", "p95_ms", "recall_at_10"}
     (recall,) = [m for m in manifest["end_to_end"] if m["name"] == "recall_at_10"]
     assert recall["workloads"] == ["vec1m768.knn_c1", "vec500k768f.knn99p_c1", CELL]
-    # together and in order after those that were there (PR 48 appends the share of sets read from the rows)
-    assert [m["name"] for m in manifest["per_layer"]][-8:] == READERS + ["graph.reach_rows_share"]
-    for m in manifest["per_layer"][-8:-1]:
+    # together and in order after those that were there, found BY NAME: later PRs append (PR 48 the share of
+    # sets read from the rows, PR 49 the interpreter's books)
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index(READERS[0])
+    assert names[at:at + 8] == READERS + ["graph.reach_rows_share"] and at > names.index("exec.predicate_compiles")
+    for m in manifest["per_layer"][at:at + 7]:
         assert m["workloads"] == [CELL] and m["moves"] == "p50_ms"
-    assert manifest["per_layer"][-1] == {
+    assert manifest["per_layer"][at + 7] == {
         "name": "graph.reach_rows_share", "unit": "ratio", "better": "higher", "source": "program_span",
         "layer": "kernels", "moves": "p50_ms", "workloads": [CELL, "snbsf3ic1d.near20_c8"]}
     mine = {m["name"] for m in mf.metrics_of(manifest, "per_layer", CELL)}
